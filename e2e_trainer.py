@@ -21,7 +21,9 @@ import shutil
 import yaml
 
 
-def main() -> None:
+def main():
+    """Run the simulation the command line describes; returns the server
+    (run statistics, final state) for an in-process caller."""
     ap = argparse.ArgumentParser()
     ap.add_argument("-config", required=True)
     ap.add_argument("-dataPath", default=None)
@@ -86,13 +88,13 @@ def main() -> None:
         print_rank("config defaults applied: "
                    + ", ".join(f"{k}={v!r}" for k, v in sorted(defaults.items())))
 
-    # persistent XLA compilation cache (server_config.compilation_cache_dir):
-    # repeat runs of the same protocol skip the tens-of-seconds first
-    # compile — worth it on TPU, harmless elsewhere
-    cache_dir = cfg.server_config.get("compilation_cache_dir")
-    if cache_dir:
+    # persistent XLA compilation cache: repeat runs of the same protocol
+    # skip the tens-of-seconds first compile — worth it on TPU, harmless
+    # elsewhere.  server_config.compilation_cache_dir only switches it on;
+    # the directory is JAX_COMPILATION_CACHE_DIR or <checkout>/.jax_cache
+    if cfg.server_config.get("compilation_cache_dir"):
         from msrflute_tpu.utils.backend import enable_compilation_cache
-        enable_compilation_cache(cache_dir)
+        print_rank(f"compilation cache: {enable_compilation_cache()}")
 
     task = make_task(cfg.model_config)
     train_ds, val_ds, test_ds = build_task_datasets(cfg, task)
@@ -103,8 +105,11 @@ def main() -> None:
     # experiment properties at startup (reference log_run_properties,
     # e2e_trainer.py:40-74 — AzureML run properties become metrics.jsonl)
     from msrflute_tpu.utils import log_metric
+    from msrflute_tpu.utils.backend import device_report
     log_metric("run_properties", {
         "task": cfg.task,
+        # read by tools/fullrun_protocols.py, which never imports jax
+        "device": device_report(),
         "model_type": cfg.model_config.get("model_type"),
         "strategy": cfg.strategy,
         "max_iteration": cfg.server_config.get("max_iteration"),
@@ -138,6 +143,7 @@ def main() -> None:
         print_rank("exiting preempted (EX_TEMPFAIL); resume with "
                    "server_config.resume_from_checkpoint: true")
         raise SystemExit(os.EX_TEMPFAIL)
+    return server
 
 
 if __name__ == "__main__":

@@ -225,8 +225,7 @@ def build_sample_pool(dataset: BaseDataset):
     This is the TPU-native dataloader endgame: upload the pool to HBM
     ONCE, then each round ships only ``[K, S, B]`` int32 indices and the
     round program gathers on-device — no per-round host packing of
-    feature bytes, no per-round host->device feature transfer (which
-    rides a network tunnel on remote-attached chips).  Requires the
+    feature bytes, no per-round host->device feature transfer.  Requires the
     dataset to fit in host memory to build and in HBM to use; the
     federated benchmarks (SURVEY §2.8) all fit with room to spare.
     """

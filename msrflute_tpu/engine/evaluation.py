@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..utils.compat import shard_map
+from jax import shard_map
 
 from ..models.base import BaseTask
 from ..parallel.mesh import CLIENTS_AXIS

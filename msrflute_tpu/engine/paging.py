@@ -948,7 +948,7 @@ class CarryPager:
 
         if self.partition_mode == "shard_map":
             from jax.sharding import PartitionSpec as P
-            from ..utils.compat import shard_map
+            from jax import shard_map
             cspec = P(CLIENTS_AXIS)
             scatter = shard_map(
                 scatter, mesh=self._pool_spec.mesh,
@@ -969,7 +969,7 @@ class CarryPager:
 
         if self.partition_mode == "shard_map":
             from jax.sharding import PartitionSpec as P
-            from ..utils.compat import shard_map
+            from jax import shard_map
             cspec = P(CLIENTS_AXIS)
             gather = shard_map(
                 gather, mesh=self._pool_spec.mesh,

@@ -210,7 +210,7 @@ class PersonalizationServer(OptimizationServer):
         client_update = engine.client_update
         cspec = P(CLIENTS_AXIS)
         rspec = P()
-        from ..utils.compat import shard_map
+        from jax import shard_map
 
         def shard_body(global_params, local_params, alphas, arrays,
                        sample_mask, client_mask, client_ids, client_lr, rng):
@@ -329,7 +329,7 @@ class PersonalizationServer(OptimizationServer):
         ``utils/utils.py:600-605``) — users ride the clients mesh axis with
         their local params stacked, exactly like the round path."""
         task = self.task
-        from ..utils.compat import shard_map
+        from jax import shard_map
         cspec = P(CLIENTS_AXIS)
         rspec = P()
 

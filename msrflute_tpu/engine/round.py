@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from ..utils.compat import shard_map
+from jax import shard_map
 
 from ..config import FLUTEConfig
 from ..data.batching import RoundBatch

@@ -50,7 +50,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .pallas_kernels import _resolve_interpret
+from .pallas_kernels import _resolve_interpret, compiled_kernels_apply
 
 _LANES = 128
 # row statistics (lse/delta/glse) ride lane-broadcast over the trailing
@@ -290,15 +290,9 @@ def _specs(block_q, block_k, d_p):
 
 
 #: grid semantics: batch/head/outer-block axes are parallel; the inner
-#: accumulation axis must execute in order (scratch carry).  Older jax
-#: spells these as strings and the params class TPUCompilerParams.
-if hasattr(pltpu, "GridDimensionSemantics"):
-    _PARALLEL = pltpu.GridDimensionSemantics.PARALLEL
-    _ARBITRARY = pltpu.GridDimensionSemantics.ARBITRARY
-else:
-    _PARALLEL, _ARBITRARY = "parallel", "arbitrary"
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+#: accumulation axis must execute in order (scratch carry)
+_PARALLEL = pltpu.GridDimensionSemantics.PARALLEL
+_ARBITRARY = pltpu.GridDimensionSemantics.ARBITRARY
 _SEMANTICS = (_PARALLEL, _PARALLEL, _PARALLEL, _ARBITRARY)
 
 
@@ -352,7 +346,7 @@ def _fwd(q, k, v, q_offset, k_offset, causal, scale, block_q, block_k,
         ),
         out_shape=[jax.ShapeDtypeStruct(qp.shape, q.dtype),
                    jax.ShapeDtypeStruct((B, H, lq_p, _STAT_LANES), jnp.float32)],
-        compiler_params=_CompilerParams(dimension_semantics=_SEMANTICS),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=_resolve_interpret(interpret),
     )(_offs(q_offset, k_offset), qp, kp, vp)
     return _bhsd(out)[:, :Lq, :, :D], lse[:, :, :Lq, 0]
@@ -393,7 +387,7 @@ def _bwd(q, k, v, out, lse, q_offset, k_offset, g, g_lse, causal, scale,
             scratch_shapes=[pltpu.VMEM((block_q, d_p), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-        compiler_params=_CompilerParams(dimension_semantics=_SEMANTICS),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interp,
     )(offs, qp, kp, vp, gp, lse_p, delta, glse_p)
 
@@ -421,7 +415,7 @@ def _bwd(q, k, v, out, lse, q_offset, k_offset, g, g_lse, causal, scale,
         ),
         out_shape=[jax.ShapeDtypeStruct(kp.shape, k.dtype),
                    jax.ShapeDtypeStruct(vp.shape, v.dtype)],
-        compiler_params=_CompilerParams(dimension_semantics=_SEMANTICS),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
         interpret=interp,
     )(offs, qp, kp, vp, gp, lse_p, delta, glse_p)
     return (_bhsd(dq)[:, :Lq, :, :D], _bhsd(dk)[:, :Lk, :, :D],
@@ -672,9 +666,10 @@ def flash_attention_lse(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         raise ValueError(f"expected [B, L, H, D], got {q.shape}")
     if k.shape != v.shape:
         raise ValueError(f"k/v shapes differ: {k.shape} vs {v.shape}")
-    if interpret is None and jax.default_backend() != "tpu":
+    if interpret is None and not compiled_kernels_apply():
         # off-TPU default: exact dense math (see module docstring for why
-        # interpret-mode kernels are not safe under shard_map)
+        # interpret-mode kernels are not safe under shard_map); likewise
+        # on TPU where GSPMD would have to partition the kernel
         return _dense_lse(q, k, v, q_offset, k_offset, bool(causal))
     if interpret is None and not force_flash:
         # compiled TPU path: the dispatch gate

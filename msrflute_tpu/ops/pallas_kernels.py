@@ -21,8 +21,9 @@ the fused elementwise passes over flattened updates.  Three kernels:
   pallas_apply``); XLA spells the same math as a dozen sub-lane-sized
   ops per leaf, this kernel as three aligned HBM streams.
 
-All degrade gracefully: on non-TPU backends they run in Pallas interpret
-mode (tests) or fall back to jnp.
+On non-TPU backends they run under the Pallas TPU interpreter (tests);
+the callers in ``privacy`` and ``ops.quantization`` take their jnp path
+there instead.
 """
 
 from __future__ import annotations
@@ -50,26 +51,29 @@ def _pad_to_grid(flat: jnp.ndarray):
     return x.reshape(padded // _LANES, _LANES), n
 
 
-def _interpret_params():
-    """TPU-interpreter params when this jax has them (they implement the
-    pltpu PRNG primitives, unlike generic interpret mode); plain
-    ``interpret=True`` on older releases that predate InterpretParams."""
-    ip = getattr(pltpu, "InterpretParams", None)
-    return ip() if ip is not None else True
-
-
-def _interpret_default():
-    """Off-TPU, run kernels under the TPU interpreter."""
-    if jax.default_backend() == "tpu":
+def compiled_kernels_apply() -> bool:
+    """Whether a caller with a jnp alternative should take the compiled
+    kernel at this point of the trace: on a TPU backend, and only where
+    one device holds the whole operand — a single-device process, or
+    inside ``shard_map`` (manual mesh axes).  Mosaic kernels cannot be
+    partitioned by GSPMD: under a multi-device ``jit`` outside
+    ``shard_map`` (the ``(clients, model)`` tensor-sharded round, the
+    server-side tail of a sharded round) lowering refuses them, so those
+    traces keep the jnp path."""
+    if jax.default_backend() != "tpu":
         return False
-    return _interpret_params()
+    return jax.device_count() == 1 or \
+        bool(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 def _resolve_interpret(interpret):
+    """``None`` -> compiled on TPU, the TPU interpreter elsewhere;
+    ``True`` -> the TPU interpreter (it implements the pltpu PRNG
+    primitives, unlike generic interpret mode)."""
     if interpret is None:
-        return _interpret_default()
+        interpret = jax.default_backend() != "tpu"
     if interpret is True:
-        return _interpret_params()
+        return pltpu.InterpretParams()
     return interpret
 
 
@@ -88,7 +92,7 @@ def bits_to_normal(b1: jnp.ndarray, b2: jnp.ndarray) -> jnp.ndarray:
     The float conversion routes through int32: after ``>> 8`` the value
     fits in 24 bits so the reinterpretation is exact, and mosaic lowers
     uint32->int32->f32 while rejecting the direct uint32->f32 cast
-    (observed on silicon, ``tpu_pallas_tests.log`` round 4).
+    (observed on silicon).
     """
     u1 = (b1 >> 8).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 24)) + 1e-12
     u2 = (b2 >> 8).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 24))
@@ -111,16 +115,6 @@ def fused_gaussian_noise(flat: jnp.ndarray, scale: jnp.ndarray,
                          interpret: Optional[bool] = None) -> jnp.ndarray:
     """``flat * scale + sigma * N(0,1)`` with on-core noise generation."""
     interpret = _resolve_interpret(interpret)
-    if interpret is True:
-        # old-jax off-TPU path: generic interpret mode cannot lower the
-        # pltpu PRNG primitives, so run the SAME Box-Muller math on
-        # jax.random bits (different stream than the on-core PRNG, same
-        # distribution — the DP-critical transform is shared)
-        k1, k2 = jax.random.split(jax.random.PRNGKey(jnp.asarray(seed)))
-        b1 = jax.random.bits(k1, flat.shape, jnp.uint32)
-        b2 = jax.random.bits(k2, flat.shape, jnp.uint32)
-        x = flat.astype(jnp.float32)
-        return (x * scale + sigma * bits_to_normal(b1, b2)).astype(flat.dtype)
     x2d, n = _pad_to_grid(flat.astype(jnp.float32))
     rows = x2d.shape[0]
     grid = rows // _BLOCK_ROWS

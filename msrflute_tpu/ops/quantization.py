@@ -50,6 +50,19 @@ def approx_quantile_abs(x: jnp.ndarray, q, n_bins: int = 2048) -> jnp.ndarray:
     return (bin_i + jnp.clip(frac, 0.0, 1.0)) * hi / n_bins
 
 
+def bin_sparsify(g: jnp.ndarray, lo, hi, thresh, n_bins: int) -> jnp.ndarray:
+    """The elementwise core in plain jnp: nearest of ``n_bins`` labels on
+    ``linspace(lo, hi)`` (== the reference's half-bin-shifted bucketize),
+    zero where ``|g| <= thresh``.  The non-TPU path of
+    :func:`quantize_array` and the reference the Pallas kernel
+    (:func:`~msrflute_tpu.ops.pallas_kernels.quant_bin_sparsify`) is
+    checked against."""
+    width = (hi - lo) / jnp.maximum(n_bins - 1, 1)
+    idx = jnp.clip(jnp.round((g - lo) / jnp.maximum(width, 1e-30)),
+                   0, n_bins - 1)
+    return jnp.where(jnp.abs(g) > thresh, lo + idx * width, 0.0)
+
+
 def quantize_array(grad: jnp.ndarray, n_bins: int,
                    quant_threshold: float,
                    min_grad: Optional[jnp.ndarray] = None,
@@ -59,21 +72,18 @@ def quantize_array(grad: jnp.ndarray, n_bins: int,
     components (reference ``quant_bins`` + thresholding).
 
     Stats (min/max/quantile) run in XLA; on TPU the elementwise
-    bin+sparsify pass runs as the fused Pallas kernel."""
+    bin+sparsify pass runs as the fused Pallas kernel where a compiled
+    kernel can apply (``pallas_kernels.compiled_kernels_apply``)."""
     g = grad.astype(jnp.float32)
     lo = jnp.min(g) if min_grad is None else min_grad
     hi = jnp.max(g) if max_grad is None else max_grad
     thresh = (approx_quantile_abs(g, quant_threshold) if approx
               else jnp.quantile(jnp.abs(g), quant_threshold))
-    if jax.default_backend() == "tpu":
-        from .pallas_kernels import quant_bin_sparsify
+    from .pallas_kernels import compiled_kernels_apply, quant_bin_sparsify
+    if compiled_kernels_apply():
         out = quant_bin_sparsify(g.reshape(-1), lo, hi, thresh, n_bins)
         return out.reshape(grad.shape).astype(grad.dtype)
-    width = (hi - lo) / jnp.maximum(n_bins - 1, 1)
-    # nearest-label rounding (== reference's half-bin-shifted bucketize)
-    idx = jnp.clip(jnp.round((g - lo) / jnp.maximum(width, 1e-30)), 0, n_bins - 1)
-    binned = lo + idx * width
-    return jnp.where(jnp.abs(g) > thresh, binned, 0.0).astype(grad.dtype)
+    return bin_sparsify(g, lo, hi, thresh, n_bins).astype(grad.dtype)
 
 
 def quantize_pytree(tree: Any, quant_threshold: Optional[float],
@@ -95,7 +105,4 @@ def quantize_pytree(tree: Any, quant_threshold: Optional[float],
     lo, hi = jnp.min(flat), jnp.max(flat)
     thresh = (approx_quantile_abs(flat, quant_threshold) if approx
               else jnp.quantile(jnp.abs(flat), quant_threshold))
-    width = (hi - lo) / jnp.maximum(n_bins - 1, 1)
-    idx = jnp.clip(jnp.round((flat - lo) / jnp.maximum(width, 1e-30)), 0, n_bins - 1)
-    binned = lo + idx * width
-    return unravel(jnp.where(jnp.abs(flat) > thresh, binned, 0.0))
+    return unravel(bin_sparsify(flat, lo, hi, thresh, n_bins))

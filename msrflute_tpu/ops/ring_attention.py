@@ -22,7 +22,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 SEQUENCE_AXIS = "sequence"

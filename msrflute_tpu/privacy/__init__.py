@@ -182,14 +182,17 @@ def apply_global_dp(agg_grad: Any, dp_config, rng: jax.Array,
     per-element std ``global_sigma * max_grad / num_clients``.
 
     On TPU this runs the fused Pallas kernel (noise generated on-core,
-    never materialized in HBM); elsewhere the jnp path.
+    never materialized in HBM) where a compiled kernel can apply
+    (``ops.pallas_kernels.compiled_kernels_apply``); elsewhere the jnp
+    path.
     """
     flat, unravel = ravel_pytree(agg_grad)
     sigma = float(dp_config.get("global_sigma", 0.0))
     max_grad = float(dp_config.get("max_grad", 1.0))
     noise_scale = sigma * max_grad / jnp.maximum(num_clients, 1.0)
-    if jax.default_backend() == "tpu":
-        from ..ops.pallas_kernels import fused_gaussian_noise
+    from ..ops.pallas_kernels import (compiled_kernels_apply,
+                                      fused_gaussian_noise)
+    if compiled_kernels_apply():
         seed = jax.random.randint(rng, (), 0, 2**31 - 1)
         noisy = fused_gaussian_noise(flat, jnp.asarray(1.0, flat.dtype),
                                      noise_scale, seed)

@@ -9,7 +9,7 @@ with a measured-zero-overhead fast path — see docs/observability.md):
   ride the EXISTING flatpack packed-stats single transfer (zero new
   ``device_get``s);
 - :mod:`.profiling` — opt-in ``jax.profiler`` capture for a configured
-  round window, compat-guarded for old jax;
+  round window;
 - :mod:`.watchdog` — NaN-loss / round-time-regression /
   checkpoint-failure-streak detectors with log/mark/abort actions, plus
   the longitudinal tier (stall / rss_leak / throughput_drift);
@@ -22,8 +22,8 @@ event records, re-exported by ``utils.logging``) and :mod:`.timing` (the
 bench/tools stopwatch primitives).
 
 This package imports no jax at import time (``bench.py`` must pick a
-backend before jax loads); :mod:`.profiling` touches jax only through
-``utils.compat`` when a capture actually starts.
+backend before jax loads); :mod:`.profiling` imports jax only when a
+capture actually starts.
 """
 
 from __future__ import annotations
@@ -123,8 +123,8 @@ class Telemetry:
         metrics.set_max_log_mb(max_log_mb)
         if self.tracer is not None and max_log_mb > 0:
             self.tracer.max_log_bytes = int(max_log_mb * 2 ** 20)
-        # lazy import: profiling reaches for jax (via utils.compat) only
-        # when a capture window is configured and actually starts
+        # lazy import: profiling reaches for jax only when a capture
+        # window is configured and actually starts
         from .profiling import RoundProfiler
         self.profiler = RoundProfiler(self.raw.get("profile_rounds"),
                                       self.out_dir)

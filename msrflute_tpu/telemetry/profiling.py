@@ -8,9 +8,9 @@ round range reaches ``lo`` and stops at the first boundary at or past
 ``hi``, so a fused chunk spanning the window edge profiles whole (the
 profiler cannot cut a compiled program in half).
 
-Degrades gracefully on old jax (the container's 0.4.37) through the
-:mod:`msrflute_tpu.utils.compat` wrappers: a failed start/stop logs one
-warning and disables further attempts instead of killing the run.
+A capture that cannot start (``jax.profiler`` allows one trace per
+process; the server's flag-gated ``profile_dir`` trace may hold it) logs
+one warning and disables the window instead of killing the run.
 """
 
 from __future__ import annotations
@@ -87,24 +87,29 @@ class RoundProfiler:
 
     # ------------------------------------------------------------------
     def _start(self) -> None:
-        from ..utils.compat import profiler_start_trace
-        if profiler_start_trace(self.out_dir):
-            self.active = True
-            _LOGGER.info("flutescope: jax.profiler capture started -> %s",
-                         self.out_dir)
-        else:
+        import jax
+        try:
+            jax.profiler.start_trace(self.out_dir)
+        except RuntimeError as exc:
             self.failed = True
             _LOGGER.warning(
-                "flutescope: jax.profiler trace unavailable on this jax "
-                "version/backend; telemetry.profile_rounds disabled for "
-                "this run")
+                "flutescope: jax.profiler capture could not start (%s); "
+                "telemetry.profile_rounds disabled for this run", exc)
+            return
+        self.active = True
+        _LOGGER.info("flutescope: jax.profiler capture started -> %s",
+                     self.out_dir)
 
     def _stop(self) -> None:
-        from ..utils.compat import profiler_stop_trace
+        import jax
         self.active = False
-        if profiler_stop_trace():
-            self.captured = True
-            _LOGGER.info("flutescope: jax.profiler capture written to %s",
-                         self.out_dir)
-        else:
+        try:
+            jax.profiler.stop_trace()
+        except RuntimeError as exc:
             self.failed = True
+            _LOGGER.warning(
+                "flutescope: jax.profiler capture could not stop (%s)", exc)
+            return
+        self.captured = True
+        _LOGGER.info("flutescope: jax.profiler capture written to %s",
+                     self.out_dir)

@@ -132,19 +132,15 @@ def signature_diff(old: Dict[str, List[Any]],
 
 
 # ----------------------------------------------------------------------
-# executable analyses (None-safe across jax versions/backends)
+# executable analyses (None-safe across backends)
 # ----------------------------------------------------------------------
 def cost_analysis(compiled: Any) -> Dict[str, float]:
     """``{"flops", "bytes_accessed"}`` of a compiled executable, or ``{}``
-    when the backend/jax version cannot provide it (multihost partial
-    executables, very old runtimes).  The normalization — 0.4.x returns
-    a one-dict-per-device list — lives HERE so bench/profiler/telemetry
-    can never disagree about it."""
+    when the backend cannot provide it (multihost partial executables).
+    The key normalization lives HERE so bench/profiler/telemetry can
+    never disagree about it."""
     try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else {}
-        cost = dict(cost)
+        cost = dict(compiled.cost_analysis() or {})
     except Exception:
         return {}
     out = {}

@@ -1,34 +1,15 @@
-"""jax version compatibility shims.
+"""Chip peak tables: the MFU and roofline denominators.
 
-Leaf module (imports only jax): the package targets the current jax API
-surface, but the container's baked-in toolchain may lag — ``jax.shard_map``
-was promoted out of ``jax.experimental.shard_map`` (and its replication
-check renamed ``check_rep`` -> ``check_vma``) after 0.4.x.  Every internal
-module imports :func:`shard_map` from here so the call sites can stay
-written against the modern signature.
+Leaf module (imports only jax, lazily): vendor-published per-chip peak
+FLOP/s and HBM bandwidth keyed by ``device.device_kind``.  A device that
+is neither a CPU nor in the table is an ERROR — a chip the table does
+not know can never be priced against somebody else's peak.  The CPU
+figures are documented NOMINAL round numbers that keep the scorecard's
+MFU column computable in the CPU test environment (ROADMAP Queue 3
+item 6 removes them); they are never comparable to a chip's.
 """
 
 from __future__ import annotations
-
-try:  # modern jax: top-level export, check_vma kwarg
-    from jax import shard_map as _shard_map
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax <= 0.4.x: experimental home, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f, *, mesh=None, in_specs=None, out_specs=None,
-              check_vma=None, **kwargs):
-    """``jax.shard_map`` with the modern signature on every jax we run on
-    (``check_vma`` maps to ``check_rep`` on older releases)."""
-    if check_vma is not None:
-        kwargs[_CHECK_KW] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kwargs)
-
 
 # ----------------------------------------------------------------------
 # chip peak-FLOPs table (flutescope device-truth: the MFU denominator)
@@ -52,31 +33,38 @@ TPU_PEAK_FLOPS = {
 #: columns were published against this) — now sourced from the one table
 V5E_BF16_PEAK_FLOPS = TPU_PEAK_FLOPS["v5e"]
 
-#: documented NOMINAL peak for CPU (and unknown device kinds): a fixed
-#: round number so CPU MFU values exist, are deterministic, and compare
-#: across CPU runs — never against a real chip's.  ~a few-core host's
-#: practical f32 throughput order of magnitude.
+#: documented NOMINAL peak for CPU only: a fixed round number so CPU MFU
+#: values exist, are deterministic, and compare across CPU runs — never
+#: against a real chip's.  ~a few-core host's practical f32 throughput
+#: order of magnitude.
 CPU_NOMINAL_PEAK_FLOPS = 1e11
+
+
+def _lookup(device, table, cpu_nominal):
+    """``(kind, value)`` from ``table`` by longest substring match on the
+    lowercased ``device_kind``; the CPU platform gets ``cpu_nominal``;
+    anything else raises."""
+    if device is None:
+        import jax
+        device = jax.devices()[0]
+    kind = str(getattr(device, "device_kind", "") or "").lower()
+    matches = [key for key in table if key in kind]
+    if matches:
+        return kind, table[max(matches, key=len)]
+    if getattr(device, "platform", None) == "cpu" or kind == "cpu":
+        return kind, cpu_nominal
+    raise ValueError(
+        f"device kind {kind!r} is not in the chip peak table "
+        "(utils/compat.py): add its published peak with a source "
+        "instead of pricing it against another device's")
 
 
 def chip_peak_flops(device=None):
     """``(kind, peak_flops)`` for ``device`` (default: this process's
-    first jax device).  TPU kinds resolve through :data:`TPU_PEAK_FLOPS`;
-    CPU and unrecognized kinds fall back to
-    :data:`CPU_NOMINAL_PEAK_FLOPS` so MFU stays computable everywhere
-    (flutescope's CPU-fallback contract — the scorecard records the kind
-    next to the number so a reader can tell which regime it is)."""
-    if device is None:
-        import jax
-        device = jax.devices()[0]
-    kind = str(getattr(device, "device_kind", "cpu") or "cpu").lower()
-    best = None
-    for key, peak in TPU_PEAK_FLOPS.items():
-        if key in kind and (best is None or len(key) > len(best[0])):
-            best = (key, peak)
-    if best is not None:
-        return kind, best[1]
-    return kind, CPU_NOMINAL_PEAK_FLOPS
+    first jax device).  TPU kinds resolve through :data:`TPU_PEAK_FLOPS`,
+    the CPU platform gets :data:`CPU_NOMINAL_PEAK_FLOPS` (the scorecard
+    records the kind next to the number), and an unknown kind raises."""
+    return _lookup(device, TPU_PEAK_FLOPS, CPU_NOMINAL_PEAK_FLOPS)
 
 
 #: HBM bandwidth (bytes/s) per TPU chip generation (vendor-published),
@@ -94,47 +82,13 @@ TPU_HBM_BYTES_PER_SEC = {
     "v6 lite": 1640e9,
 }
 
-#: documented NOMINAL bandwidth for CPU / unknown kinds — the same
-#: fixed-round-number contract as :data:`CPU_NOMINAL_PEAK_FLOPS`
+#: documented NOMINAL bandwidth for CPU — the same fixed-round-number
+#: contract as :data:`CPU_NOMINAL_PEAK_FLOPS`
 CPU_NOMINAL_HBM_BYTES_PER_SEC = 5e10
 
 
 def chip_hbm_bytes_per_sec(device=None):
-    """``(kind, bytes_per_sec)`` for ``device`` (default: this process's
-    first jax device) — the memory-side twin of :func:`chip_peak_flops`,
-    with the identical longest-substring matching and CPU fallback."""
-    if device is None:
-        import jax
-        device = jax.devices()[0]
-    kind = str(getattr(device, "device_kind", "cpu") or "cpu").lower()
-    best = None
-    for key, bw in TPU_HBM_BYTES_PER_SEC.items():
-        if key in kind and (best is None or len(key) > len(best[0])):
-            best = (key, bw)
-    if best is not None:
-        return kind, best[1]
-    return kind, CPU_NOMINAL_HBM_BYTES_PER_SEC
-
-
-def profiler_start_trace(log_dir: str) -> bool:
-    """Start a ``jax.profiler`` trace, tolerating old-jax/backend quirks
-    (0.4.x raises from a second start or on backends without profiler
-    support).  Returns success — telemetry's profiling window degrades
-    to a logged warning instead of killing a run."""
-    try:
-        import jax
-        jax.profiler.start_trace(log_dir)
-        return True
-    except Exception:
-        return False
-
-
-def profiler_stop_trace() -> bool:
-    """Stop the active ``jax.profiler`` trace; False when no trace was
-    running or the profiler is unavailable on this jax."""
-    try:
-        import jax
-        jax.profiler.stop_trace()
-        return True
-    except Exception:
-        return False
+    """``(kind, bytes_per_sec)`` for ``device`` — the memory-side twin of
+    :func:`chip_peak_flops`, with the identical matching and errors."""
+    return _lookup(device, TPU_HBM_BYTES_PER_SEC,
+                   CPU_NOMINAL_HBM_BYTES_PER_SEC)

@@ -65,7 +65,7 @@ def test_to_dict_roundtrip():
 
 
 def test_schema_rejects_unknown_key_with_suggestion():
-    # VERDICT round 2: a typo'd ``initial_lr_clients`` must fail loudly
+    # review round 2: a typo'd ``initial_lr_clients`` must fail loudly
     # instead of silently falling back to the 0.01 default
     bad = {**MINI, "server_config": {**MINI["server_config"],
                                      "initial_lr_clients": 0.5}}

@@ -212,7 +212,7 @@ def test_ef_device_table_unit_semantics(tmp_path):
 
 
 def test_ef_device_table_k512_round(tmp_path):
-    """VERDICT r4 #7: the device-resident EF path at K=512 on the
+    """review round 4 #7: the device-resident EF path at K=512 on the
     virtual 8-device mesh — one full engine round, residuals land for
     every participating client, RAM never holds a [K, n_params] host
     matrix on the round path."""

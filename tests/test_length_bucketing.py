@@ -1,4 +1,4 @@
-"""Length bucketing (VERDICT r2 item 5): variable-length token tasks stop
+"""Length bucketing (review round 2 item 5): variable-length token tasks stop
 paying max-L padding FLOPs — cropping all-pad tail columns is math-identical
 because SeqLMTask's position masks derive from the ids, not from L.
 

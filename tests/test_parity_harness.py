@@ -1,5 +1,5 @@
 """Cross-framework parity: the ACTUAL reference (torch, /root/reference)
-vs msrflute_tpu on identical blobs + identical init (VERDICT r2 item 3).
+vs msrflute_tpu on identical blobs + identical init (review round 2 item 3).
 
 The full 20-round artifact is PARITY.json (tools/parity/run_parity.py);
 this test runs the deterministic LR protocol for 3 rounds so the claim
@@ -188,7 +188,7 @@ def test_bert_checkpoint_forward_exact(tmp_path):
     (the reference via its model_name_or_path pretrained path,
     ``/root/reference/experiments/mlm_bert/model.py:119-123``; ours via the
     same config key with HF's torch->flax conversion) and must produce the
-    same masked-LM loss on the same pre-masked batch (VERDICT r3 item 4).
+    same masked-LM loss on the same pre-masked batch (review round 3 item 4).
     Runs without the reference mount: the torch side is the same HF
     ``BertForMaskedLM`` the reference wraps."""
     import numpy as np
@@ -236,7 +236,7 @@ def test_bert_checkpoint_forward_exact(tmp_path):
 @pytest.mark.skipif(not os.path.isdir("/root/reference"),
                     reason="reference mount absent")
 def test_resnet_gn_transplant_forward_exact():
-    """GN-configured ResNet cross-check (VERDICT r3 item 6): build the
+    """GN-configured ResNet cross-check (review round 3 item 6): build the
     REFERENCE ResNet with group_norm actually honored
     (``ResNet(BasicBlock, [2,2,2,2], num_classes, group_norm=32)`` —
     the experiment wrapper ignores its config and calls bare
@@ -346,7 +346,7 @@ def test_dga_extension_mode_trajectory_exact(tmp_path):
 @pytest.mark.skipif(not os.path.isdir("/root/reference"),
                     reason="reference mount not available")
 def test_fedlabels_vat_label_selection_matches_reference():
-    """Semisupervision cross-check, selection half (VERDICT r3 missing
+    """Semisupervision cross-check, selection half (review round 3 missing
     item: FedLabels never compared against the real reference).  The
     pseudo-label selector is the reference's ``get_label_VAT``
     (``utils/utils.py:620-680``, comp='var'): per-sample variance
@@ -513,7 +513,7 @@ def test_fedlabels_combine_matches_reference():
 @pytest.mark.skipif(not os.path.isdir("/root/reference"),
                     reason="reference mount not available")
 def test_ecg_transplant_forward_exact():
-    """ECG family cross-check (VERDICT r3 missing item 2): compose the
+    """ECG family cross-check (review round 3 missing item 2): compose the
     REFERENCE's own building blocks (``experiments/ecg_cnn/model.py`` —
     ConvNormPool x2, LSTM-over-channels, [h;c] attention mix, adaptive
     max-pool, fc) with ``norm_type='group'`` actually honored (the
@@ -612,7 +612,7 @@ def test_ecg_transplant_forward_exact():
 @pytest.mark.skipif(not os.path.isdir("/root/reference"),
                     reason="reference mount not available")
 def test_fednewsrec_transplant_forward_exact():
-    """FedNewsRec family cross-check (VERDICT r3 missing item 2, the
+    """FedNewsRec family cross-check (review round 3 missing item 2, the
     last family with zero cross-framework evidence): instantiate the
     REFERENCE's actual ``FedNewsRec`` torch net
     (``experiments/fednewsrec/fednewsrec_model.py:316-360``) with a

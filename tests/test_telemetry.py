@@ -217,12 +217,17 @@ def test_parse_profile_rounds_forms():
 
 
 def test_round_profiler_degrades_gracefully(monkeypatch, tmp_path):
-    """A jax whose profiler refuses to start must disable the window,
-    not kill the run (the 0.4.37 degradation contract)."""
-    from msrflute_tpu.telemetry.profiling import RoundProfiler
-    from msrflute_tpu.utils import compat
+    """A profiler that refuses to start (one trace per process, and the
+    flag-gated ``profile_dir`` trace may hold it) must disable the
+    window, not kill the run."""
+    import jax
 
-    monkeypatch.setattr(compat, "profiler_start_trace", lambda d: False)
+    from msrflute_tpu.telemetry.profiling import RoundProfiler
+
+    def busy(log_dir):
+        raise RuntimeError("Profile has already been started.")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", busy)
     prof = RoundProfiler("1:3", str(tmp_path))
     prof.observe(0)
     assert not prof.active
@@ -237,14 +242,15 @@ def test_round_profiler_window_inside_fused_chunk_still_fires(
     """profile_rounds: 5 with fused chunks of 4 (boundaries 0,4,8,...):
     the chunk [4,8) INTERSECTS the window, so the capture must start at
     boundary 4 and stop at 8 — not silently never fire."""
+    import jax
+
     from msrflute_tpu.telemetry.profiling import RoundProfiler
-    from msrflute_tpu.utils import compat
 
     calls = []
-    monkeypatch.setattr(compat, "profiler_start_trace",
-                        lambda d: calls.append("start") or True)
-    monkeypatch.setattr(compat, "profiler_stop_trace",
-                        lambda: calls.append("stop") or True)
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d: calls.append("start"))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append("stop"))
     prof = RoundProfiler(5, str(tmp_path))
     for r0 in range(0, 16, 4):
         prof.observe(r0, rounds=4)
@@ -253,14 +259,15 @@ def test_round_profiler_window_inside_fused_chunk_still_fires(
 
 
 def test_round_profiler_window_drives_start_stop(monkeypatch, tmp_path):
+    import jax
+
     from msrflute_tpu.telemetry.profiling import RoundProfiler
-    from msrflute_tpu.utils import compat
 
     calls = []
-    monkeypatch.setattr(compat, "profiler_start_trace",
-                        lambda d: calls.append(("start", d)) or True)
-    monkeypatch.setattr(compat, "profiler_stop_trace",
-                        lambda: calls.append(("stop",)) or True)
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d: calls.append(("start", d)))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: calls.append(("stop",)))
     prof = RoundProfiler("2:4", str(tmp_path))
     for r in range(6):
         prof.observe(r)

@@ -1,0 +1,143 @@
+"""Ahead-of-time compiles for the chip, made without the chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described (``v5e:2x2``) and not attached, so what Mosaic refuses — a slice
+not aligned to the tiling, too much VMEM, a kernel that cannot be batched —
+fails in tier-1 instead of in a chip call.  Nothing runs: these say nothing
+about results or times.  Every kernel is called with ``interpret=False``;
+the default asks ``jax.default_backend()`` and would take the interpreter.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+import pytest  # noqa: E402
+from jax.sharding import (Mesh, NamedSharding,  # noqa: E402
+                          PartitionSpec as P, SingleDeviceSharding)
+
+from msrflute_tpu.ops import pallas_attention as pa  # noqa: E402
+from msrflute_tpu.ops.pallas_kernels import (fused_gaussian_noise,  # noqa: E402
+                                             fused_sgd_apply,
+                                             quant_bin_sparsify)
+
+#: a little over one CNN_FEMNIST dense layer (9216 x 128), and not a
+#: multiple of the kernels' 256 x 128 block
+N = 1_200_003
+CLIENTS = 10
+
+
+@pytest.fixture(scope="module")
+def topology():
+    """The described v5e:2x2 devices; skipped where the topology cannot be
+    described.  The persistent compile cache is off around these
+    compiles: such an entry is written but cannot be read back without a
+    chip, and the next compile would warn."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as exc:  # no libtpu / no such topology here
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {exc!r}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def chip(topology):
+    return SingleDeviceSharding(topology[0])
+
+
+def _noise(x):
+    return fused_gaussian_noise(x, jnp.float32(1.0), jnp.float32(0.5),
+                                jnp.int32(7), interpret=False)
+
+
+def _quant(x):
+    return quant_bin_sparsify(x, jnp.min(x), jnp.max(x),
+                              jnp.float32(0.1), 256, interpret=False)
+
+
+def _sgd(x):
+    return fused_sgd_apply(x, x * 0.5, x * 0.25, jnp.float32(0.1),
+                           jnp.float32(0.9), jnp.float32(1.0),
+                           interpret=False)
+
+
+@pytest.mark.parametrize("batched", [False, True],
+                         ids=["plain", "vmap_clients"])
+@pytest.mark.parametrize("kernel", [_noise, _quant, _sgd],
+                         ids=["gaussian_noise", "quant_bin_sparsify",
+                              "sgd_apply"])
+def test_elementwise_kernel_compiles_for_v5e(chip, kernel, batched):
+    """Plain, and under ``vmap`` over the round's clients — the form the
+    quantization kernel takes inside the round program."""
+    shape = (CLIENTS, N) if batched else (N,)
+    fn = jax.vmap(kernel) if batched else kernel
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+    compiled = jax.jit(fn).lower(x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+FLASH_SHAPES = {
+    # the long-context cell's geometry: block-aligned, bf16
+    "aligned_bf16": ((2, 2048, 4, 64), jnp.bfloat16),
+    # L and D both need padding, f32
+    "unaligned_f32": ((1, 1000, 3, 24), jnp.float32),
+}
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["full", "causal"])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("shape_id", list(FLASH_SHAPES))
+def test_flash_attention_compiles_for_v5e(chip, shape_id, direction, causal):
+    shape, dtype = FLASH_SHAPES[shape_id]
+    spec = jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    def forward(q, k, v):
+        return pa.flash_attention(q, k, v, causal=causal, force_flash=True,
+                                  interpret=False)
+
+    def backward(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(forward(*a).astype(jnp.float32)),
+                        argnums=(0, 1, 2))(q, k, v)
+
+    fn = forward if direction == "forward" else backward
+    compiled = jax.jit(fn).lower(spec, spec, spec).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("inside_shard_map", [True, False],
+                         ids=["shard_map", "gspmd"])
+def test_kernel_branch_follows_partitioning(topology, monkeypatch,
+                                            inside_shard_map):
+    """Mosaic kernels cannot be partitioned by GSPMD.  On a four-chip mesh
+    the global-DP noise branch takes the compiled kernel inside ``shard_map``
+    and the jnp path under a plain multi-device ``jit`` — where lowering
+    the kernel would raise (the shipped ``mlm_bert`` config's
+    ``model_axis_size: 4`` round).  ``default_backend`` is steered here,
+    in the test: it still sees the CPU."""
+    from msrflute_tpu.privacy import apply_global_dp
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topology).reshape(4, 1), ("clients", "model"))
+    spec = P("clients")
+
+    def noised(g):
+        return apply_global_dp({"w": g}, {"global_sigma": 0.1},
+                               jax.random.PRNGKey(0), jnp.float32(10.0))["w"]
+
+    fn = (jax.shard_map(noised, mesh=mesh, in_specs=spec, out_specs=spec,
+                        check_vma=False)
+          if inside_shard_map else noised)
+    x = jax.ShapeDtypeStruct((8, 4096), jnp.float32,
+                             sharding=NamedSharding(mesh, spec))
+    compiled = jax.jit(fn).lower(x).compile()
+    assert ("tpu_custom_call" in compiled.as_text()) == inside_shard_map

@@ -1,6 +1,6 @@
-"""Turn a committed flash_crossover.json sweep into concrete settings.
+"""Turn a flash_crossover.json sweep into concrete settings.
 
-``tools/flash_crossover_sweep.py`` (queue job 92) measures fwd+bwd wall
+``tools/flash_crossover_sweep.py`` measures fwd+bwd wall
 time of dense vs flash per length x kernel-tile choice.  This tool reads
 that artifact and prints, per length: the best tile, the flash/dense
 speedup, and the recommended settings —

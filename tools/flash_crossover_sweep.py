@@ -36,10 +36,7 @@ def main() -> int:
     assert jax.default_backend() == "tpu", jax.default_backend()
     from msrflute_tpu.ops.pallas_attention import flash_attention
     from msrflute_tpu.utils.backend import enable_compilation_cache
-    import os
-    enable_compilation_cache(os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        ".jax_cache"))
+    enable_compilation_cache()
 
     B, H, D = 4, 4, 64  # the longctx bench's RingLM head geometry
     rng = np.random.default_rng(0)

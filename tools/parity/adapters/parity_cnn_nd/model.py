@@ -1,4 +1,4 @@
-"""Dropout-free variant of the CNN parity adapter (VERDICT r3 item 3).
+"""Dropout-free variant of the CNN parity adapter (review round 3 item 3).
 
 Subclasses the reference's own CNN task class
 (``experiments/cv_cnn_femnist/model.py:82``, net = FedML ``CNN_DropOut``)

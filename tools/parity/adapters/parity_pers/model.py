@@ -1,4 +1,4 @@
-"""Personalization parity adapter (VERDICT r3 item 5).
+"""Personalization parity adapter (review round 3 item 5).
 
 Subclasses the reference's own LR task class
 (``experiments/cv_lr_mnist/model.py:23``) with two additions the

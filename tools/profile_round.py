@@ -108,21 +108,16 @@ def main(argv=None) -> None:
         out["device_resident_pool"] = pool_mode
 
         # ---- optional trace chunk: profiler instrumentation inflates
-        # wall time, so it is NOT counted into the steady-state stats.
-        # Capture goes through the compat wrappers (telemetry's
-        # profile_rounds path) so old jax degrades to a note, not a
-        # crash ----
+        # wall time, so it is NOT counted into the steady-state stats ----
         if args.trace:
-            from msrflute_tpu.utils.compat import (profiler_start_trace,
-                                                   profiler_stop_trace)
-            if profiler_start_trace(args.trace):
+            jax.profiler.start_trace(args.trace)
+            try:
                 server.config.server_config.max_iteration += fuse
                 server.train()
                 jax.block_until_ready(server.state.params)
-                profiler_stop_trace()
-                out["trace_dir"] = args.trace
-            else:
-                out["trace_error"] = "jax.profiler unavailable"
+            finally:
+                jax.profiler.stop_trace()
+            out["trace_dir"] = args.trace
 
         # ---- timed chunks (the steady state) ----
         per_round = []
