@@ -1,0 +1,637 @@
+"""The benchmark harness: one cell, once, through the real CLI.
+
+Everything that belongs to one cell, configuration, traffic mix, layer
+metric or model reference is a FILE found by name under ``root``
+(``workloads/``, ``configs/``, ``traffic/``, ``layer_metrics/``) or
+``reference/``; no such name is written here or in ``run.py``.
+
+A run, in order (all in one process, which holds the chips):
+
+1. data from ``--seed`` (``datagen.py``), weights from ``--seed`` (the
+   model reference's ``init``), the shipped experiment yaml with the
+   configuration's and the traffic mix's overlays;
+2. ``e2e_trainer.main()`` in-process with ``sys.argv`` set, observed from
+   outside by wrapping ``OptimizationServer.train`` (hand the program the
+   seeded weights), ``RoundEngine.dispatch_rounds`` and
+   ``PackedStats.fetch`` (the fence) — same arguments, same results;
+3. before the first timed dispatch, the CHECK program: the engine's own
+   one-round dispatch of round 0's cohort traced under
+   ``jax.default_matmul_precision("highest")``, on a copy of the state;
+4. one evaluation period (the traffic mix's ``period_rounds``) of warm-up
+   (every program of a period has then run once), the window of whole
+   evaluation periods, then a graceful preemption
+   (``server.preemption.request``), the drain and ``SystemExit(75)``;
+5. after the trainer has returned: the plain reference
+   (``reference/fedround.py``), round 0 at ``highest`` and every round of
+   the first timed dispatch at the default precision, and the comparison
+   (``check.py``).
+
+``setup_s`` is process start to window open and so holds 1-4's set-up
+and the check program, not the reference (5), which no user pays.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import copy
+import glob
+import importlib.util
+import json
+import math
+import os
+import shutil
+import sys
+import tempfile
+import time
+
+import numpy as np
+
+BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(BENCH_DIR)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+
+def say(record: dict) -> None:
+    """An earlier line of the run's output (the result line is the last)."""
+    print(json.dumps(record), flush=True)
+
+
+# ----------------------------------------------------------------------
+# files by name
+# ----------------------------------------------------------------------
+def read_json(path: str) -> dict:
+    with open(path) as fh:
+        return json.load(fh)
+
+
+def load_module(path: str):
+    name = "bench_" + os.path.relpath(path, BENCH_DIR).replace(
+        os.sep, "_").replace(".", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_cell(root: str, name: str) -> dict:
+    """``workloads/<name>.json`` -> its configuration and traffic mix."""
+    cell = read_json(os.path.join(root, "workloads", f"{name}.json"))
+    cell["name"] = name
+    cell["config_doc"] = read_json(
+        os.path.join(root, "configs", f"{cell['config']}.json"))
+    cell["traffic_doc"] = read_json(
+        os.path.join(root, "traffic", f"{cell['traffic']}.json"))
+    return cell
+
+
+def load_layer_metrics(root: str) -> dict:
+    """Every reader under ``layer_metrics/``: ``{name: module}``; a module
+    has ``UNIT`` and ``read(ctx) -> float | None``."""
+    return {os.path.splitext(os.path.basename(p))[0]: load_module(p)
+            for p in sorted(glob.glob(
+                os.path.join(root, "layer_metrics", "*.py")))}
+
+
+def merge(base: dict, overlay: dict) -> dict:
+    out = dict(base)
+    for key, value in overlay.items():
+        if isinstance(value, dict) and isinstance(out.get(key), dict):
+            out[key] = merge(out[key], value)
+        else:
+            out[key] = copy.deepcopy(value)
+    return out
+
+
+def build_config(cell: dict, trace: bool, control: dict | None) -> dict:
+    import yaml
+    doc = cell["config_doc"]
+    with open(os.path.join(REPO, doc["base_yaml"])) as fh:
+        cfg = yaml.safe_load(fh)
+    cfg = merge(cfg, doc.get("overlay", {}))
+    cfg = merge(cfg, cell["traffic_doc"].get("overlay", {}))
+    if control:
+        cfg = merge(cfg, control["overlay"])
+    data = cfg["server_config"]["data_config"]
+    data["val"]["val_data"] = "val.hdf5"
+    data["test"]["test_data"] = "test.hdf5"
+    cfg["client_config"]["data_config"]["train"]["list_of_train_data"] = \
+        "train.hdf5"
+    # the window ends by preemption, never by running out of rounds
+    cfg["server_config"]["max_iteration"] = 10 ** 9
+    if trace:
+        # host spans only: the device-metric bus and the compile
+        # introspection stay off, so the round program is the untraced
+        # run's program
+        cfg["server_config"]["telemetry"] = {
+            "enable": True, "trace": True, "devbus": False, "xla": False,
+            "rollup": False, "flight": False, "scorecard": False}
+    return cfg
+
+
+# ----------------------------------------------------------------------
+# observation from outside
+# ----------------------------------------------------------------------
+class CompileLog:
+    """Every program jax requests from the backend (a persistent-cache hit
+    is still a request) and every cache hit/miss, with the time it ended
+    (copied from ``chip_smoke.py``)."""
+
+    def __init__(self):
+        self.compiles = []  # (end_ts, seconds, fun_name)
+        self.hits = []
+        self.misses = []
+
+    def install(self) -> None:
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(self._duration)
+        monitoring.register_event_listener(self._event)
+
+    def _duration(self, event, duration, **kwargs):
+        if event == BACKEND_COMPILE_EVENT:
+            self.compiles.append((time.time(), float(duration),
+                                  str(kwargs.get("fun_name"))))
+
+    def _event(self, event, **kwargs):
+        if event == CACHE_HIT_EVENT:
+            self.hits.append(time.time())
+        elif event == CACHE_MISS_EVENT:
+            self.misses.append(time.time())
+
+    def between(self, t0: float, t1: float) -> list:
+        return [c for c in self.compiles if t0 < c[0] <= t1]
+
+
+_COMPILES = None
+
+
+def compile_log() -> CompileLog:
+    """One listener per process (jax keeps listeners for good)."""
+    global _COMPILES
+    if _COMPILES is None:
+        _COMPILES = CompileLog()
+        _COMPILES.install()
+    return _COMPILES
+
+
+def _tree_copy(tree):
+    import jax
+    import jax.numpy as jnp
+    return jax.tree.map(
+        lambda x: jnp.copy(x) if isinstance(x, jax.Array) else x, tree)
+
+
+def round_inputs(batches, client_lrs, server_lrs, quant) -> list:
+    """Copies of every round's packed input of one dispatch: what the
+    reference follows after the run."""
+    return [{
+        "x": np.array(batch.arrays["x"]),
+        "y": np.array(batch.arrays["y"]),
+        "sample_mask": np.array(batch.sample_mask),
+        "client_mask": np.array(batch.client_mask),
+        "client_lr": float(client_lrs[r]),
+        "server_lr": float(server_lrs[r]),
+        "quant_quantile": float(quant[r]) if quant else None,
+    } for r, batch in enumerate(batches)]
+
+
+class Run:
+    """State of one run: the hooks write it, the reduction reads it."""
+
+    def __init__(self, cell: dict, seconds: float, trace: bool,
+                 work: str, weights: dict, t_start: float,
+                 readings: bool = False):
+        traffic = cell["traffic_doc"]
+        self.readings = bool(readings)
+        self.seconds = float(seconds)
+        self.trace = bool(trace)
+        self.work = work
+        self.weights = weights
+        self.t_start = t_start
+        # one evaluation period of warm-up, one period traced
+        self.period = int(traffic["period_rounds"])
+        self.server = None
+        self.dispatches = []     # dict(rounds, clients)
+        # dict(ts, rounds, rounds_done, losses, client_count)
+        self.fences = []
+        self.first_rounds = None  # the first dispatch's inputs, per round
+        self.check = None        # the check program's round 0
+        self.first_state_params = None   # device copy, until the fence
+        self.first_dispatch_params = None
+        self.window_open = None  # index into fences
+        self.window_close = None
+        self.profile = None      # dict(dir, t0, t1, sync_ts)
+        self.profile_state = "idle"
+
+    # -- the wrapped calls ---------------------------------------------
+    def on_train(self, server) -> None:
+        """Hand the program the benchmark's seeded weights."""
+        import jax
+
+        from msrflute_tpu.engine.round import ServerState
+        self.server = server
+        state = server.state
+        have = jax.tree.structure(state.params)
+        want = jax.tree.structure(self.weights)
+        if have != want:
+            raise RuntimeError(
+                "the reference's weights do not match the program's "
+                f"parameter tree: {want} vs {have}")
+        for mine, theirs in zip(jax.tree.leaves(self.weights),
+                                jax.tree.leaves(state.params)):
+            if mine.shape != theirs.shape or mine.dtype != theirs.dtype:
+                raise RuntimeError(
+                    f"weight leaf {mine.shape}/{mine.dtype} vs the "
+                    f"program's {theirs.shape}/{theirs.dtype}")
+        placed = jax.tree.map(
+            lambda mine, theirs: jax.device_put(mine, theirs.sharding),
+            self.weights, state.params)
+        server.state = ServerState(placed, state.opt_state,
+                                   state.strategy_state, state.round)
+
+    def run_check_program(self, dispatch, fetch, engine, state, batches,
+                          client_lrs, server_lrs, rng, kwargs) -> None:
+        """Round 0's cohort through the engine's own dispatch, one round,
+        traced under ``highest``, on a copy of the state (the call
+        donates its state)."""
+        import jax
+
+        from msrflute_tpu.engine.round import ServerState
+        quant = kwargs.get("quant_thresholds")
+        self.first_rounds = round_inputs(batches, client_lrs, server_lrs,
+                                         quant)
+        t0 = time.time()
+        scratch = ServerState(_tree_copy(state.params),
+                              _tree_copy(state.opt_state),
+                              _tree_copy(state.strategy_state), state.round)
+        one = dict(kwargs)
+        if quant:
+            one["quant_thresholds"] = list(quant[:1])
+        if one.get("chaos_vecs"):
+            one["chaos_vecs"] = list(one["chaos_vecs"][:1])
+        with jax.default_matmul_precision("highest"):
+            new_state, stats = dispatch(
+                engine, scratch, batches[:1], list(client_lrs[:1]),
+                list(server_lrs[:1]), rng, **one)
+        jax.block_until_ready(stats.vecs)
+        out = fetch(stats)
+        self.check = {
+            "stats": {k: np.asarray(v)[0] for k, v in out.items()},
+            "new_params": jax.device_get(new_state.params),
+            "seconds": time.time() - t0,
+        }
+        del new_state, scratch
+        say({"check_program_s": self.check["seconds"]})
+
+    def on_fence(self, out: dict, rounds: int) -> None:
+        ts = time.time()
+        counts = np.maximum(out["client_count"], 1.0)
+        done = (self.fences[-1]["rounds_done"] if self.fences else 0) + rounds
+        self.fences.append({
+            "ts": ts, "rounds": rounds, "rounds_done": done,
+            "losses": (out["train_loss_sum"] / counts).tolist(),
+            "client_count": np.asarray(out["client_count"]).tolist()})
+        if len(self.fences) == 1:
+            import jax
+            self.fences[0]["agg_grad_norm"] = np.asarray(
+                out["agg_grad_norm"]).tolist()
+            self.first_dispatch_params = jax.device_get(
+                self.first_state_params)
+            self.first_state_params = None
+        at_boundary = done % self.period == 0
+        index = len(self.fences) - 1
+        if self.readings:
+            # the compared numbers only: no warm-up and no window
+            if index == 0:
+                self.window_open = self.window_close = 0
+                self.server.preemption.request("readings taken")
+            return
+        if self.window_open is None:
+            if at_boundary and done >= self.period and index >= 1:
+                self.window_open = index
+                if self.trace:
+                    self.start_profile()
+            return
+        if self.profile_state == "on" and \
+                done - self.fences[self.window_open]["rounds_done"] >= \
+                self.period:
+            self.stop_profile()
+        if self.window_close is None and at_boundary and \
+                ts - self.fences[self.window_open]["ts"] >= self.seconds:
+            self.window_close = index
+            if self.profile_state == "on":
+                self.stop_profile()
+            self.server.preemption.request("benchmark window closed")
+
+    def start_profile(self) -> None:
+        import jax
+        trace_dir = os.path.join(self.work, "profile")
+        # the Python call tracer would slow the host it is observing
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        sync_ts = time.time()
+        with jax.profiler.TraceAnnotation("bench_clock_sync"):
+            pass
+        self.profile = {"dir": trace_dir, "t0": time.time(),
+                        "sync_ts": sync_ts}
+        self.profile_state = "on"
+
+    def stop_profile(self) -> None:
+        import jax
+        self.profile["t1"] = time.time()
+        jax.profiler.stop_trace()
+        self.profile_state = "done"
+
+    @contextlib.contextmanager
+    def watching(self):
+        from msrflute_tpu.engine import round as round_mod
+        from msrflute_tpu.engine import server as server_mod
+
+        dispatch = round_mod.RoundEngine.dispatch_rounds
+        fetch = round_mod.PackedStats.fetch
+        train = server_mod.OptimizationServer.train
+        run = self
+
+        def watched_train(server):
+            run.on_train(server)
+            return train(server)
+
+        def timed_dispatch(engine, state, batches, client_lrs, server_lrs,
+                           rng, **kwargs):
+            if run.first_rounds is None:
+                run.run_check_program(dispatch, fetch, engine, state,
+                                      batches, client_lrs, server_lrs, rng,
+                                      kwargs)
+            out = dispatch(engine, state, batches, client_lrs, server_lrs,
+                           rng, **kwargs)
+            if not run.dispatches:
+                # read back at the first fence: what the timed program
+                # made of the seeded weights
+                run.first_state_params = _tree_copy(out[0].params)
+            run.dispatches.append({
+                "rounds": len(batches),
+                "clients": float(sum(np.sum(b.client_mask)
+                                     for b in batches))})
+            return out
+
+        def timed_fetch(stats):
+            import jax
+            jax.block_until_ready(stats.vecs)
+            out = fetch(stats)
+            run.on_fence(out, stats.rounds)
+            return out
+
+        round_mod.RoundEngine.dispatch_rounds = timed_dispatch
+        round_mod.PackedStats.fetch = timed_fetch
+        server_mod.OptimizationServer.train = watched_train
+        try:
+            yield self
+        finally:
+            round_mod.RoundEngine.dispatch_rounds = dispatch
+            round_mod.PackedStats.fetch = fetch
+            server_mod.OptimizationServer.train = train
+            if self.profile_state == "on":
+                self.stop_profile()
+
+
+def run_cli(cfg: dict, task: str, data_dir: str, out_dir: str) -> int:
+    """``e2e_trainer.main()`` with ``sys.argv`` set (as ``chip_smoke.py``);
+    returns the exit status the trainer asked for (75 when preempted)."""
+    import yaml
+    if REPO not in sys.path:
+        sys.path.insert(0, REPO)
+    import e2e_trainer
+    os.makedirs(out_dir, exist_ok=True)
+    cfg_path = os.path.join(os.path.dirname(out_dir), "cell.yaml")
+    with open(cfg_path, "w") as fh:
+        yaml.safe_dump(cfg, fh)
+    argv, sys.argv = sys.argv, [
+        "e2e_trainer.py", "-config", cfg_path, "-dataPath", data_dir,
+        "-outputPath", out_dir, "-task", task]
+    try:
+        e2e_trainer.main()
+    except SystemExit as exc:
+        return int(exc.code or 0)
+    finally:
+        sys.argv = argv
+    return 0
+
+
+# ----------------------------------------------------------------------
+# reduction: the window's end-to-end metrics
+# ----------------------------------------------------------------------
+def window_metrics(run: Run) -> dict:
+    fences = run.fences[run.window_open:run.window_close + 1]
+    t_open, t_close = fences[0]["ts"], fences[-1]["ts"]
+    in_window = fences[1:]
+    per_round = [(b["ts"] - a["ts"]) / b["rounds"]
+                 for a, b in zip(fences, in_window)]
+    rounds = sum(f["rounds"] for f in in_window)
+    # fence i belongs to dispatch i: the engine drains in dispatch order
+    first = run.window_open + 1
+    clients = sum(d["clients"] for d in
+                  run.dispatches[first:run.window_close + 1])
+    losses = [v for f in in_window for v in f["losses"]]
+    return {
+        "t_open": t_open, "t_close": t_close,
+        "window_s": t_close - t_open, "dispatches": len(in_window),
+        "rounds": rounds, "clients": clients,
+        "per_round_s": per_round,
+        "nonfinite_losses": int(np.sum(~np.isfinite(losses))),
+        "round_count_gap": abs(rounds - sum(
+            d["rounds"] for d in
+            run.dispatches[first:run.window_close + 1])),
+        "setup_s": t_open - run.t_start,
+    }
+
+
+def end_to_end(win: dict, cell_name: str) -> dict:
+    per_round = np.asarray(win["per_round_s"])
+    metrics = {
+        "clients_per_s": {"value": win["clients"] / win["window_s"],
+                          "unit": "clients/s"},
+        "round_s_p50": {"value": float(np.percentile(per_round, 50)),
+                        "unit": "s"},
+        "round_s_p90": {"value": float(np.percentile(per_round, 90)),
+                        "unit": "s"},
+        "setup_s": {"value": win["setup_s"], "unit": "s"},
+    }
+    # a metric that BENCHMARK.json keeps to some cells is reported there only
+    listed = read_json(os.path.join(REPO, "BENCHMARK.json"))["end_to_end"]
+    for entry in listed:
+        if cell_name not in entry.get("workloads", [cell_name]):
+            metrics.pop(entry["name"], None)
+    return metrics
+
+
+def device_report() -> dict:
+    """The device as jax reports it.  ``memory_peak_bytes`` is the peak on
+    the fullest chip: the allocator's peak in use plus, where the backend
+    keeps it apart (the TPU runtime reserves a compiled program's scratch
+    memory outside ``bytes_in_use``), its peak reservation.  The two
+    parts stand beside the sum under keys of their own."""
+    import jax
+    devices = jax.devices()
+    stats = [d.memory_stats() or {} for d in devices]
+    say({"memory_stats": stats[0]})
+    fullest = max(stats, key=lambda s: s.get("peak_bytes_in_use", 0) +
+                  s.get("peak_bytes_reserved", 0))
+    in_use = int(fullest.get("peak_bytes_in_use", 0))
+    reserved = int(fullest.get("peak_bytes_reserved", 0))
+    return {"platform": devices[0].platform, "kind": devices[0].device_kind,
+            "count": len(devices), "memory_peak_bytes": in_use + reserved,
+            "memory_peak_in_use_bytes": in_use,
+            "memory_peak_reserved_bytes": reserved}
+
+
+def read_spans(out_dir: str) -> list:
+    """The program's own host spans (``telemetry/spans.py``), epoch clock."""
+    path = os.path.join(out_dir, "models", "telemetry", "events.jsonl")
+    spans = []
+    if os.path.exists(path):
+        with open(path) as fh:
+            for line in fh:
+                rec = json.loads(line)
+                if rec.get("kind") == "span":
+                    spans.append(rec)
+    return spans
+
+
+# ----------------------------------------------------------------------
+def run_cell(name: str, seed: int, seconds: float, trace: bool,
+             root: str = BENCH_DIR, control: str | None = None,
+             t_start: float | None = None, readings: bool = False) -> dict:
+    """One run of one cell; returns the result line's object.  The caller
+    (``run.py``) has already checked the platform and the chips.
+    ``readings``: stop after the first timed dispatch and return the
+    compared numbers alone (what a limit is set from), no metrics."""
+    from . import check, datagen, trace_reduce
+
+    t_start = time.time() if t_start is None else t_start
+    cell = load_cell(root, name)
+    doc = cell["config_doc"]
+    control_doc = (read_json(os.path.join(root, "controls",
+                                          f"{control}.json"))
+                   if control else None)
+    cfg = build_config(cell, trace, control_doc)
+
+    from msrflute_tpu.utils.backend import enable_compilation_cache
+    cache_dir = enable_compilation_cache()
+    compiles = compile_log()
+
+    model = load_module(os.path.join(BENCH_DIR, "reference",
+                                     f"{doc['reference']['model']}.py"))
+    weights = model.init(
+        np.random.default_rng(np.random.SeedSequence([int(seed), 1])),
+        cfg["model_config"])
+
+    work = tempfile.mkdtemp(prefix="bench_")
+    try:
+        data_dir = os.path.join(work, "data")
+        t0 = time.time()
+        datagen.write_splits(data_dir, seed, doc["data"])
+        say({"cell": name, "seed": int(seed), "compilation_cache": cache_dir,
+             "data_gen_s": time.time() - t0, "control": control})
+
+        run = Run(cell, seconds, trace, work, weights, t_start, readings)
+        out_dir = os.path.join(work, "out")
+        with run.watching():
+            status = run_cli(cfg, doc["task"], data_dir, out_dir)
+        if run.window_close is None or status != os.EX_TEMPFAIL:
+            raise RuntimeError(
+                f"the trainer ended (status {status}) before the window "
+                f"closed: {len(run.fences)} fences")
+        device = device_report()  # the program's peak, before the reference
+        if not readings:
+            win = window_metrics(run)
+            in_window = compiles.between(win["t_open"], win["t_close"])
+        spans = read_spans(out_dir) if trace else []
+        run.server = None  # the program's state is freed before the reference
+
+        t0 = time.time()
+        fedround = load_module(os.path.join(BENCH_DIR, "reference",
+                                            "fedround.py"))
+        reference = dict(
+            forward=model.forward, model_config=cfg["model_config"],
+            params=weights, strategy=doc["reference"]["strategy"],
+            block=int(doc["reference"].get("block", 1)))
+        # round 0 at `highest`, for the check program (traced under
+        # `highest` above); every round of the first dispatch at the
+        # backend's default precision, which is what the configurations
+        # state and the timed program runs at
+        ref_check = fedround.run_rounds(
+            rounds=run.first_rounds[:1], precision="highest", **reference)[0]
+        refs_timed = fedround.run_rounds(
+            rounds=run.first_rounds, precision=None, **reference)
+        reference_s = time.time() - t0
+
+        numbers = check.compare(
+            init_params=weights, ref_check=ref_check, refs_timed=refs_timed,
+            rounds=run.first_rounds, check_stats=run.check["stats"],
+            check_params=run.check["new_params"],
+            timed_first=run.fences[0],
+            timed_first_params=run.first_dispatch_params,
+            dp=doc["reference"].get("dp"))
+        if not readings:
+            numbers += [
+                ("window_compiles", float(len(in_window))),
+                ("nonfinite_losses", float(win["nonfinite_losses"])),
+                ("round_count_gap", float(win["round_count_gap"])),
+            ]
+        verdicts = check.judge(numbers, doc["check_limits"])
+        for v in verdicts:
+            say({"compared": v["name"], "value": v["value"],
+                 "limit": v["limit"], "ok": v["ok"]})
+        correct = all(v["ok"] for v in verdicts)
+        if readings:
+            say({"reference_s": reference_s,
+                 "first_fence_s": run.fences[0]["ts"] - t_start})
+            return {"correct": bool(correct), "readings": True,
+                    "device": device, "compared": verdicts}
+        say({"window_s": win["window_s"], "dispatches": win["dispatches"],
+             "rounds": win["rounds"],
+             "round_samples": len(win["per_round_s"]),
+             "reference_s": reference_s,
+             "window_compile_names": [c[2] for c in in_window],
+             "compile_requests": len(compiles.compiles),
+             "cache_hits": len(compiles.hits),
+             "cache_misses": len(compiles.misses),
+             "first_fence_s": run.fences[0]["ts"] - t_start})
+
+        result = {
+            "correct": bool(correct),
+            "attempted": int(win["clients"]),
+            # every client update of a round whose loss is not finite
+            "failed": int(win["nonfinite_losses"] *
+                          win["clients"] / max(win["rounds"], 1)),
+            "device": device,
+            "compared": verdicts,
+        }
+        if not trace:
+            result["metrics"] = end_to_end(win, name)
+            return result
+
+        reduced = trace_reduce.reduce_profile(run.profile, spans)
+        say({"trace": reduced["summary"]})
+        result["device"]["busy_s"] = reduced["busy_s"]
+        result["device"]["window_s"] = reduced["window_s"]
+        result["breakdown"] = reduced["breakdown"]
+        ctx = {
+            "cell": cell, "config": cfg, "spans": spans, "window": win,
+            "trace": reduced, "device": device,
+            "peaks": read_json(os.path.join(BENCH_DIR, "peaks.json")),
+            "model": model, "fedround": fedround, "weights": weights,
+            "first_inputs": run.first_rounds[0],
+        }
+        metrics = {}
+        for metric, reader in load_layer_metrics(root).items():
+            value = reader.read(ctx)
+            if value is not None and math.isfinite(value):
+                metrics[metric] = {"value": float(value),
+                                   "unit": reader.UNIT}
+        result["metrics"] = metrics
+        return result
+    finally:
+        shutil.rmtree(work, ignore_errors=True)

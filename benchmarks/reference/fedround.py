@@ -1,0 +1,197 @@
+"""Plain reference: federated rounds, written out.
+
+Float32 under ``jax.default_matmul_precision("highest")``, a Python loop
+over the round's clients in blocks (``vmap`` of one client's plain
+training: a block is what fits beside nothing else on the chip and keeps
+the reference shorter than the window), ``jax.grad``, plain SGD.  Imports nothing of
+``msrflute_tpu`` and takes nothing the program made: the weights come
+from the model reference's ``init`` (which the harness also hands to the
+program), the data from the benchmark's generator, and the order of
+batches from the round's packed input (an input of the program, not a
+result of it).
+
+Semantics (the reference's ``core/client.py`` / ``core/trainer.py`` /
+``core/strategies``):
+
+- a client starts from the global weights, takes one SGD step per batch
+  in the packed order (loss = cross entropy averaged over the batch's
+  real rows; an all-padding batch is skipped), and returns
+  ``pseudo_gradient = global - trained``, ``train_loss`` = the sum of its
+  batch losses and ``num_samples`` = its real rows;
+- ``fedavg``: weight = ``num_samples`` capped at 100;
+- ``dga``: weight = ``exp(-beta * train_loss / num_samples)`` capped at
+  100; the payload is quantised per leaf (``quantise``) when the
+  configuration says so; global DP adds N(0, (sigma * max_grad / K)^2)
+  to the aggregate — the reference leaves the NOISE out and the check
+  tests the residual's moments instead;
+- aggregate = sum(w_k * payload_k) / sum(w_k); server SGD:
+  ``new = global - server_lr * aggregate``.
+"""
+
+from __future__ import annotations
+
+import contextlib
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+MAX_WEIGHT = 100.0  # the reference's core/strategies/utils.py filter
+
+
+def xent(logits, labels, mask):
+    logp = jax.nn.log_softmax(logits, axis=-1)
+    per_row = -jnp.take_along_axis(logp, labels[:, None], axis=-1)[:, 0]
+    return jnp.sum(per_row * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def quantise(g, thresh_quantile, bits):
+    """The reference's ``quant_model`` on one leaf: the nearest of
+    ``2**bits`` levels on ``linspace(min, max)``, zero where ``|g|`` is at
+    most the ``thresh_quantile`` quantile of ``|g|``."""
+    n_bins = 2 ** int(bits)
+    lo, hi = jnp.min(g), jnp.max(g)
+    thresh = jnp.quantile(jnp.abs(g), thresh_quantile)
+    width = (hi - lo) / (n_bins - 1)
+    idx = jnp.clip(jnp.round((g - lo) / jnp.maximum(width, 1e-30)),
+                   0, n_bins - 1)
+    return jnp.where(jnp.abs(g) > thresh, lo + idx * width, 0.0)
+
+
+@functools.lru_cache(maxsize=None)
+def _block_fn(forward, model_items, strategy_items):
+    """One jitted function for a BLOCK of clients (``vmap`` of the plain
+    per-client training below): the block's weighted payload sum and,
+    per client, train loss, sample count, weight and update norm."""
+    model_config = dict(model_items)
+    strategy = dict(strategy_items)
+
+    def loss_fn(params, x, y, mask):
+        return xent(forward(params, x, model_config), y, mask)
+
+    def one_client(global_params, xs, ys, masks, live_client, lr, quantile):
+        def step(carry, batch):
+            params, loss_sum = carry
+            x, y, mask = batch
+            loss, grads = jax.value_and_grad(loss_fn)(params, x, y, mask)
+            live = (jnp.sum(mask) > 0).astype(jnp.float32)
+            params = jax.tree.map(lambda p, g: p - live * lr * g,
+                                  params, grads)
+            return (params, loss_sum + live * loss), None
+
+        (trained, loss_sum), _ = jax.lax.scan(
+            step, (global_params, jnp.zeros(())), (xs, ys, masks))
+        pseudo = jax.tree.map(lambda a, b: a - b, global_params, trained)
+        n = jnp.sum(masks)
+        norm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(pseudo)))
+        if strategy["name"] == "dga":
+            w = jnp.exp(-float(strategy["beta"]) * loss_sum /
+                        jnp.maximum(n, 1.0))
+            if strategy.get("quant_bits") is not None:
+                pseudo = jax.tree.map(
+                    lambda g: quantise(g, quantile, strategy["quant_bits"]),
+                    pseudo)
+        else:
+            w = n
+        w = jnp.clip(jnp.nan_to_num(w, nan=0.0, posinf=0.0), 0.0,
+                     MAX_WEIGHT) * live_client
+        return (jax.tree.map(lambda g: g * w, pseudo), loss_sum, n, w, norm)
+
+    def block(global_params, xs, ys, masks, live_clients, lr, quantile):
+        terms, loss_sum, n, w, norm = jax.vmap(
+            one_client, in_axes=(None, 0, 0, 0, 0, None, None))(
+                global_params, xs, ys, masks, live_clients, lr, quantile)
+        return (jax.tree.map(lambda t: jnp.sum(t, axis=0), terms),
+                loss_sum, n, w, norm)
+
+    return jax.jit(block)
+
+
+def run_round(forward, model_config: dict, params: dict, batch: dict,
+              client_lr: float, server_lr: float, strategy: dict,
+              block: int = 1, precision: str | None = "highest") -> dict:
+    """One round over ``batch`` = ``{"x": [K,S,B,...], "y": [K,S,B],
+    "sample_mask": [K,S,B], "client_mask": [K]}`` (numpy), ``block``
+    clients at a time (the last block is padded with masked-out clients,
+    so one program serves every block).  ``strategy`` = ``{"name":
+    "fedavg"}`` or ``{"name": "dga", "beta", "quant_bits",
+    "quant_quantile"}``.  ``precision`` = the matmul precision (``None``:
+    the backend's default, which is what the program runs at as
+    configured).  Returns host numpy: the aggregate, the new
+    weights, and per live client the train loss, sample count, weight and
+    pseudo-gradient norm."""
+    static = {k: v for k, v in strategy.items() if k != "quant_quantile"}
+    fn = _block_fn(forward, tuple(sorted(model_config.items())),
+                   tuple(sorted(static.items())))
+    quantile = jnp.float32(strategy.get("quant_quantile") or 0.0)
+    live_all = (np.asarray(batch["client_mask"]) > 0).astype(np.float32)
+    total_k = len(live_all)
+    block = max(1, min(int(block), total_k))
+    weighted = None
+    losses, counts, weights, norms = [], [], [], []
+
+    def padded(a, lo):
+        part = np.asarray(a[lo:lo + block])
+        short = block - part.shape[0]
+        if short:
+            part = np.concatenate(
+                [part, np.zeros((short,) + part.shape[1:], part.dtype)])
+        return part
+
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        dev_params = jax.tree.map(jnp.asarray, params)
+        for lo in range(0, total_k, block):
+            live = padded(live_all, lo)
+            term, loss_sum, n, w, norm = fn(
+                dev_params, jnp.asarray(padded(batch["x"], lo)),
+                jnp.asarray(padded(batch["y"], lo)).astype(jnp.int32),
+                jnp.asarray(padded(batch["sample_mask"], lo)).astype(
+                    jnp.float32),
+                jnp.asarray(live), jnp.float32(client_lr), quantile)
+            weighted = term if weighted is None else jax.tree.map(
+                jnp.add, weighted, term)
+            keep = live > 0
+            losses += np.asarray(loss_sum)[keep].tolist()
+            counts += np.asarray(n)[keep].tolist()
+            weights += np.asarray(w)[keep].tolist()
+            norms += np.asarray(norm)[keep].tolist()
+        total = max(sum(weights), 1e-12)
+        aggregate = jax.tree.map(lambda g: g / total, weighted)
+        new_params = jax.tree.map(lambda p, g: p - server_lr * g,
+                                  dev_params, aggregate)
+    return {"aggregate": jax.tree.map(np.asarray, aggregate),
+            "new_params": jax.tree.map(np.asarray, new_params),
+            "train_loss": np.asarray(losses),
+            "num_samples": np.asarray(counts),
+            "weight": np.asarray(weights), "pseudo_norm": np.asarray(norms)}
+
+
+def run_rounds(forward, model_config: dict, params: dict, rounds: list,
+               strategy: dict, block: int = 1,
+               precision: str | None = "highest") -> list:
+    """The rounds of one dispatch, in turn: round ``r + 1`` starts from
+    round ``r``'s new weights.  ``rounds`` = the packed inputs, each with
+    its ``client_lr``, ``server_lr`` and (quantised payloads)
+    ``quant_quantile``.  Returns one ``run_round`` result per round."""
+    out = []
+    for inputs in rounds:
+        per_round = dict(strategy)
+        if per_round.get("quant_bits") is not None:
+            per_round["quant_quantile"] = inputs["quant_quantile"]
+        out.append(run_round(forward, model_config, params, inputs,
+                             inputs["client_lr"], inputs["server_lr"],
+                             per_round, block, precision))
+        params = out[-1]["new_params"]
+    return out
+
+
+def flops_per_step(forward, model_config: dict, params: dict, x, y, mask,
+                   count) -> float:
+    """Matmul and convolution operations of ONE forward+backward on one
+    batch, counted by ``count`` (``benchmarks/flops.py``) on this plain
+    model — what the algorithm requires, nothing recomputed."""
+    def loss_fn(p, x_, y_, m_):
+        return xent(forward(p, x_, model_config), y_, m_)
+    return count(jax.value_and_grad(loss_fn), params, x, y, mask)
