@@ -17,6 +17,7 @@ from __future__ import annotations
 import argparse
 import os
 import shutil
+import time
 
 import yaml
 
@@ -24,6 +25,16 @@ import yaml
 def main():
     """Run the simulation the command line describes; returns the server
     (run statistics, final state) for an in-process caller."""
+    # set-up phases as (name, start, end) on the epoch clock: they are
+    # over before a telemetry scope exists, and are handed to it then
+    phases, phase_start = [], time.time()
+
+    def phase_done(name: str) -> None:
+        nonlocal phase_start
+        now = time.time()
+        phases.append((name, phase_start, now))
+        phase_start = now
+
     ap = argparse.ArgumentParser()
     ap.add_argument("-config", required=True)
     ap.add_argument("-dataPath", default=None)
@@ -59,6 +70,13 @@ def main():
     cfg.data_path = args.dataPath or cfg.data_path
     cfg.output_path = args.outputPath
     cfg.validate(cfg.data_path)
+    from msrflute_tpu.telemetry import trace_config_enabled
+    if trace_config_enabled(cfg.server_config.get("telemetry")):
+        # from here on every trace / lower / compile jax reports is
+        # kept for the scope (engine construction and init_state
+        # included); a telemetry-off run registers nothing
+        from msrflute_tpu.telemetry import compiles
+        compiles.install()
 
     # plugin-folder resolution (reference loads experiments/<task>/ by the
     # -task name, utils/dataloaders_utils.py:9-23): an explicit
@@ -96,11 +114,13 @@ def main():
         from msrflute_tpu.utils.backend import enable_compilation_cache
         print_rank(f"compilation cache: {enable_compilation_cache()}")
 
+    phase_done("cli_config")
     task = make_task(cfg.model_config)
     train_ds, val_ds, test_ds = build_task_datasets(cfg, task)
     print_rank(f"task={cfg.task} users={len(train_ds)} "
                f"val={len(val_ds) if val_ds else 0} "
                f"test={len(test_ds) if test_ds else 0}")
+    phase_done("data_load")
 
     # experiment properties at startup (reference log_run_properties,
     # e2e_trainer.py:40-74 — AzureML run properties become metrics.jsonl)
@@ -130,6 +150,9 @@ def main():
                         test_dataset=test_ds,
                         server_train_dataset=build_server_train_dataset(cfg, task),
                         model_dir=model_dir, mesh=mesh)
+    phase_done("server_build")
+    if server.scope is not None:
+        server.scope.emit_spans(phases)
     server.run()
 
     # graceful preemption (SIGTERM/SIGINT mid-run, or the chaos drill's

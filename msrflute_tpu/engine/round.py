@@ -47,7 +47,8 @@ from ..resilience.chaos import (CORRUPT_NAN, CORRUPT_SCALE,
 from ..traffic.schedule import STALE_HIST_BINS
 from ..robust import make_shield
 from ..strategies.base import BaseStrategy
-from ..telemetry import devbus_config_enabled, xla_config_enabled
+from ..telemetry import (NULL_SPAN, devbus_config_enabled,
+                         xla_config_enabled)
 from ..telemetry import xla as xla_telemetry
 from ..telemetry.devbus import DeviceMetricBus
 from ..utils.flatpack import AxisPacker, FlatPacker, ScalarStager
@@ -72,12 +73,29 @@ class PackedStats:
     packer: FlatPacker          #: single-round slot table
     rounds: int                 #: R rounds in this chunk
     stacked: bool               #: True if ``vecs`` carry a leading [R] axis
+    #: the engine's span factory when tracing is on (``stats_d2h``)
+    span: Optional[Callable] = None
+
+    def is_ready(self) -> bool:
+        """Whether the chunk's program has produced the stats: a
+        question, not a fence (asked only when tracing is on)."""
+        return all(v.is_ready() for v in self.vecs.values())
+
+    def wait(self) -> None:
+        """Block until the stats are there (the traced run's
+        ``fence_wait``; :meth:`fetch` blocks by itself otherwise)."""
+        jax.block_until_ready(self.vecs)
 
     def fetch(self) -> Dict[str, np.ndarray]:
         """Fetch + decode: ONE host transfer per dtype group (the honest
         end-of-chunk fence), then pure numpy views.  Leaves come back
         with a leading ``[R]`` round axis like ``run_rounds`` always
         returned."""
+        with (self.span("stats_d2h", rounds=self.rounds)
+              if self.span is not None else NULL_SPAN):
+            return self._fetch()
+
+    def _fetch(self) -> Dict[str, np.ndarray]:
         host = jax.device_get(self.vecs)
         if self.stacked:
             return self.packer.unpack_np_stacked(host)
@@ -102,12 +120,25 @@ class BucketedStats:
     server)."""
 
     rounds_stats: list  #: one PackedStats per round, dispatch order
+    #: the engine's span factory when tracing is on (``stats_d2h``)
+    span: Optional[Callable] = None
 
     @property
     def rounds(self) -> int:
         return len(self.rounds_stats)
 
+    def is_ready(self) -> bool:
+        return all(ps.is_ready() for ps in self.rounds_stats)
+
+    def wait(self) -> None:
+        jax.block_until_ready([ps.vecs for ps in self.rounds_stats])
+
     def fetch(self) -> Dict[str, np.ndarray]:
+        with (self.span("stats_d2h", rounds=self.rounds)
+              if self.span is not None else NULL_SPAN):
+            return self._fetch()
+
+    def _fetch(self) -> Dict[str, np.ndarray]:
         host = jax.device_get([ps.vecs for ps in self.rounds_stats])
         decoded = [ps.packer.unpack_np(h)
                    for ps, h in zip(self.rounds_stats, host)]
@@ -350,6 +381,11 @@ class RoundEngine:
         #: most recent dispatch
         self.last_dispatch_puts = 0
         self.last_staged_bytes = 0
+        #: the server's span factory (``Telemetry.span``) when tracing
+        #: is on: what a dispatch is made of (``stage_host``, ``h2d``,
+        #: ``launch``) and the stats transfer (``stats_d2h``) become
+        #: child spans.  None: every site is one check, nothing else.
+        self.span_factory: Optional[Callable] = None
 
         # deterministic chaos client faults (server_config.chaos): when the
         # schedule injects dropout/straggling, the round program takes two
@@ -657,6 +693,12 @@ class RoundEngine:
         server's host tail, which owns emitting them)."""
         out, self._mega_events = self._mega_events, []
         return out
+
+    def _span(self, name: str, **args):
+        """One child span of the server's ``dispatch`` — the shared
+        no-op context unless the server handed over a factory."""
+        return self.span_factory(name, **args) \
+            if self.span_factory is not None else NULL_SPAN
 
     def _note_compiles(self, name: str, fn: Callable) -> None:
         """Append one ``compile_log`` entry per NEW compiled variant of
@@ -1764,6 +1806,63 @@ class RoundEngine:
         groups and one for the scalar groups, run the unpacking jit."""
         R = len(batches)
         stacked = R > 1
+        with self._span("stage_host", rounds=R) as span:
+            ax_packer, stager, ax_bufs, sc_bufs, pool_args = \
+                self._stage_host(state, batches, client_lrs, server_lrs,
+                                 leakage_threshold, quant_thresholds,
+                                 chaos_vecs)
+            staged_bytes = int(
+                sum(b.nbytes for b in ax_bufs.values()) +
+                sum(b.nbytes for b in sc_bufs.values()))
+            if span is not None:
+                span["bytes"] = staged_bytes
+        ax_sharding = (NamedSharding(self.mesh, P(None, CLIENTS_AXIS))
+                       if stacked else self._client_sharding)
+        with self._span("h2d", rounds=R, bytes=staged_bytes,
+                        puts=len(ax_bufs) + len(sc_bufs)):
+            # ONE staging transfer per dtype group: each put runs on the
+            # whole per-dtype dict, so the transfer count equals the
+            # group count — the dispatch-cost contract the tier-1 guard
+            # pins
+            ax_dev = jax.device_put(ax_bufs, ax_sharding)
+            sc_dev = jax.device_put(sc_bufs, self._replicated)
+        self.last_dispatch_puts = len(ax_bufs) + len(sc_bufs)
+        self.last_staged_bytes = staged_bytes
+        with self._span("launch", rounds=R) as span:
+            key = (R, ax_packer.signature, stager.signature)
+            fn = self._staged_cache.get(key)
+            if fn is None:
+                fn = self._instrument(f"staged_r{R}",
+                                      self._build_staged_fn(R, ax_packer,
+                                                            stager),
+                                      rounds=R)
+                self._staged_cache[key] = fn
+            seen = len(self.compile_log)
+            params, opt_state, strategy_state, vecs = fn(
+                state.params, state.opt_state, state.strategy_state,
+                ax_dev, sc_dev, rng, *pool_args)
+            self._note_compiles(f"staged_r{R}", fn)
+            if span is not None:
+                span["compiled"] = len(self.compile_log) > seen
+        new_state = ServerState(params, opt_state, strategy_state,
+                                state.round + R)
+        packer = self._stats_packers[
+            ("single", batches[0].sample_mask.shape[0])]
+        return new_state, PackedStats(vecs, packer, rounds=R,
+                                      stacked=stacked,
+                                      span=self.span_factory)
+
+    def _stage_host(self, state: ServerState, batches: list,
+                    client_lrs: list, server_lrs: list,
+                    leakage_threshold: Optional[float],
+                    quant_thresholds: Optional[list],
+                    chaos_vecs: Optional[list]) -> tuple:
+        """The host half of a staged dispatch: stack the rounds' arrays,
+        assemble the fault vectors and scalars, pack each dtype group
+        into one buffer.  Returns ``(ax_packer, stager, ax_bufs,
+        sc_bufs, pool_args)``."""
+        R = len(batches)
+        stacked = R > 1
 
         def stack(pick):
             vals = [pick(b) for b in batches]
@@ -1808,37 +1907,8 @@ class RoundEngine:
             }
         ax_packer = AxisPacker(axis_tree, lead_ndim=2 if stacked else 1)
         stager = ScalarStager(sc_tree)
-        key = (R, ax_packer.signature, stager.signature)
-        fn = self._staged_cache.get(key)
-        if fn is None:
-            fn = self._instrument(f"staged_r{R}",
-                                  self._build_staged_fn(R, ax_packer,
-                                                        stager),
-                                  rounds=R)
-            self._staged_cache[key] = fn
-        ax_bufs = ax_packer.pack_np(axis_tree)
-        sc_bufs = stager.pack_np(sc_tree)
-        ax_sharding = (NamedSharding(self.mesh, P(None, CLIENTS_AXIS))
-                       if stacked else self._client_sharding)
-        # ONE staging transfer per dtype group: each put runs on the
-        # whole per-dtype dict, so the transfer count equals the group
-        # count — the dispatch-cost contract the tier-1 guard pins
-        ax_dev = jax.device_put(ax_bufs, ax_sharding)
-        sc_dev = jax.device_put(sc_bufs, self._replicated)
-        self.last_dispatch_puts = len(ax_bufs) + len(sc_bufs)
-        self.last_staged_bytes = int(
-            sum(b.nbytes for b in ax_bufs.values()) +
-            sum(b.nbytes for b in sc_bufs.values()))
-        params, opt_state, strategy_state, vecs = fn(
-            state.params, state.opt_state, state.strategy_state, ax_dev,
-            sc_dev, rng, *pool_args)
-        self._note_compiles(f"staged_r{R}", fn)
-        new_state = ServerState(params, opt_state, strategy_state,
-                                state.round + R)
-        packer = self._stats_packers[
-            ("single", batches[0].sample_mask.shape[0])]
-        return new_state, PackedStats(vecs, packer, rounds=R,
-                                      stacked=stacked)
+        return (ax_packer, stager, ax_packer.pack_np(axis_tree),
+                stager.pack_np(sc_tree), pool_args)
 
     # ------------------------------------------------------------------
     def run_round(self, state: ServerState, batch: RoundBatch,
@@ -1893,7 +1963,8 @@ class RoundEngine:
         new_state = ServerState(params, opt_state, strategy_state,
                                 state.round + 1)
         packer = self._stats_packers[("single", batch.sample_mask.shape[0])]
-        return new_state, PackedStats(vecs, packer, rounds=1, stacked=False)
+        return new_state, PackedStats(vecs, packer, rounds=1, stacked=False,
+                                      span=self.span_factory)
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -2011,7 +2082,8 @@ class RoundEngine:
         # core trace recorded (the scan body traced it just above)
         packer = self._stats_packers[
             ("single", batches[0].sample_mask.shape[0])]
-        return new_state, PackedStats(vecs, packer, rounds=R, stacked=True)
+        return new_state, PackedStats(vecs, packer, rounds=R, stacked=True,
+                                      span=self.span_factory)
 
     # ------------------------------------------------------------------
     # cohort shape-bucketing (server_config.cohort_bucketing): one
@@ -2789,129 +2861,151 @@ class RoundEngine:
             round_flops = 0.0
             round_hbm = 0
             for b, batch in enumerate(buckets):
-                arrays_host, pool_args = self._host_arrays([batch])
-                axis_tree = {
-                    "arrays": arrays_host,
-                    "sample_mask": batch.sample_mask,
-                    "client_mask": batch.client_mask,
-                    "client_ids": batch.client_ids,
-                }
-                if self.carry_paged:
-                    axis_tree["carry_slots"] = self._batch_slots(batch)
-                entry = (chaos_vecs[r][b] if chaos_vecs is not None
-                         else None)
-                chaos_host = self._chaos_host(
-                    [entry] if entry is not None else None,
-                    stacked=False)
-                if chaos_host:
-                    axis_tree["chaos"] = tuple(chaos_host)
-                sc_tree = {
-                    "client_lr": lr_dt(client_lrs[r]),
-                    "round_idx": rd_dt(cur.round),
-                    "leakage": lr_dt(leakage_threshold
-                                     if leakage_threshold is not None
-                                     else np.inf),
-                    "quant": lr_dt(quant_thresholds[r]
-                                   if quant_thresholds is not None
-                                   else -1.0),
-                }
-                ax_packer = AxisPacker(axis_tree, lead_ndim=1)
-                stager = ScalarStager(sc_tree)
-                K, S = (int(batch.sample_mask.shape[0]),
-                        int(batch.sample_mask.shape[1]))
-                ax_bufs = ax_packer.pack_np(axis_tree)
-                sc_bufs = stager.pack_np(sc_tree)
-                # flint: disable=put-loop one staged put per dtype group per BUCKET PROGRAM (each loop iteration dispatches its own compiled grid; the leaves are already flatpacked)
-                ax_dev = jax.device_put(ax_bufs, self._client_sharding)
-                # flint: disable=put-loop same — the scalar group's single staged buffer for this bucket's dispatch
-                sc_dev = jax.device_put(sc_bufs, self._replicated)
+                # the three children of `dispatch`, once per bucket
+                # program; `rounds` is 1 on a round's first bucket and 0
+                # on the others, so that a division by rounds counts
+                # each round once
+                span_rounds = 1 if b == 0 else 0
+                with self._span("stage_host", rounds=span_rounds,
+                                bucket=b) as span:
+                    arrays_host, pool_args = self._host_arrays([batch])
+                    axis_tree = {
+                        "arrays": arrays_host,
+                        "sample_mask": batch.sample_mask,
+                        "client_mask": batch.client_mask,
+                        "client_ids": batch.client_ids,
+                    }
+                    if self.carry_paged:
+                        axis_tree["carry_slots"] = self._batch_slots(batch)
+                    entry = (chaos_vecs[r][b] if chaos_vecs is not None
+                             else None)
+                    chaos_host = self._chaos_host(
+                        [entry] if entry is not None else None,
+                        stacked=False)
+                    if chaos_host:
+                        axis_tree["chaos"] = tuple(chaos_host)
+                    sc_tree = {
+                        "client_lr": lr_dt(client_lrs[r]),
+                        "round_idx": rd_dt(cur.round),
+                        "leakage": lr_dt(leakage_threshold
+                                         if leakage_threshold is not None
+                                         else np.inf),
+                        "quant": lr_dt(quant_thresholds[r]
+                                       if quant_thresholds is not None
+                                       else -1.0),
+                    }
+                    ax_packer = AxisPacker(axis_tree, lead_ndim=1)
+                    stager = ScalarStager(sc_tree)
+                    K, S = (int(batch.sample_mask.shape[0]),
+                            int(batch.sample_mask.shape[1]))
+                    ax_bufs = ax_packer.pack_np(axis_tree)
+                    sc_bufs = stager.pack_np(sc_tree)
+                    bucket_bytes = int(
+                        sum(bf.nbytes for bf in ax_bufs.values()) +
+                        sum(bf.nbytes for bf in sc_bufs.values()))
+                    if span is not None:
+                        span["bytes"] = bucket_bytes
+                with self._span("h2d", rounds=span_rounds, bucket=b,
+                                bytes=bucket_bytes,
+                                puts=len(ax_bufs) + len(sc_bufs)):
+                    # flint: disable=put-loop one staged put per dtype group per BUCKET PROGRAM (each loop iteration dispatches its own compiled grid; the leaves are already flatpacked)
+                    ax_dev = jax.device_put(ax_bufs, self._client_sharding)
+                    # flint: disable=put-loop same — the scalar group's single staged buffer for this bucket's dispatch
+                    sc_dev = jax.device_put(sc_bufs, self._replicated)
                 puts += len(ax_bufs) + len(sc_bufs)
-                staged_bytes += int(
-                    sum(bf.nbytes for bf in ax_bufs.values()) +
-                    sum(bf.nbytes for bf in sc_bufs.values()))
-                # megabatch dispatch gate: when the server attached a
-                # super-batch tape, pick megabatch vs per-client vmap
-                # PER BUCKET — cached per (K, S) geometry, priced on
-                # the compiled cost model at first sight (both arms
-                # run once; the verdict is deterministic because cost
-                # analyses are static)
-                tape = getattr(batch, "mega", None)
-                fn_mega = tp_dev = None
-                if tape is not None and self.megabatch:
-                    tape_tree = {"ptr": tape.ptr, "seg": tape.seg}
-                    tape_packer = AxisPacker(tape_tree, lead_ndim=1)
-                    fn_mega = self._bucket_collect_fn(
-                        K, S, ax_packer, stager, tape_packer=tape_packer)
-                    tp_bufs = tape_packer.pack_np(tape_tree)
-                    # flint: disable=put-loop the tape's single int32 staged buffer for this bucket's dispatch
-                    tp_dev = jax.device_put(tp_bufs, self._client_sharding)
-                    puts += len(tp_bufs)
-                    staged_bytes += int(sum(bf.nbytes
-                                            for bf in tp_bufs.values()))
-                fn = self._bucket_collect_fn(K, S, ax_packer, stager)
-                arm = (self._mega_gate.get((K, S))
-                       if fn_mega is not None else "vmap")
-                out = None
-                if fn_mega is not None and arm is None and \
-                        self.megabatch_autotune and self.xla is not None:
-                    out_v = fn(cur.params, cur.strategy_state, ax_dev,
-                               sc_dev, rngs[r], *pool_args)
-                    self._note_compiles(f"bucket_collect_s{S}", fn)
-                    cost_v = dict(self.xla.last_dispatch or {})
-                    out_m = fn_mega(cur.params, cur.strategy_state,
-                                    ax_dev, sc_dev, rngs[r], tp_dev,
-                                    *pool_args)
-                    self._note_compiles(f"megabatch_collect_s{S}",
-                                        fn_mega)
-                    cost_m = dict(self.xla.last_dispatch or {})
-                    secs_v = self._roofline_secs(cost_v)
-                    secs_m = self._roofline_secs(cost_m)
-                    if secs_m <= secs_v:
-                        arm, out = "mega", out_m
-                    else:
-                        arm, out = "vmap", out_v
-                        self.push_megabatch_event({
-                            "kind": "megabatch_fallback",
-                            "reason": "aot_cost",
-                            "clients": K, "steps": S,
-                            "lanes": int(tape.lanes),
-                            "depth": int(tape.depth),
-                            "mega_secs_est": secs_m,
-                            "vmap_secs_est": secs_v,
-                        })
-                    self._mega_gate[(K, S)] = arm
-                    # the live-MFU snapshot must describe the CHOSEN arm
-                    self.xla.last_dispatch = (cost_v if arm == "vmap"
-                                              else cost_m)
-                elif fn_mega is not None and arm is None:
-                    # no compiled cost model in reach (telemetry.xla off
-                    # or autotune disabled): the server's analytic slots
-                    # precheck already priced the tape — trust it
-                    arm = "mega"
-                    self._mega_gate[(K, S)] = arm
-                if out is None:
-                    if arm == "mega":
-                        out = fn_mega(cur.params, cur.strategy_state,
-                                      ax_dev, sc_dev, rngs[r], tp_dev,
-                                      *pool_args)
+                staged_bytes += bucket_bytes
+                with self._span("launch", rounds=span_rounds,
+                                bucket=b) as span:
+                    seen = len(self.compile_log)
+                    # megabatch dispatch gate: when the server attached a
+                    # super-batch tape, pick megabatch vs per-client vmap
+                    # PER BUCKET — cached per (K, S) geometry, priced on
+                    # the compiled cost model at first sight (both arms
+                    # run once; the verdict is deterministic because cost
+                    # analyses are static)
+                    tape = getattr(batch, "mega", None)
+                    fn_mega = tp_dev = None
+                    if tape is not None and self.megabatch:
+                        tape_tree = {"ptr": tape.ptr, "seg": tape.seg}
+                        tape_packer = AxisPacker(tape_tree, lead_ndim=1)
+                        fn_mega = self._bucket_collect_fn(
+                            K, S, ax_packer, stager, tape_packer=tape_packer)
+                        tp_bufs = tape_packer.pack_np(tape_tree)
+                        # flint: disable=put-loop the tape's single int32 staged buffer for this bucket's dispatch
+                        tp_dev = jax.device_put(tp_bufs, self._client_sharding)
+                        puts += len(tp_bufs)
+                        staged_bytes += int(sum(bf.nbytes
+                                                for bf in tp_bufs.values()))
+                    fn = self._bucket_collect_fn(K, S, ax_packer, stager)
+                    arm = (self._mega_gate.get((K, S))
+                           if fn_mega is not None else "vmap")
+                    out = None
+                    if fn_mega is not None and arm is None and \
+                            self.megabatch_autotune and self.xla is not None:
+                        out_v = fn(cur.params, cur.strategy_state, ax_dev,
+                                   sc_dev, rngs[r], *pool_args)
+                        self._note_compiles(f"bucket_collect_s{S}", fn)
+                        cost_v = dict(self.xla.last_dispatch or {})
+                        out_m = fn_mega(cur.params, cur.strategy_state,
+                                        ax_dev, sc_dev, rngs[r], tp_dev,
+                                        *pool_args)
                         self._note_compiles(f"megabatch_collect_s{S}",
                                             fn_mega)
-                    else:
-                        out = fn(cur.params, cur.strategy_state, ax_dev,
-                                 sc_dev, rngs[r], *pool_args)
-                        self._note_compiles(f"bucket_collect_s{S}", fn)
-                if self.xla is not None and \
-                        self.xla.last_dispatch is not None:
-                    round_flops += float(
-                        self.xla.last_dispatch.get("flops") or 0.0)
-                    round_hbm = max(round_hbm, int(
-                        self.xla.last_dispatch.get("hbm_bytes") or 0))
+                        cost_m = dict(self.xla.last_dispatch or {})
+                        secs_v = self._roofline_secs(cost_v)
+                        secs_m = self._roofline_secs(cost_m)
+                        if secs_m <= secs_v:
+                            arm, out = "mega", out_m
+                        else:
+                            arm, out = "vmap", out_v
+                            self.push_megabatch_event({
+                                "kind": "megabatch_fallback",
+                                "reason": "aot_cost",
+                                "clients": K, "steps": S,
+                                "lanes": int(tape.lanes),
+                                "depth": int(tape.depth),
+                                "mega_secs_est": secs_m,
+                                "vmap_secs_est": secs_v,
+                            })
+                        self._mega_gate[(K, S)] = arm
+                        # the live-MFU snapshot must describe the CHOSEN arm
+                        self.xla.last_dispatch = (cost_v if arm == "vmap"
+                                                  else cost_m)
+                    elif fn_mega is not None and arm is None:
+                        # no compiled cost model in reach (telemetry.xla off
+                        # or autotune disabled): the server's analytic slots
+                        # precheck already priced the tape — trust it
+                        arm = "mega"
+                        self._mega_gate[(K, S)] = arm
+                    if out is None:
+                        if arm == "mega":
+                            out = fn_mega(cur.params, cur.strategy_state,
+                                          ax_dev, sc_dev, rngs[r], tp_dev,
+                                          *pool_args)
+                            self._note_compiles(f"megabatch_collect_s{S}",
+                                                fn_mega)
+                        else:
+                            out = fn(cur.params, cur.strategy_state, ax_dev,
+                                     sc_dev, rngs[r], *pool_args)
+                            self._note_compiles(f"bucket_collect_s{S}", fn)
+                    if self.xla is not None and \
+                            self.xla.last_dispatch is not None:
+                        round_flops += float(
+                            self.xla.last_dispatch.get("flops") or 0.0)
+                        round_hbm = max(round_hbm, int(
+                            self.xla.last_dispatch.get("hbm_bytes") or 0))
+                    if span is not None:
+                        span["compiled"] = len(self.compile_log) > seen
                 outs.append(out)
-            params, opt_state, strategy_state, vecs = finalize(
-                cur.params, cur.opt_state, cur.strategy_state,
-                tuple(outs), jnp.asarray(server_lrs[r], jnp.float32),
-                rngs[r])
-            self._note_compiles("bucket_finalize", finalize)
+            with self._span("launch", rounds=0, finalize=True) as span:
+                seen = len(self.compile_log)
+                params, opt_state, strategy_state, vecs = finalize(
+                    cur.params, cur.opt_state, cur.strategy_state,
+                    tuple(outs), jnp.asarray(server_lrs[r], jnp.float32),
+                    rngs[r])
+                self._note_compiles("bucket_finalize", finalize)
+                if span is not None:
+                    span["compiled"] = len(self.compile_log) > seen
             if self.xla is not None and \
                     self.xla.last_dispatch is not None:
                 round_flops += float(
@@ -2937,7 +3031,7 @@ class RoundEngine:
         from ..data.batching import ceil_div
         self.last_dispatch_puts = ceil_div(puts, R)
         self.last_staged_bytes = int(staged_bytes // R)
-        return cur, BucketedStats(per_round)
+        return cur, BucketedStats(per_round, span=self.span_factory)
 
     def run_rounds(self, state: ServerState, batches: list,
                    client_lrs: list, server_lrs: list,

@@ -388,6 +388,22 @@ class OptimizationServer:
         # packed stats, wall clocks), so strict transfer mode and the
         # one-fetch-per-round guard hold unchanged.
         self.scope = make_telemetry(sc.get("telemetry"), model_dir)
+        # flag-gated profiling (reference server/client do_profiling flags,
+        # core/schema.py:84,233) — emits a TensorBoard-readable XLA trace.
+        # An alias of telemetry.profile_rounds: the one RoundProfiler
+        # is given the second chunk's rounds (or the only chunk's) when
+        # the loop starts; without a telemetry scope the server keeps a
+        # bare one, writing to <model_dir>/profile as it always did
+        self._do_profiling = bool(
+            sc.get("do_profiling", False) or
+            config.client_config.get("do_profiling", False))
+        self._profiler = None
+        if self.scope is not None:
+            self._profiler = self.scope.profiler
+        elif self._do_profiling:
+            from ..telemetry.profiling import RoundProfiler
+            self._profiler = RoundProfiler(
+                None, os.path.join(model_dir, "profile"))
         #: (device_kind, peak_flops) of the mesh's chip — the live-MFU
         #: denominator, resolved once (utils/compat.py chip table, CPU
         #: nominal fallback); None when the device-truth layer is off
@@ -395,7 +411,14 @@ class OptimizationServer:
         if self.engine.xla is not None:
             from ..utils.compat import chip_peak_flops
             self._chip = chip_peak_flops(next(iter(self.mesh.devices.flat)))
+        #: whether host spans are recorded: the non-blocking readiness
+        #: probes (ring / inflight / ready_at_start) are asked only then
+        self._tracing = self.scope is not None and \
+            self.scope.tracer is not None
         if self.scope is not None:
+            # what a dispatch and a stats fetch are made of: the engine
+            # opens its child spans through the scope
+            self.engine.span_factory = self.scope.span
             self.ckpt.telemetry = self.scope
             self.scope.watchdog.on_mark = self._watchdog_mark
             # flight-record context (ISSUE 13): the persisted forensic
@@ -649,12 +672,6 @@ class OptimizationServer:
         self.quant_thresh = cc.get("quant_thresh") or             config.model_config.get("quant_threshold")
         self.quant_anneal = float(cc.get("quant_anneal", 1.0) or 1.0)
 
-        # flag-gated profiling (reference server/client do_profiling flags,
-        # core/schema.py:84,233) — emits a TensorBoard-readable XLA trace
-        self._profile_dir = None
-        self._chunks_run = 0
-        if sc.get("do_profiling", False) or cc.get("do_profiling", False):
-            self._profile_dir = os.path.join(model_dir, "profile")
 
         self._eval_fn = build_eval_fn(task, self.mesh,
                                       self.engine.partition_mode)
@@ -1234,11 +1251,13 @@ class OptimizationServer:
                         self.scope.rollup.flush_window(partial=True)
                     except Exception:
                         pass
+            if self._profiler is not None:
+                # an aborted run leaves no profiler window open
+                self._profiler.finish()
             if self.scope is not None:
                 # the trace of an ABORTED run is exactly the trace the
-                # operator needs; close any open profiler window and
-                # materialize trace.json whatever path exited the loop
-                self.scope.profiler.finish()
+                # operator needs: materialize trace.json whatever path
+                # exited the loop
                 try:
                     # compile/recompile events buffered after the last
                     # drain (e.g. an eval compile) land in the streams,
@@ -1285,10 +1304,6 @@ class OptimizationServer:
             # fusing rounds would cut the replay cadence
             print_rank("server replay forces rounds_per_step=1")
             rounds_per_step = 1
-        # which chunk to profile: the second (post-compile) when there will
-        # be more than one, else the only one
-        profile_chunk = (0 if max_iteration - self.state.round <=
-                         rounds_per_step else 1)
 
         def chunk_R(r0: int) -> int:
             until_val = (val_freq - (r0 % val_freq)
@@ -1298,8 +1313,24 @@ class OptimizationServer:
             return min(rounds_per_step, max_iteration - r0,
                        until_val, until_rec)
 
-        def pack_chunk(R: int) -> list:
-            with self._tspan("pack", rounds=R):
+        if self._do_profiling and self._profiler.window is None and \
+                self.state.round < max_iteration:
+            # do_profiling = profile_rounds over the second chunk
+            # (post-compile) when there will be more than one, else over
+            # the only one
+            lo = self.state.round
+            if max_iteration - lo > rounds_per_step:
+                lo += chunk_R(lo)
+            self._profiler.window = (lo, lo + chunk_R(lo))
+
+        def device_fence() -> None:
+            # the profiler stops only once the window's last chunk has
+            # run (the newest state is the last program's output)
+            jax.block_until_ready(self.state.params)
+
+        def pack_chunk(R: int, round0: int) -> list:
+            # with look-ahead packing this is the NEXT chunk's round0
+            with self._tspan("pack", rounds=R, chunk=round0):
                 return _pack_chunk_inner(R)
 
         def _pack_chunk_inner(R: int) -> list:
@@ -1398,12 +1429,14 @@ class OptimizationServer:
                 break
             tic = time.time()
             R = chunk_R(round_no)
-            if self.scope is not None:
-                # opt-in jax.profiler window (telemetry.profile_rounds):
-                # chunk boundaries are the only safe start/stop points;
-                # the chunk's round RANGE decides, so a window inside a
-                # fused chunk still captures (the whole chunk)
-                self.scope.profiler.observe(round_no, rounds=R)
+            if self._profiler is not None:
+                # opt-in jax.profiler window (telemetry.profile_rounds,
+                # or do_profiling): chunk boundaries are the only safe
+                # start/stop points; the chunk's round RANGE decides, so
+                # a window inside a fused chunk still captures (the
+                # whole chunk)
+                self._profiler.observe(round_no, rounds=R,
+                                       fence=device_fence)
 
             # host-orchestrated per-round paths (RL re-weighting, SCAFFOLD
             # controls) share the normal round bookkeeping tail
@@ -1431,16 +1464,11 @@ class OptimizationServer:
             if prefetched is not None and prefetched[0] == R:
                 batches = prefetched[1]
             else:
-                batches = pack_chunk(R)
+                batches = pack_chunk(R, round_no)
             prefetched = None
             self._record_staged_bytes(batches, R)
 
             chunk_rng = self._next_rng()
-            # flag-gated profiling (reference cProfile hooks, SURVEY §5.1)
-            profile_this = (self._profile_dir is not None and
-                            self._chunks_run == profile_chunk)
-            if profile_this:
-                jax.profiler.start_trace(self._profile_dir)
             quant_thresholds = None
             if self.quant_thresh is not None:
                 # per-round annealed thresholds (core/server.py:294-298),
@@ -1459,7 +1487,12 @@ class OptimizationServer:
                 # stream order, ahead of the donating program (only the
                 # newest ring entry can still be unsaved)
                 if not ch["latest_saved"]:
-                    self.ckpt.save_latest(ch["state"])
+                    # single-slot writer: this waits for the save still
+                    # in flight, so it is a span of its own
+                    with self._tspan("ckpt_presubmit",
+                                     round=ch["round0"] + ch["R"],
+                                     chunk=ch["round0"]):
+                        self.ckpt.save_latest(ch["state"])
                     ch["latest_saved"] = True
             if self.fleet_pager is not None:
                 # fleet paging: map the chunk's cohorts onto pool slots
@@ -1468,7 +1501,7 @@ class OptimizationServer:
                 # and before this dispatch) — batches gain their
                 # carry_slots vectors here
                 with self._tspan("fleet_page", round0=round_no,
-                                 rounds=R):
+                                 rounds=R, chunk=round_no):
                     new_sstate = self.fleet_pager.prepare_chunk(
                         batches, self.state.strategy_state)
                     if new_sstate is not self.state.strategy_state:
@@ -1533,9 +1566,20 @@ class OptimizationServer:
             # exists exactly for this overlap (round k's window stays
             # open while the host packs/dispatches k+1)
             device_span = (self.scope.begin("round_device",
-                                            round0=round_no, rounds=R)
+                                            round0=round_no, rounds=R,
+                                            chunk=round_no)
                            if self.scope is not None else None)
-            with self._tspan("dispatch", round0=round_no, rounds=R):
+            # read BEFORE any work, and only when spans are recorded:
+            # how many chunks the ring holds and how many of them the
+            # device has not finished (a question, not a fence) — 0
+            # in flight means the device had nothing left to run when
+            # the host began preparing this chunk
+            probe = ({"ring": len(pending),
+                      "inflight": sum(not ch["stats"].is_ready()
+                                      for ch in pending)}
+                     if self._tracing else {})
+            with self._tspan("dispatch", round0=round_no, rounds=R,
+                             chunk=round_no, **probe):
                 if self.cohort_bucketing is not None:
                     self.state, packed = \
                         self.engine.dispatch_bucketed_rounds(
@@ -1594,18 +1638,13 @@ class OptimizationServer:
             # executes this one (reading the stats below is what blocks)
             if lookahead_pack and round_no + R < max_iteration:
                 next_R = chunk_R(round_no + R)
-                prefetched = (next_R, pack_chunk(next_R))
+                prefetched = (next_R, pack_chunk(next_R, round_no + R))
                 if fleet_prefetch:
                     # hand the packed cohort to the fleet-prefetch
                     # worker: missing carry rows stage off-thread while
                     # the device executes, so the next prepare_chunk's
                     # page-in assembly is a staging-buffer copy
                     self.fleet_pager.prefetch_chunk(prefetched[1])
-            if profile_this:
-                jax.block_until_ready(self.state.params)
-                jax.profiler.stop_trace()
-                print_rank(f"wrote profiler trace to {self._profile_dir}")
-            self._chunks_run += 1
             round_no += R
 
             while len(pending) >= self.pipeline_depth and pending:
@@ -1642,7 +1681,7 @@ class OptimizationServer:
             # are exactly what a trace reader needs to see.
             ch = pending.popleft()
             with self._tspan("preempt_drain", round0=ch["round0"],
-                             rounds=ch["R"]):
+                             rounds=ch["R"], chunk=ch["round0"]):
                 self._drain_chunk(ch, val_freq, rec_freq)
             self.pipelined_chunks += 1
         self.ckpt.wait()  # async checkpoint saves must be durable on return
@@ -1670,11 +1709,13 @@ class OptimizationServer:
             self.ckpt.update_status({"preempted": None})
         self._log_timing()
         flush_metrics()
+        if self._profiler is not None:
+            # a window still open (the run ended inside it) stops here
+            self._profiler.finish(fence=device_fence)
         if self.scope is not None:
-            # close any open profiler window and make trace.json
-            # complete/loadable; the tracer stays open so a later
-            # train() on the same server appends to the same trace
-            self.scope.profiler.finish()
+            # make trace.json complete/loadable; the tracer stays open
+            # so a later train() on the same server appends to the same
+            # trace
             self.scope.flush()
         return self.state
 
@@ -1700,7 +1741,16 @@ class OptimizationServer:
         way, which the pipeline equivalence tests pin)."""
         R = chunk["R"]
         round0 = chunk["round0"]
-        with self._tspan("stats_fetch", round0=round0, rounds=R):
+        with self._tspan("stats_fetch", round0=round0, rounds=R,
+                         chunk=round0) as span:
+            if span is not None:
+                # traced runs split the fence from the transfer: was
+                # the chunk already done (a question), the wait until it
+                # is, then the device_get + unpack (`stats_d2h`, opened
+                # inside fetch).  The untraced path is the one call.
+                span["ready_at_start"] = chunk["stats"].is_ready()
+                with self._tspan("fence_wait", rounds=R):
+                    chunk["stats"].wait()
             stats = chunk["stats"].fetch()
         if self.scope is not None:
             # the fetch is the honest end-of-chunk fence: the device
@@ -1722,10 +1772,11 @@ class OptimizationServer:
             # Runs BEFORE the host tail so housekeeping/eval at this
             # boundary read current rows.
             with self._tspan("fleet_writeback", round0=round0,
-                             rounds=R):
+                             rounds=R, chunk=round0):
                 self.fleet_pager.complete_writeback(chunk["fleet_wb"])
 
-        with self._tspan("host_tail", round0=round0, rounds=R):
+        with self._tspan("host_tail", round0=round0, rounds=R,
+                         chunk=round0):
             self._drain_host_tail(chunk, stats, val_freq, rec_freq)
         self.run_stats["secsPerRoundHostTail"].append(
             (time.time() - toc) / R)
@@ -2913,6 +2964,11 @@ class OptimizationServer:
         later eval's ``device_put`` on the already-placed arrays is a
         no-op (the RL path evaluates twice per round, and on a remote-
         attached chip the re-transfer would otherwise dominate eval)."""
+        with self._tspan("eval_pack", split=split,
+                         cached=split in self._eval_batches_cache):
+            return self._packed_eval_batches_inner(split)
+
+    def _packed_eval_batches_inner(self, split: str):
         batches = self._eval_batches_cache.get(split)
         if batches is None:
             from jax.sharding import NamedSharding, PartitionSpec as P

@@ -9,7 +9,9 @@ with a measured-zero-overhead fast path — see docs/observability.md):
   ride the EXISTING flatpack packed-stats single transfer (zero new
   ``device_get``s);
 - :mod:`.profiling` — opt-in ``jax.profiler`` capture for a configured
-  round window;
+  round window, with a clock mark shared with ``events.jsonl``;
+- :mod:`.compiles` — ``jax.monitoring``'s trace / lower / compile
+  durations as ``jit_trace`` / ``jit_lower`` / ``compile`` spans;
 - :mod:`.watchdog` — NaN-loss / round-time-regression /
   checkpoint-failure-streak detectors with log/mark/abort actions, plus
   the longitudinal tier (stall / rss_leak / throughput_drift);
@@ -43,7 +45,8 @@ __all__ = [
     "DeviceMetricBus", "NULL_SPAN", "SpanToken",
     "Stopwatch", "Telemetry", "Tracer", "Watchdog", "WatchdogAbort",
     "devbus_config_enabled", "emit_event", "make_telemetry",
-    "scalar_time", "telemetry_config_enabled", "xla_config_enabled",
+    "scalar_time", "telemetry_config_enabled", "trace_config_enabled",
+    "xla_config_enabled",
 ]
 
 #: subdirectory of the model dir holding trace.json/events.jsonl/profiles
@@ -57,6 +60,14 @@ def telemetry_config_enabled(raw: Optional[Dict[str, Any]]) -> bool:
     """Whether a raw ``server_config.telemetry`` block turns the
     subsystem on (absent or ``enable: false`` => off)."""
     return bool(raw) and bool(dict(raw).get("enable", True))
+
+
+def trace_config_enabled(raw: Optional[Dict[str, Any]]) -> bool:
+    """Whether the span tracer is on for this config: what the CLI asks
+    before it registers the compile listeners (``compiles.install``),
+    so that a telemetry-off run registers none."""
+    return telemetry_config_enabled(raw) and \
+        bool(dict(raw).get("trace", True))
 
 
 def devbus_config_enabled(raw: Optional[Dict[str, Any]]) -> bool:
@@ -126,11 +137,22 @@ class Telemetry:
         # lazy import: profiling reaches for jax only when a capture
         # window is configured and actually starts
         from .profiling import RoundProfiler
-        self.profiler = RoundProfiler(self.raw.get("profile_rounds"),
-                                      self.out_dir)
+        self.profiler = RoundProfiler(
+            self.raw.get("profile_rounds"),
+            os.path.join(self.out_dir, "xla_profile"), tracer=self.tracer)
+        # jax's trace / lower / compile durations become spans: what the
+        # CLI's early install() buffered (engine construction,
+        # init_state) is handed over now
+        self.compiles = None
+        if self.tracer is not None:
+            from . import compiles
+            self.compiles = compiles.install()
+            self.compiles.attach(self.tracer)
 
     # -- spans ----------------------------------------------------------
     def span(self, name: str, **args: Any):
+        """Context manager; yields the span's args (a dict the block may
+        add to) or None when the trace is off."""
         inner = (self.tracer.span(name, **args)
                  if self.tracer is not None else NULL_SPAN)
         if self.rollup is None:
@@ -144,10 +166,17 @@ class Telemetry:
     def _rollup_span(self, name: str, inner):
         t0 = time.perf_counter()
         try:
-            with inner:
-                yield
+            with inner as span_args:
+                yield span_args
         finally:
             self.rollup.observe_phase(name, time.perf_counter() - t0)
+
+    def emit_spans(self, spans) -> None:
+        """``(name, start, end)`` epoch times of phases that were over
+        before this scope existed (the CLI's set-up)."""
+        if self.tracer is not None:
+            for name, t0, t1 in spans:
+                self.tracer.emit_span(name, t0, t1)
 
     def begin(self, name: str, **args: Any) -> Optional[SpanToken]:
         if self.tracer is not None:
@@ -295,6 +324,8 @@ class Telemetry:
 
     def close(self) -> None:
         self.profiler.finish()
+        if self.compiles is not None:
+            self.compiles.detach(self.tracer)
         self.watchdog.stop_stall_monitor()
         if self.rollup is not None:
             self.rollup.close()

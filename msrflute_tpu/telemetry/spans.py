@@ -24,6 +24,18 @@ Two span APIs, because the pipelined loop needs both:
   them (round k's device window stays open across the host's dispatch of
   k+1).  These land on virtual "in flight" tracks so overlapping spans
   never nest wrongly in a viewer.
+- ``tracer.emit_span("compile", t0, t1, fun_name=...)`` — a span that is
+  already over, with the epoch times its observer took (set-up phases
+  noted before the tracer existed, ``jax.monitoring`` durations).
+
+What an ``events.jsonl`` span record carries besides ``name``, ``ts``
+(epoch seconds), ``dur_s`` and its args: ``sid`` (its own id),
+``parent`` (the ``sid`` of the innermost span open on the same thread
+when it began — for a begin/end span, on the opener's thread — else
+null) and ``thread`` (the thread's name; the virtual track's for a
+begin/end span).  A span without a ``chunk`` arg inherits its parent's,
+so everything done for one dispatched chunk shares ``chunk=<round0>``.
+Self time = ``dur_s`` minus the union of the children's intervals.
 
 Hard constraints (the zero-cost / zero-transfer contract, pinned by
 ``tests/test_telemetry_contract.py``):
@@ -54,18 +66,30 @@ NULL_SPAN = contextlib.nullcontext()
 _FLIGHT_TID_BASE = 1_000_000
 
 
+def _flight_track(slot: int) -> str:
+    """A virtual track's name: its ``thread_name`` row in the trace and
+    the ``thread`` of the begin/end spans placed on it."""
+    return f"rounds in flight (slot {slot})"
+
+
 class SpanToken:
     """Handle for an explicit begin/end span (see :meth:`Tracer.begin`)."""
 
-    __slots__ = ("name", "args", "t0_us", "tid", "done")
+    __slots__ = ("name", "args", "t0_us", "tid", "done", "sid", "parent",
+                 "thread")
 
     def __init__(self, name: str, args: Dict[str, Any], t0_us: float,
-                 tid: int):
+                 tid: int, sid: Optional[int] = None,
+                 parent: Optional[int] = None,
+                 thread: Optional[str] = None):
         self.name = name
         self.args = args
         self.t0_us = t0_us
         self.tid = tid
         self.done = False
+        self.sid = sid
+        self.parent = parent
+        self.thread = thread
 
 
 class Tracer:
@@ -100,6 +124,10 @@ class Tracer:
         self._epoch0 = time.time()
         self._pid = os.getpid()
         self._named_threads: set = set()
+        self._next_sid = 0
+        #: per thread: the (sid, chunk) of every span open on it,
+        #: outermost first — what ``parent`` and ``chunk`` are read from
+        self._open = threading.local()
         self._free_slots: List[int] = []
         self._next_slot = 0
         self._jsonl_fh = open(self.events_path, "a", encoding="utf-8")
@@ -138,6 +166,23 @@ class Tracer:
                 "args": {"name": threading.current_thread().name}})
         return ident
 
+    def _stack(self) -> list:
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        return stack
+
+    def _link(self, args: Dict[str, Any]) -> tuple:
+        """``(sid, parent)`` of a span opening now on this thread; the
+        parent's ``chunk`` is handed down into ``args``.  Caller holds
+        the lock."""
+        self._next_sid += 1
+        stack = self._stack()
+        parent, chunk = stack[-1] if stack else (None, None)
+        if chunk is not None:
+            args.setdefault("chunk", chunk)
+        return self._next_sid, parent
+
     def _alloc_flight_tid(self) -> int:
         if self._free_slots:
             return _FLIGHT_TID_BASE + self._free_slots.pop()
@@ -146,7 +191,7 @@ class Tracer:
         tid = _FLIGHT_TID_BASE + slot
         self._events.append({
             "name": "thread_name", "ph": "M", "pid": self._pid,
-            "tid": tid, "args": {"name": f"rounds in flight (slot {slot})"}})
+            "tid": tid, "args": {"name": _flight_track(slot)}})
         return tid
 
     # -- emission -------------------------------------------------------
@@ -167,7 +212,8 @@ class Tracer:
         self._events.append(event)
 
     def _emit_complete(self, name: str, t0_us: float, dur_us: float,
-                       args: Dict[str, Any], tid: int) -> None:
+                       args: Dict[str, Any], tid: int, sid: int,
+                       parent: Optional[int], thread: str) -> None:
         with self._lock:
             self._append_trace({
                 "name": name, "ph": "X", "ts": round(t0_us, 1),
@@ -176,26 +222,38 @@ class Tracer:
             # flint: disable=event-schema events.jsonl record-type tag, not a telemetry event name
             self._jsonl({"kind": "span", "name": name,
                          "ts": round(self._epoch_of(t0_us), 6),
-                         "dur_s": round(dur_us / 1e6, 6), **args})
+                         "dur_s": round(dur_us / 1e6, 6), "sid": sid,
+                         "parent": parent, "thread": thread, **args})
 
     # -- public span API ------------------------------------------------
     @contextlib.contextmanager
     def span(self, name: str, **args: Any):
-        """Context-managed span on the calling thread's track."""
+        """Context-managed span on the calling thread's track.  Yields
+        its args: what is known only once the work is done (bytes
+        staged, whether a compile happened) is written into them inside
+        the block."""
         with self._lock:
             tid = self._thread_tid()
+            sid, parent = self._link(args)
+        stack = self._stack()
+        stack.append((sid, args.get("chunk")))
         t0 = self._now_us()
         try:
-            yield
+            yield args
         finally:
-            self._emit_complete(name, t0, self._now_us() - t0, args, tid)
+            stack.pop()
+            self._emit_complete(name, t0, self._now_us() - t0, args, tid,
+                                sid, parent,
+                                threading.current_thread().name)
 
     def begin(self, name: str, **args: Any) -> SpanToken:
         """Open a span that another code path will :meth:`end` — the
         pipelined-overlap case, placed on a virtual in-flight track."""
         with self._lock:
             tid = self._alloc_flight_tid()
-        return SpanToken(name, args, self._now_us(), tid)
+            sid, parent = self._link(args)
+        return SpanToken(name, args, self._now_us(), tid, sid, parent,
+                         _flight_track(tid - _FLIGHT_TID_BASE))
 
     def end(self, token: Optional[SpanToken]) -> None:
         if token is None or token.done:
@@ -203,9 +261,25 @@ class Tracer:
         token.done = True
         self._emit_complete(token.name, token.t0_us,
                             self._now_us() - token.t0_us, token.args,
-                            token.tid)
+                            token.tid, token.sid, token.parent,
+                            token.thread)
         with self._lock:
             self._free_slots.append(token.tid - _FLIGHT_TID_BASE)
+
+    def emit_span(self, name: str, t0_epoch: float, t1_epoch: float,
+                  thread: Optional[str] = None, **args: Any) -> None:
+        """A span that is already over, with the epoch times its
+        observer took.  Its parent is whatever is open on the calling
+        thread now (a compile reported at its end lies inside the
+        ``launch`` that caused it); ``thread`` names the thread it ran
+        on where that is not the caller's."""
+        with self._lock:
+            tid = self._thread_tid()
+            sid, parent = self._link(args)
+        self._emit_complete(
+            name, (t0_epoch - self._epoch0) * 1e6,
+            (t1_epoch - t0_epoch) * 1e6, args, tid, sid, parent,
+            thread or threading.current_thread().name)
 
     def instant(self, name: str, **args: Any) -> None:
         """One structured instant event (chaos fault, checkpoint

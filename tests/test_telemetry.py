@@ -224,7 +224,7 @@ def test_round_profiler_degrades_gracefully(monkeypatch, tmp_path):
 
     from msrflute_tpu.telemetry.profiling import RoundProfiler
 
-    def busy(log_dir):
+    def busy(log_dir, **kwargs):
         raise RuntimeError("Profile has already been started.")
 
     monkeypatch.setattr(jax.profiler, "start_trace", busy)
@@ -248,7 +248,7 @@ def test_round_profiler_window_inside_fused_chunk_still_fires(
 
     calls = []
     monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: calls.append("start"))
+                        lambda d, **kw: calls.append("start"))
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: calls.append("stop"))
     prof = RoundProfiler(5, str(tmp_path))
@@ -265,7 +265,7 @@ def test_round_profiler_window_drives_start_stop(monkeypatch, tmp_path):
 
     calls = []
     monkeypatch.setattr(jax.profiler, "start_trace",
-                        lambda d: calls.append(("start", d)))
+                        lambda d, **kw: calls.append(("start", d)))
     monkeypatch.setattr(jax.profiler, "stop_trace",
                         lambda: calls.append(("stop",)))
     prof = RoundProfiler("2:4", str(tmp_path))

@@ -94,9 +94,26 @@ def test_telemetry_off_constructs_no_telemetry_state(tmp_path,
     # telemetry off constructs no rollup engine and no flight recorder
     monkeypatch.setattr(tel.rollup, "RollupEngine", bomb)
     monkeypatch.setattr(tel.rollup, "FlightRecorder", bomb)
+    # ISSUE 24: no compile listener is registered, no readiness probe
+    # is asked (is_ready / wait exist for the traced path alone), no
+    # profiler object exists, and the engine holds no span factory
+    import msrflute_tpu.telemetry.compiles as compiles
+    import msrflute_tpu.telemetry.profiling as profiling
+    from msrflute_tpu.engine import round as round_mod
+    monkeypatch.setattr(compiles, "install", bomb)
+    monkeypatch.setattr(compiles, "CompileSpans", bomb)
+    monkeypatch.setattr(profiling, "RoundProfiler", bomb)
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener", bomb)
+    monkeypatch.setattr(jax.monitoring, "register_event_listener", bomb)
+    monkeypatch.setattr(round_mod.PackedStats, "is_ready", bomb)
+    monkeypatch.setattr(round_mod.PackedStats, "wait", bomb)
+    import jax._src.array as jarray
+    monkeypatch.setattr(jarray.ArrayImpl, "is_ready", bomb)
     server, state = _run(_cfg(pipeline_depth=1), tmp_path)
     assert state.round == 6
     assert server.scope is None
+    assert server.engine.span_factory is None and server._profiler is None
     assert not server.engine.devbus.enabled
     assert server.engine.xla is None
     # no scorecard either — nothing to regress-gate without telemetry
