@@ -25,6 +25,8 @@ import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 from flax import serialization
 
 from ..resilience.integrity import (CheckpointCorruptionError,
@@ -67,6 +69,15 @@ def _merge(template: ServerState, restored: dict) -> ServerState:
         strategy_state=merged["strategy_state"],
         round=int(restored.get("round", 0)),
     )
+
+
+@jax.jit
+def _copy_device_leaves(leaves: list) -> list:
+    """Fresh buffers for every device leaf, as ONE device program.  The
+    body asks for the copies: ``jax.jit`` may hand an output that is
+    just its input back as the input's own buffer, the very one the
+    next round step donates.  Each copy keeps its leaf's sharding."""
+    return [jnp.copy(x) for x in leaves]
 
 
 def _state_to_bytes(state: ServerState) -> bytes:
@@ -361,6 +372,13 @@ class CheckpointManager:
                 # stalling) device rounds
                 with (self.telemetry.span("ckpt_async_write")
                       if self.telemetry is not None else NULL_SPAN):
+                    # wait for the snapshot program BEFORE asking for its
+                    # transfers: a device_get on arrays still to be
+                    # computed queues its device-to-host copies to fire at
+                    # the round program's end, where they can get ahead of
+                    # the training thread's stats fetch that the same end
+                    # releases (the fence was then seen ~2 ms late)
+                    jax.block_until_ready(snap)
                     blob = serialization.msgpack_serialize(
                         serialization.to_state_dict(jax.device_get(snap)))
                     del snap  # release the HBM snapshot before the write
@@ -382,7 +400,10 @@ class CheckpointManager:
                     self._mp_busy = False
                     self._mp_cond.notify_all()
 
-    def _mp_submit(self, state: ServerState) -> None:
+    def _mp_submit(self, state: ServerState) -> Dict[str, int]:
+        """Hand a snapshot of ``state`` to the writer thread; returns
+        what the snapshot launched on the device (``leaves`` copied,
+        ``programs`` dispatched) for the caller's span."""
         # single-slot, not latest-wins: wait for the in-flight save first,
         # so the on-disk latest can lag the status log by AT MOST the one
         # in-flight round — the same durability window the orbax path
@@ -399,25 +420,35 @@ class CheckpointManager:
                 self._mp_cond.wait()
         # device-side copy: the round step donates the live param/opt
         # buffers, so the snapshot must be arrays nothing else consumes.
-        # The copies are enqueued on the device stream BEFORE any later
-        # donating program, so they read the pre-donation values; the
-        # writer thread's device_get then overlaps the next rounds.
+        # ONE program copies every device leaf (a copy per leaf ran into
+        # the runtime's bound on programs in flight on a 62-leaf model:
+        # the submit then sat until the running round program retired).
+        # It is enqueued on the device stream BEFORE any later donating
+        # program, so it reads the pre-donation values; the writer
+        # thread's device_get then overlaps the next rounds.
         # Host numpy leaves (e.g. mutable strategy_state arrays) are
         # np.copy'd for the same reason: a by-reference share would let
         # an in-place mutation on the training thread reach the writer's
         # serialize mid-flight and persist a torn value.
-        import jax.numpy as jnp
-        import numpy as _np
-        snap = jax.tree.map(
-            lambda x: jnp.copy(x) if isinstance(x, jax.Array)
-            else (_np.copy(x) if isinstance(x, _np.ndarray) else x),
-            _payload(state))
+        leaves, treedef = jax.tree.flatten(_payload(state))
+        on_device = [i for i, x in enumerate(leaves)
+                     if isinstance(x, jax.Array)]
+        if on_device:
+            copies = _copy_device_leaves([leaves[i] for i in on_device])
+            for i, copied in zip(on_device, copies):
+                leaves[i] = copied
+        snap = jax.tree.unflatten(
+            treedef, [np.copy(x) if isinstance(x, np.ndarray) else x
+                      for x in leaves])
         with self._mp_cond:
             self._mp_mailbox = snap
             self._mp_cond.notify()
+        return {"leaves": len(on_device), "programs": int(bool(on_device))}
 
     # -- save ----------------------------------------------------------
-    def save_latest(self, state: ServerState) -> None:
+    def save_latest(self, state: ServerState) -> Optional[Dict[str, int]]:
+        """Save ``latest``; the async msgpack path returns what its
+        device snapshot launched (see :meth:`_mp_submit`)."""
         if self.backend == "orbax":
             self._commit_pending_latest()
             committed = self._latest_slot()
@@ -426,12 +457,12 @@ class CheckpointManager:
                     else self._LATEST_SLOTS[0])
             self._orbax_save(self._orbax_path(slot), state)
             self._pending_slot = slot
-            return
+            return None
         if self.async_latest:
-            self._mp_submit(state)
-            return
+            return self._mp_submit(state)
         self._write(os.path.join(self.model_dir, LATEST), state,
                     keep_prev=True)
+        return None
 
     def backup(self, state: ServerState, round_no: int,
                best_names: Tuple[str, ...] = ()) -> None:

@@ -1488,11 +1488,17 @@ class OptimizationServer:
                 # newest ring entry can still be unsaved)
                 if not ch["latest_saved"]:
                     # single-slot writer: this waits for the save still
-                    # in flight, so it is a span of its own
+                    # in flight, so it is a span of its own.  Nothing in
+                    # it may wait for the chunk's own program: the host
+                    # staging of the next chunk, below, is what the ring
+                    # hides behind that program.
                     with self._tspan("ckpt_presubmit",
                                      round=ch["round0"] + ch["R"],
-                                     chunk=ch["round0"]):
-                        self.ckpt.save_latest(ch["state"])
+                                     rounds=ch["R"],
+                                     chunk=ch["round0"]) as span:
+                        launched = self.ckpt.save_latest(ch["state"])
+                        if span is not None and launched:
+                            span.update(launched)
                     ch["latest_saved"] = True
             if self.fleet_pager is not None:
                 # fleet paging: map the chunk's cohorts onto pool slots

@@ -14,14 +14,17 @@ to serial automatically.
 import json
 import os
 
+import flax.linen as nn
 import jax
 import numpy as np
+import pytest
 from flax import serialization
 from jax.flatten_util import ravel_pytree
 
 from msrflute_tpu.config import FLUTEConfig
 from msrflute_tpu.engine import OptimizationServer
 from msrflute_tpu.models import make_task
+from msrflute_tpu.models.cv import ClassificationTask
 from msrflute_tpu.utils.logging import init_logging
 
 
@@ -69,12 +72,37 @@ def _val_ds():
     return ArraysDataset(users, per)
 
 
-def _run(depth, synth_dataset, root):
-    model_dir = os.path.join(root, f"models_d{depth}")
-    log_dir = os.path.join(root, f"log_d{depth}")
+class _DeepMLP(nn.Module):
+    """33 small Dense layers = 66 parameter leaves: more device leaves
+    than a runtime lets programs be in flight (32), the shape of state
+    on which a copy per leaf made the pre-dispatch snapshot wait for the
+    running round program (ISSUE 25)."""
+
+    num_classes: int = 4
+    width: int = 8
+    depth: int = 32
+
+    @nn.compact
+    def __call__(self, x, train: bool = False):
+        x = x.reshape((x.shape[0], -1))
+        for _ in range(self.depth):
+            x = x + 0.1 * nn.tanh(nn.Dense(self.width)(x))
+        return nn.Dense(self.num_classes)(x)
+
+
+def _task(model, cfg):
+    if model == "lr":
+        return make_task(cfg.model_config)
+    return ClassificationTask(_DeepMLP(), example_shape=(8,),
+                              name="deep_mlp_66_leaves", num_classes=4)
+
+
+def _run(depth, synth_dataset, root, model="lr", tag="", **server_over):
+    model_dir = os.path.join(root, f"models_d{depth}{tag}")
+    log_dir = os.path.join(root, f"log_d{depth}{tag}")
     init_logging(log_dir)
-    cfg = _cfg(depth)
-    task = make_task(cfg.model_config)
+    cfg = _cfg(depth, **server_over)
+    task = _task(model, cfg)
     server = OptimizationServer(task, cfg, synth_dataset,
                                 val_dataset=_val_ds(),
                                 model_dir=model_dir, seed=7)
@@ -100,11 +128,13 @@ def _stepped_series(records):
     return series
 
 
-def test_pipeline_bit_identical_to_serial(synth_dataset, tmp_path):
+@pytest.mark.parametrize("model", ["lr", "deep_66_leaves"])
+def test_pipeline_bit_identical_to_serial(synth_dataset, tmp_path, model):
     srv0, st0, rec0, latest0, status0 = _run(0, synth_dataset,
-                                             str(tmp_path))
+                                             str(tmp_path), model)
     srv1, st1, rec1, latest1, status1 = _run(1, synth_dataset,
-                                             str(tmp_path))
+                                             str(tmp_path), model)
+    assert len(jax.tree.leaves(st1.params)) == (2 if model == "lr" else 66)
 
     # the depth-1 run must actually have overlapped (6 of 9 chunks sit
     # strictly inside val boundaries), the depth-0 run never
@@ -140,6 +170,20 @@ def test_pipeline_bit_identical_to_serial(synth_dataset, tmp_path):
 
     # host-tail observability feeds bench.py's new output fields
     assert len(srv1.run_stats["secsPerRoundHostTail"]) == 9
+
+    # a run stopped at round 6 and resumed from the `latest` its
+    # pre-dispatch snapshots wrote continues to the same bits
+    _run(1, synth_dataset, str(tmp_path), model, tag="_resumed",
+         max_iteration=6)
+    srv2, st2, _, latest2, _ = _run(
+        1, synth_dataset, str(tmp_path), model, tag="_resumed",
+        resume_from_checkpoint=True)
+    assert st2.round == 9 and srv2.pipelined_chunks == 2
+    np.testing.assert_array_equal(
+        flat1, np.asarray(ravel_pytree(jax.device_get(st2.params))[0]))
+    for leaf1, leaf2 in zip(jax.tree.leaves(latest1),
+                            jax.tree.leaves(latest2)):
+        np.testing.assert_array_equal(np.asarray(leaf1), np.asarray(leaf2))
 
 
 def test_host_orchestrated_paths_fall_back_to_serial(synth_dataset,

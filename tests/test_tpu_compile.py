@@ -141,3 +141,33 @@ def test_kernel_branch_follows_partitioning(topology, monkeypatch,
                              sharding=NamedSharding(mesh, spec))
     compiled = jax.jit(fn).lower(x).compile()
     assert ("tpu_custom_call" in compiled.as_text()) == inside_shard_map
+
+
+def test_latest_snapshot_is_one_copy_program_on_the_four_chip_mesh(topology):
+    """The async writer's device snapshot (``checkpoint._copy_device_leaves``)
+    on the 2x2 mesh, 64 leaves at ResNet-18 widths, replicated weights and
+    a clients-sharded pool: one program, a copy for every leaf, each output
+    with its input's sharding, and no collective (a gather here would wait
+    for the other chips between two launches)."""
+    from msrflute_tpu.engine.checkpoint import _copy_device_leaves
+    mesh = Mesh(np.asarray(topology).reshape(4, 1), ("clients", "model"))
+    replicated = NamedSharding(mesh, P())
+    sharded = NamedSharding(mesh, P("clients"))
+    leaves = [jax.ShapeDtypeStruct((3, 3, 512, 512) if i % 2 else (512,),
+                                   jnp.float32, sharding=replicated)
+              for i in range(62)]
+    leaves += [jax.ShapeDtypeStruct((1024, 4096), jnp.float32,
+                                    sharding=sharded),
+               jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated)]
+    compiled = _copy_device_leaves.lower(leaves).compile()
+    text = compiled.as_text()
+    assert text.count(" copy(") + text.count("copy-start(") >= 63, \
+        "a leaf came back without a copy"
+    for collective in ("all-gather", "all-reduce", "all-to-all",
+                       "collective-permute"):
+        assert collective not in text
+    for want, got in zip(leaves, compiled.output_shardings):
+        assert got.is_equivalent_to(want.sharding, len(want.shape))
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 0, "an output aliases its input"
+    assert memory.output_size_in_bytes >= memory.argument_size_in_bytes
