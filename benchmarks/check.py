@@ -4,7 +4,7 @@
 configuration's file gives it (a number without a limit is an error, a
 limit without a number is ignored: a configuration without DP has no
 noise moments, a single-round dispatch no later rounds).  All on the
-host, in numpy float64.
+host, in numpy float64, one leaf at a time.
 
 Three parties:
 
@@ -34,24 +34,25 @@ import numpy as np
 
 
 def _leaves(tree, prefix=""):
+    """``(name, leaf)`` in sorted-key order, each leaf as it is stored."""
     if isinstance(tree, dict):
         for key in sorted(tree):
             yield from _leaves(tree[key], f"{prefix}/{key}")
     else:
-        yield prefix, np.asarray(tree, np.float64)
+        yield prefix, np.atleast_1d(np.asarray(tree))
 
 
-def _delta(before, after):
-    return [(name, a - b) for (name, a), (_, b) in
-            zip(_leaves(before), _leaves(after))]
+def _minus(before, after, out=None):
+    """``before - after`` of one leaf in float64 (the operands are cast on
+    the way in, element for element what a float64 copy of each would
+    give); ``out``: a float64 buffer to reuse."""
+    return np.subtract(before, after, out=out, dtype=np.float64)
 
 
-def _norm(leaves) -> float:
-    return float(np.sqrt(sum(np.sum(v * v) for _, v in leaves)))
-
-
-def _dot(a, b) -> float:
-    return float(sum(np.sum(x * y) for (_, x), (_, y) in zip(a, b)))
+def _sum_squares(trees) -> float:
+    """Sum over the leaves of the sum of squares, in float64."""
+    return sum(np.sum(np.multiply(leaf, leaf, dtype=np.float64))
+               for _, leaf in trees)
 
 
 def compare(*, init_params, ref_check: dict, refs_timed: list, rounds: list,
@@ -61,7 +62,15 @@ def compare(*, init_params, ref_check: dict, refs_timed: list, rounds: list,
     round 0, ``refs_timed`` = the default-precision reference's result
     for each round of the first dispatch, ``rounds`` = those rounds'
     inputs.  ``dp`` = ``{"sigma", "max_grad"}`` where the configuration
-    adds global-DP noise to the aggregate."""
+    adds global-DP noise to the aggregate.
+
+    One leaf at a time: every norm, dot product and worst-leaf gap is
+    accumulated in float64 leaf by leaf, in sorted-key order, and at most
+    two float64 copies of ONE leaf are alive at a time, never a tree (a
+    600 M-parameter tree is 4.8 GB in float64).  Only the DP branch's
+    noise residuals keep all elements at once (their mean, std and
+    kurtosis are taken over the whole vector): a configuration with
+    global DP has to be small enough for two such vectors."""
     numbers = []
     ref = ref_check
     num_clients = max(float(np.sum(rounds[0]["client_mask"] > 0)), 1.0)
@@ -75,31 +84,71 @@ def compare(*, init_params, ref_check: dict, refs_timed: list, rounds: list,
                     abs(float(check_stats["grad_norm"]) - ref_pseudo) /
                     ref_pseudo))
 
-    ref_delta = _delta(init_params, ref["new_params"])     # = lr * aggregate
-    check_delta = _delta(init_params, check_params)
-    ref_norm = _norm(ref_delta)
-    ref_agg = _norm(list(_leaves(ref["aggregate"])))
-    elements = sum(v.size for _, v in ref_delta)
+    # deltas are ``init - after`` (= lr * aggregate for one round).  Per
+    # leaf: the check program's against the `highest` reference's, then
+    # the timed dispatch's (``got``) against the default-precision
+    # reference's after as many rounds (``want``)
+    elements = sum(leaf.size for _, leaf in _leaves(init_params))
+    resid = np.empty(elements) if dp is not None else None
+    timed_resid = np.empty(elements) if dp is not None else None
+    ref_sq = diff_sq = want_sq = got_sq = got_want = 0
+    ref_leaf_norms, check_leaf_norms = [], []
+    at = 0
+    for (_, init), (_, ref_new), (_, check_new), (_, want_new), \
+            (_, got_new) in zip(
+                _leaves(init_params), _leaves(ref["new_params"]),
+                _leaves(check_params),
+                _leaves(refs_timed[-1]["new_params"]),
+                _leaves(timed_first_params)):
+        ref_delta = _minus(init, ref_new)
+        buffer = np.multiply(ref_delta, ref_delta)
+        leaf_sq = np.sum(buffer)
+        ref_sq += leaf_sq
+        ref_leaf_norms.append(float(np.sqrt(leaf_sq)))
+        check_delta = _minus(init, check_new, out=buffer)
+        diff = np.subtract(check_delta, ref_delta, out=ref_delta)
+        np.multiply(check_delta, check_delta, out=check_delta)
+        check_leaf_norms.append(float(np.sqrt(np.sum(check_delta))))
+        if dp is None:
+            np.multiply(diff, diff, out=diff)
+            diff_sq += np.sum(diff)
+        else:
+            resid[at:at + diff.size] = diff.ravel()
+
+        want = _minus(init, want_new, out=diff)
+        np.multiply(want, want, out=buffer)
+        want_sq += np.sum(buffer)
+        got = _minus(init, got_new, out=buffer)
+        if dp is not None:
+            np.subtract(got, want, out=timed_resid[at:at + got.size].reshape(
+                got.shape))
+        np.multiply(want, got, out=want)
+        got_want += np.sum(want)
+        np.multiply(got, got, out=got)
+        got_sq += np.sum(got)
+        at += init.size
+        # the next leaf's two buffers are made once these are gone
+        del ref_delta, buffer, check_delta, diff, want, got
+
+    ref_norm = float(np.sqrt(ref_sq))
+    ref_agg = float(np.sqrt(_sum_squares(_leaves(ref["aggregate"]))))
     # global DP adds N(0, noise^2) to every element of the aggregate
     noise = (float(dp["sigma"]) * float(dp["max_grad"]) / num_clients
              if dp else 0.0)
     if dp is None:
-        diff = [(n, a - b) for (n, a), (_, b) in zip(check_delta, ref_delta)]
-        numbers.append(("update_diff", _norm(diff) / ref_norm))
-        leaf_norms = [float(np.sqrt(np.sum(v * v))) for _, v in ref_delta]
-        floor = float(np.median(leaf_norms))
+        numbers.append(("update_diff",
+                        float(np.sqrt(diff_sq)) / ref_norm))
+        floor = float(np.median(ref_leaf_norms))
         numbers.append(("update_gap_worst_leaf", max(
-            abs(float(np.sqrt(np.sum(c * c))) - r) / max(r, floor)
-            for (_, c), r in zip(check_delta, leaf_norms))))
+            abs(c - r) / max(r, floor)
+            for c, r in zip(check_leaf_norms, ref_leaf_norms))))
         numbers.append(("agg_norm_gap",
                         abs(float(check_stats["agg_grad_norm"]) - ref_agg) /
                         ref_agg))
     else:
         # the program adds the noise and the reference does not: the
         # residual in units of lr * noise is N(0, 1)
-        resid = np.concatenate([
-            (a - b).ravel() for (_, a), (_, b) in
-            zip(check_delta, ref_delta)]) / (server_lr * noise)
+        resid /= server_lr * noise
         centred = resid - resid.mean()
         std = float(resid.std())
         numbers += [
@@ -111,6 +160,7 @@ def compare(*, init_params, ref_check: dict, refs_timed: list, rounds: list,
             # shows as a residual far outside the noise
             ("noise_outlier_share", float(np.mean(np.abs(resid) > 6.0))),
         ]
+        del resid, centred
 
     # -- the timed program's first dispatch against the reference --------
     ref_losses = [float(np.mean(r["train_loss"])) for r in refs_timed]
@@ -125,28 +175,27 @@ def compare(*, init_params, ref_check: dict, refs_timed: list, rounds: list,
         for c, r in zip(timed_first["client_count"], rounds))) +
         abs(len(timed_first["client_count"]) - len(rounds))))
     # round 0's aggregate as the server optimizer gets it, noise included
-    timed_agg = _norm(list(_leaves(refs_timed[0]["aggregate"])))
+    timed_agg = float(np.sqrt(_sum_squares(
+        _leaves(refs_timed[0]["aggregate"]))))
     want_agg = float(np.sqrt(timed_agg ** 2 + elements * noise ** 2))
     numbers.append(("timed_agg_norm_gap",
                     abs(float(timed_first["agg_grad_norm"][0]) - want_agg) /
                     want_agg))
     # what the whole dispatch did to the weights, against the reference
     # after as many rounds: by length and by direction, on both sides
-    want = _delta(init_params, refs_timed[-1]["new_params"])
-    got = _delta(init_params, timed_first_params)
-    want_norm = _norm(want)
+    want_norm = float(np.sqrt(want_sq))
     lr_noise = float(np.sqrt(sum(
         (float(r["server_lr"]) * noise) ** 2 for r in rounds)))
     numbers.append(("timed_update_norm_gap", abs(
-        _norm(got) / float(np.sqrt(want_norm ** 2 +
-                                   elements * lr_noise ** 2)) - 1.0)))
+        float(np.sqrt(got_sq)) / float(np.sqrt(want_norm ** 2 +
+                                               elements * lr_noise ** 2)) -
+        1.0)))
     numbers.append(("timed_update_projection_gap",
-                    abs(_dot(got, want) / want_norm ** 2 - 1.0)))
+                    abs(float(got_want) / want_norm ** 2 - 1.0)))
     if dp is not None:
-        resid = np.concatenate([(a - b).ravel() for (_, a), (_, b) in
-                                zip(got, want)]) / lr_noise
+        timed_resid /= lr_noise
         numbers.append(("timed_noise_std_gap",
-                        abs(float(resid.std()) - 1.0)))
+                        abs(float(timed_resid.std()) - 1.0)))
     return numbers
 
 

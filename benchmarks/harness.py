@@ -1,9 +1,28 @@
 """The benchmark harness: one cell, once, through the real CLI.
 
 Everything that belongs to one cell, configuration, traffic mix, layer
-metric or model reference is a FILE found by name under ``root``
-(``workloads/``, ``configs/``, ``traffic/``, ``layer_metrics/``) or
-``reference/``; no such name is written here or in ``run.py``.
+metric, data generator or model reference is a FILE found by name under
+``root`` (``workloads/``, ``configs/``, ``traffic/``, ``controls/``,
+``layer_metrics/``), and for ``generators/`` and ``reference/`` under
+``root`` first, then here; no such name is written here or in ``run.py``.
+
+A configuration's file may name (every such key is optional):
+
+- ``data.generator``: ``generators/<name>.py`` with
+  ``write_splits(data_dir, seed, spec)``, ``spec`` = the ``data`` block.
+  Absent: ``images`` = ``datagen.py``.  Shipped beside it: ``tokens``.
+- ``reference.model``: ``reference/<name>.py`` (required), the plain
+  model.  It defines ``init(rng, model_config)`` and
+  ``forward(params, x, model_config)`` and may define, over one step's
+  batch (every ``[K, S, B, ...]`` array of the packed round at ``[k, s]``
+  and ``sample_mask``):
+  ``loss(params, batch, model_config)``, absent: cross entropy of
+  ``forward(x)`` against ``y`` over the real rows;
+  ``sample_count(batch)``, the strategy's weight, absent: the real rows;
+  ``required_flops(params, batch, model_config)``, the operations a
+  step requires (what the utilisation reader counts, ``readers.py``),
+  absent: the dots of that loss's forward and backward as written
+  (``flops.py``).
 
 A run, in order (all in one process, which holds the chips):
 
@@ -73,6 +92,24 @@ def load_module(path: str):
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def find_module(root: str, sub: str, name: str):
+    """``<root>/<sub>/<name>.py``, else the benchmark's own."""
+    for base in (root, BENCH_DIR):
+        path = os.path.join(base, sub, f"{name}.py")
+        if os.path.exists(path):
+            return load_module(path)
+    raise FileNotFoundError(f"no {sub}/{name}.py under {root} or {BENCH_DIR}")
+
+
+def load_generator(root: str, data: dict):
+    """The data generator a configuration's ``data`` block names."""
+    name = data.get("generator", "images")
+    if name == "images":
+        from . import datagen
+        return datagen
+    return find_module(root, "generators", name)
 
 
 def load_cell(root: str, name: str) -> dict:
@@ -183,11 +220,11 @@ def _tree_copy(tree):
 
 
 def round_inputs(batches, client_lrs, server_lrs, quant) -> list:
-    """Copies of every round's packed input of one dispatch: what the
-    reference follows after the run."""
+    """Copies of every round's packed input of one dispatch (every array
+    of the packed batch, the two masks and the round's three numbers):
+    what the reference follows after the run."""
     return [{
-        "x": np.array(batch.arrays["x"]),
-        "y": np.array(batch.arrays["y"]),
+        **{key: np.array(value) for key, value in batch.arrays.items()},
         "sample_mask": np.array(batch.sample_mask),
         "client_mask": np.array(batch.client_mask),
         "client_lr": float(client_lrs[r]),
@@ -458,12 +495,18 @@ def end_to_end(win: dict, cell_name: str) -> dict:
                         "unit": "s"},
         "setup_s": {"value": win["setup_s"], "unit": "s"},
     }
-    # a metric that BENCHMARK.json keeps to some cells is reported there only
-    listed = read_json(os.path.join(REPO, "BENCHMARK.json"))["end_to_end"]
-    for entry in listed:
-        if cell_name not in entry.get("workloads", [cell_name]):
-            metrics.pop(entry["name"], None)
+    for name in kept_to_other_cells("end_to_end", cell_name):
+        metrics.pop(name, None)
     return metrics
+
+
+def kept_to_other_cells(group: str, cell_name: str) -> set:
+    """The metrics of ``BENCHMARK.json``'s ``group`` whose ``workloads``
+    key does not list this cell: a metric that the file keeps to some
+    cells is reported there only."""
+    listed = read_json(os.path.join(REPO, "BENCHMARK.json"))[group]
+    return {entry["name"] for entry in listed
+            if cell_name not in entry.get("workloads", [cell_name])}
 
 
 def device_report() -> dict:
@@ -499,6 +542,15 @@ def read_spans(out_dir: str) -> list:
     return spans
 
 
+def closed(result: dict, verdicts: list) -> dict:
+    """The result with every number compared beside its limit as its last
+    key, and the same as the last lines of standard error."""
+    for v in verdicts:
+        print(f"compared {v['name']} {v['value']!r} limit {v['limit']!r} "
+              f"{'ok' if v['ok'] else 'NOT OK'}", file=sys.stderr, flush=True)
+    return {**result, "compared": verdicts}
+
+
 # ----------------------------------------------------------------------
 def run_cell(name: str, seed: int, seconds: float, trace: bool,
              root: str = BENCH_DIR, control: str | None = None,
@@ -507,7 +559,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     (``run.py``) has already checked the platform and the chips.
     ``readings``: stop after the first timed dispatch and return the
     compared numbers alone (what a limit is set from), no metrics."""
-    from . import check, datagen, trace_reduce
+    from . import check, trace_reduce
 
     t_start = time.time() if t_start is None else t_start
     cell = load_cell(root, name)
@@ -521,8 +573,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     cache_dir = enable_compilation_cache()
     compiles = compile_log()
 
-    model = load_module(os.path.join(BENCH_DIR, "reference",
-                                     f"{doc['reference']['model']}.py"))
+    model = find_module(root, "reference", doc["reference"]["model"])
     weights = model.init(
         np.random.default_rng(np.random.SeedSequence([int(seed), 1])),
         cfg["model_config"])
@@ -531,7 +582,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     try:
         data_dir = os.path.join(work, "data")
         t0 = time.time()
-        datagen.write_splits(data_dir, seed, doc["data"])
+        load_generator(root, doc["data"]).write_splits(
+            data_dir, seed, doc["data"])
         say({"cell": name, "seed": int(seed), "compilation_cache": cache_dir,
              "data_gen_s": time.time() - t0, "control": control})
 
@@ -554,7 +606,9 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         fedround = load_module(os.path.join(BENCH_DIR, "reference",
                                             "fedround.py"))
         reference = dict(
-            forward=model.forward, model_config=cfg["model_config"],
+            forward=model.forward, loss=getattr(model, "loss", None),
+            sample_count=getattr(model, "sample_count", None),
+            model_config=cfg["model_config"],
             params=weights, strategy=doc["reference"]["strategy"],
             block=int(doc["reference"].get("block", 1)))
         # round 0 at `highest`, for the check program (traced under
@@ -585,11 +639,12 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
             say({"compared": v["name"], "value": v["value"],
                  "limit": v["limit"], "ok": v["ok"]})
         correct = all(v["ok"] for v in verdicts)
+
         if readings:
             say({"reference_s": reference_s,
                  "first_fence_s": run.fences[0]["ts"] - t_start})
-            return {"correct": bool(correct), "readings": True,
-                    "device": device, "compared": verdicts}
+            return closed({"correct": bool(correct), "readings": True,
+                           "device": device}, verdicts)
         say({"window_s": win["window_s"], "dispatches": win["dispatches"],
              "rounds": win["rounds"],
              "round_samples": len(win["per_round_s"]),
@@ -607,11 +662,10 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
             "failed": int(win["nonfinite_losses"] *
                           win["clients"] / max(win["rounds"], 1)),
             "device": device,
-            "compared": verdicts,
         }
         if not trace:
             result["metrics"] = end_to_end(win, name)
-            return result
+            return closed(result, verdicts)
 
         reduced = trace_reduce.reduce_profile(run.profile, spans)
         say({"trace": reduced["summary"]})
@@ -626,12 +680,15 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
             "first_inputs": run.first_rounds[0],
         }
         metrics = {}
+        elsewhere = kept_to_other_cells("per_layer", name)
         for metric, reader in load_layer_metrics(root).items():
+            if metric in elsewhere:
+                continue
             value = reader.read(ctx)
             if value is not None and math.isfinite(value):
                 metrics[metric] = {"value": float(value),
                                    "unit": reader.UNIT}
         result["metrics"] = metrics
-        return result
+        return closed(result, verdicts)
     finally:
         shutil.rmtree(work, ignore_errors=True)

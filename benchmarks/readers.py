@@ -53,13 +53,24 @@ def round_program(ctx: dict) -> tuple:
 
 
 def required_flops_per_round(ctx: dict) -> float:
-    """Forward + backward matmul/convolution operations one round needs,
-    from the shapes of the round's own input and the plain model."""
-    first = ctx["first_inputs"]
-    x, y, mask = (first[k][0, 0] for k in ("x", "y", "sample_mask"))
-    per_step = ctx["fedround"].flops_per_step(
-        ctx["model"].forward, ctx["config"]["model_config"], ctx["weights"],
-        x, y.astype("int32"), mask.astype("float32"), matmul_flops)
+    """Forward + backward matmul/convolution operations one round needs:
+    the dots of the step's loss (the model reference's own, else
+    classification: ``fedround.seam``) on the first step's whole batch
+    and the plain model, or the model reference's own
+    ``required_flops(params, batch, model_config)`` where its plain form
+    computes more than the algorithm needs (experts written densely)."""
+    first, model, fedround = (ctx[k] for k in
+                              ("first_inputs", "model", "fedround"))
+    batch = {k: fedround.on_device(v[0, 0])
+             for k, v in fedround.step_arrays(first).items()}
+    model_config = ctx["config"]["model_config"]
+    if hasattr(model, "required_flops"):
+        per_step = float(model.required_flops(ctx["weights"], batch,
+                                              model_config))
+    else:
+        per_step = fedround.flops_per_step(
+            model.forward, model_config, ctx["weights"], batch,
+            matmul_flops, getattr(model, "loss", None))
     live_steps = float((first["sample_mask"].sum(axis=-1) > 0).sum())
     return per_step * live_steps
 

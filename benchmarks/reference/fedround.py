@@ -17,7 +17,11 @@ Semantics (the reference's ``core/client.py`` / ``core/trainer.py`` /
   in the packed order (loss = cross entropy averaged over the batch's
   real rows; an all-padding batch is skipped), and returns
   ``pseudo_gradient = global - trained``, ``train_loss`` = the sum of its
-  batch losses and ``num_samples`` = its real rows;
+  batch losses and ``num_samples`` = its real rows.  A model reference
+  may bring the task's own ``loss(params, batch, model_config)`` and
+  ``sample_count(batch)`` over one step's batch (every array of the
+  packed batch and ``sample_mask``); ``next_token_loss`` below is the
+  plain one for token sequences;
 - ``fedavg``: weight = ``num_samples`` capped at 100;
 - ``dga``: weight = ``exp(-beta * train_loss / num_samples)`` capped at
   100; the payload is quantised per leaf (``quantise``) when the
@@ -46,6 +50,59 @@ def xent(logits, labels, mask):
     return jnp.sum(per_row * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
 
+def next_token_loss(forward, params, batch, model_config):
+    """Cross entropy over the real target positions of ``[B, L]`` token
+    rows, sum / count (the reference's seq-to-seq trainers,
+    ``ignore_index`` padding): float32 log-softmax; targets = ``y`` where
+    the batch has one of ``x``'s rank, else ``x`` shifted by one (the
+    model then reads ``x[:, :-1]``); weight = ``tok_mask`` of the target
+    positions (absent: targets that are not the padding id 0) x
+    ``sample_mask``."""
+    x = batch["x"]
+    if "y" in batch and batch["y"].ndim == x.ndim:
+        inputs, targets, real = x, batch["y"], batch.get("tok_mask")
+    else:
+        inputs, targets, real = x[:, :-1], x[:, 1:], batch.get("tok_mask")
+        real = None if real is None else real[:, 1:]
+    if real is None:
+        real = (targets != 0).astype(jnp.float32)
+    logp = jax.nn.log_softmax(
+        forward(params, inputs, model_config).astype(jnp.float32), axis=-1)
+    per_token = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+    weight = real * batch["sample_mask"][:, None]
+    return jnp.sum(per_token * weight) / jnp.maximum(jnp.sum(weight), 1.0)
+
+
+def seam(forward, loss=None, sample_count=None) -> tuple:
+    """``(loss, sample_count)`` of one step's batch: the model
+    reference's own where it defines them, else image classification
+    (``xent`` of ``forward(x)`` against ``y`` over the real rows, and the
+    real rows)."""
+    def classification(params, batch, model_config):
+        return xent(forward(params, batch["x"], model_config), batch["y"],
+                    batch["sample_mask"])
+
+    def rows(batch):
+        return jnp.sum(batch["sample_mask"])
+
+    return loss or classification, sample_count or rows
+
+
+def on_device(array):
+    """A packed array as the reference's program takes it: ids and labels
+    int32, everything else float32."""
+    array = np.asarray(array)
+    return jnp.asarray(array, jnp.int32 if np.issubdtype(
+        array.dtype, np.integer) else jnp.float32)
+
+
+def step_arrays(batch: dict) -> dict:
+    """The arrays of a round's packed input that are cut into steps
+    (``[K, S, B, ...]``): all but ``client_mask`` and the round's
+    numbers."""
+    return {k: v for k, v in batch.items() if getattr(v, "ndim", 0) >= 3}
+
+
 def quantise(g, thresh_quantile, bits):
     """The reference's ``quant_model`` on one leaf: the nearest of
     ``2**bits`` levels on ``linspace(min, max)``, zero where ``|g|`` is at
@@ -60,30 +117,28 @@ def quantise(g, thresh_quantile, bits):
 
 
 @functools.lru_cache(maxsize=None)
-def _block_fn(forward, model_items, strategy_items):
+def _block_fn(forward, loss, sample_count, model_items, strategy_items):
     """One jitted function for a BLOCK of clients (``vmap`` of the plain
     per-client training below): the block's weighted payload sum and,
     per client, train loss, sample count, weight and update norm."""
     model_config = dict(model_items)
     strategy = dict(strategy_items)
+    loss, sample_count = seam(forward, loss, sample_count)
 
-    def loss_fn(params, x, y, mask):
-        return xent(forward(params, x, model_config), y, mask)
-
-    def one_client(global_params, xs, ys, masks, live_client, lr, quantile):
+    def one_client(global_params, steps, live_client, lr, quantile):
         def step(carry, batch):
             params, loss_sum = carry
-            x, y, mask = batch
-            loss, grads = jax.value_and_grad(loss_fn)(params, x, y, mask)
-            live = (jnp.sum(mask) > 0).astype(jnp.float32)
+            value, grads = jax.value_and_grad(loss)(params, batch,
+                                                    model_config)
+            live = (jnp.sum(batch["sample_mask"]) > 0).astype(jnp.float32)
             params = jax.tree.map(lambda p, g: p - live * lr * g,
                                   params, grads)
-            return (params, loss_sum + live * loss), None
+            return (params, loss_sum + live * value), None
 
         (trained, loss_sum), _ = jax.lax.scan(
-            step, (global_params, jnp.zeros(())), (xs, ys, masks))
+            step, (global_params, jnp.zeros(())), steps)
         pseudo = jax.tree.map(lambda a, b: a - b, global_params, trained)
-        n = jnp.sum(masks)
+        n = jnp.sum(jax.vmap(sample_count)(steps))
         norm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(pseudo)))
         if strategy["name"] == "dga":
             w = jnp.exp(-float(strategy["beta"]) * loss_sum /
@@ -98,10 +153,10 @@ def _block_fn(forward, model_items, strategy_items):
                      MAX_WEIGHT) * live_client
         return (jax.tree.map(lambda g: g * w, pseudo), loss_sum, n, w, norm)
 
-    def block(global_params, xs, ys, masks, live_clients, lr, quantile):
+    def block(global_params, steps, live_clients, lr, quantile):
         terms, loss_sum, n, w, norm = jax.vmap(
-            one_client, in_axes=(None, 0, 0, 0, 0, None, None))(
-                global_params, xs, ys, masks, live_clients, lr, quantile)
+            one_client, in_axes=(None, 0, 0, None, None))(
+                global_params, steps, live_clients, lr, quantile)
         return (jax.tree.map(lambda t: jnp.sum(t, axis=0), terms),
                 loss_sum, n, w, norm)
 
@@ -110,11 +165,14 @@ def _block_fn(forward, model_items, strategy_items):
 
 def run_round(forward, model_config: dict, params: dict, batch: dict,
               client_lr: float, server_lr: float, strategy: dict,
-              block: int = 1, precision: str | None = "highest") -> dict:
+              block: int = 1, precision: str | None = "highest",
+              loss=None, sample_count=None) -> dict:
     """One round over ``batch`` = ``{"x": [K,S,B,...], "y": [K,S,B],
-    "sample_mask": [K,S,B], "client_mask": [K]}`` (numpy), ``block``
-    clients at a time (the last block is padded with masked-out clients,
-    so one program serves every block).  ``strategy`` = ``{"name":
+    "sample_mask": [K,S,B], "client_mask": [K]}`` (numpy; a token task's
+    batch has ``tok_mask`` and may have no ``y``: every ``[K,S,B,...]``
+    array goes to ``loss``), ``block`` clients at a time (the last block
+    is padded with masked-out clients, so one program serves every
+    block).  ``loss`` / ``sample_count``: see ``seam``.  ``strategy`` = ``{"name":
     "fedavg"}`` or ``{"name": "dga", "beta", "quant_bits",
     "quant_quantile"}``.  ``precision`` = the matmul precision (``None``:
     the backend's default, which is what the program runs at as
@@ -122,8 +180,10 @@ def run_round(forward, model_config: dict, params: dict, batch: dict,
     weights, and per live client the train loss, sample count, weight and
     pseudo-gradient norm."""
     static = {k: v for k, v in strategy.items() if k != "quant_quantile"}
-    fn = _block_fn(forward, tuple(sorted(model_config.items())),
+    fn = _block_fn(forward, loss, sample_count,
+                   tuple(sorted(model_config.items())),
                    tuple(sorted(static.items())))
+    steps = step_arrays(batch)
     quantile = jnp.float32(strategy.get("quant_quantile") or 0.0)
     live_all = (np.asarray(batch["client_mask"]) > 0).astype(np.float32)
     total_k = len(live_all)
@@ -145,10 +205,8 @@ def run_round(forward, model_config: dict, params: dict, batch: dict,
         for lo in range(0, total_k, block):
             live = padded(live_all, lo)
             term, loss_sum, n, w, norm = fn(
-                dev_params, jnp.asarray(padded(batch["x"], lo)),
-                jnp.asarray(padded(batch["y"], lo)).astype(jnp.int32),
-                jnp.asarray(padded(batch["sample_mask"], lo)).astype(
-                    jnp.float32),
+                dev_params,
+                {k: on_device(padded(v, lo)) for k, v in steps.items()},
                 jnp.asarray(live), jnp.float32(client_lr), quantile)
             weighted = term if weighted is None else jax.tree.map(
                 jnp.add, weighted, term)
@@ -170,7 +228,8 @@ def run_round(forward, model_config: dict, params: dict, batch: dict,
 
 def run_rounds(forward, model_config: dict, params: dict, rounds: list,
                strategy: dict, block: int = 1,
-               precision: str | None = "highest") -> list:
+               precision: str | None = "highest", loss=None,
+               sample_count=None) -> list:
     """The rounds of one dispatch, in turn: round ``r + 1`` starts from
     round ``r``'s new weights.  ``rounds`` = the packed inputs, each with
     its ``client_lr``, ``server_lr`` and (quantised payloads)
@@ -182,16 +241,18 @@ def run_rounds(forward, model_config: dict, params: dict, rounds: list,
             per_round["quant_quantile"] = inputs["quant_quantile"]
         out.append(run_round(forward, model_config, params, inputs,
                              inputs["client_lr"], inputs["server_lr"],
-                             per_round, block, precision))
+                             per_round, block, precision, loss,
+                             sample_count))
         params = out[-1]["new_params"]
     return out
 
 
-def flops_per_step(forward, model_config: dict, params: dict, x, y, mask,
-                   count) -> float:
-    """Matmul and convolution operations of ONE forward+backward on one
-    batch, counted by ``count`` (``benchmarks/flops.py``) on this plain
-    model — what the algorithm requires, nothing recomputed."""
-    def loss_fn(p, x_, y_, m_):
-        return xent(forward(p, x_, model_config), y_, m_)
-    return count(jax.value_and_grad(loss_fn), params, x, y, mask)
+def flops_per_step(forward, model_config: dict, params: dict, batch: dict,
+                   count, loss=None) -> float:
+    """Matmul and convolution operations of ONE forward+backward of the
+    step's loss (``seam``) on one step's ``batch``, counted by ``count``
+    (``benchmarks/flops.py``) on this plain model — what the algorithm
+    requires, nothing recomputed."""
+    loss, _ = seam(forward, loss)
+    return count(jax.value_and_grad(
+        lambda p, b: loss(p, b, model_config)), params, batch)

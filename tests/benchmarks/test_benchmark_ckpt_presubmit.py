@@ -40,7 +40,9 @@ def test_reader_on_the_recorded_spans(recorded, reader):
     assert entry[0]["source"] == "program_span"
     assert entry[0]["layer"] == "checkpoint"
     assert entry[0]["moves"] == "clients_per_s"
-    assert bench["per_layer"][-1]["name"] == METRIC, "new entries go last"
+    names = [m["name"] for m in bench["per_layer"]]
+    assert names.index(METRIC) > names.index("setup_start_s"), \
+        "new entries go after the ones that were there"
 
 
 def test_reader_divides_by_the_chunks_rounds_not_by_dispatches(recorded,

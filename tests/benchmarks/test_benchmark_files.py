@@ -93,6 +93,61 @@ def test_every_entry_has_its_file(bench):
     assert used == {c["name"] for c in bench["configs"]}
 
 
+def _cells_of(metric, cells):
+    return set(metric.get("workloads", cells))
+
+
+def test_every_cell_reports_what_the_contract_asks(bench):
+    """``setup_s``, one more end-to-end metric and a per-layer metric in
+    every cell; a per-layer metric only in cells that report the
+    end-to-end metric it moves."""
+    cells = {w["name"] for w in bench["workloads"]}
+    e2e = {m["name"]: _cells_of(m, cells) for m in bench["end_to_end"]}
+    assert e2e["setup_s"] == cells
+    for cell in cells:
+        assert [n for n, where in e2e.items()
+                if cell in where and n != "setup_s"], cell
+        assert [m for m in bench["per_layer"]
+                if cell in _cells_of(m, cells)], cell
+    for metric in bench["per_layer"]:
+        assert _cells_of(metric, cells) <= e2e[metric["moves"]], metric
+
+
+def test_a_metric_kept_to_some_cells_is_reported_there_only(bench):
+    sys.path.insert(0, REPO)
+    from benchmarks import harness
+    cells = {w["name"] for w in bench["workloads"]}
+    for group in ("end_to_end", "per_layer"):
+        for cell in cells:
+            assert harness.kept_to_other_cells(group, cell) == {
+                m["name"] for m in bench[group]
+                if cell not in _cells_of(m, cells)}
+    win = {"clients": 100, "window_s": 4.0, "setup_s": 1.0,
+           "per_round_s": [0.8, 1.0, 0.8, 1.0, 2.0]}
+    for cell in cells:
+        got = set(harness.end_to_end(win, cell))
+        assert got == {n for n in ("clients_per_s", "round_s_p50",
+                                   "round_s_p90", "setup_s")
+                       if n not in harness.kept_to_other_cells(
+                           "end_to_end", cell)}
+        assert {"clients_per_s", "setup_s"} <= got
+
+
+def test_the_fence_median_reader_reads_the_window(bench):
+    sys.path.insert(0, REPO)
+    from benchmarks import harness
+    reader = harness.load_layer_metrics(BENCH)["round_fence_p50_ms"]
+    entry = [m for m in bench["per_layer"]
+             if m["name"] == "round_fence_p50_ms"]
+    assert len(entry) == 1 and entry[0]["unit"] == reader.UNIT
+    assert entry[0]["source"] == "host_clock"
+    # two groups of dispatches and one stalled fence: the middle one
+    ctx = {"window": {"per_round_s": [0.16, 0.21, 0.16, 0.21, 0.2, 4.0]}}
+    assert reader.read(ctx) == pytest.approx(205.0)
+    assert reader.read({"window": {"per_round_s": []}}) is None
+    assert reader.read({}) is None
+
+
 def test_run_py_names_no_cell_configuration_or_metric(bench):
     with open(os.path.join(BENCH, "run.py")) as fh:
         source = fh.read()

@@ -77,8 +77,8 @@ def test_sound_run_agrees_with_the_reference(tiny_root):
                            "device"}
     assert result["correct"], result["compared"]
     assert result["failed"] == 0 and result["attempted"] >= 6
-    assert set(result["metrics"]) >= {"clients_per_s", "round_s_p50",
-                                      "setup_s"}
+    # what every cell reports; the rest BENCHMARK.json keeps to cells
+    assert set(result["metrics"]) >= {"clients_per_s", "setup_s"}
     assert result["device"]["platform"] == "cpu"
     got = _verdicts(result)
     # float32 against float32: the engine's round and the plain round
