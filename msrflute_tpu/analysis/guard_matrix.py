@@ -65,7 +65,12 @@ GUARDED_BLOCKS = ("robust", "chaos", "cohort_bucketing", "megabatch",
                   # covers host-orchestrated rounds, the buffer==cohort
                   # geometry, fleet sampling modes, the secure_agg
                   # liveness floor, and megabatch x traced staleness
-                  "traffic")
+                  "traffic",
+                  # the chunk scan over the cohort (PR 28 made
+                  # `clients_per_chunk: 1` the path of a 1.9 GB tree):
+                  # every path that needs the whole cohort's payloads at
+                  # once still refuses it, and the docs say which
+                  "clients_per_chunk")
 
 #: the incompatibility vocabulary the matrix is checked over: config
 #: keys, strategy names and flags that appear in refusal messages and

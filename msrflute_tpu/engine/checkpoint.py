@@ -21,17 +21,21 @@ import json
 import logging
 import os
 import shutil
+import struct
 import threading
+import zlib
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import msgpack
 import numpy as np
 from flax import serialization
 
 from ..resilience.integrity import (CheckpointCorruptionError,
-                                    FailureEscalator, RetryPolicy,
-                                    blob_checksum, run_with_retry,
+                                    SIDECAR_SUFFIX, FailureEscalator,
+                                    RetryPolicy, checksum_hex,
+                                    read_sidecar, run_with_retry,
                                     tree_checksum, verify_blob,
                                     write_sidecar)
 from ..telemetry import NULL_SPAN, emit_event
@@ -45,6 +49,8 @@ LATEST = "latest_model.msgpack"
 #: back one round instead of losing the run
 LATEST_PREV = LATEST + ".prev"
 STATUS_LOG = "status_log.json"
+#: flax's msgpack extension code of an ndarray (``_MsgpackExtType``)
+_NDARRAY_EXT = 1
 
 
 def _payload(state: ServerState) -> dict:
@@ -80,9 +86,106 @@ def _copy_device_leaves(leaves: list) -> list:
     return [jnp.copy(x) for x in leaves]
 
 
-def _state_to_bytes(state: ServerState) -> bytes:
-    return serialization.msgpack_serialize(
-        serialization.to_state_dict(jax.device_get(_payload(state))))
+def _rotate(src: str, dst: str) -> None:
+    """``dst`` becomes a LINK to ``src`` (a copy where hardlinks are
+    unsupported), atomically: ``src`` — the committed latest — never
+    disappears, so at every instant of the rotate+write sequence at
+    least one slot passes its integrity check (a plain rename here would
+    open a crash window with NO loadable latest at all)."""
+    lnk = dst + ".lnk"
+    try:
+        if os.path.exists(lnk):
+            os.remove(lnk)
+        os.link(src, lnk)
+    except OSError:
+        shutil.copyfile(src, lnk)
+    os.replace(lnk, dst)
+
+
+def _header(small: int, codes: tuple, n: int) -> bytes:
+    """A msgpack length header as ``msgpack.Packer`` writes it: the
+    smallest of the 1-, 2- and 4-byte forms (``codes``) that holds
+    ``n``; ``small`` >= 0 is the format's fix form for ``n`` < 16."""
+    if 0 <= small and n <= 0x0F:
+        return bytes([small | n])
+    for code, width, fmt in zip(codes, (0xFF, 0xFFFF, 0xFFFFFFFF),
+                                (">B", ">H", ">I")):
+        if code is not None and n <= width:
+            return bytes([code]) + struct.pack(fmt, n)
+    raise ValueError(f"msgpack cannot frame a length of {n}")
+
+
+def _msgpack_chunks(node, out: list) -> None:
+    """Chunks whose concatenation IS ``flax.serialization.
+    msgpack_serialize(node)`` (held byte for byte by a test).  A dict is
+    framed here and an array leaf is framed here around the ARRAY ITSELF
+    (numpy, or a device array that :func:`_host_bytes` fetches when the
+    chunk is written); everything else (python and numpy scalars, an
+    empty array or one over flax's chunking size, an object dtype) goes
+    through flax's own call.  flax builds each array's bytes three times
+    over (``tobytes``, the inner ``packb``, the outer one) and only once
+    the whole tree is on the host: 11 s for a 1.9 GB state."""
+    if isinstance(node, dict):
+        out.append(_header(0x80, (None, 0xDE, 0xDF), len(node)))
+        # flax copies the tree with ``tree_map``, which sorts a dict's keys
+        for key in sorted(node):
+            out.append(msgpack.packb(key, strict_types=True))
+            _msgpack_chunks(node[key], out)
+    elif (isinstance(node, (np.ndarray, jax.Array))
+          and not node.dtype.hasobject and not node.dtype.isalignedstruct
+          and 0 < node.nbytes <= serialization.MAX_CHUNK_SIZE):
+        # ExtType(ndarray) around packb((shape, dtype name, bytes))
+        head = b"\x93" + msgpack.packb(
+            (node.shape, node.dtype.name), use_bin_type=True)[1:] + \
+            _header(-1, (0xC4, 0xC5, 0xC6), node.nbytes)
+        size = len(head) + node.nbytes
+        fix = {1: 0xD4, 2: 0xD5, 4: 0xD6, 8: 0xD7, 16: 0xD8}.get(size)
+        out.append((bytes([fix]) if fix is not None else
+                    _header(-1, (0xC7, 0xC8, 0xC9), size)) +
+                   bytes([_NDARRAY_EXT]) + head)
+        out.append(node)
+    else:
+        out.append(serialization.msgpack_serialize(node))
+
+
+def _host_bytes(chunk):
+    """A chunk as bytes on the host.  A device array is fetched HERE: its
+    transfer was started with the others' (:func:`_state_chunks`), so
+    the checksum and the write of one leaf overlap the transfers of the
+    leaves behind it."""
+    if isinstance(chunk, (bytes, bytearray)):
+        return chunk
+    if isinstance(chunk, jax.Array):
+        # the explicit transfer call: strict-transfer mode counts any
+        # other way to the host as an implicit sync
+        chunk = jax.device_get(chunk)
+    return np.ascontiguousarray(chunk).reshape(-1).view(np.uint8)
+
+
+def _state_chunks(payload) -> list:
+    """The msgpack form of a checkpoint payload as a list of chunks
+    (:func:`_msgpack_chunks`), written one after the other by
+    :meth:`CheckpointManager._write_blob`: no second copy of the state
+    is ever assembled.  Starts every device leaf's transfer to the host."""
+    chunks: list = []
+    _msgpack_chunks(serialization.to_state_dict(payload), chunks)
+    for chunk in chunks:
+        if isinstance(chunk, jax.Array):
+            chunk.copy_to_host_async()
+    return chunks
+
+
+def _chunks_size(chunks: list) -> int:
+    return sum(len(c) if isinstance(c, (bytes, bytearray)) else c.nbytes
+               for c in chunks)
+
+
+class _LinkTo:
+    """In place of a blob: the file to make is, byte for byte, the one
+    already at ``path`` (with its sidecar)."""
+
+    def __init__(self, path: str):
+        self.path = path
 
 
 def _state_from_bytes(data: bytes, template: ServerState) -> ServerState:
@@ -371,7 +474,7 @@ class CheckpointManager:
                 # direct visual of checkpoint IO overlapping (or
                 # stalling) device rounds
                 with (self.telemetry.span("ckpt_async_write")
-                      if self.telemetry is not None else NULL_SPAN):
+                      if self.telemetry is not None else NULL_SPAN) as span:
                     # wait for the snapshot program BEFORE asking for its
                     # transfers: a device_get on arrays still to be
                     # computed queues its device-to-host copies to fire at
@@ -379,9 +482,12 @@ class CheckpointManager:
                     # the training thread's stats fetch that the same end
                     # releases (the fence was then seen ~2 ms late)
                     jax.block_until_ready(snap)
-                    blob = serialization.msgpack_serialize(
-                        serialization.to_state_dict(jax.device_get(snap)))
-                    del snap  # release the HBM snapshot before the write
+                    blob = _state_chunks(snap)
+                    if span is not None:
+                        span["bytes"] = _chunks_size(blob)
+                    # the chunks hold the HBM snapshot now, each leaf
+                    # until the write has fetched it
+                    del snap
                     # _write_blob already retries + counts the failure
                     # toward escalation; the abort itself surfaces at the
                     # training thread's next submit/wait (escalator.check
@@ -425,7 +531,7 @@ class CheckpointManager:
         # the submit then sat until the running round program retired).
         # It is enqueued on the device stream BEFORE any later donating
         # program, so it reads the pre-donation values; the writer
-        # thread's device_get then overlaps the next rounds.
+        # thread's fetch then overlaps the next rounds.
         # Host numpy leaves (e.g. mutable strategy_state arrays) are
         # np.copy'd for the same reason: a by-reference share would let
         # an in-place mutation on the training thread reach the writer's
@@ -446,9 +552,16 @@ class CheckpointManager:
         return {"leaves": len(on_device), "programs": int(bool(on_device))}
 
     # -- save ----------------------------------------------------------
-    def save_latest(self, state: ServerState) -> Optional[Dict[str, int]]:
+    def save_latest(self, state: ServerState,
+                    same_as: Optional[str] = None
+                    ) -> Optional[Dict[str, int]]:
         """Save ``latest``; the async msgpack path returns what its
-        device snapshot launched (see :meth:`_mp_submit`)."""
+        device snapshot launched (see :meth:`_mp_submit`).  ``same_as``:
+        the file :meth:`save_best` has just written FROM THIS VERY STATE
+        (the caller's knowledge: an evaluation round's state goes out as
+        the best model and then as that round's ``latest``).  The msgpack
+        ``latest`` is then a link to it, made here and now: durable on
+        return, and no second 1.9 GB through the disk."""
         if self.backend == "orbax":
             self._commit_pending_latest()
             committed = self._latest_slot()
@@ -458,10 +571,15 @@ class CheckpointManager:
             self._orbax_save(self._orbax_path(slot), state)
             self._pending_slot = slot
             return None
+        path = os.path.join(self.model_dir, LATEST)
+        if same_as is not None:
+            self._mp_wait()  # an earlier round's latest lands first
+            self._write_blob(path, _LinkTo(same_as), keep_prev=True)
+            self.escalator.check()
+            return None
         if self.async_latest:
             return self._mp_submit(state)
-        self._write(os.path.join(self.model_dir, LATEST), state,
-                    keep_prev=True)
+        self._write((path,), state)
         return None
 
     def backup(self, state: ServerState, round_no: int,
@@ -496,10 +614,18 @@ class CheckpointManager:
                 shutil.copyfile(best, os.path.join(
                     self.model_dir, f"best_val_{name}_model_epoch{round_no}.msgpack"))
 
-    def save_best(self, state: ServerState, metric_name: str) -> None:
+    def save_best(self, state: ServerState, metric_name: str,
+                  *more_names: str) -> Optional[str]:
         """Best-val checkpoint on improvement (reference
-        ``core/evaluation.py:103-109``)."""
+        ``core/evaluation.py:103-109``), one file per metric name,
+        durable on return with the msgpack backend.  Metrics that
+        improved at the same evaluation hold the same state: it is
+        fetched and written once, the other names are links to that
+        file.  Returns the file written (for :meth:`save_latest`'s
+        ``same_as``), None with orbax."""
         if self.backend == "orbax":
+            for name in more_names:
+                self.save_best(state, name)
             # async save to a .new dir; the rename into place happens at
             # the next drain, with the previous best parked at .old until
             # the swap completes — no moment without a readable best
@@ -508,43 +634,45 @@ class CheckpointManager:
             shutil.rmtree(tmp, ignore_errors=True)
             self._orbax_save(tmp, state)
             self._pending_renames.append((tmp, final))
-            return
-        self._write(os.path.join(
-            self.model_dir, f"best_val_{metric_name}_model.msgpack"), state)
+            return None
+        paths = tuple(
+            os.path.join(self.model_dir, f"best_val_{name}_model.msgpack")
+            for name in (metric_name,) + more_names)
+        return paths[0] if self._write(paths, state) else None
 
-    def _write_blob(self, path: str, blob: bytes,
+    def _write_blob(self, path: str, blob,
                     keep_prev: bool = False) -> bool:
         """Atomic tmp-write + rename under the bounded-retry policy —
         THE write recipe, shared by the sync and async-latest paths.
+        ``blob``: bytes, or the chunks of :func:`_state_chunks`, which
+        are fetched, checksummed and written one after the other.
         Records a crc32 sidecar (verified at load) and, for the latest
         slot (``keep_prev``), rotates the previous generation to
         ``.prev`` first so corruption always has a fallback.  Returns
         success; the failure is already counted toward escalation (the
         CALLER decides where the abort surfaces — training thread only).
         """
-        checksum = blob_checksum(blob)
-
-        def _rotate(src: str, dst: str) -> None:
-            # LINK-based rotation (fall back to a copy where hardlinks
-            # are unsupported): `src` — the committed latest — never
-            # disappears, so at every instant of the rotate+write
-            # sequence at least one slot passes its integrity check (a
-            # plain rename here would open a crash window with NO
-            # loadable latest at all)
-            lnk = dst + ".lnk"
-            try:
-                if os.path.exists(lnk):
-                    os.remove(lnk)
-                os.link(src, lnk)
-            except OSError:
-                shutil.copyfile(src, lnk)
-            os.replace(lnk, dst)
+        same_as = blob.path if isinstance(blob, _LinkTo) else None
+        chunks = [] if same_as else \
+            blob if isinstance(blob, list) else [blob]
 
         def _save():
             self._io_fault()
             tmp = path + ".tmp"
-            with open(tmp, "wb") as fh:
-                fh.write(blob)
+            if same_as:
+                meta = read_sidecar(same_as)
+                _rotate(same_as, tmp)
+            else:
+                crc = size = 0
+                with open(tmp, "wb") as fh:
+                    for i, chunk in enumerate(chunks):
+                        # the host bytes stay in the list: a retry finds
+                        # them there, and the device leaf is let go
+                        chunk = chunks[i] = _host_bytes(chunk)
+                        crc = zlib.crc32(chunk, crc)
+                        fh.write(chunk)
+                        size += len(chunk)
+                meta = {"crc32": checksum_hex(crc), "size": size}
             if keep_prev and os.path.exists(path):
                 # blob then sidecar: a crash between the two leaves
                 # .prev's sidecar one generation stale, which the
@@ -554,7 +682,7 @@ class CheckpointManager:
                 if os.path.exists(path + ".sum"):
                     _rotate(path + ".sum", path + ".prev.sum")
             os.replace(tmp, path)
-            write_sidecar(path, checksum, len(blob))
+            write_sidecar(path, meta["crc32"], meta["size"])
 
         if run_with_retry(_save, self.retry,
                           what=f"checkpoint save {os.path.basename(path)}"):
@@ -566,10 +694,33 @@ class CheckpointManager:
                    consecutive=self.escalator.consecutive)
         return False
 
-    def _write(self, path: str, state: ServerState,
-               keep_prev: bool = False) -> None:
-        self._write_blob(path, _state_to_bytes(state), keep_prev=keep_prev)
+    def _write(self, targets: Tuple[str, ...], state: ServerState) -> bool:
+        """The synchronous save, on the caller's thread: ``targets[0]``
+        written (the ``latest`` slot keeps its previous generation),
+        every further target a link to it with its sidecar — a later
+        save of any of these names replaces that name's file and leaves
+        the others'."""
+        first = targets[0]
+        with (self.telemetry.span("ckpt_write")
+              if self.telemetry is not None else NULL_SPAN) as span:
+            blob = _state_chunks(_payload(state))
+            if span is not None:
+                span["bytes"] = _chunks_size(blob)
+            done = self._write_blob(
+                first, blob, keep_prev=os.path.basename(first) == LATEST)
+            del blob
+
+        def _link_twins():
+            for twin in targets[1:]:
+                _rotate(first, twin)
+                _rotate(first + SIDECAR_SUFFIX, twin + SIDECAR_SUFFIX)
+
+        if done and targets[1:] and not run_with_retry(
+                _link_twins, self.retry, what="checkpoint links"):
+            self.escalator.record_failure(f"save {targets[1]}")
+            done = False
         self.escalator.check()
+        return done
 
     # -- load ----------------------------------------------------------
     def load(self, template: ServerState,

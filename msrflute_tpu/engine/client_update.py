@@ -191,6 +191,9 @@ def build_client_update(task: BaseTask, client_opt_cfg,
             "updatable_layers: the flat fused kernel has no per-leaf "
             "freeze mask — drop one of them")
     sgd_mu = float(client_opt_cfg.get("momentum", 0.0) or 0.0)
+    # what the model counts inside its forward pass (an expert layer's
+    # load): summed over the local steps, out as ``stats["ctr_<name>"]``
+    counter_names = tuple(getattr(task, "counter_names", ()))
 
     def client_update(global_params, arrays: Dict[str, jnp.ndarray],
                       sample_mask: jnp.ndarray, lr: jnp.ndarray,
@@ -217,7 +220,7 @@ def build_client_update(task: BaseTask, client_opt_cfg,
 
         def one_step(carry, xs):
             (params, opt_state, rng, loss_sum, s, s2, n_acc, wloss_acc,
-             ns_acc) = carry
+             ns_acc, ctr_acc) = carry
             batch_arrays, mask = xs
             batch = dict(batch_arrays)
             batch["sample_mask"] = mask
@@ -252,6 +255,8 @@ def build_client_update(task: BaseTask, client_opt_cfg,
             # aggregation weights and DGA's train_loss/num_samples metric
             ns_acc = (ns_acc + has_data * _aux.get(
                 "train_sample_count", jnp.sum(mask))).astype(sdt)
+            ctr_acc = {name: ctr_acc[name] + has_data *
+                       _aux["counters"][name] for name in counter_names}
             if pallas_sgd:
                 # megakernel tail: the whole optimizer step is one
                 # fused pass over the flattened param vector, with the
@@ -270,7 +275,7 @@ def build_client_update(task: BaseTask, client_opt_cfg,
                     tx, grads, opt_state, params,
                     update_mask=update_mask, has_data=has_data)
             return (params, opt_state, rng, loss_sum, s, s2, n_acc,
-                    wloss_acc, ns_acc), None
+                    wloss_acc, ns_acc, ctr_acc), None
 
         params = local_params
         loss_sum = jnp.zeros((), sdt)
@@ -280,7 +285,8 @@ def build_client_update(task: BaseTask, client_opt_cfg,
         wloss_acc = jnp.zeros((), sdt)
         ns_acc = jnp.zeros((), sdt)
         carry = (params, opt_state, rng, loss_sum, s, s2, n_acc, wloss_acc,
-                 ns_acc)
+                 ns_acc, {name: jnp.zeros((), jnp.float32)
+                          for name in counter_names})
         if hparams.num_epochs <= 1 or not hparams.fused_epochs:
             # num_epochs == 1 is the exact historical trace either way;
             # the legacy unrolled path (megakernel.fused_epochs: false)
@@ -307,7 +313,7 @@ def build_client_update(task: BaseTask, client_opt_cfg,
 
             carry, _ = jax.lax.scan(fused_step, carry, step_ids)
         (params, opt_state, rng, loss_sum, s, s2, n_acc, wloss_acc,
-         ns_acc) = carry
+         ns_acc, ctr_acc) = carry
 
         pseudo_grad = jax.tree.map(lambda w0, w: w0 - w, global_params, params)
         if freeze:
@@ -328,6 +334,8 @@ def build_client_update(task: BaseTask, client_opt_cfg,
         # counting unit below
         stats["mean_sample_loss"] = wloss_acc / jnp.maximum(
             rows * hparams.num_epochs, 1.0)
+        for name in counter_names:
+            stats[f"ctr_{name}"] = ctr_acc[name]
         # ns_acc is the task's counting unit for this client — the
         # epoch loop re-counts per epoch like the reference
         # (train_desired_samples accumulates per epoch), so divide back

@@ -348,8 +348,9 @@ class RoundEngine:
                     "break mask cancellation")
             if self.clients_per_chunk:
                 raise ValueError(
-                    "fused RL is incompatible with clients_per_chunk: "
-                    "re-weighting needs the full payload stack")
+                    "fused RL (wantRL) is incompatible with "
+                    "clients_per_chunk: re-weighting needs the full "
+                    "payload stack")
             if float(getattr(strategy, "stale_prob", 0.0) or 0.0) > 0.0:
                 raise ValueError("fused RL does not support stale_prob")
             from ..config import RLConfig
@@ -981,8 +982,18 @@ class RoundEngine:
                     ((slot_k,) if carry_paged else ()) + \
                     ((corrupt_k,) if chaos_corruption else ()) + \
                     ((stale_k,) if traffic_staleness else ())
-                parts, tls, nss, stats, stale, carry_rows, sub_norms = \
-                    jax.vmap(per_client)(*vmap_args)
+                if clients_per_chunk == 1:
+                    # one client at a time: the client's program as it
+                    # is, with no batch axis (a Pallas call with scalar
+                    # prefetch does not batch, and a tree of 10^8
+                    # parameters gains nothing from a leading 1)
+                    one = per_client(*jax.tree.map(lambda a: a[0],
+                                                   vmap_args))
+                    parts, tls, nss, stats, stale, carry_rows, sub_norms = \
+                        jax.tree.map(lambda a: a[None], one)
+                else:
+                    parts, tls, nss, stats, stale, carry_rows, \
+                        sub_norms = jax.vmap(per_client)(*vmap_args)
                 # per-client privacy-attack metrics stay per-client (the
                 # server needs the distribution for the adaptive leakage
                 # threshold, core/server.py:397-409)
@@ -1055,10 +1066,17 @@ class RoundEngine:
                     local["parts"][name] = {
                         "grad_sum": wsum(w_now, trees),
                         "weight_sum": jnp.sum(w_now),
-                        "grad_sum_def": wsum(w_def, trees),
                         "weight_sum_def": jnp.sum(w_def),
                         "weight_sum_raw": jnp.sum(ws),
                     }
+                    if stale_prob > 0.0 or not clients_per_chunk:
+                        # the deferred clients' sum is a second tree; the
+                        # chunk scan would carry it as an accumulator of
+                        # its own, so it exists there only where a client
+                        # can be deferred (outside the scan XLA drops an
+                        # unused one)
+                        local["parts"][name]["grad_sum_def"] = wsum(
+                            w_def, trees)
                 local.update({
                     "train_loss_sum": jnp.sum(tls),
                     "num_samples_sum": jnp.sum(nss),
@@ -1068,6 +1086,11 @@ class RoundEngine:
                     "stats_var_sum": jnp.sum(stats["var_corrected"] * cm_k),
                     "stats_norm_sum": jnp.sum(stats["norm"] * cm_k),
                 })
+                for key in stats:
+                    if key.startswith("ctr_"):
+                        # what the model counted in its forward passes
+                        # (BaseTask.counter_names), over the live clients
+                        local[key] = jnp.sum(stats[key] * cm_k)
                 if shield_counts is not None:
                     # per-cause quarantine counters: psum'd with the
                     # other locals and packed into the single-transfer
@@ -1474,6 +1497,8 @@ class RoundEngine:
                 "grad_norm": collected["stats_norm_sum"] / jnp.maximum(collected["client_count"], 1.0),
                 "agg_grad_norm": optax.global_norm(agg),
             }
+            round_stats.update({k: v for k, v in collected.items()
+                                if k.startswith("ctr_")})
             round_stats.update(chaos_stats)
             round_stats.update(traffic_stats)
             round_stats.update(secagg_stats)

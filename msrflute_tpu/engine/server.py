@@ -24,7 +24,7 @@ import logging
 import os
 import time
 from collections import deque
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1782,8 +1782,15 @@ class OptimizationServer:
                 self.fleet_pager.complete_writeback(chunk["fleet_wb"])
 
         with self._tspan("host_tail", round0=round0, rounds=R,
-                         chunk=round0):
+                         chunk=round0) as span:
             self._drain_host_tail(chunk, stats, val_freq, rec_freq)
+            if span is not None:
+                # what the model counted in its forward passes
+                # (BaseTask.counter_names: an expert layer's load), summed
+                # over the chunk's rounds; it came with the packed stats
+                for key in stats:
+                    if key.startswith("ctr_"):
+                        span[key[4:]] = float(np.sum(stats[key]))
         self.run_stats["secsPerRoundHostTail"].append(
             (time.time() - toc) / R)
         if self.scope is not None:
@@ -2552,8 +2559,15 @@ class OptimizationServer:
         self.ckpt.update_status(status_update)
 
         with self._tspan("ckpt_submit", round=round_no):
+            saved_state, best_file = self._best_file_of
+            self._best_file_of = (None, None)
             if not skip_latest:
-                self.ckpt.save_latest(self.state)
+                # the state this round's evaluation has just written as
+                # the best model (no fall-back replaced it since): the
+                # same bytes, so `latest` is a link to that file
+                self.ckpt.save_latest(
+                    self.state, same_as=best_file
+                    if saved_state is self.state else None)
             self.ckpt.backup(self.state, round_no,
                              best_names=tuple(self.best_val))
         if self.scaffold_store is not None:
@@ -2958,6 +2972,8 @@ class OptimizationServer:
 
     # ------------------------------------------------------------------
     _last_val: MetricsDict = {}
+    #: (state, file) of the best-model save of the evaluation in progress
+    _best_file_of: Tuple[Any, Optional[str]] = (None, None)
 
     def _split_cfg(self, split: str):
         dc = self.config.server_config.data_config
@@ -3016,6 +3032,7 @@ class OptimizationServer:
         improved = False
         if split == "val":
             self._last_val = metrics
+            bettered = []
             for name, metric in metrics.items():
                 if not np.isfinite(metric.value):
                     # eval-side non-finite guard, host half: a NaN/Inf
@@ -3030,9 +3047,16 @@ class OptimizationServer:
                 prev = self.best_val.get(name)
                 if prev is None or metric.is_better_than(prev):
                     self.best_val[name] = metric
-                    self.ckpt.save_best(self.state, name)
+                    bettered.append(name)
                     if name == self.best_model_criterion:
                         improved = True
+            if bettered:
+                # one file per metric, one fetch and one write for all;
+                # durable before the status log names the new best_val.
+                # This round's `latest`, if it is of this very state,
+                # becomes a link to that file (_round_housekeeping_inner)
+                self._best_file_of = (
+                    self.state, self.ckpt.save_best(self.state, *bettered))
             # convergence-tier crossing (traffic.target_accuracy): the
             # FIRST val eval at/above the target pins the round — the
             # rounds_to_target_accuracy bench.py records and `scope

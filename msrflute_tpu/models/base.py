@@ -43,6 +43,11 @@ class BaseTask:
     #: padding may be cropped per round (``data.batching.seq_length_bucket``);
     #: the model must derive its position mask from the ids, never from L
     seq_pad_keys: Tuple[str, ...] = ()
+    #: scalars the model counts inside its forward pass (an expert layer's
+    #: load), returned by ``loss`` as ``aux["counters"][name]``; the client
+    #: update sums them over the local steps and the round over its
+    #: clients, and they leave in the packed stats as ``ctr_<name>``
+    counter_names: Tuple[str, ...] = ()
 
     def init_params(self, rng: jax.Array) -> Params:
         raise NotImplementedError

@@ -1,0 +1,342 @@
+"""LFM2-MoE (LiquidAI LFM2-24B-A2B) — one chip's share of an
+expert-parallel deployment, as a causal-LM task for the federated round.
+
+Net-new vs the reference (FLUTE ships no such model).  The layer
+equations are written out in ``benchmarks/reference/lfm2_moe.py`` (the
+plain float32 form the benchmark compares this module with); in short,
+``h = x + op(norm(x)); y = h + ffn(norm(h))`` with
+
+- ``op`` a gated short convolution (``W_in`` to three gates, a causal
+  depthwise convolution of ``conv_L_cache`` taps, ``W_out``) or
+  grouped-query attention (RMSNorm on every head's query and key,
+  rotate-half RoPE, ``num_attention_heads`` query heads over
+  ``num_key_value_heads`` key-value heads, causal softmax), by
+  ``layer_types``;
+- ``ffn`` a dense SwiGLU in the leading ``num_dense_layers`` and after
+  them the held share of ``num_experts`` sigmoid-routed SwiGLU experts
+  (:func:`msrflute_tpu.ops.moe.held_experts_ffn`: ``experts_held``
+  experts from ``expert_offset``, ``num_experts_per_tok`` a token over
+  all experts, nothing dropped, no exchange on one chip);
+- a final RMSNorm and logits against the TIED embedding.
+
+The parameter tree's names are a checkpoint contract and are the plain
+reference's (``layer_<i>/{norm_op, norm_ffn, conv | attn, mlp | moe}``).
+
+Attention is a BLOCKED PLAIN path: ``attention_block`` query rows at a
+time against the keys up to the block's end, each block a
+``jax.checkpoint`` (the scores of a 4,096-token row, 32 x 4096 x 4096
+floats, never stand whole).  The Pallas flash kernel
+(``ops/pallas_attention.py``) takes equal head counts and its planner's
+dense fallback does not fit beside a 1.9 GB tree; this model does not
+call it.  The sequence is padded to a whole number of blocks inside the
+module and the padding's logits are cut off again (causal: padding at
+the end changes nothing before it).
+
+``model_config.dtype: bfloat16`` computes activations and matmul
+operands in bfloat16 over float32 master weights (norms, the router and
+the loss stay float32); ``remat: true`` recomputes each layer in the
+backward pass.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ..ops.moe import held_experts_ffn
+from .base import parse_dtype
+from .nlp import SequenceLMTask, _TokenDatasetMixin
+
+#: what the expert layers count, summed over layers and local steps
+#: (``ops.moe.held_experts_ffn``); the engine carries them to the packed
+#: round stats as ``ctr_<name>``
+COUNTERS = ("moe_pairs_held", "moe_max_load", "moe_pairs_dropped",
+            "moe_layer_steps")
+
+
+def _normal(std: float):
+    return nn.initializers.normal(std)
+
+
+class _RMSNorm(nn.Module):
+    eps: float
+
+    @nn.compact
+    def __call__(self, x):
+        weight = self.param("weight", nn.initializers.ones, (x.shape[-1],))
+        xf = x.astype(jnp.float32)
+        y = xf * jax.lax.rsqrt(
+            jnp.mean(xf * xf, axis=-1, keepdims=True) + self.eps) * weight
+        return y.astype(x.dtype)
+
+
+class _GatedShortConv(nn.Module):
+    taps: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, z):  # [B, L, D]
+        hidden = z.shape[-1]
+        w_in = self.param("w_in", _normal(0.02), (hidden, 3 * hidden))
+        w_conv = self.param("w_conv", _normal(0.5), (hidden, self.taps))
+        w_out = self.param("w_out", _normal(0.02), (hidden, hidden))
+        gate_b, gate_c, u = jnp.split(z @ w_in.astype(self.dtype), 3,
+                                      axis=-1)
+        bu = gate_b * u
+        padded = jnp.pad(bu, ((0, 0), (self.taps - 1, 0), (0, 0)))
+        length = z.shape[1]
+        taps = w_conv.astype(self.dtype)
+        v = sum(taps[:, j] * padded[:, self.taps - 1 - j:
+                                    self.taps - 1 - j + length]
+                for j in range(self.taps))
+        return (gate_c * v) @ w_out.astype(self.dtype)
+
+
+def _rope(x, theta: float):
+    """Rotate-half RoPE on ``[B, L, heads, D]`` at positions 0..L-1,
+    angles in float32."""
+    length, dim = x.shape[1], x.shape[-1]
+    inv_freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    angles = jnp.arange(length, dtype=jnp.float32)[:, None] * inv_freq[None]
+    angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
+    half = dim // 2
+    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return (x * jnp.cos(angles).astype(x.dtype) +
+            rotated * jnp.sin(angles).astype(x.dtype))
+
+
+def _attention_rows(q_rows, k, v, row0: int):
+    """One block of query rows ``[B, R, KV, G, D]`` from position
+    ``row0`` over the keys ``[B, M, KV, D]`` up to the block's end;
+    softmax in float32."""
+    scale = q_rows.shape[-1] ** -0.5
+    scores = jnp.einsum("brkgd,bmkd->bkgrm", q_rows, k).astype(
+        jnp.float32) * scale
+    rows = row0 + jnp.arange(q_rows.shape[1])[:, None]
+    cols = jnp.arange(k.shape[1])[None, :]
+    scores = jnp.where(cols <= rows, scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bkgrm,bmkd->brkgd", probs, v)
+
+
+class _GQAttention(nn.Module):
+    heads: int
+    kv_heads: int
+    head_dim: int
+    eps: float
+    theta: float
+    block: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, z):  # [B, L, D], L a multiple of block
+        batch, length, hidden = z.shape
+        heads, kv, dim = self.heads, self.kv_heads, self.head_dim
+        wq = self.param("wq", _normal(0.02), (hidden, heads * dim))
+        wk = self.param("wk", _normal(0.02), (hidden, kv * dim))
+        wv = self.param("wv", _normal(0.02), (hidden, kv * dim))
+        wo = self.param("wo", _normal(0.02), (heads * dim, hidden))
+        q = _RMSNorm(self.eps, name="norm_q")(
+            (z @ wq.astype(self.dtype)).reshape(batch, length, heads, dim))
+        k = _RMSNorm(self.eps, name="norm_k")(
+            (z @ wk.astype(self.dtype)).reshape(batch, length, kv, dim))
+        v = (z @ wv.astype(self.dtype)).reshape(batch, length, kv, dim)
+        q, k = _rope(q, self.theta), _rope(k, self.theta)
+        # query head h reads key-value head h // (heads / kv_heads)
+        q = q.reshape(batch, length, kv, heads // kv, dim)
+        rows = jax.checkpoint(_attention_rows, static_argnums=(3,))
+        out = [rows(q[:, r0:r0 + self.block], k[:, :r0 + self.block],
+                    v[:, :r0 + self.block], r0)
+               for r0 in range(0, length, self.block)]
+        out = jnp.concatenate(out, axis=1).reshape(batch, length,
+                                                   heads * dim)
+        return out @ wo.astype(self.dtype)
+
+
+class _DenseMLP(nn.Module):
+    width: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, z):
+        hidden = z.shape[-1]
+        w1 = self.param("w1", _normal(0.02), (hidden, self.width))
+        w3 = self.param("w3", _normal(0.02), (hidden, self.width))
+        w2 = self.param("w2", _normal(0.02), (self.width, hidden))
+        return (jax.nn.silu(z @ w1.astype(self.dtype)) *
+                (z @ w3.astype(self.dtype))) @ w2.astype(self.dtype)
+
+
+class _HeldExperts(nn.Module):
+    """``experts_held`` of ``num_experts`` routed SwiGLU experts, from
+    ``expert_offset``; returns ``(y, counters)``."""
+    num_experts: int
+    experts_held: int
+    expert_offset: int
+    per_token: int
+    width: int
+    scaling: float
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, z):
+        hidden = z.shape[-1]
+        held = self.experts_held
+        router = self.param("router", _normal(hidden ** -0.5),
+                            (hidden, self.num_experts))
+        bias = self.param("select_bias", _normal(0.1), (self.num_experts,))
+        w1 = self.param("w1", _normal(0.02), (held, hidden, self.width))
+        w3 = self.param("w3", _normal(0.02), (held, hidden, self.width))
+        w2 = self.param("w2", _normal(0.02), (held, self.width, hidden))
+        y, counters = held_experts_ffn(
+            z.reshape(-1, hidden), router, bias, w1.astype(self.dtype),
+            w3.astype(self.dtype), w2.astype(self.dtype),
+            experts_per_token=self.per_token,
+            expert_offset=self.expert_offset, scaling=self.scaling)
+        return y.reshape(z.shape), counters
+
+
+class _Layer(nn.Module):
+    op: str
+    ffn: str
+    cfg: Any  # hashable tuple of (key, value): the sizes, see _LFM2
+
+    @nn.compact
+    def __call__(self, x):
+        c = dict(self.cfg)
+        eps, dtype = c["norm_eps"], c["dtype"]
+        z = _RMSNorm(eps, name="norm_op")(x)
+        if self.op == "conv":
+            h = x + _GatedShortConv(c["conv_L_cache"], dtype, name="conv")(z)
+        else:
+            h = x + _GQAttention(
+                c["num_attention_heads"], c["num_key_value_heads"],
+                c["head_dim"], eps, c["rope_theta"], c["attention_block"],
+                dtype, name="attn")(z)
+        z = _RMSNorm(eps, name="norm_ffn")(h)
+        if self.ffn == "dense":
+            return h + _DenseMLP(c["intermediate_size"], dtype,
+                                 name="mlp")(z), {}
+        y, counters = _HeldExperts(
+            c["num_experts"], c["experts_held"], c["expert_offset"],
+            c["num_experts_per_tok"], c["moe_intermediate_size"],
+            c["routed_scaling_factor"], dtype, name="moe")(z)
+        return h + y, counters
+
+
+class _LFM2(nn.Module):
+    vocab_size: int
+    hidden_size: int
+    layers: Tuple[Tuple[str, str], ...]  # (operator, ffn) per layer
+    cfg: Any
+    remat: bool = False
+
+    @nn.compact
+    def __call__(self, x):  # [B, L] int32 -> logits [B, L, V], counters
+        c = dict(self.cfg)
+        dtype, block = c["dtype"], c["attention_block"]
+        length = x.shape[1]
+        x = jnp.pad(x, ((0, 0), (0, -length % block)))
+        table = self.param("embedding", _normal(0.02),
+                           (self.vocab_size, self.hidden_size))
+        h = jnp.take(table, x, axis=0).astype(dtype)
+        layer_cls = nn.remat(_Layer) if self.remat else _Layer
+        counters: Dict[str, jnp.ndarray] = {}
+        for i, (op, ffn) in enumerate(self.layers):
+            # explicit names: the tree is the same with remat on or off
+            h, counted = layer_cls(op, ffn, self.cfg, name=f"layer_{i}")(h)
+            for key, value in counted.items():
+                counters[key] = counters.get(key, 0.0) + value
+        h = _RMSNorm(c["norm_eps"], name="norm_emb")(h)
+        logits = h @ table.T.astype(dtype)
+        return logits[:, :length], counters
+
+
+def layer_kinds(model_config) -> Tuple[Tuple[str, str], ...]:
+    """``layer_types`` (comma-separated ``conv`` / ``full_attention``)
+    and ``num_dense_layers`` -> ``((operator, ffn), ...)``."""
+    ops = [t.strip() for t in str(model_config["layer_types"]).split(",")]
+    unknown = sorted(set(ops) - {"conv", "full_attention"})
+    if unknown:
+        raise ValueError(f"model_config.layer_types: unknown {unknown}; "
+                         "expected conv or full_attention")
+    dense = int(model_config.get("num_dense_layers", 0))
+    return tuple((op, "dense" if i < dense else "moe")
+                 for i, op in enumerate(ops))
+
+
+class LFM2Task(_TokenDatasetMixin, SequenceLMTask):
+    """Causal-LM task over :class:`_LFM2`; int token rows pass through
+    the dataset as they are.  The expert layers' counters leave through
+    the loss's aux (``aux["counters"]``) and the engine sums them into
+    the packed round stats."""
+
+    counter_names = COUNTERS
+
+    def init_params(self, rng: jax.Array):
+        # nothing of the tree depends on the length (RoPE, no position
+        # table): a short dummy keeps the init program small.  ONE
+        # program: run eagerly, the module's init compiles every
+        # primitive of the forward pass on its own (24 s at the
+        # published widths, none of it kept by the persistent cache)
+        dummy = jnp.zeros((1, 8), jnp.int32)
+        return jax.jit(self.module.init)(rng, dummy)["params"]
+
+    def _apply(self, params, inputs):
+        return self.module.apply({"params": params}, inputs)[0]
+
+    def loss(self, params, batch, rng=None, train=True):
+        inputs, targets, tok_mask = self._inputs_targets(batch)
+        logits, counters = self.module.apply({"params": params}, inputs)
+        value, aux = self._masked_xent(logits.astype(jnp.float32), targets,
+                                       tok_mask, batch)
+        if counters:
+            aux["counters"] = counters
+        return value, aux
+
+
+def make_lfm2_task(model_config) -> LFM2Task:
+    layers = layer_kinds(model_config)
+    hidden = int(model_config["hidden_size"])
+    heads = int(model_config["num_attention_heads"])
+    moe = any(ffn == "moe" for _, ffn in layers)
+    num_experts = int(model_config.get("num_experts", 0) or 0)
+    held = int(model_config.get("experts_held", num_experts) or 0)
+    offset = int(model_config.get("expert_offset", 0) or 0)
+    if moe and not 0 < held <= num_experts - offset:
+        raise ValueError(
+            f"model_config: experts_held={held} from expert_offset={offset} "
+            f"does not lie within num_experts={num_experts}")
+    cfg = tuple(sorted({
+        "dtype": parse_dtype(model_config),
+        "norm_eps": float(model_config.get("norm_eps", 1e-5)),
+        "rope_theta": float(model_config.get("rope_theta", 1e6)),
+        "conv_L_cache": int(model_config.get("conv_L_cache", 3)),
+        "num_attention_heads": heads,
+        "num_key_value_heads": int(model_config.get("num_key_value_heads",
+                                                    heads)),
+        "head_dim": int(model_config.get("head_dim", hidden // heads)),
+        "attention_block": int(model_config.get("attention_block", 512)),
+        "intermediate_size": int(model_config.get("intermediate_size",
+                                                  4 * hidden)),
+        "moe_intermediate_size": int(
+            model_config.get("moe_intermediate_size", hidden)),
+        "num_experts": num_experts,
+        "experts_held": held,
+        "expert_offset": offset,
+        "num_experts_per_tok": int(model_config.get("num_experts_per_tok",
+                                                    1)),
+        "routed_scaling_factor": float(
+            model_config.get("routed_scaling_factor", 1.0)),
+    }.items()))
+    module = _LFM2(vocab_size=int(model_config["vocab_size"]),
+                   hidden_size=hidden, layers=layers, cfg=cfg,
+                   remat=bool(model_config.get("remat", False)))
+    task = LFM2Task(module, seq_len=int(model_config.get("seq_len", 4096)),
+                    name="lfm2_moe")
+    if not moe:
+        task.counter_names = ()
+    return task

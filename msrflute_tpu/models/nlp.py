@@ -140,7 +140,13 @@ class SequenceLMTask(BaseTask):
         dummy = jnp.zeros((1, self.seq_len - 1), jnp.int32)
         return self.module.init(rng, dummy)["params"]
 
-    def _logits_targets(self, params, batch: Batch):
+    def _apply(self, params, inputs):
+        """The module's logits."""
+        return self.module.apply({"params": params}, inputs)
+
+    def _inputs_targets(self, batch: Batch):
+        """What the module is fed, what it is scored against and the
+        weight of each scored position."""
         x = batch["x"].astype(jnp.int32)
         if "y" in batch and batch["y"].ndim == x.ndim:
             # explicit per-position targets: with ref_initial_prediction
@@ -170,11 +176,13 @@ class SequenceLMTask(BaseTask):
                 tok_mask = tok_mask.astype(jnp.float32)[:, 1:]
             else:
                 tok_mask = (targets != 0).astype(jnp.float32)
+        return inputs, targets, tok_mask * batch["sample_mask"][:, None]
+
+    def _logits_targets(self, params, batch: Batch):
+        inputs, targets, tok_mask = self._inputs_targets(batch)
         # f32 logits regardless of the module's compute dtype (bf16 MXU
         # matmuls, float32 softmax/xent — see models.base.parse_dtype)
-        logits = self.module.apply({"params": params},
-                                   inputs).astype(jnp.float32)
-        tok_mask = tok_mask * batch["sample_mask"][:, None]
+        logits = self._apply(params, inputs).astype(jnp.float32)
         return logits, targets, tok_mask
 
     #: how the TRAINER counts this task's samples for aggregation weights
@@ -186,7 +194,9 @@ class SequenceLMTask(BaseTask):
 
     def loss(self, params, batch: Batch, rng: Optional[jax.Array] = None,
              train: bool = True):
-        logits, targets, tok_mask = self._logits_targets(params, batch)
+        return self._masked_xent(*self._logits_targets(params, batch), batch)
+
+    def _masked_xent(self, logits, targets, tok_mask, batch: Batch):
         per_tok = softmax_xent(logits, targets)
         total = jnp.sum(per_tok * tok_mask)
         count = jnp.maximum(jnp.sum(tok_mask), 1.0)

@@ -113,3 +113,8 @@ def _load_builtins() -> None:
         TASK_REGISTRY.setdefault("RINGLM", ringlm.make_ringlm_task)
     except ImportError:
         pass
+    try:
+        from . import lfm2
+        TASK_REGISTRY.setdefault("LFM2_MOE", lfm2.make_lfm2_task)
+    except ImportError:
+        pass

@@ -22,6 +22,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
@@ -163,3 +165,303 @@ class MoEFFN(nn.Module):
         y_all = jnp.einsum("teh,ehd->ted", h, w_out)       # [T, E, D]
         y = jnp.take_along_axis(y_all, eid[:, None, None], axis=1)[:, 0]
         return (y * gate[:, None]).reshape(*lead, D)
+
+
+# ======================================================================
+# One chip's share of an expert-parallel layer: the layer is told which
+# experts it holds (``expert_offset .. expert_offset + experts_held - 1``
+# of ``num_experts``), routes every token over ALL experts with the
+# published experts per token, and computes its own experts' part of the
+# result for the tokens routed to them.  Nothing is dropped: the pair
+# buffer is sized for the worst routing.  On one chip there is no
+# exchange, and no code stands in for one.
+# ======================================================================
+#: rows of one tile of the sorted pair buffer; every held expert's rows
+#: start on a tile boundary, so a tile belongs to exactly one expert
+TILE_ROWS = 128
+#: output columns a grid step
+TILE_COLS = 256
+#: stable kernel names (the trace's operation names; the benchmark's
+#: roofline readers find the kernels by them)
+GMM_NAME = "expert_gmm_fwd"
+GMM_T_NAME = "expert_gmm_dx"
+TGMM_NAME = "expert_gmm_dw"
+
+
+def _interpret() -> bool:
+    # off the TPU the plain interpreter: the TPU-flavoured one
+    # (``pltpu.InterpretParams``) works through ordered callbacks, which
+    # ``remat`` under a scan over layers refuses
+    return jax.default_backend() != "tpu"
+
+
+def _dot_precision(dtype):
+    """The kernels' matmul precision: the context's default for float32
+    operands (under ``highest`` Mosaic contracts them in full float32),
+    one MXU pass for bfloat16 operands, which are exact MXU inputs and
+    which Mosaic refuses to contract at a float32 precision."""
+    return lax.Precision.DEFAULT if dtype == jnp.bfloat16 else None
+
+
+def _col_tile(n: int) -> int:
+    tile = min(TILE_COLS, n)
+    if n % tile:
+        raise ValueError(f"expert width {n} is no multiple of {tile}")
+    return tile
+
+
+def _gmm(x, w, tile_expert, n_active, *, transpose_rhs: bool, name: str):
+    """``out[tile i] = x[tile i] @ w[tile_expert[i]]`` (``w[e].T`` with
+    ``transpose_rhs``) for the first ``n_active`` tiles of ``TILE_ROWS``
+    rows; the rows of later tiles are NOT written.  ``x``: ``[M, K]``,
+    ``w``: ``[E, K, N]`` (``[E, N, K]`` transposed).  The whole
+    contraction is one block, so consecutive tiles of one expert reuse
+    the weight block that is already in VMEM."""
+    m, k = x.shape
+    n = w.shape[1] if transpose_rhs else w.shape[2]
+    tn = _col_tile(n)
+    dims = (((1,), (1,)), ((), ())) if transpose_rhs \
+        else (((1,), (0,)), ((), ()))
+
+    def kernel(tile_expert_ref, x_ref, w_ref, o_ref):
+        del tile_expert_ref
+        o_ref[...] = lax.dot_general(
+            x_ref[...], w_ref[...], dims, precision=_dot_precision(x.dtype),
+            preferred_element_type=jnp.float32).astype(o_ref.dtype)
+
+    if transpose_rhs:
+        w_spec = pl.BlockSpec((None, tn, k), lambda j, i, te: (te[i], j, 0))
+    else:
+        w_spec = pl.BlockSpec((None, k, tn), lambda j, i, te: (te[i], 0, j))
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            in_specs=[pl.BlockSpec((TILE_ROWS, k), lambda j, i, te: (i, 0)),
+                      w_spec],
+            out_specs=pl.BlockSpec((TILE_ROWS, tn),
+                                   lambda j, i, te: (i, j)),
+            grid=(n // tn, n_active)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(), name=name,
+    )(tile_expert, x, w)
+
+
+def _tgmm(x, dy, tile_expert, n_active, num_experts: int):
+    """``out[e] = sum over e's tiles of x[tile].T @ dy[tile]``: the
+    weight gradient ``[E, K, N]``.  Every expert owns at least one tile
+    (``plan_pairs``), so every block of the output is written."""
+    m, k = x.shape
+    n = dy.shape[1]
+    tn = _col_tile(n)
+    n_tiles = tile_expert.shape[0]
+
+    def kernel(tile_expert_ref, n_active_ref, x_ref, dy_ref, o_ref, acc_ref):
+        i = pl.program_id(1)
+        here = tile_expert_ref[i]
+        first = jnp.logical_or(
+            i == 0, tile_expert_ref[jnp.maximum(i - 1, 0)] != here)
+        last = jnp.logical_or(
+            i == n_active_ref[0] - 1,
+            tile_expert_ref[jnp.minimum(i + 1, n_tiles - 1)] != here)
+
+        @pl.when(first)
+        def _():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        acc_ref[...] += lax.dot_general(
+            x_ref[...], dy_ref[...], (((0,), (0,)), ((), ())),
+            precision=_dot_precision(x.dtype),
+            preferred_element_type=jnp.float32)
+
+        @pl.when(last)
+        def _():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct((num_experts, k, n), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            in_specs=[
+                pl.BlockSpec((TILE_ROWS, k), lambda j, i, te, na: (i, 0)),
+                pl.BlockSpec((TILE_ROWS, tn), lambda j, i, te, na: (i, j))],
+            out_specs=pl.BlockSpec((None, k, tn),
+                                   lambda j, i, te, na: (te[i], 0, j)),
+            grid=(n // tn, n_active),
+            scratch_shapes=[pltpu.VMEM((k, tn), jnp.float32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(), name=TGMM_NAME,
+    )(tile_expert, jnp.reshape(n_active, (1,)), x, dy)
+
+
+@jax.custom_vjp
+def grouped_matmul(x, w, tile_expert, n_active):
+    """Rows of ``x`` (sorted by expert, each expert's rows starting on a
+    tile boundary) times their expert's matrix.  Rows beyond the active
+    tiles come back unwritten and must not be read: ``held_experts_ffn``
+    gathers only rows that a pair owns."""
+    return _gmm(x, w, tile_expert, n_active, transpose_rhs=False,
+                name=GMM_NAME)
+
+
+def _grouped_matmul_fwd(x, w, tile_expert, n_active):
+    return grouped_matmul(x, w, tile_expert, n_active), \
+        (x, w, tile_expert, n_active)
+
+
+def _grouped_matmul_bwd(saved, dy):
+    x, w, tile_expert, n_active = saved
+    dx = _gmm(dy, w, tile_expert, n_active, transpose_rhs=True,
+              name=GMM_T_NAME)
+    dw = _tgmm(x, dy, tile_expert, n_active, w.shape[0])
+    return dx, dw.astype(w.dtype), None, None
+
+
+grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def route_tokens(z, router_w, select_bias, experts_per_token: int,
+                 scaling: float = 1.0):
+    """Sigmoid routing with a selection bias over ALL experts:
+    ``(chosen [T, k] int32, gate [T, k] float32)``.  The logits are
+    float32 at ``highest`` whatever the context (a choice that flips on
+    rounding is a discrete event); the bias enters the choice only and
+    gets no gradient; the gate is renormalised over all chosen experts,
+    held here or not."""
+    logits = jnp.matmul(z.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, chosen = lax.top_k(
+        scores + lax.stop_gradient(select_bias.astype(jnp.float32)),
+        experts_per_token)
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    gate = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6) * scaling
+    return chosen, gate
+
+
+def plan_pairs(chosen, experts_held: int, expert_offset: int):
+    """Where each (token, chosen expert) pair that falls on a held expert
+    lies in the sorted pair buffer.  Every held expert gets at least one
+    tile and starts on a tile boundary; the buffer has room for every
+    pair of every token on held experts (``T * k`` rows) plus a tile of
+    slack an expert, so no routing drops a pair.
+
+    Returns ``row_of_pair [T, k]`` (a held pair's row; 0, which is always
+    a written row, for a pair elsewhere), ``held [T, k]`` bool,
+    ``pair_of_row [M]`` (the flat pair ``t * k + j`` that owns the row;
+    ``T * k`` = no pair: a gather there fills zeros),
+    ``tile_expert [M / TILE_ROWS]``, ``n_active`` and
+    ``counts [experts_held]``."""
+    tokens, k = chosen.shape
+    pairs = tokens * k
+    n_tiles = -(-pairs // TILE_ROWS) + experts_held
+    local = chosen.reshape(pairs) - expert_offset
+    held = (local >= 0) & (local < experts_held)
+    onehot = jax.nn.one_hot(jnp.where(held, local, experts_held),
+                            experts_held + 1, dtype=jnp.int32)[:, :-1]
+    counts = jnp.sum(onehot, axis=0)
+    # a pair's rank among its expert's pairs, in token order
+    rank = jnp.sum((jnp.cumsum(onehot, axis=0) - 1) * onehot, axis=1)
+    tiles = jnp.maximum(-(-counts // TILE_ROWS), 1)
+    tile_end = jnp.cumsum(tiles)
+    start = (tile_end - tiles) * TILE_ROWS
+    row = jnp.where(held, start[jnp.clip(local, 0, experts_held - 1)] + rank,
+                    n_tiles * TILE_ROWS)
+    pair_of_row = jnp.full((n_tiles * TILE_ROWS,), pairs, jnp.int32).at[
+        row].set(jnp.arange(pairs, dtype=jnp.int32), mode="drop")
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, jnp.arange(n_tiles, dtype=jnp.int32),
+                         side="right"), experts_held - 1).astype(jnp.int32)
+    return (jnp.where(held, row, 0).reshape(tokens, k),
+            held.reshape(tokens, k), pair_of_row, tile_expert,
+            tile_end[-1].astype(jnp.int32), counts)
+
+
+# The two moves between token order and the sorted pair buffer.  Each is
+# a gather, and so is its transpose, written out here: a row belongs to
+# one pair and a held pair to one row, so the scatter-add that autodiff
+# would make of a gather is the other map's gather (XLA's scatter of
+# 17,408 rows of 2,048 floats took 14 s to compile for the chip, per
+# expert layer, and runs row by row).
+@jax.custom_vjp
+def rows_of_tokens(z, pair_of_row, row_of_pair, held):
+    """``z [T, D]`` -> the pair buffer ``[M, D]``: row ``r`` is its
+    pair's token (zeros where no pair owns the row)."""
+    per_token = row_of_pair.shape[1]
+    return z.at[pair_of_row // per_token].get(mode="fill", fill_value=0)
+
+
+def _rows_of_tokens_fwd(z, pair_of_row, row_of_pair, held):
+    return rows_of_tokens(z, pair_of_row, row_of_pair, held), \
+        (row_of_pair, held)
+
+
+def _rows_of_tokens_bwd(saved, d_rows):
+    row_of_pair, held = saved
+    d_z = jnp.sum(jnp.where(held[..., None], d_rows[row_of_pair], 0), axis=1)
+    return d_z, None, None, None
+
+
+rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
+
+
+@jax.custom_vjp
+def tokens_of_rows(y_rows, weight, pair_of_row, row_of_pair):
+    """The pair buffer ``y_rows [M, D]`` -> ``y [T, D]``: each token's
+    rows, weighted (``weight [T, k]``, 0 for a pair held elsewhere)."""
+    return jnp.einsum("tkd,tk->td", y_rows[row_of_pair], weight)
+
+
+def _tokens_of_rows_fwd(y_rows, weight, pair_of_row, row_of_pair):
+    return tokens_of_rows(y_rows, weight, pair_of_row, row_of_pair), \
+        (y_rows, weight, pair_of_row, row_of_pair)
+
+
+def _tokens_of_rows_bwd(saved, d_y):
+    y_rows, weight, pair_of_row, row_of_pair = saved
+    per_token = weight.shape[1]
+    d_weight = jnp.einsum("tkd,td->tk", y_rows[row_of_pair], d_y)
+    weight_of_row = weight.reshape(-1).at[pair_of_row].get(
+        mode="fill", fill_value=0)
+    d_rows = d_y.at[pair_of_row // per_token].get(
+        mode="fill", fill_value=0) * weight_of_row[:, None]
+    return d_rows.astype(y_rows.dtype), d_weight.astype(weight.dtype), \
+        None, None
+
+
+tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
+
+
+def held_experts_ffn(z, router_w, select_bias, w1, w3, w2, *,
+                     experts_per_token: int, expert_offset: int = 0,
+                     scaling: float = 1.0):
+    """The held experts' part of a routed SwiGLU layer for tokens
+    ``z [T, D]``: ``sum over chosen AND held i of g_i E_i(z)``.
+    ``w1``/``w3``: ``[held, D, H]``, ``w2``: ``[held, H, D]``,
+    ``router_w``: ``[D, num_experts]``.  Returns ``(y [T, D],
+    counters)``; the counters (float32 scalars) are what the telemetry
+    reads: pairs on held experts, the largest held expert's load, and
+    pairs without a row (0 by construction)."""
+    held_n = w1.shape[0]
+    chosen, gate = route_tokens(z, router_w, select_bias, experts_per_token,
+                                scaling)
+    row_of_pair, held, pair_of_row, tile_expert, n_active, counts = \
+        plan_pairs(chosen, held_n, expert_offset)
+    x_sorted = rows_of_tokens(z, pair_of_row, row_of_pair, held)
+    hidden = jax.nn.silu(grouped_matmul(x_sorted, w1, tile_expert,
+                                        n_active)) * \
+        grouped_matmul(x_sorted, w3, tile_expert, n_active)
+    y_sorted = grouped_matmul(hidden, w2, tile_expert, n_active)
+    weight = jnp.where(held, gate, 0.0).astype(z.dtype)
+    y = tokens_of_rows(y_sorted, weight, pair_of_row, row_of_pair)
+    placed = jnp.sum((pair_of_row < chosen.size).astype(jnp.float32))
+    on_held = jnp.sum(counts).astype(jnp.float32)
+    counters = {"moe_pairs_held": on_held,
+                "moe_max_load": jnp.max(counts).astype(jnp.float32),
+                "moe_pairs_dropped": on_held - placed,
+                "moe_layer_steps": jnp.ones((), jnp.float32)}
+    return y, counters

@@ -47,11 +47,16 @@ class CheckpointEscalationError(RuntimeError):
 # ----------------------------------------------------------------------
 # checksums
 # ----------------------------------------------------------------------
+def checksum_hex(crc: int) -> str:
+    """A (running) ``zlib.crc32`` as the sidecars record it."""
+    return f"{crc & 0xFFFFFFFF:08x}"
+
+
 def blob_checksum(blob: bytes) -> str:
     """crc32 (hex) of a serialized checkpoint blob.  crc32, not a
     cryptographic hash: the threat model is torn writes and bit rot, not
     an adversary, and crc32 streams at memory bandwidth."""
-    return f"{zlib.crc32(blob) & 0xFFFFFFFF:08x}"
+    return checksum_hex(zlib.crc32(blob))
 
 
 def tree_checksum(dir_path: str) -> str:

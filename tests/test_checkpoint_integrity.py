@@ -268,3 +268,202 @@ def test_drain_failure_counts_toward_escalation_but_keeps_renames(
     # the queued rename survives (its tmp dir may belong to an EARLIER
     # successful save; the isdir guard skips truly-failed ones)
     assert len(cm._pending_renames) == 1
+
+
+# ----------------------------------------------------------------------
+# the streamed msgpack form (PR 28): flax's bytes, no assembled blob
+# ----------------------------------------------------------------------
+def _awkward_tree():
+    """Every kind of leaf the framing has to tell apart: sizes on both
+    sides of msgpack's 1-, 2- and 4-byte length forms, a 0-d array, an
+    empty one, bfloat16, integers, numpy and python scalars, None, more
+    than 15 keys in one dict, and keys that sort differently from their
+    insertion order."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(0)
+    many = {f"k{i:02d}": np.float32(i) for i in range(20)}
+    return {
+        "params": {
+            "z_last": rng.standard_normal((300, 70)).astype(np.float32),
+            "a_first": rng.standard_normal((3,)).astype(np.float32),
+            "tiny": np.zeros((1,), np.int8),
+            "scalar0d": np.array(2.5, np.float32),
+            "empty": np.zeros((0, 4), np.float32),
+            "mid": np.arange(100, dtype=np.int32),       # 400 bytes: bin16
+            "wide": np.arange(40000, dtype=np.int16),    # 80 kB: bin32
+            "bf16": jnp.arange(12, dtype=jnp.bfloat16).reshape(3, 4),
+            "on_device": jnp.linspace(0.0, 1.0, 1000).reshape(10, 100),
+        },
+        "opt_state": {"0": {"count": np.int32(7), "mu": None}, "1": many},
+        "strategy_state": {},
+        "round": 12,
+    }
+
+
+def test_streamed_chunks_are_flax_msgpack_byte_for_byte():
+    from flax import serialization
+
+    from msrflute_tpu.engine import checkpoint as ckpt_mod
+    tree = _awkward_tree()
+    chunks = ckpt_mod._state_chunks(tree)
+    mine = b"".join(bytes(ckpt_mod._host_bytes(c)) for c in chunks)
+    assert mine == serialization.msgpack_serialize(
+        serialization.to_state_dict(tree))
+    assert ckpt_mod._chunks_size(chunks) == len(mine)
+    # array leaves ride as they are (no copy is assembled): the big ones
+    # are chunks of their own, and a device leaf stays on the device
+    # until its chunk is written
+    import jax
+    assert any(isinstance(c, jax.Array) for c in chunks)
+    assert any(isinstance(c, np.ndarray) and c.nbytes == 84000
+               for c in chunks)
+
+
+def test_one_fetch_serves_every_best_name_and_each_loads(tmp_path):
+    from msrflute_tpu.engine import checkpoint as ckpt_mod
+    cm = CheckpointManager(str(tmp_path), retry=_no_sleep_policy())
+    built = []
+    real = ckpt_mod._state_chunks
+    ckpt_mod._state_chunks = lambda payload: built.append(1) or real(payload)
+    try:
+        cm.save_best(_state(3, scale=3.0), "loss", "acc")
+    finally:
+        ckpt_mod._state_chunks = real
+    assert built == [1]
+    loss, acc = (tmp_path / f"best_val_{n}_model.msgpack"
+                 for n in ("loss", "acc"))
+    assert os.path.samefile(loss, acc)  # a link, not a second write
+    for name in ("loss", "acc"):
+        assert cm.load_best(_state(0), name).round == 3
+    # a later save of one name replaces that name's file only
+    cm.save_best(_state(5, scale=5.0), "acc")
+    assert not os.path.samefile(loss, acc)
+    assert cm.load_best(_state(0), "loss").round == 3
+    assert cm.load_best(_state(0), "acc").round == 5
+    meta = json.load(open(str(loss) + ".sum"))
+    assert meta["crc32"] == blob_checksum(open(loss, "rb").read())
+
+
+def test_a_retried_streamed_write_lands_whole(tmp_path):
+    """The first attempt dies after the chunks were fetched; the retry
+    finds the host bytes in the list and writes the same file."""
+    import jax.numpy as jnp
+    fails = iter([True, False, False])
+    cm = CheckpointManager(
+        str(tmp_path), retry=_no_sleep_policy(),
+        io_fault=lambda: next(fails) and (_ for _ in ()).throw(
+            OSError("injected")))
+    state = ServerState(params={"w": jnp.full((64, 32), 2.0)},
+                        opt_state={}, strategy_state={}, round=9)
+    cm.save_latest(state)
+    restored = cm.load(ServerState(params={"w": np.zeros((64, 32),
+                                                         np.float32)},
+                                   opt_state={}, strategy_state={}, round=0))
+    assert restored.round == 9
+    np.testing.assert_array_equal(np.asarray(restored.params["w"]), 2.0)
+
+
+@pytest.mark.parametrize("async_latest", [False, True],
+                         ids=["sync", "async_writer"])
+def test_the_latest_of_a_state_just_saved_as_best_is_a_link(tmp_path,
+                                                            async_latest):
+    """An evaluation round saves its state twice, as the best model and
+    as ``latest``.  The best-model save is durable on return, with the
+    async writer too (the status log names the new best value next);
+    told that the ``latest`` is of the very same state (``same_as``),
+    the manager makes it a link to that file, durable on return (no
+    second fetch, no second 1.9 GB through the disk), rotation and
+    sidecars as ever; without that word a state is written whole."""
+    import jax.numpy as jnp
+
+    from msrflute_tpu.engine import checkpoint as ckpt_mod
+
+    def device_state(round_no):
+        return ServerState(params={"w": jnp.full((8, 4), float(round_no))},
+                           opt_state={"m": jnp.zeros((8, 4))},
+                           strategy_state={}, round=round_no)
+
+    template = device_state(0)
+    cm = CheckpointManager(str(tmp_path), retry=_no_sleep_policy(),
+                           async_latest=async_latest)
+    built = []
+    real = ckpt_mod._state_chunks
+    ckpt_mod._state_chunks = lambda payload: built.append(1) or real(payload)
+    latest, best = tmp_path / LATEST, tmp_path / "best_val_loss_model.msgpack"
+    try:
+        cm.save_latest(device_state(1))
+        state = device_state(2)
+        written = cm.save_best(state, "loss", "acc")
+        # no wait: the best model is on the disk, whole, with its sidecar
+        assert written == str(best)
+        meta = json.load(open(str(best) + ".sum"))
+        assert meta["crc32"] == blob_checksum(open(best, "rb").read())
+        assert cm.save_latest(state, same_as=written) is None
+        # no wait here either: round 1's latest landed first and rotated
+        assert os.path.samefile(latest, best)
+        assert json.load(open(str(latest) + ".sum")) == meta
+        assert len(built) == 2  # round 1's latest, round 2's best
+        assert cm.load(template).round == 2
+        assert cm.load(template, LATEST_PREV).round == 1
+        # without the caller's word the same values are written whole
+        cm.save_latest(state)
+        cm.wait()
+        assert len(built) == 3 and not os.path.samefile(latest, best)
+        assert cm.load(template, LATEST_PREV).round == 2
+        # a later best model replaces its own name's file only
+        cm.save_best(device_state(4), "loss")
+        assert cm.load_best(template, "loss").round == 4
+        assert cm.load_best(template, "acc").round == 2
+        assert cm.load(template).round == 2
+    finally:
+        ckpt_mod._state_chunks = real
+
+
+def test_links_and_whole_saves_interleaved_under_the_async_writer(tmp_path):
+    """The training thread and the writer meet on files: best-model
+    saves and the links to them are made on this thread, `latest` saves
+    of the rounds between on the writer's.  Many rounds without a wait
+    between them, the interpreter switching threads every few
+    instructions: every file that lands is whole and of the state it is
+    named for."""
+    import sys
+    import time
+
+    import jax.numpy as jnp
+
+    def device_state(round_no):
+        return ServerState(params={"w": jnp.full((16, 8), float(round_no))},
+                           opt_state={}, strategy_state={}, round=round_no)
+
+    template = device_state(0)
+    cm = CheckpointManager(str(tmp_path), retry=_no_sleep_policy(),
+                           async_latest=True)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    deadline = time.time() + 60
+    last_best = None
+    try:
+        for round_no in range(1, 121):
+            assert time.time() < deadline, "the saves stalled"
+            state = device_state(round_no)
+            written = None
+            if round_no % 3 == 0:  # an evaluation round: best, then latest
+                written = cm.save_best(state, "loss", "acc")
+                last_best = round_no
+            cm.save_latest(state, same_as=written)
+            if round_no % 10 == 0:
+                # whatever has landed by now is whole and consistent
+                got = cm.load(template)
+                assert got.round == round_no
+                np.testing.assert_array_equal(
+                    np.asarray(got.params["w"]), float(round_no))
+        cm.wait()
+    finally:
+        sys.setswitchinterval(interval)
+    assert cm.load(template).round == 120
+    assert cm.load(template, LATEST_PREV).round == 119
+    for name in ("loss", "acc"):
+        best = cm.load_best(template, name)
+        assert best.round == last_best == 120
+        np.testing.assert_array_equal(np.asarray(best.params["w"]), 120.0)
+    assert cm.escalator.consecutive == 0

@@ -9,6 +9,7 @@ the running round program, so the host sat in the pre-dispatch submit
 instead of staging the next chunk.
 """
 
+import os
 import threading
 
 import jax
@@ -251,15 +252,16 @@ def test_writer_asks_for_the_transfer_only_once_the_snapshot_is_computed(
     x = jnp.eye(384) * 0.5
     long_program(x).block_until_ready()
     ready_when_fetched = []
-    real_get = jax.device_get
+    real_chunks = ckpt_mod._state_chunks
 
-    def watching_get(tree):
+    def watching_chunks(tree):
+        # where the writer starts every leaf's transfer to the host
         ready_when_fetched.append(all(
             leaf.is_ready() for leaf in jax.tree.leaves(tree)
             if isinstance(leaf, jax.Array)))
-        return real_get(tree)
+        return real_chunks(tree)
 
-    monkeypatch.setattr(ckpt_mod.jax, "device_get", watching_get)
+    monkeypatch.setattr(ckpt_mod, "_state_chunks", watching_chunks)
     mgr = CheckpointManager(str(tmp_path), backend="msgpack",
                             async_latest=True)
     # the state is what the program in flight is still computing
@@ -269,3 +271,58 @@ def test_writer_asks_for_the_transfer_only_once_the_snapshot_is_computed(
     mgr.save_latest(state)
     mgr.wait()
     assert ready_when_fetched == [True]
+
+
+def test_a_best_model_save_is_durable_on_return_beside_a_busy_writer(
+        tmp_path, monkeypatch):
+    """An evaluation round saves its state as the best model and then as
+    ``latest``.  The best-model save runs on the caller's thread, also
+    with the async writer, and is on the disk when it returns, while the
+    writer is still inside an earlier round's ``latest``: the status log
+    that names the new best value is written next.  The ``latest`` of
+    that state is a link to the file, made in its turn: it waits for the
+    earlier ``latest`` to land, then rotates it to ``.prev``."""
+    gate, entered = threading.Event(), threading.Event()
+    real_write = CheckpointManager._write_blob
+
+    def gated_write(self, path, blob, keep_prev=False):
+        if threading.current_thread().name == "ckpt-latest-writer":
+            entered.set()
+            assert gate.wait(timeout=60), "test gate never opened"
+        return real_write(self, path, blob, keep_prev=keep_prev)
+
+    monkeypatch.setattr(CheckpointManager, "_write_blob", gated_write)
+    programs = []
+    real_program = ckpt_mod._copy_device_leaves
+    monkeypatch.setattr(
+        ckpt_mod, "_copy_device_leaves",
+        lambda leaves: programs.append(len(leaves)) or real_program(leaves))
+
+    mgr = CheckpointManager(str(tmp_path), backend="msgpack",
+                            async_latest=True)
+    mgr.save_latest(_state(3))
+    assert entered.wait(timeout=60), "the writer never started the save"
+    state = _state(4, scale=2.0)
+    written = mgr.save_best(state, "loss", "acc")
+    template = _state(0, scale=0.0)
+    for name in ("loss", "acc"):  # durable now, the writer still busy
+        assert os.path.exists(os.path.join(
+            str(tmp_path), f"best_val_{name}_model.msgpack.sum"))
+    assert len(programs) == 1  # no snapshot program for the best model
+
+    linked = threading.Event()
+    link = threading.Thread(
+        target=lambda: (mgr.save_latest(state, same_as=written),
+                        linked.set()), name="link-latest")
+    link.start()
+    assert not linked.wait(timeout=0.5), \
+        "the link did not wait for the earlier latest to land"
+    gate.set()
+    assert linked.wait(timeout=60)
+    link.join(timeout=60)
+    assert not link.is_alive()
+    assert len(programs) == 1
+    for name in ("loss", "acc"):
+        assert mgr.load_best(template, name).round == 4
+    assert mgr.load(template).round == 4
+    assert mgr.load(template, ckpt_mod.LATEST_PREV).round == 3

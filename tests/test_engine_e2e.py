@@ -131,6 +131,59 @@ def test_async_latest_msgpack_checkpoint(synth_dataset, mesh8, tmp_path):
     assert s2.train().round == 5
 
 
+@pytest.mark.parametrize("pipeline_depth", [0, 1], ids=["serial", "ring"])
+def test_status_log_never_names_a_best_model_that_is_not_on_disk(
+        synth_dataset, mesh8, tmp_path, pipeline_depth):
+    """With the async writer too, a best-model save is durable before
+    ``status_log.json`` names the new ``best_val`` (a crash in between
+    would otherwise resume with a best value that no file holds); and an
+    evaluation round's ``latest``, told to be of the very state the best
+    model was just written from, is a link to that file."""
+    import json
+    import os
+
+    from msrflute_tpu.engine.checkpoint import LATEST
+    from msrflute_tpu.resilience.integrity import blob_checksum
+
+    cfg = _config(max_iteration=6, checkpoint_async=True,
+                  pipeline_depth=pipeline_depth)
+    task = make_task(cfg.model_config)
+    d = tmp_path / "models"
+    server = OptimizationServer(task, cfg, synth_dataset,
+                                val_dataset=synth_dataset,
+                                model_dir=str(d), mesh=mesh8, seed=7)
+    assert server.ckpt.async_latest
+    named, linked = {}, []
+    update_status = server.ckpt.update_status
+    save_latest = server.ckpt.save_latest
+
+    def checked_status(update):
+        for key, value in update.items():
+            if key.startswith("best_val_") and key != "best_val_hib" and \
+                    named.get(key) != value:
+                named[key] = value
+                path = d / f"{key}_model.msgpack"
+                meta = json.loads((d / f"{key}_model.msgpack.sum")
+                                  .read_text())
+                assert meta["crc32"] == blob_checksum(path.read_bytes())
+        return update_status(update)
+
+    def watched_latest(state, same_as=None):
+        out = save_latest(state, same_as=same_as)
+        if same_as is not None:
+            linked.append(os.path.samefile(d / LATEST, same_as))
+        return out
+
+    server.ckpt.update_status = checked_status
+    server.ckpt.save_latest = watched_latest
+    server.train()
+    assert {"best_val_acc", "best_val_loss"} <= set(named)
+    if pipeline_depth == 0:
+        # every round's latest goes out in housekeeping: the rounds whose
+        # evaluation improved a metric linked it
+        assert linked and all(linked)
+
+
 def test_orbax_async_checkpoint_backend(synth_dataset, mesh8, tmp_path):
     """server_config.checkpoint_backend: orbax — async saves land durable
     checkpoints and resume restores the exact state, like msgpack."""

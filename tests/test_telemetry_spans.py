@@ -182,6 +182,9 @@ def test_async_writer_span_is_on_another_thread(cli_run):
     assert writes
     for span in writes:
         assert span["thread"] != "MainThread" and span["parent"] is None
+        # what the save wrote (benchmarks/layer_metrics/ckpt_write_ms.py
+        # reads only spans that say so)
+        assert isinstance(span["bytes"], int) and span["bytes"] > 0
 
 
 def test_serial_dispatch_finds_nothing_in_flight(serial_run):
@@ -215,7 +218,8 @@ def test_setup_phase_ends_before_the_first_dispatch(cli_run, name):
     assert span["dur_s"] >= 0 and span["ts"] + span["dur_s"] <= first
     phases = [_spans(cli_run["records"], n)[0] for n in SETUP]
     for a, b in zip(phases, phases[1:]):
-        assert a["ts"] + a["dur_s"] <= b["ts"] + 1e-6
+        # ts and dur_s are each rounded to a microsecond in the record
+        assert a["ts"] + a["dur_s"] <= b["ts"] + 2e-6
 
 
 @pytest.mark.parametrize("name", COMPILES)

@@ -171,3 +171,63 @@ def test_latest_snapshot_is_one_copy_program_on_the_four_chip_mesh(topology):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes == 0, "an output aliases its input"
     assert memory.output_size_in_bytes >= memory.argument_size_in_bytes
+
+
+# ----------------------------------------------------------------------
+# the expert layer's grouped matmuls at LFM2-24B-A2B's widths (PR 28)
+# ----------------------------------------------------------------------
+EXPERT = {"tokens": 4096, "per_token": 4, "held": 8, "hidden": 2048,
+          "width": 1536}
+
+
+@pytest.mark.parametrize("dtype, precision", [
+    (jnp.float32, None), (jnp.float32, "highest"),
+    (jnp.bfloat16, "highest")], ids=["f32", "f32_highest", "bf16_highest"])
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+def test_expert_layer_compiles_for_v5e_at_published_widths(
+        chip, monkeypatch, direction, dtype, precision):
+    """One chip's share of an expert layer (8 of 64 experts, hidden 2,048,
+    expert width 1,536, a 4,096-token row): the three Pallas kernels are
+    in the program under their stable names and fit the chip's fast
+    memory, in float32 as the cell runs them, under ``highest`` as the
+    benchmark's check program traces them, and with bfloat16 operands
+    under ``highest`` as its lower-precision control does (Mosaic refuses
+    a float32 contraction of bfloat16 operands: the kernels ask for one
+    pass there).  ``default_backend`` is steered here, in the test."""
+    import contextlib
+    from msrflute_tpu.ops import moe
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    e = EXPERT
+
+    def spec(*shape, kind=dtype):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=chip)
+
+    def ffn(z, router, bias, w1, w3, w2):
+        return moe.held_experts_ffn(z, router, bias, w1, w3, w2,
+                                    experts_per_token=e["per_token"])
+
+    def backward(*args):
+        return jax.grad(lambda *a: jnp.sum(ffn(*a)[0]),
+                        argnums=(0, 1, 3, 4, 5))(*args)
+
+    args = (spec(e["tokens"], e["hidden"]),
+            spec(e["hidden"], 64, kind=jnp.float32),
+            spec(64, kind=jnp.float32),
+            spec(e["held"], e["hidden"], e["width"]),
+            spec(e["held"], e["hidden"], e["width"]),
+            spec(e["held"], e["width"], e["hidden"]))
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        compiled = jax.jit(ffn if direction == "forward" else
+                           backward).lower(*args).compile()
+    text = compiled.as_text()
+    names = [moe.GMM_NAME] if direction == "forward" else \
+        [moe.GMM_NAME, moe.GMM_T_NAME, moe.TGMM_NAME]
+    for name in names:
+        assert name in text, name
+    # the moves between token order and the pair buffer are gathers in
+    # both directions: no scatter of [rows, hidden] (14 s of compile a
+    # layer, and run row by row); the routing's own transpose, 16,384
+    # scores into a flat [tokens * experts], stays a scatter
+    import re
+    assert not re.search(r"= (f32|bf16)\[\d+,\d+\]\S* scatter\(", text)
