@@ -12,7 +12,8 @@ the fused elementwise passes over flattened updates.  Three kernels:
   server-side global-DP step (``privacy.apply_global_dp``).
 - :func:`quant_bin_sparsify` — histogram binning to ``n_bins`` levels +
   magnitude sparsification in one pass (the elementwise core of
-  ``ops.quantization``; min/max/quantile stay in XLA where sort belongs).
+  ``ops.quantization``; min/max and the threshold's rank selection stay
+  in XLA, as reductions).
 - :func:`fused_sgd_apply` — the momentum-SGD parameter update over the
   FLATTENED param vector in one pass: ``m' = g + mu*m``, ``p' = p -
   lr*m'``, with the all-padding-step no-op gate folded in.  The opt-in

@@ -12,9 +12,10 @@ per-layer (or global) min/max histogram binning of the gradient into
   greater survives (``quant.py:50-51``).
 
 TPU-native: pure jnp, runs inside the jitted round under vmap over clients.
-This is the designated Pallas-fusion candidate (SURVEY.md §7): a fused
-clip->noise->bin pass over the flat update; see
-:mod:`msrflute_tpu.ops.pallas_kernels`.
+The threshold is an exact rank selection (:func:`quantile_abs`: a fixed
+number of fused compare-and-count passes over the leaf, no sort); the
+elementwise bin+sparsify pass is the Pallas kernel of
+:mod:`msrflute_tpu.ops.pallas_kernels` on TPU.
 """
 
 from __future__ import annotations
@@ -23,18 +24,92 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+
+#: ``|x|`` of a float32 as its bit pattern: non-negative floats order as
+#: their patterns do as int32, NaN above inf
+_ABS_BITS = 0x7FFFFFFF
+_INF_BITS = 0x7F800000
+
+
+def abs_order_stats(x: jnp.ndarray, low, high):
+    """``jnp.sort(jnp.abs(x).ravel())[low]`` and ``[high]`` without the
+    sort, bit for bit, plus whether ``x`` holds a NaN (which then orders
+    above inf, as in the sort).  ``low`` and ``high`` are int32 ranks,
+    traced or not, with ``low <= high <= low + 1`` inside ``[0, n)``.
+
+    The rank-``low`` pattern is the largest ``v`` with
+    ``count(|x| < v) <= low``; that predicate is monotone in ``v``, so
+    ``v`` is built bit by bit from the top: 31 passes, each one fused
+    compare-and-count that only reads ``x``.  One more pass gives rank
+    ``high``: the same value if more than ``high`` elements are ``<=`` it,
+    else the least element above it.  The loop has a fixed length, so it
+    batches under ``vmap`` with no masking."""
+    def bits(leaf):
+        # rebuilt where it is used, so that it fuses into each pass's
+        # reduction and no |x| copy of the leaf is kept
+        return lax.bitcast_convert_type(
+            leaf.astype(jnp.float32), jnp.int32) & _ABS_BITS
+
+    def keep_or_clear(i, prefix):
+        # the leaf tied to the loop counter: the TPU compiler otherwise
+        # hoists its bitcast out of the loop as an int32 copy of the leaf
+        leaf, _ = lax.optimization_barrier((x, i))
+        cand = prefix | jnp.left_shift(jnp.int32(1), 30 - i)
+        below = jnp.sum(bits(leaf) < cand, dtype=jnp.int32)
+        return jnp.where(below <= low, cand, prefix)
+
+    with jax.named_scope("quant_select"):
+        low_bits = lax.fori_loop(0, 31, keep_or_clear, jnp.int32(0))
+        b = bits(x)
+        at_most = jnp.sum(b <= low_bits, dtype=jnp.int32)
+        above = jnp.min(jnp.where(b > low_bits, b, _ABS_BITS))
+        has_nan = jnp.max(b) > _INF_BITS
+        high_bits = jnp.where(at_most > high, low_bits, above)
+    return (lax.bitcast_convert_type(low_bits, jnp.float32),
+            lax.bitcast_convert_type(high_bits, jnp.float32), has_nan)
+
+
+def quantile_ranks(n: int, q):
+    """Ranks ``low``/``high`` (int32) and their weights for the linear
+    quantile ``q`` of ``n`` sorted values, computed as
+    ``jax._src.numpy.reductions._quantile`` computes them: float32
+    ``q * (n - 1)``, floor and ceil, clamped to ``[0, n - 1]``.  Above
+    2**24 elements float32 ``n - 1`` can round up to ``n``; the ranks
+    are held to the last element once more as integers, where
+    ``jnp.quantile``'s gather clips."""
+    last = jnp.float32(n) - 1
+    pos = jnp.asarray(q, jnp.float32) * last
+    low, high = jnp.floor(pos), jnp.ceil(pos)
+    high_weight = pos - low
+    low_weight = 1 - high_weight
+    low = jnp.minimum(lax.clamp(0.0, low, last).astype(jnp.int32), n - 1)
+    high = jnp.minimum(lax.clamp(0.0, high, last).astype(jnp.int32), n - 1)
+    return low, high, low_weight, high_weight
+
+
+def quantile_abs(x: jnp.ndarray, q) -> jnp.ndarray:
+    """``jnp.quantile(jnp.abs(x), q)`` (float32, method ``linear``) by
+    selection instead of a sort: the two order statistics of
+    :func:`quantile_ranks` from :func:`abs_order_stats`, blended as
+    ``jnp.quantile`` blends them; NaN if ``x`` holds one.  ``q`` may be
+    traced."""
+    low, high, low_weight, high_weight = quantile_ranks(x.size, q)
+    low_value, high_value, has_nan = abs_order_stats(x, low, high)
+    return jnp.where(has_nan, jnp.nan,
+                     low_value * low_weight + high_value * high_weight)
 
 
 def approx_quantile_abs(x: jnp.ndarray, q, n_bins: int = 2048) -> jnp.ndarray:
     """Histogram-CDF approximation of ``quantile(|x|, q)``.
 
-    ``jnp.quantile`` sorts — O(n log n) *per leaf per client* under the
-    round's vmap, which profiling flagged as the dominant cost of a
-    DGA+quant round.  A fixed-width histogram of ``|x|`` is one O(n)
-    scatter-add; the threshold is linearly interpolated inside the bin
-    where the CDF crosses ``q``.  Max error is one bin width
-    (``max|x| / n_bins``) — far below the annealed-threshold granularity
-    the reference runs with (``extensions/quantization/quant.py:50-51``).
+    An estimate, not the published threshold (:func:`quantile_abs` is
+    that, exactly, in 32 passes over the leaf): a fixed-width histogram
+    of ``|x|`` is one O(n) scatter-add, and the threshold is linearly
+    interpolated inside the bin where the CDF crosses ``q``.  Max error
+    is one bin width (``max|x| / n_bins``) — far below the
+    annealed-threshold granularity the reference runs with
+    (``extensions/quantization/quant.py:50-51``).
     """
     a = jnp.abs(x.reshape(-1).astype(jnp.float32))
     hi = jnp.maximum(jnp.max(a), 1e-30)
@@ -71,14 +146,15 @@ def quantize_array(grad: jnp.ndarray, n_bins: int,
     """Quantize one tensor to ``n_bins`` levels, zeroing sub-threshold
     components (reference ``quant_bins`` + thresholding).
 
-    Stats (min/max/quantile) run in XLA; on TPU the elementwise
-    bin+sparsify pass runs as the fused Pallas kernel where a compiled
-    kernel can apply (``pallas_kernels.compiled_kernels_apply``)."""
+    Stats (min/max and the threshold's rank selection) run in XLA; on TPU
+    the elementwise bin+sparsify pass runs as the fused Pallas kernel
+    where a compiled kernel can apply
+    (``pallas_kernels.compiled_kernels_apply``)."""
     g = grad.astype(jnp.float32)
     lo = jnp.min(g) if min_grad is None else min_grad
     hi = jnp.max(g) if max_grad is None else max_grad
     thresh = (approx_quantile_abs(g, quant_threshold) if approx
-              else jnp.quantile(jnp.abs(g), quant_threshold))
+              else quantile_abs(g, quant_threshold))
     from .pallas_kernels import compiled_kernels_apply, quant_bin_sparsify
     if compiled_kernels_apply():
         out = quant_bin_sparsify(g.reshape(-1), lo, hi, thresh, n_bins)
@@ -91,8 +167,8 @@ def quantize_pytree(tree: Any, quant_threshold: Optional[float],
                     approx: bool = False) -> Any:
     """Quantize every leaf (reference ``quant_model``).  ``global_stats``
     computes one min/max/threshold across all leaves (``quant.py:36-39``).
-    ``approx`` swaps the exact sort-based quantile for the O(n)
-    histogram-CDF estimate (config ``client_config.quant_approx``)."""
+    ``approx`` swaps the exact quantile for the one-pass histogram-CDF
+    estimate (config ``client_config.quant_approx``)."""
     if quant_threshold is None:
         return tree
     n_bins = 2 ** int(quant_bits)
@@ -103,6 +179,9 @@ def quantize_pytree(tree: Any, quant_threshold: Optional[float],
     from jax.flatten_util import ravel_pytree
     flat, unravel = ravel_pytree(tree)
     lo, hi = jnp.min(flat), jnp.max(flat)
+    # the exact threshold in the type jnp.quantile gave it: the leaves'
+    # for a static quantile, float32 for a traced one
     thresh = (approx_quantile_abs(flat, quant_threshold) if approx
-              else jnp.quantile(jnp.abs(flat), quant_threshold))
+              else quantile_abs(flat, quant_threshold).astype(
+                  jnp.result_type(flat, quant_threshold)))
     return unravel(bin_sparsify(flat, lo, hi, thresh, n_bins))

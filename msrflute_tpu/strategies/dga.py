@@ -47,8 +47,8 @@ class DGA(BaseStrategy):
         if bits is None and mc is not None:
             bits = mc.get("quant_bits")
         self.quant_bits = int(bits) if bits is not None else 10
-        # O(n) histogram-CDF threshold instead of a sort per leaf per
-        # client (see ops.quantization.approx_quantile_abs)
+        # a histogram-CDF estimate of the threshold instead of the exact
+        # rank selection (see ops.quantization.approx_quantile_abs)
         self.quant_approx = bool(cc.get("quant_approx", False))
 
     def client_weight(self, *, num_samples, train_loss, stats, rng):

@@ -31,7 +31,7 @@ Config::
       quant_bits: 4          # 2^bits levels; EF is what makes 2-4 viable
       quant_thresh: 0.0      # |.|-quantile zeroed before binning
       quant_anneal: 1.0      # per-round threshold multiplier (DGA's knob)
-      quant_approx: false    # O(n) histogram quantile instead of sort
+      quant_approx: false    # histogram estimate of the quantile, not exact
 
 Composition: local DP runs inside ``client_payloads``'s per-client
 transform BEFORE the EF step, so the noised payload is what gets

@@ -88,6 +88,33 @@ def test_approx_quantile_error_bound(seed, q, scale):
     assert abs(approx - exact) <= 2 * bin_w + 1e-9
 
 
+@given(st.integers(1, 2 ** 31 - 1), st.integers(1, 3000),
+       st.floats(0.0, 1.0), st.floats(1e-30, 1e30), st.floats(0.0, 1.0))
+@settings(**SETTINGS)
+def test_quantile_selection_is_the_sorted_quantile(seed, n, q, scale, ties):
+    """The exact threshold's two order statistics are the sort's, bit for
+    bit, for any size, quantile, scale and share of exact zeros; the
+    blend is jnp.quantile's to one ulp."""
+    from msrflute_tpu.ops.quantization import (abs_order_stats,
+                                               quantile_abs, quantile_ranks)
+    rng = np.random.default_rng(seed)
+    x = (rng.normal(size=n) * scale).astype(np.float32)
+    x[rng.random(n) < ties] = 0.0
+    low, high, _, _ = quantile_ranks(n, np.float32(q))
+    low_value, high_value, has_nan = abs_order_stats(jnp.asarray(x), low,
+                                                     high)
+    ordered = np.sort(np.abs(x))
+    assert np.float32(low_value).view(np.int32) == \
+        ordered[int(low)].view(np.int32)
+    assert np.float32(high_value).view(np.int32) == \
+        ordered[int(high)].view(np.int32)
+    assert not bool(has_nan)
+    want = np.float32(jnp.quantile(jnp.abs(jnp.asarray(x)), np.float32(q)))
+    got = np.float32(quantile_abs(jnp.asarray(x), np.float32(q)))
+    if np.isfinite(want):
+        assert abs(float(got) - float(want)) <= np.spacing(np.abs(want))
+
+
 @given(st.integers(1, 2 ** 31 - 1), st.integers(2, 6), st.integers(1, 8))
 @settings(**SETTINGS)
 def test_moe_dispatch_indices_invariants(seed, n_experts, capacity):

@@ -9,6 +9,7 @@ the default asks ``jax.default_backend()`` and would take the interpreter.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -85,6 +86,23 @@ def test_elementwise_kernel_compiles_for_v5e(chip, kernel, batched):
     x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
     compiled = jax.jit(fn).lower(x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_threshold_selection_compiles_for_v5e_as_one_counted_loop(chip):
+    """The quantiser's exact threshold at the CNN cell's leaf and cohort,
+    under the round's client ``vmap`` and a traced quantile, as the
+    chip's compiler sees it: no sort, and the leaf only read — the
+    program's scratch is smaller than the leaf (without the barrier in
+    ``abs_order_stats`` the leaf's bitcast is hoisted out of the loop as
+    an int32 copy of it, at 170 clients though not at 10)."""
+    from msrflute_tpu.ops.quantization import quantile_abs
+    leaf = jax.ShapeDtypeStruct((170, 9216, 128), jnp.float32,
+                                sharding=chip)
+    q = jax.ShapeDtypeStruct((), jnp.float32, sharding=chip)
+    compiled = jax.jit(jax.vmap(quantile_abs, (0, None))).lower(
+        leaf, q).compile()
+    assert not re.search(r"\bsort\(", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 170 * 9216 * 128 * 4
 
 
 FLASH_SHAPES = {
