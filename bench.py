@@ -581,16 +581,11 @@ def _server_overhead_extras(server) -> dict:
     if tail:
         out["host_tail_secs_p50"] = round(
             float(np.percentile(tail, 50)), 5)
-    # dispatch-cost observability (ISSUE 6 satellite): whether the run
-    # staged its inputs as one packed buffer per dtype group, and what
-    # the last faithful dispatch actually paid — the bench-side mirror
-    # of the tier-1 transfer-count guard (tests/test_input_staging.py)
+    # dispatch-cost observability (ISSUE 6 satellite): what the last
+    # faithful dispatch staged
     engine = getattr(server, "engine", None)
     if engine is not None:
         out["dispatch"] = {
-            "input_staging": bool(getattr(engine, "input_staging", False)),
-            "puts_per_dispatch": int(getattr(engine,
-                                             "last_dispatch_puts", 0)),
             "staged_kb": round(
                 getattr(engine, "last_staged_bytes", 0) / 1024.0, 2),
         }
@@ -1312,91 +1307,6 @@ def bench_secagg_ab(on_tpu: bool) -> dict:
     return out
 
 
-def bench_megakernel_ab(on_tpu: bool) -> dict:
-    """Fused-epoch megakernel vs legacy unrolled epoch loop (ISSUE 12
-    acceptance): the SAME CNN protocol at ``num_epochs > 1``, run with
-    the default fused single-scan inner loop vs
-    ``megakernel.fused_epochs: false`` (the pre-PR trace, whose step-scan
-    body is CLONED once per epoch).  Steady-state per-step compute is
-    identical by construction — the bloat the fused path removes is
-    PROGRAM TEXT, so the headline ``secs_per_round`` here is
-    compile-INCLUSIVE (total wall from server build through ``rounds``
-    trained rounds, divided by rounds — what a short-lived or
-    shape-churning run actually pays); the steady-state number rides
-    along so nobody mistakes the win for a math change.  Per-arm
-    compile_seconds come from the device-truth layer's timed AOT path
-    (telemetry/xla.py) — the same observability the per-protocol
-    ``device_truth`` block now records."""
-    import tempfile
-
-    import jax
-    from msrflute_tpu.engine import OptimizationServer
-    from msrflute_tpu.models import make_task
-    from msrflute_tpu.parallel import make_mesh
-    from msrflute_tpu.telemetry.timing import Stopwatch
-
-    epochs = 4 if on_tpu else 8
-    rounds = 10 if on_tpu else 2
-    steady = 10 if on_tpu else 2
-    out = {"protocol": "cnn_femnist" if on_tpu else "cnn_small",
-           "num_epochs": epochs,
-           "rounds_per_arm": rounds, "steady_rounds_per_arm": steady}
-    for arm, block in (("fused", None),
-                       ("legacy", {"fused_epochs": False})):
-        if on_tpu:
-            cfg = _flute_config({"model_type": "CNN", "num_classes": 62},
-                                20, 0.1, fuse=1)
-            data = _image_dataset(64, 240, (28, 28, 1), 62,
-                                  np.random.default_rng(0))
-        else:
-            # shrunken CNN (host-CPU conv minutes would blow the bench
-            # deadline at FEMNIST size); the program-bloat mechanism
-            # under test is identical — the legacy arm still clones the
-            # conv step-scan body once per epoch
-            cfg = _flute_config({"model_type": "CNN", "num_classes": 10,
-                                 "image_size": 14}, 8, 0.1, fuse=1)
-            cfg.server_config["num_clients_per_iteration"] = 8
-            data = _image_dataset(8, 8, (14, 14, 1), 10,
-                                  np.random.default_rng(0))
-        cfg.client_config["num_epochs"] = epochs
-        cfg.server_config["telemetry"] = {"enable": True}
-        if block is not None:
-            cfg.server_config["megakernel"] = dict(block)
-        task = make_task(cfg.model_config)
-        with tempfile.TemporaryDirectory() as tmp:
-            with Stopwatch() as sw_cold:
-                server = OptimizationServer(task, cfg, data,
-                                            model_dir=tmp,
-                                            mesh=make_mesh(), seed=0)
-                cfg.server_config.max_iteration = rounds
-                server.train()
-                jax.block_until_ready(server.state.params)
-            cfg.server_config.max_iteration = rounds + steady
-            with Stopwatch() as sw_steady:
-                server.train()
-                jax.block_until_ready(server.state.params)
-            out[f"{arm}_secs_per_round"] = round(sw_cold.secs / rounds, 4)
-            out[f"{arm}_steady_secs_per_round"] = round(
-                sw_steady.secs / steady, 4)
-            if server.engine.xla is not None:
-                out[f"{arm}_compile_seconds"] = round(sum(
-                    rec.get("compile_seconds", 0.0)
-                    for rec in server.engine.xla.summary().values()), 3)
-            out[f"{arm}_compiled_programs"] = len(
-                server.engine.compile_log)
-            out[f"{arm}_recompiles"] = int(server.engine.recompile_count)
-    out["speedup"] = round(out["legacy_secs_per_round"]
-                           / max(out["fused_secs_per_round"], 1e-9), 3)
-    out["steady_speedup"] = round(
-        out["legacy_steady_secs_per_round"]
-        / max(out["fused_steady_secs_per_round"], 1e-9), 3)
-    out["regime"] = (
-        "compile-inclusive: the legacy arm's program text (and so its "
-        "compile time) grows linearly in num_epochs; steady-state "
-        "per-step math is identical by construction")
-    return out
-
-
 def _separable_dataset(pool, spu, dim, classes, rng, spread=3.0):
     """Learnable synthetic federated pool (class-mean + noise): the
     traffic A/B races two orchestrations TO A TARGET ACCURACY, so the
@@ -1977,8 +1887,6 @@ def main() -> None:
          "BENCH_BUCKETING_AB"),
         ("megabatch_ab", bench_megabatch_ab, not on_tpu,
          "BENCH_MEGABATCH_AB"),
-        ("megakernel_ab", bench_megakernel_ab, not on_tpu,
-         "BENCH_MEGAKERNEL_AB"),
         ("traffic_ab", bench_traffic_ab, not on_tpu, "BENCH_TRAFFIC_AB"),
     ]
     for name, fn, default_on, env_var in gated:

@@ -22,7 +22,7 @@ body's helper in another file, a round path's fetch three calls deep.
   tracer-dependent Python loop bounds.
 - **put-loop**         per-leaf ``jax.device_put`` loops in hot-path
   modules; since PR 6 the dispatch inputs cross as one staged buffer
-  per dtype group (``server_config.input_staging``).
+  per dtype group (``engine/round.py::_dispatch_staged``).
 - **schema-drift**     ``schema.py`` vs ``config.py`` vs docs
   cross-consistency.
 - **shard-ready**      cohort-axis host logic that would break under a

@@ -78,7 +78,7 @@ GUARDED_BLOCKS = ("robust", "chaos", "cohort_bucketing", "megabatch",
 #: matrix cell.
 VOCAB = ("wantRL", "scaffold", "ef_quant", "personalization",
          "clients_per_chunk", "adaptive_clipping", "dump_norm_stats",
-         "secure_agg", "input_staging", "fused_carry", "stale_prob",
+         "secure_agg", "fused_carry", "stale_prob",
          "fedavg", "fedprox",
          # cross-client megabatching refusal tokens (PR 16)
          "apply_metrics", "fedlabels", "pallas_apply",

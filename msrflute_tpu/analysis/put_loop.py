@@ -6,8 +6,8 @@ dtype group (``utils/flatpack.py`` ``AxisPacker``/``ScalarStager``, one
 ``device_put`` per group).  A ``device_put`` inside a loop or
 comprehension pays one transfer per iteration instead — exactly the
 ~8-10 per-leaf puts per dispatch that ``tools/dispatch_cost_probe.py``
-measured (~88 ms suspect on a remote-attached chip) and that
-``server_config.input_staging`` removed.
+measured (~88 ms suspect on a remote-attached chip) and that the staged
+dispatch (``engine/round.py::_dispatch_staged``) removed.
 
 Flagged, in hot-path modules only (``engine/``, ``ops/``,
 ``strategies/``, ``telemetry/``, ``robust/``): any
@@ -19,9 +19,9 @@ Deliberately lexical (no data-flow): a put whose operand is a packed
 per-dtype dict is ONE call on the whole tree and never sits in a loop;
 the loop shape IS the smell.  Function/lambda bodies reset the loop
 context — a staging closure defined inside a loop is called elsewhere
-and judged there.  Legitimate loops (one-time pool uploads, legacy
-A/B paths kept for ``tools/dispatch_cost_probe.py``) carry a
-``# flint: disable=put-loop reason`` pragma.
+and judged there.  Legitimate loops (one-time pool uploads, one staged
+put per bucket program) carry a ``# flint: disable=put-loop reason``
+pragma.
 """
 
 from __future__ import annotations

@@ -86,10 +86,10 @@ _STRUCTURAL = {"data_config", "optimizer_config", "annealing_config",
 DOCUMENTED_KNOBS = (
     "pipeline_depth", "rounds_per_step", "checkpoint_async",
     "checkpoint_backend", "compilation_cache_dir", "step_bucketing",
-    # universal overlap (PR 6): an operator who cannot find the carry /
-    # staging knobs will keep paying the serial fallback and the
-    # per-leaf dispatch tax without knowing the lever exists
-    "fused_carry", "input_staging",
+    # universal overlap (PR 6): an operator who cannot find the carry
+    # knob will keep paying the serial fallback without knowing the
+    # lever exists
+    "fused_carry",
     # resilience knobs: an operator who cannot find the preemption /
     # fault-injection drill in the runbook will learn about it from a
     # lost run instead
@@ -104,9 +104,9 @@ DOCUMENTED_KNOBS = (
     # tuning drill will keep paying masked FLOPs padding every client
     # to the slowest one
     "cohort_bucketing",
-    # megakernel local SGD: an operator who cannot find the fusion /
-    # pallas-apply knobs will keep paying per-epoch program bloat and
-    # sub-MXU optimizer tails on small models
+    # megakernel local SGD: an operator who cannot find the
+    # pallas-apply knob will keep paying sub-MXU optimizer tails on
+    # small models
     "megakernel",
     # precision policy: an operator who cannot find the bf16 drill will
     # leave the MXU's half-rate f32 path on forever — or flip dtypes

@@ -419,11 +419,9 @@ class ServerConfig(Config):
     # schema.COHORT_BUCKETING_KEYS; absent (the default) keeps the
     # monolithic [K, S, B] round program
     cohort_bucketing: Optional[Dict[str, Any]] = None
-    # megakernel local SGD (engine/client_update.py): epoch/step loop
-    # fusion (default on even when the block is absent) and the opt-in
-    # pallas fused SGD apply — free-form dict validated by
-    # schema.MEGAKERNEL_KEYS; `enable: false` restores the legacy
-    # per-epoch unrolled trace for A/Bs
+    # megakernel local SGD (engine/client_update.py): the opt-in pallas
+    # fused SGD apply — free-form dict validated by
+    # schema.MEGAKERNEL_KEYS
     megakernel: Optional[Dict[str, Any]] = None
     # precision policy (engine/client_update.py): params/compute/stats
     # dtypes for the client inner loop — free-form dict validated by

@@ -196,7 +196,7 @@ class IndexRoundBatch:
     by :func:`build_sample_pool` (0 for padding slots — masked anyway).
     The mask/count fields match :class:`RoundBatch`; there is deliberately
     NO ``arrays`` field — feature rows exist only on-device, and the one
-    consumer is ``RoundEngine._stage_arrays`` (pool mode).
+    consumer is ``RoundEngine._host_arrays`` (pool mode).
     """
 
     indices: np.ndarray

@@ -10,10 +10,9 @@ preserved exactly (SURVEY.md §7):
   (``core/client.py:309-312``) — optax init inside the function;
 - per-batch loss -> grad -> clip -> stats -> step
   (``core/trainer.py:341-414``) — ONE ``lax.scan`` over the flattened
-  ``[num_epochs * steps]`` grid (megakernel epoch fusion, PR 12: the body
-  is traced once whatever the epoch count; ``megakernel.fused_epochs:
-  false`` restores the legacy one-scan-per-epoch unrolled trace, which is
-  bit-identical in f32 but whose program text grows linearly in epochs);
+  ``[num_epochs * steps]`` grid (epoch fusion, PR 12: the body is traced
+  once whatever the epoch count, so program size and compile time stay
+  flat in it);
 - ``desired_max_samples`` early stop (``core/trainer.py:363-364``) — encoded
   in the batch packing (zero-mask beyond the cap), with all-padding steps
   gated so they change nothing;
@@ -63,14 +62,6 @@ class ClientHParams:
     #: frozen at every inner step, like the reference's per-param lr=0
     #: (set_component_wise_lr, core/trainer.py:725-751)
     updatable_layers: Optional[Tuple[str, ...]] = None
-    #: megakernel epoch fusion (default ON): run all ``num_epochs *
-    #: steps`` local steps as ONE ``lax.scan`` instead of cloning the
-    #: step-scan body once per epoch — program size and compile time
-    #: stay flat in num_epochs (the PR-12 bloat fix;
-    #: ``server_config.megakernel.fused_epochs: false`` restores the
-    #: legacy unrolled trace for A/Bs).  num_epochs == 1 traces the
-    #: exact historical program either way.
-    fused_epochs: bool = True
     #: opt-in pallas fused SGD apply (``server_config.megakernel.
     #: pallas_apply``): the inner step's optimizer tail runs as ONE
     #: kernel pass over the flattened param vector
@@ -287,16 +278,11 @@ def build_client_update(task: BaseTask, client_opt_cfg,
         carry = (params, opt_state, rng, loss_sum, s, s2, n_acc, wloss_acc,
                  ns_acc, {name: jnp.zeros((), jnp.float32)
                           for name in counter_names})
-        if hparams.num_epochs <= 1 or not hparams.fused_epochs:
-            # num_epochs == 1 is the exact historical trace either way;
-            # the legacy unrolled path (megakernel.fused_epochs: false)
-            # clones the scan body once per epoch — program size and
-            # compile time grow linearly in num_epochs (the A/B arm)
-            for _ in range(hparams.num_epochs):
-                carry, _ = jax.lax.scan(one_step, carry,
-                                        (arrays, sample_mask))
+        if hparams.num_epochs <= 1:
+            # one epoch: the scan reads the step grids as its xs
+            carry, _ = jax.lax.scan(one_step, carry, (arrays, sample_mask))
         else:
-            # megakernel epoch fusion: ONE scan over the flattened
+            # epoch fusion: ONE scan over the flattened
             # [num_epochs * steps] grid — the body is traced once, and
             # each step dynamic-slices its batch out of the resident
             # [S, B, ...] grids (an HBM-local gather, no host bytes)

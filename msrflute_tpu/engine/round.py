@@ -42,8 +42,7 @@ from ..data.batching import RoundBatch
 from ..models.base import BaseTask
 from ..optim import make_optimizer
 from ..parallel.mesh import CLIENTS_AXIS, MODEL_AXIS, make_mesh
-from ..resilience.chaos import (CORRUPT_NAN, CORRUPT_SCALE,
-                                CORRUPT_SIGN_FLIP)
+from ..resilience import chaos as chaos_modes
 from ..traffic.schedule import STALE_HIST_BINS
 from ..robust import make_shield
 from ..strategies.base import BaseStrategy
@@ -169,6 +168,57 @@ class ServerState:
     round: int = 0
 
 
+def gather_pool(pool, arrays, sample_mask):
+    """Device-resident mode: ``arrays`` carries pool indices; gather the
+    feature rows in-program (one XLA gather per key, HBM-local — no host
+    bytes moved).  Padding slots index row 0, so zero the gathered rows
+    with the sample mask: padding then holds zeros exactly like host
+    packing (pool-vs-host bit-identity by construction, not by every
+    task loss masking perfectly — tests/test_device_pool.py)."""
+    idx = arrays["__idx__"]
+    m = sample_mask
+    return {
+        k: pool[k][idx]
+        * m.reshape(m.shape + (1,) * (pool[k].ndim - 1)).astype(pool[k].dtype)
+        for k in pool}
+
+
+def _stat_sums(tls, nss, stats, cm) -> Dict[str, Any]:
+    """The loss / sample / live-client / gradient-stat sums over a
+    client axis whose live mask is ``cm``."""
+    return {
+        "train_loss_sum": jnp.sum(tls),
+        "num_samples_sum": jnp.sum(nss),
+        "client_count": jnp.sum(cm),
+        "stats_mean_sum": jnp.sum(stats["mean"] * cm),
+        "stats_mag_sum": jnp.sum(stats["mag"] * cm),
+        "stats_var_sum": jnp.sum(stats["var_corrected"] * cm),
+        "stats_norm_sum": jnp.sum(stats["norm"] * cm),
+    }
+
+
+def _round_stats(collected, part_sums, agg) -> Dict[str, Any]:
+    """The round's scalar stats from its collected sums: what every
+    round program packs, before its own counters join."""
+    default_part = part_sums.get("default") or \
+        next(iter(part_sums.values()))
+    count = collected["client_count"]
+    # the floor of the count is taken once a mean, as the programs have
+    # always traced it (hoisting it would change every cell's HLO)
+    return {
+        "train_loss_sum": collected["train_loss_sum"],
+        "num_samples_sum": collected["num_samples_sum"],
+        "client_count": count,
+        "weight_sum": default_part["weight_sum"],
+        "weight_sum_raw": default_part["weight_sum_raw"],
+        "grad_mean": collected["stats_mean_sum"] / jnp.maximum(count, 1.0),
+        "grad_mag": collected["stats_mag_sum"] / jnp.maximum(count, 1.0),
+        "grad_var": collected["stats_var_sum"] / jnp.maximum(count, 1.0),
+        "grad_norm": collected["stats_norm_sum"] / jnp.maximum(count, 1.0),
+        "agg_grad_norm": optax.global_norm(agg),
+    }
+
+
 class RoundEngine:
     """Compiles and runs the per-round SPMD program."""
 
@@ -185,21 +235,11 @@ class RoundEngine:
         freeze = cc.get("freeze_layer") or []
         if isinstance(freeze, str):
             freeze = [freeze]
-        # megakernel local SGD (server_config.megakernel): epoch/step
-        # fusion is DEFAULT-ON (one scan over the flattened
-        # [num_epochs * steps] grid; num_epochs == 1 traces the exact
-        # historical program), the pallas fused SGD apply opt-in.  An
-        # explicit `enable: false` restores the full legacy trace.
-        _mk_raw = sc.get("megakernel") or {}
-        _mk_on = not _mk_raw or bool(_mk_raw.get("enable", True))
-        self.megakernel = {
-            "fused_epochs": bool(_mk_raw.get("fused_epochs", True))
-            if _mk_on else False,
-            "pallas_apply": bool(_mk_raw.get("pallas_apply", False))
-            if _mk_on else False,
-        }
-        if self.megakernel["pallas_apply"] and \
-                jax.default_backend() != "tpu":
+        # the opt-in pallas fused SGD apply
+        # (server_config.megakernel.pallas_apply)
+        pallas_apply = bool(
+            (sc.get("megakernel") or {}).get("pallas_apply", False))
+        if pallas_apply and jax.default_backend() != "tpu":
             # the round runs client_update inside shard_map over virtual
             # CPU devices off-TPU, where interpret-mode pallas kernels
             # deadlock (the documented reason ops/pallas_attention.py
@@ -208,8 +248,7 @@ class RoundEngine:
             raise ValueError(
                 "megakernel.pallas_apply requires a TPU backend: the "
                 "interpret-mode kernel cannot run inside the shard_map'd "
-                "round on CPU — drop the flag (fused_epochs still "
-                "applies) or run on TPU")
+                "round on CPU — drop the flag or run on TPU")
         # precision policy (server_config.precision): params/compute/
         # stats dtypes for the client inner loop.  Absent — or every
         # entry "float32" — compiles the exact f32 legacy trace (the
@@ -227,8 +266,7 @@ class RoundEngine:
             fedprox_mu=float(cc.get("fedprox_mu", 0.0) or 0.0),
             num_epochs=int(cc.get("num_epochs", 1) or 1),
             freeze_layers=tuple(freeze),
-            fused_epochs=self.megakernel["fused_epochs"],
-            pallas_apply=self.megakernel["pallas_apply"],
+            pallas_apply=pallas_apply,
             param_dtype=self.precision.get("params"),
             compute_dtype=self.precision.get("compute"),
             stats_dtype=self.precision.get("stats"),
@@ -369,13 +407,8 @@ class RoundEngine:
             from ..parallel.mesh import pad_to_mesh
             self._rl = FusedRL(rl_cfg, pad_to_mesh(int(ncpi), self.mesh))
 
-        # single-buffer input staging (server_config.input_staging,
-        # default on): per-round host inputs — masks, ids, chaos
-        # vectors, lr/round scalars, and the feature (or index) grids —
-        # cross the host boundary as ONE buffer per dtype group
-        # (utils/flatpack.py AxisPacker/ScalarStager) instead of ~8-10
-        # per-leaf device_puts per dispatch (tools/dispatch_cost_probe).
-        self.input_staging = bool(sc.get("input_staging", True))
+        #: the staged dispatch's jitted programs (_build_staged_fn), by
+        #: rounds per dispatch and packer signatures
         self._staged_cache: Dict[Any, Callable] = {}
         #: dispatch-cost observability (bench extras + the tier-1
         #: regression guard): host->device put calls and bytes of the
@@ -544,11 +577,6 @@ class RoundEngine:
             # residual masks per bucket before decoding; the int32
             # telescoping is exact either way, so bucketed == monolithic
             # bit-identical (tests/test_secagg_compose.py)
-            if not self.input_staging:
-                raise ValueError(
-                    "cohort_bucketing requires input_staging (the "
-                    "legacy per-leaf dispatch path is kept only for the "
-                    "staging A/B) — drop `input_staging: false`")
             if self.shield is not None and \
                     float(getattr(strategy, "stale_prob", 0.0) or 0.0) > 0:
                 raise ValueError(
@@ -656,11 +684,10 @@ class RoundEngine:
         default_mode = ("gspmd" if self.mesh.shape.get(MODEL_AXIS, 1) > 1
                         else "shard_map")
         self.partition_mode = mesh_cfg.get("partition", default_mode)
-        self._multi_cache = {}
         #: {geometry key: FlatPacker} — slot tables for decoding the
         #: packed stats buffers, recorded when the round program traces
         self._stats_packers: Dict[Any, FlatPacker] = {}
-        self._round_step = self._build_round_step()
+        self._round_step_core = self._build_round_step()
 
     # ------------------------------------------------------------------
     def _instrument(self, name: str, jitted: Callable,
@@ -795,13 +822,340 @@ class RoundEngine:
         # flint: disable=put-loop one-time pool upload at attach, not per-round dispatch
         self._pool = {k: jax.device_put(np.asarray(v), self._replicated)
                       for k, v in pool_arrays.items()}
-        self._multi_cache = {}
         self._staged_cache = {}
         self._stats_packers = {}
         self._bucket_collect_cache = {}
         self._bucket_collect_core = {}
         self._bucket_finalize = None
-        self._round_step = self._build_round_step()
+        self._round_step_core = self._build_round_step()
+
+    # ------------------------------------------------------------------
+    # what the round builders share: the monolithic round
+    # (_build_round_step) and the bucketed one (_get_bucket_collect_core
+    # + _get_bucket_finalize) trace these same bodies.  The compile-time
+    # flags come from self; none of them branches on who calls.
+    # ------------------------------------------------------------------
+    def _gather_axis(self, x):
+        """Shard-local ``[K_local]`` -> full replicated ``[K]`` cohort
+        (the median vote, the robust payload stack and the carry scatter
+        need every client, not this shard's slice); under GSPMD the
+        arrays are global already."""
+        if self.partition_mode == "shard_map":
+            return jax.lax.all_gather(x, CLIENTS_AXIS, axis=0, tiled=True)
+        return x
+
+    def _per_client_fn(self, update_for: Callable, params, strategy_state,
+                       client_lr, round_idx, leakage_threshold,
+                       quant_threshold, rng, cohort_ids, cohort_mask
+                       ) -> Callable:
+        """The per-client step that every round builder ``vmap``s (or,
+        at ``clients_per_chunk: 1``, calls on one client): local training
+        through the strategy, chaos corruption, the secure-aggregation
+        mask, the live-client mask and the stale coin.
+
+        ``update_for(rows)`` gives the client-update function from the
+        client's trailing rows: the engine's own everywhere but in the
+        megabatch collect, whose rows are what its lane scan has already
+        trained and whose function hands them back."""
+        strategy = self.strategy
+        stale_prob = self.stale_prob
+        carry_paged = self.carry_paged
+        device_carry = self.device_carry
+        chaos_corruption = self.chaos_corruption
+        traffic_staleness = self.traffic_staleness
+        wants_cohort = bool(getattr(strategy, "wants_cohort", False))
+
+        def per_client(arr_c, mask_c, cm_c, cid_c, *rest):
+            # Deterministic independent stream per (round, client):
+            # jax.random.fold_in discipline (SURVEY.md §7 hard parts).
+            # rng folds on the TRUE client id even under fleet paging —
+            # only the carry table index is remapped — so a client's
+            # whole local update is independent of which grid slot or
+            # bucket it landed in: the bit-identity anchor
+            rest = list(rest)
+            slot_c = rest.pop(0) if carry_paged else cid_c
+            corrupt_c = rest.pop(0) if chaos_corruption else None
+            stale_c = rest.pop(0) if traffic_staleness else None
+            rng_c = jax.random.fold_in(rng, cid_c)
+            update_fn = update_for(tuple(rest))
+            # traced staleness (fluteflow): the arrival plane's TRUE
+            # broadcast-version gap replaces the strategy's in-jit
+            # staleness model — passed only when the engine compiled the
+            # operand in, so staleness-blind strategies keep their exact
+            # call signature
+            stale_kw = {"staleness": stale_c} if traffic_staleness else {}
+            carry_row = None
+            if device_carry:
+                # carry strategies gather their own table rows from
+                # strategy_state by row id (the client id for resident
+                # tables, the page-pool SLOT id under fleet paging) and
+                # return the per-client carry update row alongside the
+                # payload
+                parts, tl, ns, stats, carry_row = \
+                    strategy.client_step_carry(
+                        update_fn, params, arr_c, mask_c, client_lr,
+                        rng_c, client_id=slot_c, live_mask=cm_c,
+                        round_idx=round_idx,
+                        leakage_threshold=leakage_threshold,
+                        quant_threshold=quant_threshold,
+                        strategy_state=strategy_state, **stale_kw)
+            else:
+                parts, tl, ns, stats = strategy.client_step(
+                    update_fn, params, arr_c, mask_c, client_lr, rng_c,
+                    round_idx=round_idx,
+                    leakage_threshold=leakage_threshold,
+                    quant_threshold=quant_threshold,
+                    strategy_state=strategy_state, **stale_kw)
+            if chaos_corruption:
+                # adversarial chaos (resilience/chaos.py corrupt modes,
+                # already gated on the live client_mask): the DEFAULT
+                # payload this client would transmit is what gets
+                # corrupted — local training, stats, and the claimed
+                # weight stay honest-looking, exactly the threat
+                # fluteshield screens for
+                pg0, w0 = parts["default"]
+                mult = jnp.where(
+                    corrupt_c == chaos_modes.CORRUPT_SCALE,
+                    self._corrupt_scale,
+                    jnp.where(corrupt_c == chaos_modes.CORRUPT_SIGN_FLIP,
+                              -self._corrupt_flip_scale, 1.0))
+                bad = corrupt_c == chaos_modes.CORRUPT_NAN
+                pg0 = jax.tree.map(
+                    lambda g: (jnp.where(
+                        bad, jnp.asarray(jnp.nan, g.dtype),
+                        g * mult.astype(g.dtype))
+                        if jnp.issubdtype(g.dtype, jnp.floating)
+                        else g), pg0)
+                parts = dict(parts)
+                parts["default"] = (pg0, w0)
+            sub_norm = jnp.zeros(())
+            if wants_cohort:
+                # secure aggregation: encode + pairwise-mask the
+                # POST-corruption payload toward the SAMPLED cohort
+                # (cohort_ids/cohort_mask, replicated: the round's, or
+                # the bucket's sub-cohort — bucket placement changes a
+                # client's mask graph, never its encoding, and masks
+                # cancel exactly); the returned sub_norm is the
+                # submitted-norm scalar a verified-aggregation server
+                # would see — the shield's masked screening votes on it
+                parts, sub_norm = strategy.mask_parts(
+                    parts, cid_c, cm_c, cohort_ids, cohort_mask, round_idx)
+            parts = {name: (tree, w * cm_c)
+                     for name, (tree, w) in parts.items()}
+            if stale_prob > 0.0:
+                coin = jax.random.bernoulli(
+                    jax.random.fold_in(rng_c, 3), stale_prob)
+                stale = coin.astype(jnp.float32) * cm_c
+            else:
+                stale = jnp.zeros(())
+            # carry_row is None (a leafless pytree — vmap passes it
+            # through) unless the strategy runs in device-carry mode
+            return (parts, tl * cm_c, ns * cm_c, stats, stale, carry_row,
+                    sub_norm)
+
+        return per_client
+
+    def _shard_sums(self, parts, tls, nss, stats, stale, cm_k,
+                    deferred_tree: Optional[str]) -> Dict[str, Any]:
+        """One shard's (or chunk's) weighted sums over its clients: per
+        payload part the weighted tree and its weight sums, then the
+        loss / sample / stat sums.
+
+        ``deferred_tree`` is where the two builders have drifted, each
+        kept as it traces (ROADMAP debt *bucketed-round-drift*): the
+        bucket collect sums the deferred clients' tree always, between
+        the weight sums (``"inline"``); the monolithic round after them
+        (``"last"``), and only where a client can be deferred or outside
+        the chunk scan, which would carry the tree as an accumulator of
+        its own (``None`` there; outside the scan XLA drops an unused
+        one).  The model counters (``ctr_*``) are summed by the
+        monolithic round alone, at its call."""
+        strategy = self.strategy
+        local = {"parts": {}}
+        for name, (trees, ws) in parts.items():
+            w_now = ws * (1.0 - stale)
+            w_def = ws * stale
+            wsum = lambda w, t: jax.tree.map(
+                lambda g: jnp.tensordot(w, g, axes=[[0], [0]]), t)
+            if name in strategy.unit_weight_parts:
+                # masked payloads: every PRESENT slot enters with
+                # coefficient exactly 1 (else pairwise masks cannot
+                # cancel); the tensordot runs in the tree's own dtype so
+                # int32 modular arithmetic wraps instead of promoting to
+                # float
+                gsum = jax.tree.map(
+                    lambda g: jnp.tensordot(
+                        cm_k.astype(g.dtype), g, axes=[[0], [0]]), trees)
+                local["parts"][name] = {
+                    "grad_sum": gsum,
+                    "weight_sum": jnp.sum(w_now),
+                    "grad_sum_def": jax.tree.map(jnp.zeros_like, gsum),
+                    "weight_sum_def": jnp.sum(w_def),
+                    "weight_sum_raw": jnp.sum(ws),
+                }
+                continue
+            sums = {"grad_sum": wsum(w_now, trees),
+                    "weight_sum": jnp.sum(w_now)}
+            if deferred_tree == "inline":
+                sums["grad_sum_def"] = wsum(w_def, trees)
+            sums["weight_sum_def"] = jnp.sum(w_def)
+            sums["weight_sum_raw"] = jnp.sum(ws)
+            if deferred_tree == "last":
+                sums["grad_sum_def"] = wsum(w_def, trees)
+            local["parts"][name] = sums
+        local.update(_stat_sums(tls, nss, stats, cm_k))
+        return local
+
+    @property
+    def _carry_split(self) -> bool:
+        """Whether the paged carry tables ride a slot-axis-sharded
+        operand of their own (fleet paging under ``shard_map``)."""
+        return self.carry_paged and self.partition_mode == "shard_map"
+
+    def _trailing_operands(self) -> tuple:
+        """The optional operands that trail a collect's fixed ones, in
+        their one positional order, as ``(name, present, spec)``: each
+        builder reads its ``in_specs`` and its unpacking from this, so
+        which slot means what is written once (with corruption off and
+        the pool on, the pool must not land in ``corrupt_mode``)."""
+        cspec, rspec = P(CLIENTS_AXIS), P()
+        # mesh-sharded page pool (fleet paging x shard_map): the carry
+        # tables enter the shard_map as their OWN operand with a
+        # P(CLIENTS_AXIS) slot-axis spec (the rest of strategy_state
+        # stays replicated), and the GLOBAL carry_slots convert to
+        # shard-local indices in-body — the gather/scatter is local to
+        # the shard that computes the lane, no cross-shard collective.
+        # GSPMD mode keeps global ids and lets the partitioner place
+        # the (still slot-axis-sharded) tables.
+        return (("carry_tables", self._carry_split, cspec),
+                ("carry_slots", self.carry_paged, cspec),
+                ("corrupt_mode", self.chaos_corruption, cspec),
+                ("staleness", self.traffic_staleness, cspec),
+                ("pool", self._pool is not None, rspec))
+
+    def _unpack_trailing(self, trailing: tuple, strategy_state, rest):
+        """Inside the ``shard_map``: the trailing positional operands
+        back to ``shard_body``'s keywords.  Returns the strategy state
+        (with this shard's table block under a sharded page pool) and
+        the keyword dict."""
+        rest = list(rest)
+        kw = {name: rest.pop(0) if on else None for name, on, _ in trailing}
+        tables = kw.pop("carry_tables")
+        if tables is not None:
+            # sharded pool: this shard's table block rejoins the
+            # replicated state, and the global slot ids drop to
+            # block-local (padding stays -1) — the allocator guaranteed
+            # every lane's slot lives on this shard
+            strategy_state = {**strategy_state, **tables}
+            off = jax.lax.axis_index(CLIENTS_AXIS) * self._carry_shard_slots
+            slots = kw["carry_slots"]
+            kw["carry_slots"] = jnp.where(slots >= 0, slots - off, -1)
+        return strategy_state, kw
+
+    def _fold_faults(self, strategy_state, sample_mask, client_mask,
+                     client_ids, extra_args):
+        """Ahead of the collect: fold the round's (or the bucket's)
+        fault and staleness operands into its masks, count them, and
+        line the collect's trailing operands up in
+        :meth:`_trailing_operands`' order.  Returns ``(sample_mask,
+        client_mask, carry_slots, collect_state, trailing_args,
+        stats)``; the counters in ``stats`` leave through the same
+        packed single-transfer buffer as every other stat (per bucket
+        they sum additively in finalize).
+
+        Chaos client faults (extra data operands, present only when the
+        engine was built with them): dropout multiplies into
+        client_mask — downstream everything (strategy weights, psum
+        denominators, stats) renormalizes exactly like mesh padding —
+        and straggling truncates sample_mask's step grid, so a
+        straggler's PARTIAL local work still aggregates
+        (CLIP/FedBuff-style partial participation)."""
+        stats = {}
+        f32 = jnp.float32
+        n_used = 0
+        if self.carry_paged:
+            # fleet paging: the host-remapped pool slot per lane — the
+            # carry gather/scatter index; everything else keeps using
+            # the true client ids
+            carry_slots = extra_args[0]
+            n_used = 1
+        else:
+            carry_slots = client_ids
+        if self.chaos_client_faults:
+            chaos_drop, chaos_keep = \
+                extra_args[n_used], extra_args[n_used + 1]
+            n_used += 2
+            step_live = (jnp.sum(sample_mask, axis=-1) > 0)      # [K, S]
+            real_steps = jnp.sum(step_live, axis=-1)             # [K]
+            keep_f = (jnp.arange(sample_mask.shape[-2])[None, :]
+                      < chaos_keep[:, None]).astype(f32)         # [K, S]
+            live_cm = client_mask * (1.0 - chaos_drop)
+            stats = {
+                "chaos_dropped": jnp.sum(client_mask * chaos_drop),
+                "chaos_straggled": jnp.sum(
+                    live_cm * (chaos_keep < real_steps)),
+                "chaos_steps_lost": jnp.sum(
+                    step_live.astype(f32) * (1.0 - keep_f)
+                    * live_cm[:, None]),
+            }
+            sample_mask = sample_mask * keep_f[..., None].astype(
+                sample_mask.dtype)
+            client_mask = live_cm
+        corrupt_args = ()
+        if self.chaos_corruption:
+            # adversarial corruption modes (one more per-round data
+            # operand): gated on the LIVE mask — a dropped client never
+            # transmits, and a padding slot's zero payload must not be
+            # NaN'd into the sum (0-weight x NaN is still NaN through a
+            # tensordot)
+            corrupt_mode = extra_args[n_used]
+            n_used += 1
+            corrupt_mode = jnp.where(client_mask > 0, corrupt_mode, 0)
+            stats.update({
+                name: jnp.sum((corrupt_mode == mode).astype(f32))
+                for name, mode in (
+                    ("chaos_nan_injected", chaos_modes.CORRUPT_NAN),
+                    ("chaos_scaled", chaos_modes.CORRUPT_SCALE),
+                    ("chaos_sign_flipped", chaos_modes.CORRUPT_SIGN_FLIP))})
+            corrupt_args = (corrupt_mode,)
+        stale_args = ()
+        if self.traffic_staleness:
+            # fluteflow traced staleness (one more per-round data
+            # operand): gated on the LIVE mask — padding slots and
+            # chaos-dropped clients contribute nothing, so their
+            # staleness must not count — and binned into the
+            # per-staleness histogram that rides the packed stats (the
+            # host replay oracle in traffic/schedule.py is the
+            # cross-check).  The strategy consumes the TRUE value; only
+            # the histogram clips at its last (overflow) bin.
+            stale_vec = extra_args[n_used]
+            n_used += 1
+            stale_vec = jnp.where(client_mask > 0, stale_vec, 0)
+            live = (client_mask > 0).astype(f32)
+            binned = jnp.minimum(stale_vec, STALE_HIST_BINS - 1)
+            stats.update({
+                f"traffic_stale_{b}": jnp.sum(
+                    (binned == b).astype(f32) * live)
+                for b in range(STALE_HIST_BINS)})
+            stats["traffic_stale_sum"] = jnp.sum(
+                stale_vec.astype(f32) * live)
+            stale_args = (stale_vec,)
+        pool_args = tuple(extra_args[n_used:])
+        if self._carry_split:
+            # the sharded pool tables ride their own cspec operand;
+            # everything else in strategy_state stays replicated
+            carry_keys = tuple(self.strategy.carry_tables)
+            collect_state = {k: v for k, v in strategy_state.items()
+                             if k not in carry_keys}
+            carry_tab_args = ({k: strategy_state[k] for k in carry_keys},)
+        else:
+            collect_state = strategy_state
+            carry_tab_args = ()
+        trailing_args = carry_tab_args + \
+            ((carry_slots,) if self.carry_paged else ()) + \
+            corrupt_args + stale_args + pool_args
+        return (sample_mask, client_mask, carry_slots, collect_state,
+                trailing_args, stats)
 
     # ------------------------------------------------------------------
     def _build_round_step(self) -> Callable:
@@ -811,7 +1165,6 @@ class RoundEngine:
         mesh = self.mesh
         cspec = P(CLIENTS_AXIS)
         rspec = P()
-        pool_mode = self._pool is not None
 
         clients_per_chunk = self.clients_per_chunk
         # fluteshield statics: all compile-time branches — a config
@@ -819,8 +1172,6 @@ class RoundEngine:
         shield = self.shield
         robust_stack = shield is not None and shield.wants_stack
         chaos_corruption = self.chaos_corruption
-        corrupt_scale = self._corrupt_scale
-        corrupt_flip_scale = self._corrupt_flip_scale
         # fluteflow static: the traced-staleness operand threads AFTER
         # corrupt_mode in every positional order below
         traffic_staleness = self.traffic_staleness
@@ -830,17 +1181,8 @@ class RoundEngine:
         carry_paged = self.carry_paged
         rl_fused = self.rl_fused
         fused_rl = self._rl
-        # mesh-sharded page pool (fleet paging x shard_map): the carry
-        # tables enter the shard_map as their OWN operand with a
-        # P(CLIENTS_AXIS) slot-axis spec (the rest of strategy_state
-        # stays replicated), and the GLOBAL carry_slots convert to
-        # shard-local indices in-body — the gather/scatter is local to
-        # the shard that computes the lane, no cross-shard collective.
-        # GSPMD mode keeps global ids and lets the partitioner place
-        # the (still slot-axis-sharded) tables.
-        carry_split = carry_paged and self.partition_mode == "shard_map"
-        carry_keys = tuple(strategy.carry_tables) if carry_paged else ()
-        shard_slots = self._carry_shard_slots
+        trailing = self._trailing_operands()
+        gather_axis = self._gather_axis
         # secure-aggregation statics: wants_cohort routes the default
         # payload through the strategy's mask_parts AFTER corruption
         # (the adversary attacks the float payload the client would
@@ -849,6 +1191,9 @@ class RoundEngine:
         # voting (the masked stack carries no plaintext norm signal)
         wants_cohort = bool(getattr(strategy, "wants_cohort", False))
         masked_screen = shield is not None and wants_cohort
+        # the deferred clients' tree (see _shard_sums)
+        deferred_tree = "last" if stale_prob > 0.0 or \
+            not clients_per_chunk else None
 
         def shard_body(params, strategy_state, arrays, sample_mask,
                        client_mask, client_ids, client_lr, round_idx,
@@ -856,117 +1201,10 @@ class RoundEngine:
                        cohort_ids=None, cohort_mask=None,
                        carry_slots=None, corrupt_mode=None,
                        staleness=None, pool=None):
-            if self.partition_mode == "shard_map":
-                # shard-local [K_local] -> full replicated [K] cohort
-                # (the median vote and the robust payload stack need
-                # every client, not this shard's slice)
-                def gather_axis(x):
-                    return jax.lax.all_gather(x, CLIENTS_AXIS, axis=0,
-                                              tiled=True)
-            else:
-                def gather_axis(x):
-                    return x
-            def gather_pool(arrays, sample_mask):
-                # device-resident mode: 'arrays' carries pool indices;
-                # gather the feature rows in-program (one XLA gather per
-                # key, HBM-local — no host bytes moved).  Padding slots
-                # index row 0, so zero the gathered rows with the sample
-                # mask: padding then holds zeros exactly like host packing
-                # (pool-vs-host bit-identity by construction, not by every
-                # task loss masking perfectly — tests/test_device_pool.py)
-                idx = arrays["__idx__"]
-                m = sample_mask
-                return {
-                    k: pool[k][idx]
-                    * m.reshape(m.shape + (1,) * (pool[k].ndim - 1)
-                                ).astype(pool[k].dtype)
-                    for k in pool}
-
-            def per_client(arr_c, mask_c, cm_c, cid_c, *rest):
-                # Deterministic independent stream per (round, client):
-                # jax.random.fold_in discipline (SURVEY.md §7 hard parts).
-                # rng folds on the TRUE client id even under fleet
-                # paging — only the carry table index is remapped.
-                rest = list(rest)
-                slot_c = rest.pop(0) if carry_paged else cid_c
-                corrupt_c = rest.pop(0) if chaos_corruption else None
-                stale_c = rest.pop(0) if traffic_staleness else None
-                rng_c = jax.random.fold_in(rng, cid_c)
-                carry_row = None
-                if device_carry:
-                    # carry strategies gather their own table rows from
-                    # strategy_state by row id (the client id for
-                    # resident tables, the page-pool SLOT id under
-                    # fleet paging) and return the per-client carry
-                    # update row alongside the payload
-                    parts, tl, ns, stats, carry_row = \
-                        strategy.client_step_carry(
-                            client_update, params, arr_c, mask_c,
-                            client_lr, rng_c, client_id=slot_c,
-                            live_mask=cm_c, round_idx=round_idx,
-                            leakage_threshold=leakage_threshold,
-                            quant_threshold=quant_threshold,
-                            strategy_state=strategy_state,
-                            **({"staleness": stale_c} if traffic_staleness
-                               else {}))
-                else:
-                    # traced staleness (fluteflow): the arrival plane's
-                    # TRUE broadcast-version gap replaces the strategy's
-                    # in-jit staleness model — passed only when the
-                    # engine compiled the operand in, so staleness-blind
-                    # strategies keep their exact call signature
-                    parts, tl, ns, stats = strategy.client_step(
-                        client_update, params, arr_c, mask_c, client_lr,
-                        rng_c, round_idx=round_idx,
-                        leakage_threshold=leakage_threshold,
-                        quant_threshold=quant_threshold,
-                        strategy_state=strategy_state,
-                        **({"staleness": stale_c} if traffic_staleness
-                           else {}))
-                if chaos_corruption:
-                    # adversarial chaos (resilience/chaos.py corrupt
-                    # modes, already gated on the live client_mask):
-                    # the DEFAULT payload this client would transmit is
-                    # what gets corrupted — local training, stats, and
-                    # the claimed weight stay honest-looking, exactly
-                    # the threat fluteshield screens for
-                    pg0, w0 = parts["default"]
-                    mult = jnp.where(
-                        corrupt_c == CORRUPT_SCALE, corrupt_scale,
-                        jnp.where(corrupt_c == CORRUPT_SIGN_FLIP,
-                                  -corrupt_flip_scale, 1.0))
-                    bad = corrupt_c == CORRUPT_NAN
-                    pg0 = jax.tree.map(
-                        lambda g: (jnp.where(
-                            bad, jnp.asarray(jnp.nan, g.dtype),
-                            g * mult.astype(g.dtype))
-                            if jnp.issubdtype(g.dtype, jnp.floating)
-                            else g), pg0)
-                    parts = dict(parts)
-                    parts["default"] = (pg0, w0)
-                sub_norm = jnp.zeros(())
-                if wants_cohort:
-                    # secure aggregation: encode + pairwise-mask the
-                    # POST-corruption payload toward the round's SAMPLED
-                    # cohort (cohort_ids/cohort_mask, replicated); the
-                    # returned sub_norm is the submitted-norm scalar a
-                    # verified-aggregation server would see — the
-                    # shield's masked screening votes on it
-                    parts, sub_norm = strategy.mask_parts(
-                        parts, cid_c, cm_c, cohort_ids, cohort_mask,
-                        round_idx)
-                parts = {name: (tree, w * cm_c)
-                         for name, (tree, w) in parts.items()}
-                if stale_prob > 0.0:
-                    coin = jax.random.bernoulli(
-                        jax.random.fold_in(rng_c, 3), stale_prob)
-                    stale = coin.astype(jnp.float32) * cm_c
-                else:
-                    stale = jnp.zeros(())
-                # carry_row is None (a leafless pytree — vmap passes it
-                # through) unless the strategy runs in device-carry mode
-                return (parts, tl * cm_c, ns * cm_c, stats, stale,
-                        carry_row, sub_norm)
+            per_client = self._per_client_fn(
+                lambda rows: client_update, params, strategy_state,
+                client_lr, round_idx, leakage_threshold, quant_threshold,
+                rng, cohort_ids, cohort_mask)
 
             def process_chunk(arr_k, sm_k, cm_k, cid_k, *rest_k):
                 """One chunk of clients -> (summed locals, per-client
@@ -977,7 +1215,7 @@ class RoundEngine:
                 corrupt_k = rest_k.pop(0) if chaos_corruption else None
                 stale_k = rest_k.pop(0) if traffic_staleness else None
                 if pool is not None:
-                    arr_k = gather_pool(arr_k, sm_k)
+                    arr_k = gather_pool(pool, arr_k, sm_k)
                 vmap_args = (arr_k, sm_k, cm_k, cid_k) + \
                     ((slot_k,) if carry_paged else ()) + \
                     ((corrupt_k,) if chaos_corruption else ()) + \
@@ -1038,54 +1276,8 @@ class RoundEngine:
                     shield_counts = (jnp.sum(q_nonfinite),
                                      jnp.sum(q_norm))
 
-                local = {"parts": {}}
-                for name, (trees, ws) in parts.items():
-                    w_now = ws * (1.0 - stale)
-                    w_def = ws * stale
-                    wsum = lambda w, t: jax.tree.map(
-                        lambda g: jnp.tensordot(w, g, axes=[[0], [0]]), t)
-                    if name in strategy.unit_weight_parts:
-                        # masked payloads: every PRESENT slot enters with
-                        # coefficient exactly 1 (else pairwise masks
-                        # cannot cancel); the tensordot runs in the
-                        # tree's own dtype so int32 modular arithmetic
-                        # wraps instead of promoting to float
-                        gsum = jax.tree.map(
-                            lambda g: jnp.tensordot(
-                                cm_k.astype(g.dtype), g, axes=[[0], [0]]),
-                            trees)
-                        local["parts"][name] = {
-                            "grad_sum": gsum,
-                            "weight_sum": jnp.sum(w_now),
-                            "grad_sum_def": jax.tree.map(
-                                jnp.zeros_like, gsum),
-                            "weight_sum_def": jnp.sum(w_def),
-                            "weight_sum_raw": jnp.sum(ws),
-                        }
-                        continue
-                    local["parts"][name] = {
-                        "grad_sum": wsum(w_now, trees),
-                        "weight_sum": jnp.sum(w_now),
-                        "weight_sum_def": jnp.sum(w_def),
-                        "weight_sum_raw": jnp.sum(ws),
-                    }
-                    if stale_prob > 0.0 or not clients_per_chunk:
-                        # the deferred clients' sum is a second tree; the
-                        # chunk scan would carry it as an accumulator of
-                        # its own, so it exists there only where a client
-                        # can be deferred (outside the scan XLA drops an
-                        # unused one)
-                        local["parts"][name]["grad_sum_def"] = wsum(
-                            w_def, trees)
-                local.update({
-                    "train_loss_sum": jnp.sum(tls),
-                    "num_samples_sum": jnp.sum(nss),
-                    "client_count": jnp.sum(cm_k),
-                    "stats_mean_sum": jnp.sum(stats["mean"] * cm_k),
-                    "stats_mag_sum": jnp.sum(stats["mag"] * cm_k),
-                    "stats_var_sum": jnp.sum(stats["var_corrected"] * cm_k),
-                    "stats_norm_sum": jnp.sum(stats["norm"] * cm_k),
-                })
+                local = self._shard_sums(parts, tls, nss, stats, stale, cm_k,
+                                         deferred_tree)
                 for key in stats:
                     if key.startswith("ctr_"):
                         # what the model counted in its forward passes
@@ -1210,32 +1402,15 @@ class RoundEngine:
                         client_mask, client_ids, client_lr, round_idx,
                         leakage_threshold, quant_threshold, rng,
                         cohort_ids, cohort_mask, *rest):
-            # trailing operands are positional through shard_map, so
-            # which slot means what depends on the compile-time flags —
-            # route them to the right keyword here (with corruption off
-            # and the pool on, the pool must not land in corrupt_mode)
-            rest = list(rest)
-            if carry_split:
-                # sharded pool: this shard's table block rejoins the
-                # replicated state, and the global slot ids drop to
-                # block-local (padding stays -1) — the allocator
-                # guaranteed every lane's slot lives on this shard
-                tables = rest.pop(0)
-                strategy_state = {**strategy_state, **tables}
-            slots = rest.pop(0) if carry_paged else None
-            if carry_split:
-                off = jax.lax.axis_index(CLIENTS_AXIS) * shard_slots
-                slots = jnp.where(slots >= 0, slots - off, -1)
-            corrupt = rest.pop(0) if chaos_corruption else None
-            stale = rest.pop(0) if traffic_staleness else None
-            pool_arg = rest.pop(0) if pool_mode else None
+            # the monolithic round always takes the cohort operands
+            # (the bucket collect only under wants_cohort)
+            strategy_state, kw = self._unpack_trailing(
+                trailing, strategy_state, rest)
             return shard_body(params, strategy_state, arrays, sample_mask,
                               client_mask, client_ids, client_lr,
                               round_idx, leakage_threshold,
                               quant_threshold, rng, cohort_ids,
-                              cohort_mask, carry_slots=slots,
-                              corrupt_mode=corrupt, staleness=stale,
-                              pool=pool_arg)
+                              cohort_mask, **kw)
 
         if self.partition_mode == "shard_map":
             out_specs = (rspec, cspec) + \
@@ -1247,11 +1422,7 @@ class RoundEngine:
                 shard_entry, mesh=mesh,
                 in_specs=(rspec, rspec, cspec, cspec, cspec, cspec, rspec,
                           rspec, rspec, rspec, rspec, rspec, rspec) +
-                         ((cspec,) if carry_split else ()) +
-                         ((cspec,) if carry_paged else ()) +
-                         ((cspec,) if chaos_corruption else ()) +
-                         ((cspec,) if traffic_staleness else ()) +
-                         ((rspec,) if pool_mode else ()),
+                         tuple(spec for _, on, spec in trailing if on),
                 out_specs=out_specs, check_vma=False)
         else:
             # GSPMD mode: plain jit — client data stays sharded on the
@@ -1260,121 +1431,27 @@ class RoundEngine:
             # (enables tensor-parallel BERT, which the reference lacks).
             sharded_collect = shard_entry
 
-        chaos_faults = self.chaos_client_faults
-
         def round_step(params, opt_state, strategy_state, arrays, sample_mask,
                        client_mask, client_ids, client_lr, server_lr,
                        round_idx, leakage_threshold, quant_threshold, rng,
                        *extra_args):
-            # chaos client faults (extra data operands, present only when
-            # the engine was built with them): dropout multiplies into
-            # client_mask — downstream everything (strategy weights, psum
-            # denominators, stats) renormalizes exactly like mesh padding
-            # — and straggling truncates sample_mask's step grid, so a
-            # straggler's PARTIAL local work still aggregates
-            # (CLIP/FedBuff-style partial participation).  The injected-
-            # fault counters join round_stats and leave through the same
-            # packed single-transfer buffer as every other stat.
-            chaos_stats = {}
             # the round's SAMPLED cohort mask, captured BEFORE chaos
             # dropout folds in: secure-aggregation clients mask toward
             # the sampled cohort, so the cancellation pass needs both
             # masks to find the (survivor, lost) edges
             sampled_cm = client_mask
-            n_used = 0
-            if carry_paged:
-                # fleet paging: the host-remapped pool slot per lane —
-                # the carry gather/scatter index; everything else keeps
-                # using the true client ids
-                carry_slots = extra_args[0]
-                n_used = 1
-            else:
-                carry_slots = client_ids
-            if chaos_faults:
-                chaos_drop, chaos_keep = \
-                    extra_args[n_used], extra_args[n_used + 1]
-                n_used += 2
-                step_live = (jnp.sum(sample_mask, axis=-1) > 0)      # [K, S]
-                real_steps = jnp.sum(step_live, axis=-1)             # [K]
-                keep_f = (jnp.arange(sample_mask.shape[-2])[None, :]
-                          < chaos_keep[:, None]).astype(jnp.float32)  # [K, S]
-                live_cm = client_mask * (1.0 - chaos_drop)
-                chaos_stats = {
-                    "chaos_dropped": jnp.sum(client_mask * chaos_drop),
-                    "chaos_straggled": jnp.sum(
-                        live_cm * (chaos_keep < real_steps)),
-                    "chaos_steps_lost": jnp.sum(
-                        step_live.astype(jnp.float32) * (1.0 - keep_f)
-                        * live_cm[:, None]),
-                }
-                sample_mask = sample_mask * keep_f[..., None].astype(
-                    sample_mask.dtype)
-                client_mask = live_cm
-            corrupt_args = ()
-            if chaos_corruption:
-                # adversarial corruption modes (one more per-round data
-                # operand): gated on the LIVE mask — a dropped client
-                # never transmits, and a padding slot's zero payload
-                # must not be NaN'd into the sum (0-weight x NaN is
-                # still NaN through a tensordot)
-                corrupt_mode = extra_args[n_used]
-                n_used += 1
-                corrupt_mode = jnp.where(client_mask > 0, corrupt_mode, 0)
-                f32 = jnp.float32
-                chaos_stats.update({
-                    "chaos_nan_injected": jnp.sum(
-                        (corrupt_mode == CORRUPT_NAN).astype(f32)),
-                    "chaos_scaled": jnp.sum(
-                        (corrupt_mode == CORRUPT_SCALE).astype(f32)),
-                    "chaos_sign_flipped": jnp.sum(
-                        (corrupt_mode == CORRUPT_SIGN_FLIP).astype(f32)),
-                })
-                corrupt_args = (corrupt_mode,)
-            stale_args = ()
-            traffic_stats = {}
-            if traffic_staleness:
-                # fluteflow traced staleness (one more per-round data
-                # operand): gated on the LIVE mask — padding slots and
-                # chaos-dropped clients contribute nothing, so their
-                # staleness must not count — and binned into the
-                # per-staleness histogram that rides the packed stats
-                # (the host replay oracle in traffic/schedule.py is the
-                # cross-check).  The strategy consumes the TRUE value;
-                # only the histogram clips at its last (overflow) bin.
-                stale_vec = extra_args[n_used]
-                n_used += 1
-                stale_vec = jnp.where(client_mask > 0, stale_vec, 0)
-                f32 = jnp.float32
-                live = (client_mask > 0).astype(f32)
-                binned = jnp.minimum(stale_vec, STALE_HIST_BINS - 1)
-                traffic_stats = {
-                    f"traffic_stale_{b}": jnp.sum(
-                        (binned == b).astype(f32) * live)
-                    for b in range(STALE_HIST_BINS)}
-                traffic_stats["traffic_stale_sum"] = jnp.sum(
-                    stale_vec.astype(f32) * live)
-                stale_args = (stale_vec,)
-            pool_args = extra_args[n_used:]
+            (sample_mask, client_mask, carry_slots, collect_state,
+             trailing_args, fault_stats) = self._fold_faults(
+                strategy_state, sample_mask, client_mask, client_ids,
+                extra_args)
             # strategies may move the broadcast point off the canonical
             # params (e.g. FedAC's momentum-like md point); default identity
             bcast = strategy.broadcast_params(params, strategy_state)
-            if carry_split:
-                # the sharded pool tables ride their own cspec operand;
-                # everything else in strategy_state stays replicated
-                collect_state = {k: v for k, v in strategy_state.items()
-                                 if k not in carry_keys}
-                carry_tab_args = ({k: strategy_state[k]
-                                   for k in carry_keys},)
-            else:
-                collect_state = strategy_state
-                carry_tab_args = ()
             collect_out = sharded_collect(
                 bcast, collect_state, arrays, sample_mask, client_mask,
                 client_ids, client_lr, round_idx, leakage_threshold,
                 quant_threshold, rng, client_ids, sampled_cm,
-                *carry_tab_args,
-                *((carry_slots,) if carry_paged else ()),
-                *corrupt_args, *stale_args, *pool_args)
+                *trailing_args)
             collected, privacy_per_client = collect_out[0], collect_out[1]
             pos = 2
             if robust_stack:
@@ -1483,24 +1560,10 @@ class RoundEngine:
                 updates, new_opt_state = self.server_tx.update(
                     agg, opt_state, params)
                 new_params = optax.apply_updates(params, updates)
-            default_part = part_sums.get("default") or \
-                next(iter(part_sums.values()))
-            round_stats = {
-                "train_loss_sum": collected["train_loss_sum"],
-                "num_samples_sum": collected["num_samples_sum"],
-                "client_count": collected["client_count"],
-                "weight_sum": default_part["weight_sum"],
-                "weight_sum_raw": default_part["weight_sum_raw"],
-                "grad_mean": collected["stats_mean_sum"] / jnp.maximum(collected["client_count"], 1.0),
-                "grad_mag": collected["stats_mag_sum"] / jnp.maximum(collected["client_count"], 1.0),
-                "grad_var": collected["stats_var_sum"] / jnp.maximum(collected["client_count"], 1.0),
-                "grad_norm": collected["stats_norm_sum"] / jnp.maximum(collected["client_count"], 1.0),
-                "agg_grad_norm": optax.global_norm(agg),
-            }
+            round_stats = _round_stats(collected, part_sums, agg)
             round_stats.update({k: v for k, v in collected.items()
                                 if k.startswith("ctr_")})
-            round_stats.update(chaos_stats)
-            round_stats.update(traffic_stats)
+            round_stats.update(fault_stats)
             round_stats.update(secagg_stats)
             round_stats.update(rl_stats)
             if shield is not None:
@@ -1541,21 +1604,25 @@ class RoundEngine:
             # axis off before core runs), so K = shape[-3].  Deliberate
             # trace-time effect: the packer IS this trace's slot table —
             # written once per compile, read only by the host decoder.
-            # flint: disable=jit-purity trace-time slot-table recording is the flatpack contract (one write per compile, host-side reads only)
             self._stats_packers[("single", sample_mask.shape[-3])] = packer
             return (new_params, new_opt_state, new_strategy_state,
                     packer.pack(round_stats))
 
-        self._round_step_core = round_step
-        return self._instrument(
-            "round_step", jax.jit(round_step, donate_argnums=(0, 1, 2)))
+        return round_step
 
     # ------------------------------------------------------------------
     def _multi_core(self, num_rounds: int) -> Callable:
-        """The un-jitted ``lax.scan``-over-rounds program body — shared by
-        the legacy per-leaf dispatch (``_multi_round_fn`` jits it
-        directly) and the staged single-buffer dispatch (which wraps it
-        in the unpacking jit)."""
+        """The un-jitted ``lax.scan``-over-rounds program body, which the
+        staged dispatch wraps in its unpacking jit.
+
+        TPU-first perf feature with no reference equivalent: FLUTE pays a
+        full server<->worker protocol exchange per round
+        (``core/federated.py:281-424``); even our single-round program pays
+        one host dispatch per round, which dominates when the controller is
+        far from the chips.  Scanning R rounds inside one program amortizes
+        dispatch/transfer to once per R rounds; client sampling stays
+        host-side (it is data-independent lookahead), eval boundaries cap R.
+        """
         core = self._round_step_core
         chaos_faults = self.chaos_client_faults
         chaos_corruption = self.chaos_corruption
@@ -1592,27 +1659,6 @@ class RoundEngine:
             return p, o, s, stats
 
         return multi
-
-    def _multi_round_fn(self, num_rounds: int) -> Callable:
-        """Jitted ``lax.scan`` over ``num_rounds`` federated rounds.
-
-        TPU-first perf feature with no reference equivalent: FLUTE pays a
-        full server<->worker protocol exchange per round
-        (``core/federated.py:281-424``); even our single-round program pays
-        one host dispatch per round, which dominates when the controller is
-        far from the chips.  Scanning R rounds inside one program amortizes
-        dispatch/transfer to once per R rounds; client sampling stays
-        host-side (it is data-independent lookahead), eval boundaries cap R.
-        """
-        cached = self._multi_cache.get(num_rounds)
-        if cached is not None:
-            return cached
-        fn = self._instrument(
-            f"multi_round_r{num_rounds}",
-            jax.jit(self._multi_core(num_rounds), donate_argnums=(0, 1, 2)),
-            rounds=num_rounds)
-        self._multi_cache[num_rounds] = fn
-        return fn
 
     # ------------------------------------------------------------------
     # RL support: a round variant that also returns per-client payloads so
@@ -1768,25 +1814,15 @@ class RoundEngine:
             out.append(np.stack(vals) if stacked else vals[0])
         return tuple(out)
 
-    def _stage_chaos(self, chaos_vecs: Optional[list], sharding,
-                     stacked: bool) -> tuple:
-        """Legacy (``input_staging: false``) per-leaf device staging of
-        the chaos operands."""
-        # flint: disable=put-loop legacy non-staged dispatch path, kept for the staging A/B (tools/dispatch_cost_probe.py)
-        return tuple(jax.device_put(arr, sharding)
-                     for arr in self._chaos_host(chaos_vecs, stacked))
-
     # ------------------------------------------------------------------
-    # single-buffer input staging (server_config.input_staging, default
-    # on): the dispatch half of the flatpack idea.  Everything the host
-    # assembles per round — the feature (or index) grids, sample/client
-    # masks, client ids, chaos fault vectors, and the lr/round/threshold
-    # scalars — crosses the host boundary as ONE buffer per dtype group
-    # (clients-axis operands via AxisPacker, replicated scalars via
-    # ScalarStager); the inverse runs INSIDE the jitted program as static
-    # slices/reshapes XLA fuses away, so the math is bit-identical to the
-    # legacy per-leaf path (tests/test_input_staging.py pins both the
-    # equivalence and the transfer count).
+    # single-buffer input staging: the dispatch half of the flatpack
+    # idea.  Everything the host assembles per round — the feature (or
+    # index) grids, sample/client masks, client ids, chaos fault vectors,
+    # and the lr/round/threshold scalars — crosses the host boundary as
+    # ONE buffer per dtype group (clients-axis operands via AxisPacker,
+    # replicated scalars via ScalarStager); the inverse runs INSIDE the
+    # jitted program as static slices/reshapes XLA fuses away
+    # (the staging tests pin the transfer count).
     # ------------------------------------------------------------------
     def _build_staged_fn(self, R: int, ax_packer: AxisPacker,
                          stager: ScalarStager) -> Callable:
@@ -1808,8 +1844,7 @@ class RoundEngine:
                             sc["client_lr"], sc["server_lr"],
                             sc["round_idx"], sc["leakage"], sc["quant"],
                             rng, *carry, *chaos, *pool_args)
-            # splitting inside the trace produces the same keys the
-            # legacy path computed eagerly — split is a pure function
+            # one key a round, split inside the trace
             rngs = jax.random.split(rng, R)
             return core(params, opt_state, strategy_state, ax["arrays"],
                         ax["sample_mask"], ax["client_mask"],
@@ -1948,48 +1983,11 @@ class RoundEngine:
         Dispatch is async; the returned :class:`PackedStats` is a lazy
         handle — nothing crosses the host boundary until ``.fetch()``.
         """
-        if self.input_staging:
-            return self._dispatch_staged(
-                state, [batch], [client_lr], [server_lr], rng,
-                leakage_threshold,
-                [quant_threshold] if quant_threshold is not None else None,
-                chaos_vecs)
-        chaos_args = self._stage_chaos(chaos_vecs, self._client_sharding,
-                                       stacked=False)
-        carry_args = ()
-        if self.carry_paged:
-            carry_args = (jax.device_put(self._batch_slots(batch),
-                                         self._client_sharding),)
-        arrays, pool_args = self._stage_arrays([batch], self._client_sharding)
-        sample_mask = jax.device_put(batch.sample_mask, self._client_sharding)
-        client_mask = jax.device_put(batch.client_mask, self._client_sharding)
-        client_ids = jax.device_put(batch.client_ids, self._client_sharding)
-        # legacy-dispatch observability: one put per chaos operand +
-        # per array key + the three grids, plus the five jnp.asarray
-        # scalar transfers below (what staged mode collapses per dtype)
-        self.last_dispatch_puts = len(chaos_args) + len(arrays) + 3 + 5
-        self.last_staged_bytes = int(
-            sum(int(a.nbytes) for a in chaos_args) +
-            sum(int(a.nbytes) for a in arrays.values()) +
-            sample_mask.nbytes + client_mask.nbytes + client_ids.nbytes)
-
-        params, opt_state, strategy_state, vecs = self._round_step(
-            state.params, state.opt_state, state.strategy_state,
-            arrays, sample_mask, client_mask, client_ids,
-            jnp.asarray(client_lr, jnp.float32),
-            jnp.asarray(server_lr, jnp.float32),
-            jnp.asarray(state.round, jnp.int32),
-            jnp.asarray(leakage_threshold if leakage_threshold is not None
-                        else jnp.inf, jnp.float32),
-            jnp.asarray(quant_threshold if quant_threshold is not None
-                        else -1.0, jnp.float32), rng, *carry_args,
-            *chaos_args, *pool_args)
-        self._note_compiles("round_step", self._round_step)
-        new_state = ServerState(params, opt_state, strategy_state,
-                                state.round + 1)
-        packer = self._stats_packers[("single", batch.sample_mask.shape[0])]
-        return new_state, PackedStats(vecs, packer, rounds=1, stacked=False,
-                                      span=self.span_factory)
+        return self._dispatch_staged(
+            state, [batch], [client_lr], [server_lr], rng,
+            leakage_threshold,
+            [quant_threshold] if quant_threshold is not None else None,
+            chaos_vecs)
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -2031,14 +2029,6 @@ class RoundEngine:
         return {k: stack(lambda b: b.arrays[k])
                 for k in batches[0].arrays}, ()
 
-    def _stage_arrays(self, batches: list, sharding):
-        """Legacy (``input_staging: false``) per-leaf device staging of
-        the round's data inputs."""
-        host, pool_args = self._host_arrays(batches)
-        # flint: disable=put-loop legacy non-staged dispatch path, kept for the staging A/B (tools/dispatch_cost_probe.py)
-        return {k: jax.device_put(v, sharding)
-                for k, v in host.items()}, pool_args
-
     # ------------------------------------------------------------------
     def dispatch_rounds(self, state: ServerState, batches: list,
                         client_lrs: list, server_lrs: list,
@@ -2053,62 +2043,9 @@ class RoundEngine:
         lazy :class:`PackedStats` handle.  This is the dispatch half of
         the server's software-pipelined loop — the host is free to consume
         the previous chunk's results while this one executes."""
-        R = len(batches)
-        if self.input_staging:
-            return self._dispatch_staged(
-                state, batches, client_lrs, server_lrs, rng,
-                leakage_threshold, quant_thresholds, chaos_vecs)
-        if R == 1:
-            return self.run_round(
-                state, batches[0], client_lrs[0], server_lrs[0], rng,
-                leakage_threshold=leakage_threshold,
-                quant_threshold=(quant_thresholds[0] if quant_thresholds
-                                 else None),
-                chaos_vecs=chaos_vecs)
-        stacked_sharding = NamedSharding(self.mesh, P(None, CLIENTS_AXIS))
-        chaos_args = self._stage_chaos(chaos_vecs, stacked_sharding,
-                                       stacked=True)
-        carry_args = ()
-        if self.carry_paged:
-            carry_args = (jax.device_put(
-                np.stack([self._batch_slots(b) for b in batches]),
-                stacked_sharding),)
-        arrays, pool_args = self._stage_arrays(batches, stacked_sharding)
-        sample_mask = jax.device_put(
-            np.stack([b.sample_mask for b in batches]), stacked_sharding)
-        client_mask = jax.device_put(
-            np.stack([b.client_mask for b in batches]), stacked_sharding)
-        client_ids = jax.device_put(
-            np.stack([b.client_ids for b in batches]), stacked_sharding)
-        self.last_dispatch_puts = len(chaos_args) + len(arrays) + 3 + 5
-        self.last_staged_bytes = int(
-            sum(int(a.nbytes) for a in chaos_args) +
-            sum(int(a.nbytes) for a in arrays.values()) +
-            sample_mask.nbytes + client_mask.nbytes + client_ids.nbytes)
-        rngs = jax.random.split(rng, R)
-
-        fn = self._multi_round_fn(R)
-        params, opt_state, strategy_state, vecs = fn(
-            state.params, state.opt_state, state.strategy_state,
-            arrays, sample_mask, client_mask, client_ids,
-            jnp.asarray(client_lrs, jnp.float32),
-            jnp.asarray(server_lrs, jnp.float32),
-            jnp.arange(state.round, state.round + R, dtype=jnp.int32),
-            jnp.asarray(leakage_threshold if leakage_threshold is not None
-                        else jnp.inf, jnp.float32),
-            jnp.asarray(quant_thresholds if quant_thresholds is not None
-                        else [-1.0] * R, jnp.float32), rngs, *carry_args,
-            *chaos_args, *pool_args)
-        self._note_compiles(f"multi_round_r{R}", fn)
-        new_state = ServerState(params, opt_state, strategy_state,
-                                state.round + R)
-        # the scan stacks the core program's packed per-round vecs into
-        # [R, n] buffers; the slot table is the single-round packer the
-        # core trace recorded (the scan body traced it just above)
-        packer = self._stats_packers[
-            ("single", batches[0].sample_mask.shape[0])]
-        return new_state, PackedStats(vecs, packer, rounds=R, stacked=True,
-                                      span=self.span_factory)
+        return self._dispatch_staged(
+            state, batches, client_lrs, server_lrs, rng,
+            leakage_threshold, quant_thresholds, chaos_vecs)
 
     # ------------------------------------------------------------------
     # cohort shape-bucketing (server_config.cohort_bucketing): one
@@ -2144,17 +2081,11 @@ class RoundEngine:
         strategy = self.strategy
         client_update = self.client_update
         mega_update = self.mega_update
-        stale_prob = self.stale_prob
         mesh = self.mesh
         cspec = P(CLIENTS_AXIS)
         rspec = P()
-        pool_mode = self._pool is not None
-        shield = self.shield
-        defer_screen = shield is not None
-        chaos_faults = self.chaos_client_faults
+        defer_screen = self.shield is not None
         chaos_corruption = self.chaos_corruption
-        corrupt_scale = self._corrupt_scale
-        corrupt_flip_scale = self._corrupt_flip_scale
         # fluteflow: the traced-staleness operand threads after
         # corrupt_mode per bucket, exactly like the monolithic round
         traffic_staleness = self.traffic_staleness
@@ -2162,9 +2093,8 @@ class RoundEngine:
         carry_paged = self.carry_paged
         # mesh-sharded page pool: same split as the monolithic round —
         # tables ride a cspec operand, global slots drop to shard-local
-        carry_split = carry_paged and self.partition_mode == "shard_map"
-        carry_keys = tuple(strategy.carry_tables) if carry_paged else ()
-        shard_slots = self._carry_shard_slots
+        trailing = self._trailing_operands()
+        gather_axis = self._gather_axis
         # secure aggregation x bucketing: each bucket runs its OWN
         # pairwise-mask graph over the bucket's sampled sub-cohort (two
         # replicated operands — the bucket's ids and sampled mask);
@@ -2173,6 +2103,30 @@ class RoundEngine:
         # aggregate is bit-identical to the monolithic round's.
         wants_cohort = bool(getattr(strategy, "wants_cohort", False))
 
+        def replay_update(mega_c):
+            # fake-update replay: the lane scan already trained this
+            # client — hand its harvested rows back through the
+            # client_update interface, so the strategy's
+            # weight/transform/carry code runs UNCHANGED.  The
+            # trace-time call counter maps the strategy's i-th
+            # client_update call to its i-th megabatch pass
+            # (personalization's global+local double train).
+            calls = {"n": 0}
+
+            def update_fn(gp, arr, mask, lr_, r_, grad_offset=None):
+                i = calls["n"]
+                calls["n"] += 1
+                if i >= len(mega_c):
+                    raise ValueError(
+                        f"{type(strategy).__name__} issued more "
+                        "client_update calls than its megabatch_passes "
+                        "declared — extend the hook or set "
+                        "supports_megabatch = False")
+                pg_i, tl_i, ns_i, st_i = mega_c[i]
+                return pg_i, tl_i, ns_i, dict(st_i)
+
+            return update_fn
+
         def shard_body(params, strategy_state, arrays, sample_mask,
                        client_mask, client_ids, client_lr, round_idx,
                        leakage_threshold, quant_threshold, rng,
@@ -2180,119 +2134,13 @@ class RoundEngine:
                        carry_slots=None, corrupt_mode=None,
                        staleness=None, pool=None,
                        ptr=None, seg=None):
-            if self.partition_mode == "shard_map":
-                def gather_axis(x):
-                    return jax.lax.all_gather(x, CLIENTS_AXIS, axis=0,
-                                              tiled=True)
-            else:
-                def gather_axis(x):
-                    return x
-
-            def gather_pool(arrays, sample_mask):
-                # device-resident mode: identical to the round program's
-                # in-program row gather (padding slots zeroed via mask)
-                idx = arrays["__idx__"]
-                m = sample_mask
-                return {
-                    k: pool[k][idx]
-                    * m.reshape(m.shape + (1,) * (pool[k].ndim - 1)
-                                ).astype(pool[k].dtype)
-                    for k in pool}
-
-            def per_client(arr_c, mask_c, cm_c, cid_c, *rest):
-                # SAME per-client stream discipline as the fused round:
-                # fold_in on the CLIENT ID, so a client's rng (and hence
-                # its whole local update) is independent of which grid
-                # slot or bucket it landed in — the bit-identity anchor
-                rest = list(rest)
-                slot_c = rest.pop(0) if carry_paged else cid_c
-                corrupt_c = rest.pop(0) if chaos_corruption else None
-                stale_c = rest.pop(0) if traffic_staleness else None
-                rng_c = jax.random.fold_in(rng, cid_c)
-                if mega:
-                    # fake-update replay: the lane scan already trained
-                    # this client — hand its harvested rows back through
-                    # the client_update interface, so the strategy's
-                    # weight/transform/carry code runs UNCHANGED.  The
-                    # trace-time call counter maps the strategy's i-th
-                    # client_update call to its i-th megabatch pass
-                    # (personalization's global+local double train).
-                    mega_c = tuple(rest)
-                    calls = {"n": 0}
-
-                    def update_fn(gp, arr, mask, lr_, r_,
-                                  grad_offset=None):
-                        i = calls["n"]
-                        calls["n"] += 1
-                        if i >= len(mega_c):
-                            raise ValueError(
-                                f"{type(strategy).__name__} issued more "
-                                "client_update calls than its "
-                                "megabatch_passes declared — extend the "
-                                "hook or set supports_megabatch = False")
-                        pg_i, tl_i, ns_i, st_i = mega_c[i]
-                        return pg_i, tl_i, ns_i, dict(st_i)
-                else:
-                    update_fn = client_update
-                carry_row = None
-                if device_carry:
-                    parts, tl, ns, stats, carry_row = \
-                        strategy.client_step_carry(
-                            update_fn, params, arr_c, mask_c,
-                            client_lr, rng_c, client_id=slot_c,
-                            live_mask=cm_c, round_idx=round_idx,
-                            leakage_threshold=leakage_threshold,
-                            quant_threshold=quant_threshold,
-                            strategy_state=strategy_state,
-                            **({"staleness": stale_c} if traffic_staleness
-                               else {}))
-                else:
-                    parts, tl, ns, stats = strategy.client_step(
-                        update_fn, params, arr_c, mask_c, client_lr,
-                        rng_c, round_idx=round_idx,
-                        leakage_threshold=leakage_threshold,
-                        quant_threshold=quant_threshold,
-                        strategy_state=strategy_state,
-                        **({"staleness": stale_c} if traffic_staleness
-                           else {}))
-                if chaos_corruption:
-                    pg0, w0 = parts["default"]
-                    mult = jnp.where(
-                        corrupt_c == CORRUPT_SCALE, corrupt_scale,
-                        jnp.where(corrupt_c == CORRUPT_SIGN_FLIP,
-                                  -corrupt_flip_scale, 1.0))
-                    bad = corrupt_c == CORRUPT_NAN
-                    pg0 = jax.tree.map(
-                        lambda g: (jnp.where(
-                            bad, jnp.asarray(jnp.nan, g.dtype),
-                            g * mult.astype(g.dtype))
-                            if jnp.issubdtype(g.dtype, jnp.floating)
-                            else g), pg0)
-                    parts = dict(parts)
-                    parts["default"] = (pg0, w0)
-                sub_norm = jnp.zeros(())
-                if wants_cohort:
-                    # encode + mask the post-corruption payload toward
-                    # the BUCKET's sampled sub-cohort (same per-client
-                    # math as the fused round — bucket placement cannot
-                    # perturb a client's encoding, only its mask graph,
-                    # and masks cancel exactly)
-                    parts, sub_norm = strategy.mask_parts(
-                        parts, cid_c, cm_c, cohort_ids, cohort_mask,
-                        round_idx)
-                parts = {name: (tree, w * cm_c)
-                         for name, (tree, w) in parts.items()}
-                if stale_prob > 0.0:
-                    coin = jax.random.bernoulli(
-                        jax.random.fold_in(rng_c, 3), stale_prob)
-                    stale = coin.astype(jnp.float32) * cm_c
-                else:
-                    stale = jnp.zeros(())
-                return (parts, tl * cm_c, ns * cm_c, stats, stale,
-                        carry_row, sub_norm)
-
+            per_client = self._per_client_fn(
+                replay_update if mega else (lambda rows: client_update),
+                params, strategy_state, client_lr, round_idx,
+                leakage_threshold, quant_threshold, rng, cohort_ids,
+                cohort_mask)
             if pool is not None:
-                arrays = gather_pool(arrays, sample_mask)
+                arrays = gather_pool(pool, arrays, sample_mask)
             mega_rows = ()
             if mega:
                 # one lane scan per strategy pass — the MXU-saturating
@@ -2344,43 +2192,8 @@ class RoundEngine:
                     pc["sub_norm"] = gather_axis(sub_norms)
                 return pc, privacy_per_client
 
-            cm_k = client_mask
-            local = {"parts": {}}
-            for name, (trees, ws) in parts.items():
-                w_now = ws * (1.0 - stale)
-                w_def = ws * stale
-                wsum = lambda w, t: jax.tree.map(
-                    lambda g: jnp.tensordot(w, g, axes=[[0], [0]]), t)
-                if name in strategy.unit_weight_parts:
-                    gsum = jax.tree.map(
-                        lambda g: jnp.tensordot(
-                            cm_k.astype(g.dtype), g, axes=[[0], [0]]),
-                        trees)
-                    local["parts"][name] = {
-                        "grad_sum": gsum,
-                        "weight_sum": jnp.sum(w_now),
-                        "grad_sum_def": jax.tree.map(
-                            jnp.zeros_like, gsum),
-                        "weight_sum_def": jnp.sum(w_def),
-                        "weight_sum_raw": jnp.sum(ws),
-                    }
-                    continue
-                local["parts"][name] = {
-                    "grad_sum": wsum(w_now, trees),
-                    "weight_sum": jnp.sum(w_now),
-                    "grad_sum_def": wsum(w_def, trees),
-                    "weight_sum_def": jnp.sum(w_def),
-                    "weight_sum_raw": jnp.sum(ws),
-                }
-            local.update({
-                "train_loss_sum": jnp.sum(tls),
-                "num_samples_sum": jnp.sum(nss),
-                "client_count": jnp.sum(cm_k),
-                "stats_mean_sum": jnp.sum(stats["mean"] * cm_k),
-                "stats_mag_sum": jnp.sum(stats["mag"] * cm_k),
-                "stats_var_sum": jnp.sum(stats["var_corrected"] * cm_k),
-                "stats_norm_sum": jnp.sum(stats["norm"] * cm_k),
-            })
+            local = self._shard_sums(parts, tls, nss, stats, stale,
+                                     client_mask, "inline")
             if self.partition_mode == "shard_map":
                 local = jax.lax.psum(local, CLIENTS_AXIS)
             out = (local, privacy_per_client)
@@ -2401,24 +2214,15 @@ class RoundEngine:
             # each shard's lanes point only at its own grid rows
             ptr = rest.pop(0) if mega else None
             seg = rest.pop(0) if mega else None
-            if carry_split:
-                tables = rest.pop(0)
-                strategy_state = {**strategy_state, **tables}
-            slots = rest.pop(0) if carry_paged else None
-            if carry_split:
-                off = jax.lax.axis_index(CLIENTS_AXIS) * shard_slots
-                slots = jnp.where(slots >= 0, slots - off, -1)
-            corrupt = rest.pop(0) if chaos_corruption else None
-            stale = rest.pop(0) if traffic_staleness else None
-            pool_arg = rest.pop(0) if pool_mode else None
+            strategy_state, kw = self._unpack_trailing(
+                trailing, strategy_state, rest)
             return shard_body(params, strategy_state, arrays, sample_mask,
                               client_mask, client_ids, client_lr,
                               round_idx, leakage_threshold,
                               quant_threshold, rng,
                               cohort_ids=cohort_ids,
-                              cohort_mask=cohort_mask, carry_slots=slots,
-                              corrupt_mode=corrupt, staleness=stale,
-                              pool=pool_arg, ptr=ptr, seg=seg)
+                              cohort_mask=cohort_mask, ptr=ptr, seg=seg,
+                              **kw)
 
         if self.partition_mode == "shard_map":
             out_specs = ((rspec, cspec) if defer_screen else
@@ -2430,11 +2234,7 @@ class RoundEngine:
                           rspec, rspec, rspec, rspec) +
                          ((rspec, rspec) if wants_cohort else ()) +
                          ((cspec, cspec) if mega else ()) +
-                         ((cspec,) if carry_split else ()) +
-                         ((cspec,) if carry_paged else ()) +
-                         ((cspec,) if chaos_corruption else ()) +
-                         ((cspec,) if traffic_staleness else ()) +
-                         ((rspec,) if pool_mode else ()),
+                         tuple(spec for _, on, spec in trailing if on),
                 out_specs=out_specs, check_vma=False)
         else:
             sharded = shard_entry
@@ -2443,11 +2243,6 @@ class RoundEngine:
                          client_mask, client_ids, client_lr, round_idx,
                          leakage_threshold, quant_threshold, rng,
                          *extra_args):
-            # chaos fold: identical semantics to the fused round —
-            # dropout multiplies into client_mask, straggling truncates
-            # the step grid, corruption modes gate on the live mask;
-            # the per-bucket counters sum additively in finalize
-            chaos_stats = {}
             # the bucket's SAMPLED mask, pre-chaos: secure-agg clients
             # mask toward it; finalize cancels toward the lost slots
             sampled_cm = client_mask
@@ -2455,87 +2250,24 @@ class RoundEngine:
             if mega:
                 tape_args = tuple(extra_args[:2])
                 extra_args = extra_args[2:]
-            n_used = 0
-            if carry_paged:
-                carry_slots = extra_args[0]
-                n_used = 1
-            else:
-                carry_slots = client_ids
-            if chaos_faults:
-                chaos_drop, chaos_keep = \
-                    extra_args[n_used], extra_args[n_used + 1]
-                n_used += 2
-                step_live = (jnp.sum(sample_mask, axis=-1) > 0)
-                real_steps = jnp.sum(step_live, axis=-1)
-                keep_f = (jnp.arange(sample_mask.shape[-2])[None, :]
-                          < chaos_keep[:, None]).astype(jnp.float32)
-                live_cm = client_mask * (1.0 - chaos_drop)
-                chaos_stats = {
-                    "chaos_dropped": jnp.sum(client_mask * chaos_drop),
-                    "chaos_straggled": jnp.sum(
-                        live_cm * (chaos_keep < real_steps)),
-                    "chaos_steps_lost": jnp.sum(
-                        step_live.astype(jnp.float32) * (1.0 - keep_f)
-                        * live_cm[:, None]),
-                }
-                sample_mask = sample_mask * keep_f[..., None].astype(
-                    sample_mask.dtype)
-                client_mask = live_cm
-            corrupt_args = ()
-            if chaos_corruption:
-                corrupt_mode = extra_args[n_used]
-                n_used += 1
-                corrupt_mode = jnp.where(client_mask > 0, corrupt_mode, 0)
-                f32 = jnp.float32
-                chaos_stats.update({
-                    "chaos_nan_injected": jnp.sum(
-                        (corrupt_mode == CORRUPT_NAN).astype(f32)),
-                    "chaos_scaled": jnp.sum(
-                        (corrupt_mode == CORRUPT_SCALE).astype(f32)),
-                    "chaos_sign_flipped": jnp.sum(
-                        (corrupt_mode == CORRUPT_SIGN_FLIP).astype(f32)),
-                })
-                corrupt_args = (corrupt_mode,)
-            stale_args = ()
-            if traffic_staleness:
-                stale_vec = extra_args[n_used]
-                n_used += 1
-                stale_vec = jnp.where(client_mask > 0, stale_vec, 0)
-                f32 = jnp.float32
-                live = (client_mask > 0).astype(f32)
-                binned = jnp.minimum(stale_vec, STALE_HIST_BINS - 1)
-                chaos_stats.update({
-                    f"traffic_stale_{b}": jnp.sum(
-                        (binned == b).astype(f32) * live)
-                    for b in range(STALE_HIST_BINS)})
-                chaos_stats["traffic_stale_sum"] = jnp.sum(
-                    stale_vec.astype(f32) * live)
-                stale_args = (stale_vec,)
-            pool_args = extra_args[n_used:]
+            (sample_mask, client_mask, carry_slots, collect_state,
+             trailing_args, fault_stats) = self._fold_faults(
+                strategy_state, sample_mask, client_mask, client_ids,
+                extra_args)
             bcast = strategy.broadcast_params(params, strategy_state)
-            if carry_split:
-                collect_state = {k: v for k, v in strategy_state.items()
-                                 if k not in carry_keys}
-                carry_tab_args = ({k: strategy_state[k]
-                                   for k in carry_keys},)
-            else:
-                collect_state = strategy_state
-                carry_tab_args = ()
             out = sharded(bcast, collect_state, arrays, sample_mask,
                           client_mask, client_ids, client_lr, round_idx,
                           leakage_threshold, quant_threshold, rng,
                           *((client_ids, sampled_cm) if wants_cohort
                             else ()),
-                          *tape_args, *carry_tab_args,
-                          *((carry_slots,) if carry_paged else ()),
-                          *corrupt_args, *stale_args, *pool_args)
+                          *tape_args, *trailing_args)
             if defer_screen:
                 result = {"pc": out[0], "privacy": out[1]}
             else:
                 result = {"local": out[0], "privacy": out[1]}
                 if device_carry:
                     result["carry"] = out[2]
-            result["chaos"] = chaos_stats
+            result["chaos"] = fault_stats
             result["ids"] = client_ids
             if wants_cohort:
                 # everything the finalize's per-bucket mask cancellation
@@ -2754,16 +2486,7 @@ class RoundEngine:
                     "weight_sum_def": jnp.zeros(()),
                     "weight_sum_raw": jnp.sum(w),
                 }}
-                collected = {
-                    "train_loss_sum": jnp.sum(tls),
-                    "num_samples_sum": jnp.sum(nss),
-                    "client_count": jnp.sum(cm),
-                    "stats_mean_sum": jnp.sum(stats["mean"] * cm),
-                    "stats_mag_sum": jnp.sum(stats["mag"] * cm),
-                    "stats_var_sum": jnp.sum(
-                        stats["var_corrected"] * cm),
-                    "stats_norm_sum": jnp.sum(stats["norm"] * cm),
-                }
+                collected = _stat_sums(tls, nss, stats, cm)
                 if robust_stack:
                     agg = strategy.combine_stack(
                         stack, cm, jax.random.fold_in(rng, 17))
@@ -2801,24 +2524,7 @@ class RoundEngine:
                 updates, new_opt_state = server_tx.update(
                     agg, opt_state, params)
                 new_params = optax.apply_updates(params, updates)
-            default_part = part_sums.get("default") or \
-                next(iter(part_sums.values()))
-            round_stats = {
-                "train_loss_sum": collected["train_loss_sum"],
-                "num_samples_sum": collected["num_samples_sum"],
-                "client_count": collected["client_count"],
-                "weight_sum": default_part["weight_sum"],
-                "weight_sum_raw": default_part["weight_sum_raw"],
-                "grad_mean": collected["stats_mean_sum"]
-                / jnp.maximum(collected["client_count"], 1.0),
-                "grad_mag": collected["stats_mag_sum"]
-                / jnp.maximum(collected["client_count"], 1.0),
-                "grad_var": collected["stats_var_sum"]
-                / jnp.maximum(collected["client_count"], 1.0),
-                "grad_norm": collected["stats_norm_sum"]
-                / jnp.maximum(collected["client_count"], 1.0),
-                "agg_grad_norm": optax.global_norm(agg),
-            }
+            round_stats = _round_stats(collected, part_sums, agg)
             chaos_tot = outs[0]["chaos"]
             for o in outs[1:]:
                 chaos_tot = jax.tree.map(jnp.add, chaos_tot, o["chaos"])

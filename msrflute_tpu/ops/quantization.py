@@ -100,31 +100,6 @@ def quantile_abs(x: jnp.ndarray, q) -> jnp.ndarray:
                      low_value * low_weight + high_value * high_weight)
 
 
-def approx_quantile_abs(x: jnp.ndarray, q, n_bins: int = 2048) -> jnp.ndarray:
-    """Histogram-CDF approximation of ``quantile(|x|, q)``.
-
-    An estimate, not the published threshold (:func:`quantile_abs` is
-    that, exactly, in 32 passes over the leaf): a fixed-width histogram
-    of ``|x|`` is one O(n) scatter-add, and the threshold is linearly
-    interpolated inside the bin where the CDF crosses ``q``.  Max error
-    is one bin width (``max|x| / n_bins``) — far below the
-    annealed-threshold granularity the reference runs with
-    (``extensions/quantization/quant.py:50-51``).
-    """
-    a = jnp.abs(x.reshape(-1).astype(jnp.float32))
-    hi = jnp.maximum(jnp.max(a), 1e-30)
-    idx = jnp.clip((a / hi * n_bins).astype(jnp.int32), 0, n_bins - 1)
-    # integer accumulators: float32 counts saturate at 2^24 (x+1 == x),
-    # silently breaking the one-bin-width error bound for >16M-element leaves
-    counts = jnp.zeros((n_bins,), jnp.int32).at[idx].add(1)
-    cdf = jnp.cumsum(counts).astype(jnp.float32) / a.size
-    # first bin whose cdf >= q, then interpolate within it
-    bin_i = jnp.argmax(cdf >= q)
-    prev = jnp.where(bin_i > 0, cdf[jnp.maximum(bin_i - 1, 0)], 0.0)
-    frac = (q - prev) / jnp.maximum(cdf[bin_i] - prev, 1e-12)
-    return (bin_i + jnp.clip(frac, 0.0, 1.0)) * hi / n_bins
-
-
 def bin_sparsify(g: jnp.ndarray, lo, hi, thresh, n_bins: int) -> jnp.ndarray:
     """The elementwise core in plain jnp: nearest of ``n_bins`` labels on
     ``linspace(lo, hi)`` (== the reference's half-bin-shifted bucketize),
@@ -141,8 +116,7 @@ def bin_sparsify(g: jnp.ndarray, lo, hi, thresh, n_bins: int) -> jnp.ndarray:
 def quantize_array(grad: jnp.ndarray, n_bins: int,
                    quant_threshold: float,
                    min_grad: Optional[jnp.ndarray] = None,
-                   max_grad: Optional[jnp.ndarray] = None,
-                   approx: bool = False) -> jnp.ndarray:
+                   max_grad: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Quantize one tensor to ``n_bins`` levels, zeroing sub-threshold
     components (reference ``quant_bins`` + thresholding).
 
@@ -153,8 +127,7 @@ def quantize_array(grad: jnp.ndarray, n_bins: int,
     g = grad.astype(jnp.float32)
     lo = jnp.min(g) if min_grad is None else min_grad
     hi = jnp.max(g) if max_grad is None else max_grad
-    thresh = (approx_quantile_abs(g, quant_threshold) if approx
-              else quantile_abs(g, quant_threshold))
+    thresh = quantile_abs(g, quant_threshold)
     from .pallas_kernels import compiled_kernels_apply, quant_bin_sparsify
     if compiled_kernels_apply():
         out = quant_bin_sparsify(g.reshape(-1), lo, hi, thresh, n_bins)
@@ -163,25 +136,20 @@ def quantize_array(grad: jnp.ndarray, n_bins: int,
 
 
 def quantize_pytree(tree: Any, quant_threshold: Optional[float],
-                    quant_bits: int = 8, global_stats: bool = False,
-                    approx: bool = False) -> Any:
+                    quant_bits: int = 8, global_stats: bool = False) -> Any:
     """Quantize every leaf (reference ``quant_model``).  ``global_stats``
-    computes one min/max/threshold across all leaves (``quant.py:36-39``).
-    ``approx`` swaps the exact quantile for the one-pass histogram-CDF
-    estimate (config ``client_config.quant_approx``)."""
+    computes one min/max/threshold across all leaves (``quant.py:36-39``)."""
     if quant_threshold is None:
         return tree
     n_bins = 2 ** int(quant_bits)
     if not global_stats:
         return jax.tree.map(
-            lambda g: quantize_array(g, n_bins, quant_threshold,
-                                     approx=approx), tree)
+            lambda g: quantize_array(g, n_bins, quant_threshold), tree)
     from jax.flatten_util import ravel_pytree
     flat, unravel = ravel_pytree(tree)
     lo, hi = jnp.min(flat), jnp.max(flat)
     # the exact threshold in the type jnp.quantile gave it: the leaves'
     # for a static quantile, float32 for a traced one
-    thresh = (approx_quantile_abs(flat, quant_threshold) if approx
-              else quantile_abs(flat, quant_threshold).astype(
-                  jnp.result_type(flat, quant_threshold)))
+    thresh = quantile_abs(flat, quant_threshold).astype(
+        jnp.result_type(flat, quant_threshold))
     return unravel(bin_sparsify(flat, lo, hi, thresh, n_bins))

@@ -235,15 +235,10 @@ FLEET_FIELD_SPECS = {
 }
 
 MEGAKERNEL_KEYS = {
-    "enable", "fused_epochs", "pallas_apply",
+    "pallas_apply",
 }
 
 MEGAKERNEL_FIELD_SPECS = {
-    "enable": ("bool", None, None),
-    # epoch/step loop fusion (default ON, block absent or not): one
-    # lax.scan over the flattened [num_epochs * steps] grid — program
-    # size and compile time stay flat in num_epochs
-    "fused_epochs": ("bool", None, None),
     # opt-in pallas fused SGD apply over the flattened param vector
     # (plain-SGD client optimizers only; TPU-targeted)
     "pallas_apply": ("bool", None, None),
@@ -484,11 +479,6 @@ SERVER_KEYS = {
     # pipelined instead of host-orchestrated serial; see
     # docs/config_extensions.md for the per-strategy tradeoffs
     "fused_carry",
-    # input_staging: single-buffer host->device dispatch staging (one
-    # packed transfer per dtype group instead of ~8-10 per-leaf
-    # device_puts per round) — default on; set false to A/B the legacy
-    # per-leaf path (tools/dispatch_cost_probe.py)
-    "input_staging",
     "rounds_per_step", "clients_per_chunk", "checkpoint_backend",
     "checkpoint_async", "compilation_cache_dir", "secure_agg", "fedbuff",
     "dump_norm_stats", "scaffold_device_controls", "scaffold_flush_freq",
@@ -528,9 +518,8 @@ SERVER_KEYS = {
     # requires cohort_bucketing (docs/config_extensions.md, RUNBOOK
     # "Closing the MFU gap")
     "megabatch",
-    # megakernel local SGD: epoch/step loop fusion (default on) + the
-    # opt-in pallas fused SGD apply — `enable: false` restores the
-    # legacy per-epoch unrolled trace (docs/config_extensions.md)
+    # megakernel local SGD: the opt-in pallas fused SGD apply
+    # (docs/config_extensions.md)
     "megakernel",
     # fleet mode: million-client populations — O(cohort) cohort draws
     # (Floyd / weighted reservoir) and, with fused_carry, a fixed-
@@ -558,7 +547,7 @@ CLIENT_KEYS = {
     "meta_optimizer_config", "ss_config",
     # TPU-native extensions
     "num_epochs", "step_bucketing", "quant_thresh", "quant_threshold",
-    "quant_bits", "quant_approx", "quant_anneal", "updatable_layers",
+    "quant_bits", "quant_anneal", "updatable_layers",
     "semisupervision",
 }
 
@@ -598,7 +587,6 @@ SERVER_FIELD_SPECS = {
     "dump_norm_stats": ("bool", None, None),
     "pipeline_depth": ("int", 0, None),
     "fused_carry": ("bool", None, None),
-    "input_staging": ("bool", None, None),
     "rounds_per_step": ("int", 1, None),
     "clients_per_chunk": ("int", 1, None),
     "model_backup_freq": ("int", 1, None),
@@ -618,7 +606,6 @@ CLIENT_FIELD_SPECS = {
     "num_epochs": ("int", 1, None),
     "desired_max_samples": ("int", 0, None),
     "quant_bits": ("int", 1, 32),
-    "quant_approx": ("bool", None, None),
     "copying_train_data": ("bool", None, None),
     "do_profiling": ("bool", None, None),
     "ignore_subtask": ("bool", None, None),

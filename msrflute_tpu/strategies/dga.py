@@ -47,9 +47,6 @@ class DGA(BaseStrategy):
         if bits is None and mc is not None:
             bits = mc.get("quant_bits")
         self.quant_bits = int(bits) if bits is not None else 10
-        # a histogram-CDF estimate of the threshold instead of the exact
-        # rank selection (see ops.quantization.approx_quantile_abs)
-        self.quant_approx = bool(cc.get("quant_approx", False))
 
     def client_weight(self, *, num_samples, train_loss, stats, rng):
         if self.aggregate_median == "softmax":
@@ -86,8 +83,7 @@ class DGA(BaseStrategy):
             thr = jnp.where(jnp.asarray(thr) >= 0, thr,
                             float(self.quant_threshold))
             pseudo_grad = quantize_pytree(
-                pseudo_grad, quant_threshold=thr, quant_bits=self.quant_bits,
-                approx=self.quant_approx)
+                pseudo_grad, quant_threshold=thr, quant_bits=self.quant_bits)
         return pseudo_grad, weight
 
     # ---- staleness buffer (replaces dga.py:260-284 host lists) --------

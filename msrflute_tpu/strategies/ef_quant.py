@@ -31,7 +31,6 @@ Config::
       quant_bits: 4          # 2^bits levels; EF is what makes 2-4 viable
       quant_thresh: 0.0      # |.|-quantile zeroed before binning
       quant_anneal: 1.0      # per-round threshold multiplier (DGA's knob)
-      quant_approx: false    # histogram estimate of the quantile, not exact
 
 Composition: local DP runs inside ``client_payloads``'s per-client
 transform BEFORE the EF step, so the noised payload is what gets
@@ -324,7 +323,6 @@ class EFQuant(FedAvg):
         self.quant_bits = int(cc.get("quant_bits", 4))
         self.quant_thresh = float(cc.get("quant_thresh", 0.0))
         self.quant_anneal = float(cc.get("quant_anneal", 1.0) or 1.0)
-        self.quant_approx = bool(cc.get("quant_approx", False))
         if not 1 <= self.quant_bits <= 16:
             raise ValueError(
                 f"ef_quant quant_bits must be in [1, 16], "
@@ -376,7 +374,7 @@ class EFQuant(FedAvg):
                            self.quant_thresh) if quant_threshold is not None \
             else self.quant_thresh
         q = quantize_array(corrected, n_bins=2 ** self.quant_bits,
-                           quant_threshold=thresh, approx=self.quant_approx)
+                           quant_threshold=thresh)
         new_res = corrected - q
         parts = dict(parts)
         parts["default"] = (unravel(q), w)
@@ -411,6 +409,5 @@ class EFQuant(FedAvg):
         corrected = pgs_flat + residuals
         q = jax.vmap(lambda row: quantize_array(
             row, n_bins=2 ** self.quant_bits,
-            quant_threshold=thresh,
-            approx=self.quant_approx))(corrected)
+            quant_threshold=thresh))(corrected)
         return q, corrected - q

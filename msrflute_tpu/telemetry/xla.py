@@ -213,9 +213,9 @@ def program_size_bytes(fn: Callable, *args: Any) -> Optional[int]:
     0 generated bytes).  Both scale with traced program TEXT — cloned
     scan bodies, unrolled epochs — not with executed FLOPs, which is
     exactly what the epoch-bloat regression guard must pin
-    (tests/test_megakernel.py): a fused-epoch program at num_epochs=4
-    sits in the same size class as num_epochs=1, the legacy unrolled
-    trace does not."""
+    (tests/test_megakernel.py): the fused-epoch program at num_epochs=4
+    sits in the same size class as num_epochs=1, where a scan body
+    cloned per epoch would not."""
     import jax
 
     try:

@@ -24,8 +24,7 @@ device, in deterministic bucket order.  Pinned here:
    (sentinel-verified), and padding efficiency >= 2x monolithic on a
    heterogeneous cohort;
 6. guards — host-orchestrated paths, clients_per_chunk,
-   dump_norm_stats, legacy input staging, schema misconfigurations all
-   refused loudly.
+   dump_norm_stats, schema misconfigurations all refused loudly.
 """
 
 import tempfile
@@ -229,6 +228,118 @@ def test_per_client_payloads_bit_identical_across_bucket_shapes():
                     assert np.array_equal(np.asarray(la)[row],
                                           np.asarray(lb)[mrow]), \
                         f"client {cid} differs on S={s_b} grid"
+
+
+_BUCKETING = {"cohort_bucketing": {"enable": True, "max_buckets": 3}}
+_ROBUST = {"screen_nonfinite": True, "norm_multiplier": 0,
+           "aggregator": "mean"}
+#: flag the shared per-client body takes -> (strategy, server overlay,
+#: one device, a stat that reads > 0 when the flag took).  Secure aggregation and the megabatch replay are not
+#: doubled here: tests/test_secagg_compose.py::
+#: test_bucketed_x_secagg_bit_identical_to_monolithic and
+#: tests/test_megabatch.py::test_megabatch_matches_vmap_bitwise_e1 hold
+#: their pairs bit for bit.
+_ONE_BUCKET_CASES = {
+    "plain": ("fedavg", {}, False, "client_count"),
+    "chaos_faults_and_corruption": ("fedavg", {"chaos": {
+        "seed": 5, "dropout_rate": 0.2, "straggler_rate": 0.2,
+        "corrupt_scale_rate": 0.2, "corrupt_sign_flip_rate": 0.2}}, False,
+        "chaos_sign_flipped"),
+    "traced_staleness": ("fedbuff", {"traffic": {"seed": 1}}, False,
+                         "traffic_stale_sum"),
+    "stale_prob": ("dga", {"stale_prob": 0.5}, False, "client_count"),
+    "fused_carry": ("scaffold", {"fused_carry": True}, False,
+                    "client_count"),
+    "paged_carry": ("scaffold", {
+        "fused_carry": True, "fleet": {"page_pool_slots": 16}}, True,
+        "client_count"),
+    # the finalize screens the gathered cohort, the monolithic round each
+    # shard before its psum: the same association on one device only
+    "shield_quarantine": ("fedavg", {
+        "chaos": {"seed": 11, "corrupt_nan_rate": 0.3},
+        "robust": _ROBUST}, True, "shield_nonfinite"),
+    "device_pool": ("fedavg", {}, False, "client_count"),
+}
+
+
+@pytest.mark.parametrize("flag", sorted(_ONE_BUCKET_CASES))
+def test_one_bucket_round_is_the_monolithic_round_bit_for_bit(flag):
+    """One bucket that holds the whole cohort on the monolithic grid:
+    collect + finalize give the monolithic round's new state and stats
+    to the bit, under each flag the builders' shared per-client body,
+    fault fold and sums take (engine/round.py)."""
+    from msrflute_tpu.data.batching import pack_round_indices
+    from msrflute_tpu.parallel import make_mesh
+    from msrflute_tpu.resilience.chaos import (CORRUPT_NAN, CORRUPT_SCALE,
+                                               CORRUPT_SIGN_FLIP)
+    strategy, over, one_device, witness = _ONE_BUCKET_CASES[flag]
+    ds = _hetero_dataset()
+    ids, steps = [0, 2, 5, 12, 3], 8  # needs 1, 2, 2, 8, 2 at B=4
+
+    def dispatch(bucketed):
+        cfg = _cfg(strategy=strategy, ncpi=8, server_over={
+            **over, **(_BUCKETING if bucketed else {})})
+        if flag == "device_pool":
+            cfg.client_config.data_config.train["device_resident"] = True
+        server = OptimizationServer(
+            make_task(cfg.model_config), cfg, ds,
+            model_dir=tempfile.mkdtemp(), seed=0,
+            mesh=make_mesh(num_devices=1) if one_device else None)
+        engine = server.engine
+        pad = server.mesh.shape["clients"]
+        if flag == "device_pool":
+            batch = pack_round_indices(ds, server._pool_offsets, ids, 4,
+                                       steps, shuffle=False,
+                                       pad_clients_to=pad)
+        else:
+            batch = pack_round_batches(ds, ids, 4, steps, shuffle=False,
+                                       pad_clients_to=pad)
+        k = batch.client_ids.shape[0]
+        lanes = np.arange(k)
+        if engine.carry_paged:
+            batch.carry_slots = np.where(batch.client_ids >= 0, lanes,
+                                         -1).astype(np.int32)
+        entry = []
+        if engine.chaos_client_faults:
+            entry += [(lanes == 1).astype(np.float32),
+                      np.where(lanes == 3, 2, steps).astype(np.float32)]
+        if engine.chaos_corruption:
+            modes = ([CORRUPT_NAN, 0, CORRUPT_NAN] if flag.startswith(
+                "shield") else [0, 0, CORRUPT_SCALE, 0, CORRUPT_SIGN_FLIP])
+            entry.append(np.resize(np.asarray(modes + [0] * k, np.int32),
+                                   k))
+        if engine.traffic_staleness:
+            entry.append((lanes % 3).astype(np.int32))
+        entry = tuple(entry) or None
+        rng = jax.random.PRNGKey(3)
+        if bucketed:
+            state, packed = engine.dispatch_bucketed_rounds(
+                server.state, [[batch]], [0.2], [1.0], rng,
+                chaos_vecs=[[entry]] if entry else None)
+        else:
+            state, packed = engine.dispatch_rounds(
+                server.state, [batch], [0.2], [1.0], rng,
+                chaos_vecs=[entry] if entry else None)
+        return jax.device_get((state.params, state.strategy_state)), \
+            packed.fetch()
+
+    (state_m, stats_m), (state_b, stats_b) = dispatch(False), dispatch(True)
+    leaves_m, leaves_b = jax.tree.leaves(state_m), jax.tree.leaves(state_b)
+    assert len(leaves_m) == len(leaves_b)
+    for a, b in zip(leaves_m, leaves_b):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    assert np.isfinite(np.asarray(leaves_m[0])).all()
+    assert set(stats_m) == set(stats_b)
+    for key in stats_m:
+        if flag == "shield_quarantine":
+            # the aggregate is summed in another program there (the
+            # finalize, from the gathered stack): its norm to rounding
+            np.testing.assert_allclose(stats_m[key], stats_b[key],
+                                       rtol=4 * np.finfo(np.float32).eps,
+                                       err_msg=key)
+        else:
+            assert np.array_equal(stats_m[key], stats_b[key]), key
+    assert float(stats_m[witness][0]) > 0
 
 
 # ======================================================================
@@ -436,7 +547,6 @@ def test_guard_host_orchestrated_paths_refused():
 @pytest.mark.parametrize("over,msg", [
     ({"clients_per_chunk": 2}, "clients_per_chunk"),
     ({"dump_norm_stats": True}, "dump_norm_stats"),
-    ({"input_staging": False}, "input_staging"),
 ])
 def test_guard_incompatible_engine_modes(over, msg):
     ds = _hetero_dataset()

@@ -82,6 +82,25 @@ def test_schema_unknown_key_nested_dataset_block():
         FLUTEConfig.from_dict(bad)
 
 
+@pytest.mark.parametrize("section,block,name", [
+    ("server_config", {"input_staging": False},
+     "server_config.input_staging"),
+    ("server_config", {"megakernel": {"enable": False}},
+     "server_config.megakernel.enable"),
+    ("server_config", {"megakernel": {"fused_epochs": False}},
+     "server_config.megakernel.fused_epochs"),
+    ("client_config", {"quant_approx": True}, "client_config.quant_approx"),
+])
+def test_schema_refuses_keys_deleted_with_their_arms(section, block, name):
+    """The arms of three settled A/Bs went with their keys (PR 30): a
+    configuration that still names one is refused by name, like any
+    misspelt key, whatever value it asks for."""
+    import re
+    bad = {**MINI, section: {**MINI[section], **block}}
+    with pytest.raises(SchemaError, match=re.escape(name) + ": unknown key"):
+        FLUTEConfig.from_dict(bad)
+
+
 def test_schema_allow_unknown_downgrades_to_warning(monkeypatch):
     monkeypatch.setenv("MSRFLUTE_ALLOW_UNKNOWN", "1")
     bad = {**MINI, "server_config": {**MINI["server_config"],

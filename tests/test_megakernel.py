@@ -4,22 +4,22 @@ regression guard.
 
 The two invariants this file pins:
 
-- **bit-identity** — the fused single-scan inner loop and the fused
-  apply-updates traversals compute the EXACT f32 bits of the legacy
-  per-epoch unrolled trace (engine-level: a whole federated run's params
-  match bitwise);
-- **program-size class** — a fused ``num_epochs=4`` program sits in the
-  same compiled-program size class as ``num_epochs=1``, pinned via
-  ``telemetry.xla.program_size_bytes`` (program TEXT, not wall-clock),
-  while the legacy unrolled trace demonstrably bloats linearly.
+- **the same training** — the fused single-scan inner loop computes what
+  a plain loop over epochs x steps computes (optax and the task's loss,
+  written out in this file), and the fused apply-updates traversals the
+  EXACT f32 bits of their three-pass spelling;
+- **program-size class** — a ``num_epochs=4`` program sits in the same
+  compiled-program size class as ``num_epochs=1``, pinned via
+  ``telemetry.xla.program_size_bytes`` (program TEXT, not wall-clock).
 """
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import optax
 import pytest
 
-from msrflute_tpu.config import FLUTEConfig, ModelConfig, OptimizerConfig
+from msrflute_tpu.config import ModelConfig, OptimizerConfig
 from msrflute_tpu.engine.client_update import (ClientHParams,
                                                build_client_update)
 from msrflute_tpu.models import make_task
@@ -49,40 +49,68 @@ def _run(task, opt, hp, seed=42):
 
 
 # ----------------------------------------------------------------------
-# bit-identity of the fused inner loop
+# the fused inner loop against a plain loop over epochs x steps
 # ----------------------------------------------------------------------
-@pytest.mark.parametrize("opt", [
-    OptimizerConfig(type="sgd", lr=0.1, momentum=0.9),
-    OptimizerConfig(type="adam", lr=0.01),
-])
-def test_fused_epochs_bitwise_equals_legacy(opt):
-    task = _lr_task()
-    hp = dict(num_epochs=4, max_grad_norm=1.0, fedprox_mu=0.01)
-    out_f = _run(task, opt, ClientHParams(fused_epochs=True, **hp))
-    out_l = _run(task, opt, ClientHParams(fused_epochs=False, **hp))
-    for a, b in zip(jax.tree.leaves(out_f), jax.tree.leaves(out_l)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+def _plain_local_steps(task, tx, params0, arrays, mask, rng, *, epochs,
+                       max_norm, mu):
+    """The same local training as a plain Python loop: optax and the
+    task's loss, nothing of the engine's.  Every step of
+    ``_client_inputs`` holds data, so no step is a no-op."""
+    params, opt_state = params0, tx.init(params0)
+    loss_sum = jnp.zeros(())
+    for _ in range(epochs):
+        for t in range(mask.shape[0]):
+            batch = {k: v[t] for k, v in arrays.items()}
+            batch["sample_mask"] = mask[t]
+            rng, sub = jax.random.split(rng)
+            (loss, _), grads = jax.value_and_grad(task.loss, has_aux=True)(
+                params, batch, sub, True)
+            grads = jax.tree.map(lambda g, w, w0: g + mu * (w - w0),
+                                 grads, params, params0)
+            scale = jnp.minimum(1.0, max_norm / jnp.maximum(
+                optax.global_norm(grads), 1e-12))
+            grads = jax.tree.map(lambda g: g * scale, grads)
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
+            loss_sum = loss_sum + loss
+    return jax.tree.map(lambda w0, w: w0 - w, params0, params), loss_sum
 
 
-def test_single_epoch_identical_either_way():
-    """num_epochs == 1 must trace the exact historical program on both
-    paths (the fused grid degenerates to the plain scan)."""
+@pytest.mark.parametrize("opt,tx", [
+    (OptimizerConfig(type="sgd", lr=0.1, momentum=0.9),
+     optax.sgd(0.1, momentum=0.9)),
+    (OptimizerConfig(type="adam", lr=0.01), optax.adam(0.1)),
+], ids=["sgd_momentum", "adam"])
+def test_fused_scan_equals_a_plain_epoch_loop(opt, tx):
+    """Four epochs as ONE scan over the flattened grid against the loop
+    written out (a wrong step order, a skipped epoch or a stale
+    optimizer state reads 1e-2 or more); ``_run`` hands the client lr
+    0.1 whatever the optimizer's own."""
     task = _lr_task()
-    opt = OptimizerConfig(type="sgd", lr=0.1)
-    out_f = _run(task, opt, ClientHParams(num_epochs=1, fused_epochs=True))
-    out_l = _run(task, opt, ClientHParams(num_epochs=1, fused_epochs=False))
-    for a, b in zip(jax.tree.leaves(out_f), jax.tree.leaves(out_l)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    hp = ClientHParams(num_epochs=4, max_grad_norm=1.0, fedprox_mu=0.01)
+    pseudo_grad, loss_sum, _, _ = _run(task, opt, hp)
+    arrays, mask = _client_inputs()
+    want_pg, want_loss = _plain_local_steps(
+        task, tx, task.init_params(jax.random.PRNGKey(0)), arrays, mask,
+        jax.random.PRNGKey(42), epochs=4, max_norm=1.0, mu=0.01)
+    # not bitwise: the loop runs operation by operation, the scan as one
+    # fused program (they differ in the last bit after a step).  Twelve
+    # float32 steps: 100 x the type's epsilon, relative
+    tol = dict(rtol=100 * np.finfo(np.float32).eps, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(pseudo_grad), jax.tree.leaves(want_pg)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), **tol)
+    np.testing.assert_allclose(np.asarray(loss_sum), np.asarray(want_loss),
+                               **tol)
 
 
 # ----------------------------------------------------------------------
 # epoch program-bloat regression guard (ISSUE 12 satellite)
 # ----------------------------------------------------------------------
-def _program_size(num_epochs, fused):
+def _program_size(num_epochs):
     task = _lr_task()
     opt = OptimizerConfig(type="sgd", lr=0.1, momentum=0.9)
     cu = build_client_update(task, opt, ClientHParams(
-        num_epochs=num_epochs, fused_epochs=fused, max_grad_norm=1.0))
+        num_epochs=num_epochs, max_grad_norm=1.0))
     arrays, mask = _client_inputs()
     size = program_size_bytes(
         jax.jit(cu), task.init_params(jax.random.PRNGKey(0)), arrays,
@@ -93,23 +121,16 @@ def _program_size(num_epochs, fused):
 
 def test_fused_epochs_hold_program_size_class():
     """num_epochs=4 compiles the same program SIZE class as num_epochs=1
-    on the fused path (pinned via telemetry.xla program bytes, not
-    wall-clock): the scan body is traced once whatever the epoch count.
-    The legacy unrolled trace is the control — it must show the linear
-    bloat the fused path removes, or this guard guards nothing."""
-    fused_1 = _program_size(1, fused=True)
-    fused_4 = _program_size(4, fused=True)
-    fused_8 = _program_size(8, fused=True)
+    (pinned via telemetry.xla program bytes, not wall-clock): the scan
+    body is traced once whatever the epoch count, where a body cloned
+    per epoch would grow by one body an epoch."""
+    size_1 = _program_size(1)
+    size_4 = _program_size(4)
+    size_8 = _program_size(8)
     # one-time delta for the indexed-gather body is allowed; past that
     # the program must be FLAT in the epoch count
-    assert fused_4 <= 1.25 * fused_1, (fused_1, fused_4)
-    assert fused_8 == fused_4, (fused_4, fused_8)
-    # control: the legacy unrolled trace must show the linear bloat this
-    # guard exists to catch (~one cloned scan body per extra epoch)
-    legacy_1 = _program_size(1, fused=False)
-    legacy_8 = _program_size(8, fused=False)
-    assert legacy_8 >= 1.8 * legacy_1, (legacy_1, legacy_8)
-    assert legacy_8 > 1.5 * fused_8, (fused_8, legacy_8)
+    assert size_4 <= 1.25 * size_1, (size_1, size_4)
+    assert size_8 == size_4, (size_4, size_8)
 
 
 # ----------------------------------------------------------------------
@@ -199,52 +220,3 @@ def test_pallas_apply_refuses_unfusable_optimizers():
         build_client_update(task, OptimizerConfig(type="sgd", lr=0.01),
                             ClientHParams(pallas_apply=True,
                                           updatable_layers=("dense",)))
-
-
-# ----------------------------------------------------------------------
-# engine-level f32 bit-identity: fused default vs full legacy trace
-# ----------------------------------------------------------------------
-def _server_cfg(megakernel=None):
-    raw = {
-        "model_config": {"model_type": "LR", "num_classes": 4,
-                         "input_dim": 8},
-        "strategy": "fedavg",
-        "server_config": {
-            "max_iteration": 4, "num_clients_per_iteration": 8,
-            "initial_lr_client": 0.3,
-            "optimizer_config": {"type": "sgd", "lr": 1.0},
-            "val_freq": 10_000, "initial_val": False,
-            "data_config": {"val": {"batch_size": 64}},
-        },
-        "client_config": {
-            "num_epochs": 3,
-            "optimizer_config": {"type": "sgd", "lr": 0.3},
-            "data_config": {"train": {"batch_size": 4}},
-        },
-    }
-    if megakernel is not None:
-        raw["server_config"]["megakernel"] = megakernel
-    return FLUTEConfig.from_dict(raw)
-
-
-def _train_params(cfg, synth_dataset, mesh8, tmp_path, tag):
-    from msrflute_tpu.engine import OptimizationServer
-    task = make_task(cfg.model_config)
-    server = OptimizationServer(task, cfg, synth_dataset,
-                                model_dir=str(tmp_path / tag), mesh=mesh8,
-                                seed=0)
-    server.train()
-    return server.state.params
-
-
-def test_engine_fused_default_bitwise_equals_legacy(synth_dataset, mesh8,
-                                                    tmp_path):
-    """A whole multi-epoch federated run under the default fused inner
-    loop produces bit-identical params to `megakernel: {enable: false}`
-    (the pre-PR trace) — the engine-level f32 identity anchor."""
-    p_fused = _train_params(_server_cfg(), synth_dataset, mesh8,
-                            tmp_path, "fused")
-    p_legacy = _train_params(_server_cfg({"enable": False}), synth_dataset,
-                             mesh8, tmp_path, "legacy")
-    for a, b in zip(jax.tree.leaves(p_fused), jax.tree.leaves(p_legacy)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

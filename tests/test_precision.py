@@ -95,15 +95,14 @@ def test_schema_rejects_non_mapping_precision():
 
 def test_schema_rejects_unknown_megakernel_key():
     raw = _raw_cfg()
-    raw["server_config"]["megakernel"] = {"fused_epoch": True}
+    raw["server_config"]["megakernel"] = {"pallas_aply": True}
     with pytest.raises(SchemaError, match="megakernel"):
         validate(raw)
 
 
 def test_schema_accepts_megakernel_block():
     raw = _raw_cfg()
-    raw["server_config"]["megakernel"] = {"fused_epochs": False,
-                                          "pallas_apply": False}
+    raw["server_config"]["megakernel"] = {"pallas_apply": False}
     validate(raw)
 
 
@@ -200,8 +199,7 @@ def test_engine_exposes_precision_policy(synth_dataset, mesh8):
                          select_strategy(cfg.strategy)(cfg, None),
                          mesh=mesh8)
     assert engine.precision == {"compute": "bfloat16"}
-    assert engine.megakernel == {"fused_epochs": True,
-                                 "pallas_apply": False}
+    assert engine.hparams.pallas_apply is False
 
 
 def test_engine_refuses_pallas_apply_off_tpu(synth_dataset, mesh8):

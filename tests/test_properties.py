@@ -73,21 +73,6 @@ def test_pack_round_batches_masked_padding_algebra(shapes, seed):
         assert all(tuple(np.round(r, 5)) in src_rows for r in real)
 
 
-@given(st.integers(1, 2 ** 31 - 1), st.floats(0.05, 0.95),
-       st.floats(1e-4, 1e3))
-@settings(**SETTINGS)
-def test_approx_quantile_error_bound(seed, q, scale):
-    """Histogram-CDF quantile stays within 2 bin widths of the exact one
-    for arbitrary scales and quantiles."""
-    from msrflute_tpu.ops.quantization import approx_quantile_abs
-    rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.normal(size=(2048,)) * scale, jnp.float32)
-    exact = float(jnp.quantile(jnp.abs(x), q))
-    approx = float(approx_quantile_abs(x, q, 1024))
-    bin_w = float(jnp.max(jnp.abs(x))) / 1024
-    assert abs(approx - exact) <= 2 * bin_w + 1e-9
-
-
 @given(st.integers(1, 2 ** 31 - 1), st.integers(1, 3000),
        st.floats(0.0, 1.0), st.floats(1e-30, 1e30), st.floats(0.0, 1.0))
 @settings(**SETTINGS)

@@ -211,8 +211,7 @@ def test_quantize_pytree_same_payload(global_stats, dtype, traced_q):
 def test_no_sort_in_the_quantiser_and_a_fixed_trip_count():
     """The exact path lowers to one counted loop and no sort, under the
     round's client ``vmap`` and a traced threshold."""
-    fn = jax.jit(jax.vmap(lambda g, q: quantize_array(g, 256, q,
-                                                      approx=False),
+    fn = jax.jit(jax.vmap(lambda g, q: quantize_array(g, 256, q),
                           (0, None)))
     lowered = fn.lower(jax.ShapeDtypeStruct((4, 96, 128), jnp.float32),
                        jax.ShapeDtypeStruct((), jnp.float32))

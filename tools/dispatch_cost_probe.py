@@ -99,7 +99,7 @@ def main() -> int:
     res["cases"]["tree16_donated_threaded"] = round(
         1e3 * (time.perf_counter() - tic) / iters, 4)
 
-    # input-staging A/B (PR 6, server_config.input_staging): the faithful
+    # input staging (PR 6, engine/round.py::_dispatch_staged): the faithful
     # round's REAL per-dispatch operand mix — [K,S,B,D] feature grid,
     # [K,S,B] sample mask, [K] client mask/ids, [K] chaos drop/
     # keep_steps/corrupt vectors, and the lr/round/threshold scalars —
