@@ -288,9 +288,10 @@ class CheckpointManager:
             import orbax.checkpoint as ocp
             self._ocp = ocp
             self._orbax = ocp.AsyncCheckpointer(ocp.StandardCheckpointHandler())
-        # msgpack async-latest: per-round ``latest`` saves hand a DEVICE
-        # snapshot to a writer thread, so the device->host transfer and
-        # the disk write overlap the next rounds' compute (the per-round
+        # msgpack async-latest: per-round ``latest`` saves, and then the
+        # best-model saves too, hand a DEVICE snapshot to a writer
+        # thread, so the device->host transfer and the disk write
+        # overlap the next rounds' compute (the per-round
         # sync fetch is the faithful-mode fullrun's dominant cost on a
         # remote-attached chip; SURVEY §7 explicitly budgets for async
         # checkpointing).  Same durability contract as the orbax path: a
@@ -298,8 +299,18 @@ class CheckpointManager:
         self.async_latest = bool(async_latest) and backend == "msgpack"
         self._mp_cond = threading.Condition()
         self._mp_mailbox = None   # single-slot device snapshot (see _mp_submit)
+        self._mp_target = None    # ... and the file it is to become
+        #: the writer keeps a best-model snapshot on the device until the
+        #: training thread waits for it (:meth:`_await_writer`)
+        self._mp_hold = False
         self._mp_busy = False
         self._mp_worker = None
+        #: file -> whether the writer's newest save of it landed
+        self._mp_landed: Dict[str, bool] = {}
+        #: the files of a best-model save still with the writer, until
+        #: :meth:`land_best` has seen it land and linked its twins
+        self._best_pending: Optional[Tuple[str, ...]] = None
+        self._best_ok = True
         os.makedirs(model_dir, exist_ok=True)
 
     # -- orbax helpers -------------------------------------------------
@@ -445,35 +456,56 @@ class CheckpointManager:
         checkpoint files externally or at process exit)."""
         if self._orbax is not None:
             self._commit_pending_latest()
-        self._mp_wait()
+        self._mp_wait("exit")
 
-    # -- msgpack async-latest writer ------------------------------------
-    def _mp_wait(self) -> None:
-        if self._mp_worker is None:
+    # -- msgpack async writer -------------------------------------------
+    def _await_writer(self, why: Optional[str] = None) -> None:
+        """The training thread waits for the writer's slot to empty: a
+        span of its own (``ckpt_wait``), since it is the one place where
+        the disk can hold the training thread."""
+        # `for`: the save last handed to the writer is what is waited for
+        why = why or ("best" if "best_val_" in (self._mp_target or "")
+                      else "latest")
+        with (self.telemetry.span("ckpt_wait", **{"for": why})
+              if self.telemetry is not None else NULL_SPAN):
+            with self._mp_cond:
+                self._mp_hold = False
+                self._mp_cond.notify_all()
+                while self._mp_mailbox is not None or self._mp_busy:
+                    self._mp_cond.wait()
+
+    def _mp_wait(self, why: Optional[str] = None) -> None:
+        """Wait until the writer holds nothing; a best-model save that
+        was with it gets its other metric names linked to the file it
+        wrote (:meth:`land_best` then says how it went)."""
+        if self._mp_worker is None and self._best_pending is None:
             return
-        with self._mp_cond:
-            while self._mp_mailbox is not None or self._mp_busy:
-                self._mp_cond.wait()
+        self._await_writer(why)
+        paths, self._best_pending = self._best_pending, None
+        if paths is not None:
+            self._best_ok = self._mp_landed.pop(paths[0], False) and \
+                self._link_twins(paths)
         # surface the writer thread's accumulated failures HERE, on the
         # training thread — an exception raised inside the daemon writer
         # would vanish and the run would train uncheckpointed forever
         self.escalator.check()
 
     def _mp_loop(self) -> None:
-        path = os.path.join(self.model_dir, LATEST)
         while True:
             with self._mp_cond:
                 while self._mp_mailbox is None:
                     self._mp_cond.wait()
-                snap = self._mp_mailbox
+                snap, path = self._mp_mailbox, self._mp_target
                 self._mp_mailbox = None
                 self._mp_busy = True
+            landed = False
             try:
                 # flutescope: the async writer's fetch+serialize+write
                 # appears on ITS OWN thread track in the trace — the
                 # direct visual of checkpoint IO overlapping (or
                 # stalling) device rounds
-                with (self.telemetry.span("ckpt_async_write")
+                with (self.telemetry.span("ckpt_async_write",
+                                          file=os.path.basename(path))
                       if self.telemetry is not None else NULL_SPAN) as span:
                     # wait for the snapshot program BEFORE asking for its
                     # transfers: a device_get on arrays still to be
@@ -482,6 +514,16 @@ class CheckpointManager:
                     # the training thread's stats fetch that the same end
                     # releases (the fence was then seen ~2 ms late)
                     jax.block_until_ready(snap)
+                    with self._mp_cond:
+                        # a best-model snapshot's transfers start when
+                        # the training thread comes to wait for the file,
+                        # which it does once the next dispatch is
+                        # launched: asked for earlier, 1.9 GB of them
+                        # queue ahead of that dispatch's inputs (0.4 s of
+                        # an idle device on the chip) or of an
+                        # evaluation's fetch
+                        while self._mp_hold:
+                            self._mp_cond.wait()
                     blob = _state_chunks(snap)
                     if span is not None:
                         span["bytes"] = _chunks_size(blob)
@@ -493,23 +535,34 @@ class CheckpointManager:
                     # training thread's next submit/wait (escalator.check
                     # there), never out of this daemon thread where it
                     # would vanish
-                    self._write_blob(path, blob, keep_prev=True)
+                    landed = self._write_blob(
+                        path, blob,
+                        keep_prev=os.path.basename(path) == LATEST)
                     del blob
-            except (KeyboardInterrupt, SystemExit):
-                raise  # fatal signals must not be logged away
             except Exception as exc:  # never kill training from the writer
-                print_rank(f"async latest save failed: {exc!r}",
-                           loglevel=logging.WARNING)
-                self.escalator.record_failure("async latest serialize")
+                print_rank(f"async save of {os.path.basename(path)} "
+                           f"failed: {exc!r}", loglevel=logging.WARNING)
+                self.escalator.record_failure(
+                    f"async serialize {os.path.basename(path)}")
+            except BaseException:
+                # fatal signals must not be logged away; they take this
+                # thread with them, and the next submit starts another
+                # (a wait on a dead writer would never return)
+                self._mp_worker = None
+                raise
             finally:
                 with self._mp_cond:
+                    self._mp_landed[path] = landed
                     self._mp_busy = False
                     self._mp_cond.notify_all()
 
-    def _mp_submit(self, state: ServerState) -> Dict[str, int]:
-        """Hand a snapshot of ``state`` to the writer thread; returns
-        what the snapshot launched on the device (``leaves`` copied,
-        ``programs`` dispatched) for the caller's span."""
+    def _mp_submit(self, state: ServerState, name: str = LATEST,
+                   hold: bool = False) -> Dict[str, int]:
+        """Hand a snapshot of ``state`` to the writer thread, to become
+        the file ``name`` of the model directory; returns what the
+        snapshot launched on the device (``leaves`` copied, ``programs``
+        dispatched) for the caller's span.  ``hold``: the writer fetches
+        it only once the training thread waits for the writer."""
         # single-slot, not latest-wins: wait for the in-flight save first,
         # so the on-disk latest can lag the status log by AT MOST the one
         # in-flight round — the same durability window the orbax path
@@ -517,13 +570,11 @@ class CheckpointManager:
         # skew between latest_model and status_log.json, and resume pairs
         # the two.)  The wait also bounds snapshot HBM to one extra copy.
         self.escalator.check()  # abort on the training thread, not the writer
+        self._await_writer()
         if self._mp_worker is None:
             self._mp_worker = threading.Thread(
                 target=self._mp_loop, name="ckpt-latest-writer", daemon=True)
             self._mp_worker.start()
-        with self._mp_cond:
-            while self._mp_mailbox is not None or self._mp_busy:
-                self._mp_cond.wait()
         # device-side copy: the round step donates the live param/opt
         # buffers, so the snapshot must be arrays nothing else consumes.
         # ONE program copies every device leaf (a copy per leaf ran into
@@ -548,6 +599,10 @@ class CheckpointManager:
                       for x in leaves])
         with self._mp_cond:
             self._mp_mailbox = snap
+            # flint: disable=thread-escape a bool, immutable: nothing to tear
+            self._mp_hold = hold
+            # flint: disable=thread-escape a str, immutable: nothing to tear
+            self._mp_target = os.path.join(self.model_dir, name)
             self._mp_cond.notify()
         return {"leaves": len(on_device), "programs": int(bool(on_device))}
 
@@ -557,11 +612,14 @@ class CheckpointManager:
                     ) -> Optional[Dict[str, int]]:
         """Save ``latest``; the async msgpack path returns what its
         device snapshot launched (see :meth:`_mp_submit`).  ``same_as``:
-        the file :meth:`save_best` has just written FROM THIS VERY STATE
-        (the caller's knowledge: an evaluation round's state goes out as
-        the best model and then as that round's ``latest``).  The msgpack
-        ``latest`` is then a link to it, made here and now: durable on
-        return, and no second 1.9 GB through the disk."""
+        the file :meth:`save_best` was given THIS VERY STATE for (the
+        caller's knowledge: an evaluation round's state goes out as the
+        best model and then as that round's ``latest``).  The msgpack
+        ``latest`` is then a link to it, made here once that file has
+        landed (:meth:`land_best`): durable on return, and no second
+        1.9 GB through the disk.  A best-model save that failed leaves
+        nothing to link to: the round then has no ``latest`` of its own,
+        as after any failed save."""
         if self.backend == "orbax":
             self._commit_pending_latest()
             committed = self._latest_slot()
@@ -574,7 +632,8 @@ class CheckpointManager:
         path = os.path.join(self.model_dir, LATEST)
         if same_as is not None:
             self._mp_wait()  # an earlier round's latest lands first
-            self._write_blob(path, _LinkTo(same_as), keep_prev=True)
+            if self._best_ok:
+                self._write_blob(path, _LinkTo(same_as), keep_prev=True)
             self.escalator.check()
             return None
         if self.async_latest:
@@ -603,7 +662,7 @@ class CheckpointManager:
                 if os.path.isdir(best) and not os.path.isdir(dst):
                     shutil.copytree(best, dst)
             return
-        self._mp_wait()  # the epoch copy must see the newest latest file
+        self._mp_wait()  # the copies must see the newest files, whole
         src = os.path.join(self.model_dir, LATEST)
         if os.path.exists(src):
             shutil.copyfile(src, os.path.join(self.model_dir,
@@ -615,14 +674,21 @@ class CheckpointManager:
                     self.model_dir, f"best_val_{name}_model_epoch{round_no}.msgpack"))
 
     def save_best(self, state: ServerState, metric_name: str,
-                  *more_names: str) -> Optional[str]:
+                  *more_names: str, hold: bool = False) -> Optional[str]:
         """Best-val checkpoint on improvement (reference
-        ``core/evaluation.py:103-109``), one file per metric name,
-        durable on return with the msgpack backend.  Metrics that
-        improved at the same evaluation hold the same state: it is
-        fetched and written once, the other names are links to that
-        file.  Returns the file written (for :meth:`save_latest`'s
-        ``same_as``), None with orbax."""
+        ``core/evaluation.py:103-109``), one file per metric name.
+        Metrics that improved at the same evaluation hold the same
+        state: it is fetched and written once, the other names are links
+        to that file.  Returns the file written (for
+        :meth:`save_latest`'s ``same_as``), None with orbax.  With the
+        async msgpack writer the state goes to the writer as a device
+        snapshot and the file is on the disk, with its links, once
+        :meth:`land_best` returns: whoever writes a NAME for this state
+        (the status log's ``best_val_*``, the ``latest`` link, a backup
+        copy) calls that first; ``hold``: the caller is about to, and
+        the writer starts the snapshot's transfers only then
+        (:meth:`_mp_submit`).  Without the writer the save is
+        synchronous: durable on return."""
         if self.backend == "orbax":
             for name in more_names:
                 self.save_best(state, name)
@@ -638,7 +704,40 @@ class CheckpointManager:
         paths = tuple(
             os.path.join(self.model_dir, f"best_val_{name}_model.msgpack")
             for name in (metric_name,) + more_names)
-        return paths[0] if self._write(paths, state) else None
+        self.land_best()  # an earlier one's links are made from ITS file
+        if self.async_latest:
+            self._mp_submit(state, os.path.basename(paths[0]), hold=hold)
+            self._best_pending = paths
+            return paths[0]
+        self._best_ok = self._write(paths, state)
+        return paths[0] if self._best_ok else None
+
+    def land_best(self) -> bool:
+        """If a best-model save is with the writer, wait for it (its
+        failure surfaces here, on the training thread).  Returns whether
+        the newest best-model save is on the disk, sidecar and links and
+        all; a failure is already counted toward escalation.  A
+        ``latest`` in flight is not waited for: the status log may run
+        that one round ahead (the single slot's bound)."""
+        if self._best_pending is not None:
+            self._mp_wait()
+        return self._best_ok
+
+    def _link_twins(self, targets: Tuple[str, ...]) -> bool:
+        """Every target after the first becomes a link to the first,
+        with its sidecar."""
+        first = targets[0]
+
+        def link():
+            for twin in targets[1:]:
+                _rotate(first, twin)
+                _rotate(first + SIDECAR_SUFFIX, twin + SIDECAR_SUFFIX)
+
+        if not targets[1:] or run_with_retry(link, self.retry,
+                                             what="checkpoint links"):
+            return True
+        self.escalator.record_failure(f"save {targets[1]}")
+        return False
 
     def _write_blob(self, path: str, blob,
                     keep_prev: bool = False) -> bool:
@@ -709,16 +808,7 @@ class CheckpointManager:
             done = self._write_blob(
                 first, blob, keep_prev=os.path.basename(first) == LATEST)
             del blob
-
-        def _link_twins():
-            for twin in targets[1:]:
-                _rotate(first, twin)
-                _rotate(first + SIDECAR_SUFFIX, twin + SIDECAR_SUFFIX)
-
-        if done and targets[1:] and not run_with_retry(
-                _link_twins, self.retry, what="checkpoint links"):
-            self.escalator.record_failure(f"save {targets[1]}")
-            done = False
+        done = done and self._link_twins(targets)
         self.escalator.check()
         return done
 
@@ -735,7 +825,7 @@ class CheckpointManager:
                 # crash mid-swap: the previous version is parked at .old
                 restored = self._orbax_load(path + ".old", template)
             return restored
-        self._mp_wait()  # an in-flight async latest must land first
+        self._mp_wait()  # what is with the writer must land first
         path = os.path.join(self.model_dir, name)
         candidates = [path]
         if name == LATEST:

@@ -188,10 +188,11 @@ class PersonalizationServer(OptimizationServer):
             self.config.server_config["rounds_per_step"] = 1
 
     def _round_housekeeping(self, round_no, val_freq, rec_freq,
-                            skip_latest=False, rng_snapshot=None):
+                            skip_latest=False, rng_snapshot=None,
+                            chunk=None):
         super()._round_housekeeping(round_no, val_freq, rec_freq,
                                     skip_latest=skip_latest,
-                                    rng_snapshot=rng_snapshot)
+                                    rng_snapshot=rng_snapshot, chunk=chunk)
         # personalized eval: convex logit interpolation over users with
         # local state (reference convex_inference during run_testvalidate,
         # core/client.py:167-183)

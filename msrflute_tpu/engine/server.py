@@ -20,6 +20,7 @@ jitted :class:`~msrflute_tpu.engine.round.RoundEngine` program.  Feature map:
 
 from __future__ import annotations
 
+import functools
 import logging
 import os
 import time
@@ -1226,6 +1227,7 @@ class OptimizationServer:
             # async checkpoint saves so the resume anchor is not missing
             # rounds — best-effort, never masking the original abort
             try:
+                self._run_pending_tail(deferred=False)
                 self.ckpt.wait()
             except Exception:
                 pass
@@ -1651,6 +1653,10 @@ class OptimizationServer:
                     # the device executes, so the next prepare_chunk's
                     # page-in assembly is a staging-buffer copy
                     self.fleet_pager.prefetch_chunk(prefetched[1])
+            # the evaluation round before this chunk held its durable
+            # tail back: the device has its next program now, and the
+            # wait for the writer falls beside it
+            self._run_pending_tail(deferred=True)
             round_no += R
 
             while len(pending) >= self.pipeline_depth and pending:
@@ -1676,6 +1682,9 @@ class OptimizationServer:
                                       rec_freq)
                     self.pipelined_chunks += 1
                 self._drain_chunk(chunk, val_freq, rec_freq)
+        # no next chunk (the last one, a preemption, max_iteration): the
+        # held-back tail runs now, and train() returns with it durable
+        self._run_pending_tail(deferred=False)
         while pending:
             # preemption landed with chunks in flight: the device work is
             # already done, so drain the ring in dispatch order — each
@@ -2037,7 +2046,8 @@ class OptimizationServer:
             self._run_server_replay()
         self._round_housekeeping(round0 + R, val_freq, rec_freq,
                                  skip_latest=chunk["latest_saved"],
-                                 rng_snapshot=chunk.get("rng_snapshot"))
+                                 rng_snapshot=chunk.get("rng_snapshot"),
+                                 chunk=round0)
 
     # ------------------------------------------------------------------
     # flutescope device-truth (telemetry/xla.py): the host-tail half.
@@ -2493,27 +2503,31 @@ class OptimizationServer:
     def _round_housekeeping(self, round_no: int, val_freq: int,
                             rec_freq: int,
                             skip_latest: bool = False,
-                            rng_snapshot: Optional[Dict[str, Any]] = None
-                            ) -> None:
+                            rng_snapshot: Optional[Dict[str, Any]] = None,
+                            chunk: Optional[int] = None) -> None:
         """Eval cadence, LR plateau decay, fallback, checkpoint, status log
         (reference ``core/server.py:448-490``).  ``skip_latest``: the
         pipelined loop already submitted this round's ``latest`` save
         before the next dispatch donated the state buffers.
         ``rng_snapshot``: the resume anchor captured at dispatch time when
         lookahead packing overlaps (see ``_rng_snapshot``); None means
-        "capture now" (plain serial loop, host-orchestrated rounds)."""
+        "capture now" (plain serial loop, host-orchestrated rounds).
+        ``chunk``: the drained chunk's id, from the chunk loop, which
+        runs a held-back durable tail after its next launch
+        (:meth:`_run_pending_tail`); None (host-orchestrated rounds)
+        runs the tail at once."""
         with self._tspan("housekeeping", round=round_no):
             self._round_housekeeping_inner(round_no, val_freq, rec_freq,
-                                           skip_latest, rng_snapshot)
+                                           skip_latest, rng_snapshot, chunk)
 
     def _round_housekeeping_inner(self, round_no: int, val_freq: int,
                                   rec_freq: int, skip_latest: bool,
-                                  rng_snapshot: Optional[Dict[str, Any]]
-                                  ) -> None:
+                                  rng_snapshot: Optional[Dict[str, Any]],
+                                  chunk: Optional[int]) -> None:
         housekeeping_tic = time.time()
         improved = False
         if round_no % val_freq == 0:
-            improved = self._maybe_eval("val", round_no)
+            improved = self._maybe_eval("val", round_no, hold_best=True)
             # client-LR decay on val plateau (core/server.py:464-469)
             if not improved and self.lr_decay_factor != 1.0:
                 self.lr_weight *= float(self.lr_decay_factor)
@@ -2529,6 +2543,12 @@ class OptimizationServer:
                 self._fall_back()
         if round_no % rec_freq == 0 and self.test_dataset is not None:
             self._maybe_eval("test", round_no)
+        # only now, and held until the tail waits for it: the writer
+        # asks for the whole snapshot's transfers at once, and whatever
+        # is queued behind them waits for all 1.9 GB (on the chip: the
+        # test evaluation's fetch, 0.25 -> 0.8 s, or the next dispatch's
+        # inputs, 0.4 s of an idle device)
+        saved_state, best_file = self._save_bettered(hold=True)
 
         status_update = {
             "i": round_no,
@@ -2548,26 +2568,69 @@ class OptimizationServer:
             status_update["plateau"] = {
                 "lr": self.plateau.lr, "best": self.plateau.best,
                 "bad_rounds": self.plateau.bad_rounds}
+        self._status_ring.append([int(round_no), dict(status_update)])
+        del self._status_ring[:-16]
+        status_update["status_ring"] = list(self._status_ring)
+        # the state this round's evaluation gave out as the best model
+        # (no fall-back replaced it since): the same bytes, so `latest`
+        # is a link to that file
+        link = best_file if saved_state is self.state and \
+            not skip_latest else None
+        tail = functools.partial(self._durable_tail, round_no,
+                                 status_update, link, skip_latest, chunk)
+        if chunk is not None and link is not None and \
+                self.ckpt.async_latest:
+            # the file is still with the writer, and nothing the tail
+            # writes needs this state's buffers (`latest` is a link): the
+            # chunk loop launches the next dispatch first, and the wait
+            # for the disk falls where the host waits for the device
+            self._pending_tail = tail
+        else:
+            tail(deferred=False)
+        # one buffered-metrics flush per chunk instead of one per metric
+        # line — the jsonl stream stays observable at round granularity
+        # while the host tail stops paying a syscall per scalar
+        flush_metrics()
+        if self.scope is not None:
+            # keep the on-disk trace fresh for long runs (throttled:
+            # the rewrite is O(events), paid at most every
+            # Tracer.FLUSH_INTERVAL_SECS)
+            self.scope.flush_throttled()
+            # endurance rollups flush on the same cadence: at most one
+            # appended record per rollup_window rounds, then the window
+            # state resets — host memory stays O(window) for any run
+            # length (ISSUE 13)
+            self.scope.rollup_housekeeping()
+        self.run_stats["secsPerRoundHousekeeping"].append(
+            time.time() - housekeeping_tic)
+
+    def _run_pending_tail(self, deferred: bool) -> None:
+        """Run the durable tail that the last evaluation round held back,
+        if it did; ``deferred``: the next dispatch has been launched."""
+        tail, self._pending_tail = self._pending_tail, None
+        if tail is not None:
+            tail(deferred=deferred)
+
+    def _durable_tail(self, round_no: int, status_update: Dict[str, Any],
+                      link: Optional[str], skip_latest: bool,
+                      chunk: Optional[int], deferred: bool) -> None:
+        """Everything that writes a NAME for round ``round_no``'s state,
+        on the training thread and in the one order a hard kill may cut
+        anywhere: the best-model file and its sidecar (the writer's,
+        waited for here) -> the status log -> ``latest`` -> backups ->
+        the paired stores' markers.  ``link``: the best-model file that
+        this round's ``latest`` is a link to."""
+        self.ckpt.land_best()
         # the status write leads the round's durable sequence (status ->
         # rows/marker -> checkpoint), and the ring keeps one snapshot
         # per recent round: whatever slot a crash leaves loadable, the
         # anchors for exactly that round are already durable
         # (flutearmor crash-point contract — _paired_status)
-        self._status_ring.append([int(round_no), dict(status_update)])
-        del self._status_ring[:-16]
-        status_update["status_ring"] = self._status_ring
         self.ckpt.update_status(status_update)
-
-        with self._tspan("ckpt_submit", round=round_no):
-            saved_state, best_file = self._best_file_of
-            self._best_file_of = (None, None)
+        with self._tspan("ckpt_submit", round=round_no, deferred=deferred,
+                         **({} if chunk is None else {"chunk": chunk})):
             if not skip_latest:
-                # the state this round's evaluation has just written as
-                # the best model (no fall-back replaced it since): the
-                # same bytes, so `latest` is a link to that file
-                self.ckpt.save_latest(
-                    self.state, same_as=best_file
-                    if saved_state is self.state else None)
+                self.ckpt.save_latest(self.state, same_as=link)
             self.ckpt.backup(self.state, round_no,
                              best_names=tuple(self.best_val))
         if self.scaffold_store is not None:
@@ -2647,22 +2710,6 @@ class OptimizationServer:
                 # below round_no - 1 become garbage (the - 1 keeps the
                 # generation a corruption fallback to .prev would need)
                 self.fleet_pager.mark_durable(int(round_no) - 1)
-        # one buffered-metrics flush per chunk instead of one per metric
-        # line — the jsonl stream stays observable at round granularity
-        # while the host tail stops paying a syscall per scalar
-        flush_metrics()
-        if self.scope is not None:
-            # keep the on-disk trace fresh for long runs (throttled:
-            # the rewrite is O(events), paid at most every
-            # Tracer.FLUSH_INTERVAL_SECS)
-            self.scope.flush_throttled()
-            # endurance rollups flush on the same cadence: at most one
-            # appended record per rollup_window rounds, then the window
-            # state resets — host memory stays O(window) for any run
-            # length (ISSUE 13)
-            self.scope.rollup_housekeeping()
-        self.run_stats["secsPerRoundHousekeeping"].append(
-            time.time() - housekeeping_tic)
 
     # ------------------------------------------------------------------
     def _val_acc(self) -> float:
@@ -2972,8 +3019,11 @@ class OptimizationServer:
 
     # ------------------------------------------------------------------
     _last_val: MetricsDict = {}
-    #: (state, file) of the best-model save of the evaluation in progress
-    _best_file_of: Tuple[Any, Optional[str]] = (None, None)
+    #: (state, metric names) a validation found better, until saved
+    _bettered: Tuple[Any, Tuple[str, ...]] = (None, ())
+    #: an evaluation round's durable tail, held back until the chunk loop
+    #: has launched the next dispatch (:meth:`_run_pending_tail`)
+    _pending_tail = None
 
     def _split_cfg(self, split: str):
         dc = self.config.server_config.data_config
@@ -3009,7 +3059,10 @@ class OptimizationServer:
             self._eval_batches_cache[split] = batches
         return batches
 
-    def _maybe_eval(self, split: str, round_no: int, force: bool = False) -> bool:
+    def _maybe_eval(self, split: str, round_no: int, force: bool = False,
+                    hold_best: bool = False) -> bool:
+        """``hold_best``: a validation that improved leaves its
+        best-model save to the caller's :meth:`_save_bettered`."""
         dataset = self.val_dataset if split == "val" else self.test_dataset
         if dataset is None or len(dataset) == 0:
             return False
@@ -3051,12 +3104,12 @@ class OptimizationServer:
                     if name == self.best_model_criterion:
                         improved = True
             if bettered:
-                # one file per metric, one fetch and one write for all;
-                # durable before the status log names the new best_val.
-                # This round's `latest`, if it is of this very state,
-                # becomes a link to that file (_round_housekeeping_inner)
-                self._best_file_of = (
-                    self.state, self.ckpt.save_best(self.state, *bettered))
+                # one file per metric, one fetch and one write for all,
+                # on the disk before the status log names the new
+                # best_val (_durable_tail waits for it)
+                self._bettered = (self.state, tuple(bettered))
+                if not hold_best:
+                    self._save_bettered()
             # convergence-tier crossing (traffic.target_accuracy): the
             # FIRST val eval at/above the target pins the round — the
             # rounds_to_target_accuracy bench.py records and `scope
@@ -3071,6 +3124,16 @@ class OptimizationServer:
                                round=round_no, acc=float(acc.value),
                                target=self.target_accuracy)
         return improved
+
+    def _save_bettered(self, hold: bool = False
+                       ) -> Tuple[Any, Optional[str]]:
+        """The best-model save of the state the last validation found
+        better, if it has not gone out yet: (that state, its file).
+        ``hold``: the round's durable tail follows (``save_best``)."""
+        state, names = self._bettered
+        self._bettered = (None, ())
+        return state, (self.ckpt.save_best(state, *names, hold=hold)
+                       if names else None)
 
     def _log_per_user_stats(self, split: str, round_no: int,
                             dataset) -> None:

@@ -368,12 +368,13 @@ def test_a_retried_streamed_write_lands_whole(tmp_path):
 def test_the_latest_of_a_state_just_saved_as_best_is_a_link(tmp_path,
                                                             async_latest):
     """An evaluation round saves its state twice, as the best model and
-    as ``latest``.  The best-model save is durable on return, with the
-    async writer too (the status log names the new best value next);
-    told that the ``latest`` is of the very same state (``same_as``),
-    the manager makes it a link to that file, durable on return (no
-    second fetch, no second 1.9 GB through the disk), rotation and
-    sidecars as ever; without that word a state is written whole."""
+    as ``latest``.  The best-model file is on the disk once
+    ``land_best`` returns (at once without the writer; the status log
+    names the new best value after that call); told that the ``latest``
+    is of the very same state (``same_as``), the manager makes it a link
+    to that file, durable on return (no second fetch, no second 1.9 GB
+    through the disk), rotation and sidecars as ever; without that word
+    a state is written whole."""
     import jax.numpy as jnp
 
     from msrflute_tpu.engine import checkpoint as ckpt_mod
@@ -394,8 +395,8 @@ def test_the_latest_of_a_state_just_saved_as_best_is_a_link(tmp_path,
         cm.save_latest(device_state(1))
         state = device_state(2)
         written = cm.save_best(state, "loss", "acc")
-        # no wait: the best model is on the disk, whole, with its sidecar
-        assert written == str(best)
+        assert written == str(best) and cm.land_best()
+        # the best model is on the disk, whole, with its sidecar
         meta = json.load(open(str(best) + ".sum"))
         assert meta["crc32"] == blob_checksum(open(best, "rb").read())
         assert cm.save_latest(state, same_as=written) is None
@@ -420,9 +421,9 @@ def test_the_latest_of_a_state_just_saved_as_best_is_a_link(tmp_path,
 
 
 def test_links_and_whole_saves_interleaved_under_the_async_writer(tmp_path):
-    """The training thread and the writer meet on files: best-model
-    saves and the links to them are made on this thread, `latest` saves
-    of the rounds between on the writer's.  Many rounds without a wait
+    """The training thread and the writer meet on files: the links to
+    a best-model file are made on this thread, the file itself and the
+    `latest` saves of the rounds between on the writer's.  Many rounds without a wait
     between them, the interpreter switching threads every few
     instructions: every file that lands is whole and of the state it is
     named for."""

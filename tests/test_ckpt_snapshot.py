@@ -273,25 +273,58 @@ def test_writer_asks_for_the_transfer_only_once_the_snapshot_is_computed(
     assert ready_when_fetched == [True]
 
 
-def test_a_best_model_save_is_durable_on_return_beside_a_busy_writer(
-        tmp_path, monkeypatch):
-    """An evaluation round saves its state as the best model and then as
-    ``latest``.  The best-model save runs on the caller's thread, also
-    with the async writer, and is on the disk when it returns, while the
-    writer is still inside an earlier round's ``latest``: the status log
-    that names the new best value is written next.  The ``latest`` of
-    that state is a link to the file, made in its turn: it waits for the
-    earlier ``latest`` to land, then rotates it to ``.prev``."""
+class _Spans:
+    """The two calls the manager makes on its telemetry scope; keeps
+    what it was given."""
+
+    def __init__(self):
+        self.spans, self.events = [], []
+
+    def span(self, name, **args):
+        import contextlib
+
+        @contextlib.contextmanager
+        def record():
+            span = {"name": name, "thread": threading.current_thread().name,
+                    **args}
+            self.spans.append(span)
+            yield span
+
+        return record()
+
+    def event(self, name, **args):
+        self.events.append((name, args))
+
+    def named(self, name):
+        return [s for s in self.spans if s["name"] == name]
+
+
+def _gate_best_writes(monkeypatch):
+    """The writer stops at the door of every best-model file until the
+    gate opens; says when it got there."""
     gate, entered = threading.Event(), threading.Event()
     real_write = CheckpointManager._write_blob
 
     def gated_write(self, path, blob, keep_prev=False):
-        if threading.current_thread().name == "ckpt-latest-writer":
+        if threading.current_thread().name == "ckpt-latest-writer" and \
+                "best_val_" in os.path.basename(path):
             entered.set()
             assert gate.wait(timeout=60), "test gate never opened"
         return real_write(self, path, blob, keep_prev=keep_prev)
 
     monkeypatch.setattr(CheckpointManager, "_write_blob", gated_write)
+    return gate, entered
+
+
+def test_a_best_model_save_rides_the_writer_and_lands_before_any_name(
+        tmp_path, monkeypatch):
+    """With the async writer a best-model save is a device snapshot in
+    the writer's slot: ``save_best`` returns at once, with nothing on
+    the disk.  Whoever writes a name for that state calls ``land_best``
+    first, which waits for the file (a ``ckpt_wait`` span that says it
+    waited for the best model) and links the other metric names to it;
+    the ``latest`` of that state is then a link to the file."""
+    gate, entered = _gate_best_writes(monkeypatch)
     programs = []
     real_program = ckpt_mod._copy_device_leaves
     monkeypatch.setattr(
@@ -300,29 +333,319 @@ def test_a_best_model_save_is_durable_on_return_beside_a_busy_writer(
 
     mgr = CheckpointManager(str(tmp_path), backend="msgpack",
                             async_latest=True)
+    mgr.telemetry = spans = _Spans()
     mgr.save_latest(_state(3))
-    assert entered.wait(timeout=60), "the writer never started the save"
     state = _state(4, scale=2.0)
     written = mgr.save_best(state, "loss", "acc")
-    template = _state(0, scale=0.0)
-    for name in ("loss", "acc"):  # durable now, the writer still busy
+    assert written == os.path.join(str(tmp_path),
+                                   "best_val_loss_model.msgpack")
+    assert len(programs) == 2  # the latest's snapshot, then the best's
+    assert entered.wait(timeout=60), "the writer never reached the file"
+    assert not os.path.exists(written)
+
+    landed = []
+    waiter = threading.Thread(target=lambda: landed.append(mgr.land_best()),
+                              name="land-best")
+    waiter.start()
+    waiter.join(timeout=0.5)
+    assert waiter.is_alive() and not landed, \
+        "land_best returned while the file was still with the writer"
+    gate.set()
+    waiter.join(timeout=60)
+    assert not waiter.is_alive() and landed == [True]
+    for name in ("loss", "acc"):
+        assert os.path.samefile(written, os.path.join(
+            str(tmp_path), f"best_val_{name}_model.msgpack"))
         assert os.path.exists(os.path.join(
             str(tmp_path), f"best_val_{name}_model.msgpack.sum"))
-    assert len(programs) == 1  # no snapshot program for the best model
+    waits = [s for s in spans.named("ckpt_wait") if s["thread"] == "land-best"]
+    assert [s["for"] for s in waits] == ["best"]
+    writes = spans.named("ckpt_async_write")
+    assert [s["file"] for s in writes] == [ckpt_mod.LATEST,
+                                          "best_val_loss_model.msgpack"]
+    assert all(s["bytes"] > 0 for s in writes)
 
-    linked = threading.Event()
-    link = threading.Thread(
-        target=lambda: (mgr.save_latest(state, same_as=written),
-                        linked.set()), name="link-latest")
-    link.start()
-    assert not linked.wait(timeout=0.5), \
-        "the link did not wait for the earlier latest to land"
-    gate.set()
-    assert linked.wait(timeout=60)
-    link.join(timeout=60)
-    assert not link.is_alive()
-    assert len(programs) == 1
+    mgr.save_latest(state, same_as=written)
+    assert len(programs) == 2
+    assert os.path.samefile(written, os.path.join(str(tmp_path),
+                                                  ckpt_mod.LATEST))
+    template = _state(0, scale=0.0)
     for name in ("loss", "acc"):
         assert mgr.load_best(template, name).round == 4
     assert mgr.load(template).round == 4
     assert mgr.load(template, ckpt_mod.LATEST_PREV).round == 3
+
+
+@pytest.mark.parametrize("reader", ["load_best", "backup", "wait"])
+def test_whoever_reads_the_files_sees_the_submitted_best_landed(
+        tmp_path, monkeypatch, reader):
+    """``fall_back_to_best`` (``load_best``), a backup copy and the exit
+    wait, each right after a best-model save was handed to a slow
+    writer: every one of them finds the new file, whole, links made."""
+    import time
+    real_write = CheckpointManager._write_blob
+
+    def slow_write(self, path, blob, keep_prev=False):
+        if threading.current_thread().name == "ckpt-latest-writer":
+            time.sleep(0.3)
+        return real_write(self, path, blob, keep_prev=keep_prev)
+
+    monkeypatch.setattr(CheckpointManager, "_write_blob", slow_write)
+    mgr = CheckpointManager(str(tmp_path), backend="msgpack",
+                            async_latest=True, backup_freq=2)
+    mgr.save_best(_state(2), "loss", "acc")
+    mgr.land_best()
+    mgr.save_best(_state(4, scale=2.0), "loss", "acc")
+    template = _state(0, scale=0.0)
+    if reader == "load_best":
+        assert mgr.load_best(template, "acc").round == 4
+    elif reader == "backup":
+        mgr.backup(_state(4, scale=2.0), 4, best_names=("loss", "acc"))
+        copy = mgr.load(template, "best_val_acc_model_epoch4.msgpack")
+        assert copy is not None and copy.round == 4
+    else:
+        mgr.wait()
+        assert not mgr._mp_busy and mgr._best_pending is None
+    for name in ("loss", "acc"):
+        path = os.path.join(str(tmp_path), f"best_val_{name}_model.msgpack")
+        assert ckpt_mod._state_from_bytes(
+            open(path, "rb").read(), template).round == 4
+
+
+@pytest.mark.parametrize("threshold", [1, 3], ids=["abort", "counted"])
+def test_a_failed_best_model_write_surfaces_on_the_calling_thread(
+        tmp_path, threshold):
+    """The writer never raises: a best-model write that failed all its
+    attempts is counted, ``land_best`` says so on the training thread
+    (and aborts there once the run's budget of failures is spent), and
+    nothing is linked to a file that is not there."""
+    from msrflute_tpu.resilience.integrity import (CheckpointEscalationError,
+                                                   RetryPolicy)
+
+    def always():
+        raise OSError("injected")
+
+    mgr = CheckpointManager(
+        str(tmp_path), backend="msgpack", async_latest=True, io_fault=always,
+        retry=RetryPolicy(retries=2, backoff_base_s=0.0, backoff_max_s=0.0,
+                          jitter=0.0, escalation_threshold=threshold))
+    state = _state(5)
+    written = mgr.save_best(state, "loss", "acc")
+    if threshold == 1:
+        with pytest.raises(CheckpointEscalationError):
+            mgr.land_best()
+    else:
+        assert mgr.land_best() is False
+        assert mgr.escalator.consecutive == 1
+        mgr.save_latest(state, same_as=written)
+    assert not any(n.endswith(".msgpack") for n in os.listdir(str(tmp_path)))
+
+
+# ----------------------------------------------------------------------
+# the round loop: the status log waits for the writer, the training
+# thread does not wait for the disk
+# ----------------------------------------------------------------------
+def _server(tmp_path, dataset, mesh, depth, **server_over):
+    from msrflute_tpu.config import FLUTEConfig
+    from msrflute_tpu.engine import OptimizationServer
+    from msrflute_tpu.models import make_task
+    cfg = FLUTEConfig.from_dict({
+        "model_config": {"model_type": "LR", "num_classes": 4,
+                         "input_dim": 8},
+        "strategy": "fedavg",
+        "server_config": {
+            "max_iteration": 6, "num_clients_per_iteration": 4,
+            "initial_lr_client": 0.5, "pipeline_depth": depth,
+            "checkpoint_async": True, "rounds_per_step": 2,
+            "optimizer_config": {"type": "sgd", "lr": 1.0},
+            "val_freq": 2, "rec_freq": 2, "initial_val": True,
+            "telemetry": {"enable": True},
+            "checkpoint_retry": {"retries": 2, "backoff_base_s": 0.0,
+                                 "jitter": 0.0},
+            "data_config": {"val": {"batch_size": 8},
+                            "test": {"batch_size": 8}},
+            **server_over},
+        "client_config": {"optimizer_config": {"type": "sgd", "lr": 0.5},
+                          "data_config": {"train": {"batch_size": 4}}}})
+    server = OptimizationServer(
+        make_task(cfg.model_config), cfg, dataset, val_dataset=dataset,
+        test_dataset=dataset, model_dir=str(tmp_path), mesh=mesh, seed=7)
+    assert server.ckpt.async_latest
+    return server
+
+
+def _status(tmp_path):
+    import json
+    path = os.path.join(str(tmp_path), ckpt_mod.STATUS_LOG)
+    if not os.path.exists(path):
+        return {}
+    with open(path) as fh:
+        return json.load(fh)
+
+
+def _span_records(tmp_path, name):
+    import json
+    path = os.path.join(str(tmp_path), "telemetry", "events.jsonl")
+    with open(path) as fh:
+        records = [json.loads(line) for line in fh if line.strip()]
+    return [r for r in records
+            if r.get("kind") == "span" and r.get("name") == name]
+
+
+@pytest.mark.parametrize("depth", [0, 1], ids=["serial", "ring"])
+def test_the_status_log_waits_for_the_writer_behind_the_next_dispatch(
+        tmp_path, monkeypatch, synth_dataset, mesh8, depth):
+    """Every dispatch ends at an evaluation that improves (a young run).
+    While the best-model file of round 2 is still with the writer, the
+    next dispatch HAS been launched and ``status_log.json`` names neither
+    the round nor its ``best_val_*``; once the file lands the log names
+    both and ``latest`` is a link to the file."""
+    from msrflute_tpu.engine.round import RoundEngine
+    gate, entered = _gate_best_writes(monkeypatch)
+    gate.set()  # the initial evaluation's save goes through
+    server = _server(tmp_path, synth_dataset, mesh8, depth)
+    launches = []
+    dispatch = RoundEngine.dispatch_rounds
+
+    def counted(engine, state, *args, **kwargs):
+        out = dispatch(engine, state, *args, **kwargs)
+        launches.append(int(state.round))
+        if len(launches) == 1:
+            # from now on the writer stops at the door
+            entered.clear()
+            gate.clear()
+        return out
+
+    monkeypatch.setattr(RoundEngine, "dispatch_rounds", counted)
+    failed = []
+
+    def run():
+        try:
+            server.train()
+        except BaseException as exc:  # noqa: BLE001 - shown to the test
+            failed.append(exc)
+            raise
+
+    trainer = threading.Thread(target=run, name="MainThread-train")
+    trainer.start()
+    try:
+        assert entered.wait(timeout=120), "no best-model save reached " \
+            "the writer"
+        # round 2's file is at the door: wait for the NEXT launch
+        import time
+        deadline = time.time() + 120
+        while len(launches) < 2 and time.time() < deadline and \
+                trainer.is_alive():
+            time.sleep(0.01)
+        assert len(launches) == 2, \
+            "the next dispatch waited for the best-model file"
+        time.sleep(0.2)  # the tail is at the writer's door by now
+        status = _status(tmp_path)
+        assert status.get("i", 0) == 0 and not any(
+            r[0] == 2 for r in status.get("status_ring", []))
+        best = os.path.join(str(tmp_path), "best_val_loss_model.msgpack")
+        before = os.path.getmtime(best)  # the initial evaluation's
+    finally:
+        gate.set()
+        trainer.join(timeout=300)
+    assert not trainer.is_alive() and not failed
+    status = _status(tmp_path)
+    assert status["i"] == 6
+    assert os.path.getmtime(best) >= before
+    assert os.path.samefile(best, os.path.join(str(tmp_path),
+                                               ckpt_mod.LATEST))
+    assert status["best_val_loss"] == server.best_val["loss"].value
+    template = server.state
+    assert server.ckpt.load_best(template, "loss").round == 6
+    # the counter that says it engaged: every evaluation round but the
+    # last ran its tail behind the next launch
+    submits = {s["round"]: s["deferred"]
+               for s in _span_records(tmp_path, "ckpt_submit")}
+    assert submits == {2: True, 4: True, 6: False}
+    waits = _span_records(tmp_path, "ckpt_wait")
+    assert {"best"} <= {s["for"] for s in waits} <= {"best", "latest",
+                                                     "exit"}
+    writes = _span_records(tmp_path, "ckpt_async_write")
+    best = sorted((s for s in writes if s["file"].startswith("best_val_")),
+                  key=lambda s: s["ts"])
+    assert len(best) == 4 and all(s["bytes"] > 0 for s in best), \
+        "rounds 0, 2, 4, 6: one whole file each"
+    # the snapshot is handed over once the test evaluation has its
+    # numbers: its fetch would queue behind the snapshot's transfers
+    tests = sorted((s for s in _span_records(tmp_path, "eval")
+                    if s["split"] == "test"), key=lambda s: s["round"])
+    assert [s["round"] for s in tests] == [2, 4, 6]
+    for write, evaluation in zip(best[1:], tests):
+        assert write["ts"] >= evaluation["ts"] + evaluation["dur_s"] - 1e-3
+
+
+def test_a_failed_best_model_write_aborts_before_the_log_names_it(
+        tmp_path, synth_dataset, mesh8):
+    """The escalator's abort for a write the writer lost is raised on
+    the training thread, in the tail's wait: the status log never names
+    the value whose file is not there."""
+    from msrflute_tpu.resilience.integrity import CheckpointEscalationError
+    server = _server(tmp_path, synth_dataset, mesh8, 1,
+                     initial_val=False,
+                     checkpoint_retry={"retries": 1, "backoff_base_s": 0.0,
+                                       "jitter": 0.0,
+                                       "escalation_threshold": 1})
+
+    def always():
+        raise OSError("injected")
+
+    server.ckpt._io_fault = always
+    with pytest.raises(CheckpointEscalationError):
+        server.train()
+    status = _status(tmp_path)
+    assert not any(key.startswith("best_val_") for key in status)
+    assert not os.path.exists(os.path.join(
+        str(tmp_path), "best_val_loss_model.msgpack"))
+
+
+@pytest.mark.parametrize("depth", [0, 1], ids=["serial", "ring"])
+def test_a_preemption_during_an_in_flight_best_save_leaves_all_paired(
+        tmp_path, monkeypatch, synth_dataset, mesh8, depth):
+    """The scheduler's signal lands while round 2's best-model file is
+    with the writer: no further dispatch, the tail runs at once
+    (``deferred: false``), and ``train()`` returns resumable with file,
+    sidecar, status log and ``latest`` all of round 2."""
+    import json
+    import time
+
+    from msrflute_tpu.resilience.integrity import blob_checksum
+    server = _server(tmp_path, synth_dataset, mesh8, depth)
+    real_write = CheckpointManager._write_blob
+
+    def slow_write(self, path, blob, keep_prev=False):
+        if threading.current_thread().name == "ckpt-latest-writer":
+            time.sleep(0.3)  # the loop meets the request, the file not yet
+        return real_write(self, path, blob, keep_prev=keep_prev)
+
+    monkeypatch.setattr(CheckpointManager, "_write_blob", slow_write)
+    save_best = server.ckpt.save_best
+
+    def signalled_save(state, *names, **how):
+        out = save_best(state, *names, **how)
+        if int(state.round) == 2:
+            assert server.ckpt._mp_busy or \
+                server.ckpt._mp_mailbox is not None
+            server.preemption.request("drill")
+        return out
+
+    server.ckpt.save_best = signalled_save
+    state = server.train()
+    assert server.preempted and state.round == 2
+    status = _status(tmp_path)
+    assert status["i"] == 2 and status["preempted"] == "drill"
+    best = os.path.join(str(tmp_path), "best_val_loss_model.msgpack")
+    latest = os.path.join(str(tmp_path), ckpt_mod.LATEST)
+    assert os.path.samefile(best, latest)
+    with open(best + ".sum") as fh:
+        meta = json.load(fh)
+    with open(best, "rb") as fh:
+        assert meta["crc32"] == blob_checksum(fh.read())
+    assert status["best_val_loss"] == server.best_val["loss"].value
+    assert server.ckpt.load(state).round == 2
+    assert [s["deferred"] for s in _span_records(tmp_path, "ckpt_submit")
+            if s["round"] == 2] == [False]

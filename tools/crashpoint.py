@@ -22,8 +22,21 @@ the uninterrupted baseline bit for bit.  ``--phase post`` kills right
 AFTER each commit instead (death with the commit landed but every
 in-memory postcondition lost).  Both serial and depth-3 pipelined loops
 are fuzzed; checkpointing is forced synchronous so every durable op
-happens on the training thread (the async writer's op ordering is
-documented as not resume-reproducible).
+happens on the training thread.  ``--writer`` fuzzes the other half:
+the async msgpack writer with an evaluation every second round, where a
+best-model file is the WRITER's commit and the round's durable tail
+(status log, ``latest`` link) follows it on the training thread behind
+the next dispatch.  A kill in either thread is the process's death: from
+that op on, no commit of any thread lands.  The writer's commits fall
+between the training thread's in an order that one run does not owe the
+next, so that matrix names its kill points (``replace:status_log.json``,
+second occurrence) instead of numbering them.  Found with it, and left
+standing (ROADMAP debt *presubmit-before-status*): the pipelined loop
+submits a ring chunk's ``latest`` BEFORE that chunk's status entry is
+written, so at ``--depths 3`` a kill between the two (this census: from
+the first ``replace:latest_model.msgpack`` to the first
+``replace:status_log.json``) resumes a round that the status ring does
+not know, soundly but not bit for bit; the serial matrix is clean.
 
 Run: ``python tools/crashpoint.py`` (CPU, ~minutes for the full
 matrix); ``tests/test_crashpoint.py`` drives :func:`fuzz` on a small
@@ -37,6 +50,7 @@ import argparse
 import json
 import os
 import sys
+import threading
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO_ROOT)
@@ -57,14 +71,19 @@ class KillSwitch:
 
     ``arm(dir, kill_at=None)`` counts ops (census mode); with
     ``kill_at=k`` it raises :class:`CrashPoint` at op k — before the
-    commit in phase ``pre``, after it in phase ``post``."""
+    commit in phase ``pre``, after it in phase ``post``.  ``kill_at``
+    may also name the op: ``("replace:status_log.json", 1)`` is the
+    second rename onto that file.  Once it has fired the process is
+    dead: every later op in scope, on any thread, raises too."""
 
     def __init__(self) -> None:
         self._orig = {name: getattr(os, name) for name in DURABLE_OPS}
+        self._lock = threading.Lock()
         self.scope_dir: str | None = None
-        self.kill_at: int | None = None
+        self.kill_at: int | tuple | None = None
         self.phase = "pre"
         self.count = 0
+        self.dead = False
         self.log: list = []
 
     def install(self) -> None:
@@ -75,12 +94,13 @@ class KillSwitch:
         for name, orig in self._orig.items():
             setattr(os, name, orig)
 
-    def arm(self, scope_dir: str, kill_at: int | None = None,
+    def arm(self, scope_dir: str, kill_at: int | tuple | None = None,
             phase: str = "pre") -> None:
         self.scope_dir = os.path.abspath(scope_dir)
         self.kill_at = kill_at
         self.phase = phase
         self.count = 0
+        self.dead = False
         self.log = []
 
     def disarm(self) -> None:
@@ -96,33 +116,45 @@ class KillSwitch:
                         os.path.abspath(str(dst)).startswith(scope))
             if not in_scope:
                 return orig(src, dst, *args, **kwargs)
-            k = self.count
-            self.count += 1
-            self.log.append(
-                (name, os.path.relpath(os.path.abspath(str(dst)), scope)))
-            if self.kill_at == k and self.phase == "pre":
-                raise CrashPoint(
-                    f"killed BEFORE durable op #{k}: {name} -> {dst}")
-            out = orig(src, dst, *args, **kwargs)
-            if self.kill_at == k and self.phase == "post":
-                raise CrashPoint(
-                    f"killed AFTER durable op #{k}: {name} -> {dst}")
+            rel = os.path.relpath(os.path.abspath(str(dst)), scope)
+            with self._lock:  # the writer thread commits too
+                if self.dead:
+                    raise CrashPoint(f"dead before {name} -> {dst}")
+                k = self.count
+                self.count += 1
+                nth = sum(entry == (name, rel) for entry in self.log)
+                self.log.append((name, rel))
+                mine = self.kill_at in (k, (f"{name}:{rel}", nth))
+                if mine and self.phase == "pre":
+                    self.dead = True
+                    raise CrashPoint(
+                        f"killed BEFORE durable op #{k}: {name} -> {dst}")
+                out = orig(src, dst, *args, **kwargs)
+                if mine and self.phase == "post":
+                    self.dead = True
+                    raise CrashPoint(
+                        f"killed AFTER durable op #{k}: {name} -> {dst}")
             return out
         return wrapped
 
 
-def _config(depth: int, rounds: int, resume: bool = False):
+def _config(depth: int, rounds: int, resume: bool = False,
+            writer: bool = False):
     from msrflute_tpu.config import FLUTEConfig
-    return FLUTEConfig.from_dict({
-        "model_config": {"model_type": "LR", "num_classes": 4,
-                         "input_dim": 8},
-        "strategy": "scaffold",  # fused_carry paged carry: the row-store
-        "server_config": {       # spill + marker sequences are in play
-            "max_iteration": rounds, "num_clients_per_iteration": 4,
-            "initial_lr_client": 0.2, "pipeline_depth": depth,
-            "fused_carry": True, "rounds_per_step": 1,
-            "val_freq": 10_000, "initial_val": False,
-            "optimizer_config": {"type": "sgd", "lr": 1.0},
+    if writer:
+        # the async writer's half: FedAvg, an evaluation every second
+        # round (young run: each improves, so each hands a best-model
+        # snapshot to the writer and holds its durable tail back), the
+        # rounds between going out as the writer's `latest`
+        strategy, mode = "fedavg", {
+            "val_freq": 2, "initial_val": True,
+            "data_config": {"val": {"batch_size": 8}},
+            "checkpoint_async": True}
+    else:
+        # fused_carry paged carry: the row-store spill + marker
+        # sequences are in play
+        strategy, mode = "scaffold", {
+            "fused_carry": True, "val_freq": 10_000, "initial_val": False,
             "data_config": {},
             # a tiny host cache forces spill-through, so the .npz +
             # marker pairing is part of every fuzzed sequence
@@ -130,15 +162,30 @@ def _config(depth: int, rounds: int, resume: bool = False):
                       "spill_freq": 1},
             # synchronous checkpoints: every durable op on the training
             # thread, op order deterministic (the fuzz precondition)
-            "checkpoint_async": False,
+            "checkpoint_async": False}
+    return FLUTEConfig.from_dict({
+        "model_config": {"model_type": "LR", "num_classes": 4,
+                         "input_dim": 8},
+        "strategy": strategy,
+        "server_config": {
+            "max_iteration": rounds, "num_clients_per_iteration": 4,
+            "initial_lr_client": 0.2, "pipeline_depth": depth,
+            "rounds_per_step": 1,
+            "optimizer_config": {"type": "sgd", "lr": 1.0},
             "checkpoint_retry": {"retries": 2, "backoff_base_s": 0.0,
                                  "jitter": 0.0},
+            **mode,
             **({"resume_from_checkpoint": True} if resume else {}),
         },
         "client_config": {
             "optimizer_config": {"type": "sgd", "lr": 0.2},
             "data_config": {"train": {"batch_size": 4}}},
     })
+
+
+def _point_order(point):
+    """Numbered kill points in their order, named ones after them."""
+    return (isinstance(point, tuple), point)
 
 
 def _dataset():
@@ -155,18 +202,23 @@ def _run(cfg, model_dir: str, dataset):
     from msrflute_tpu.engine import OptimizationServer
     from msrflute_tpu.models import make_task
 
-    server = OptimizationServer(make_task(cfg.model_config), cfg, dataset,
-                                model_dir=model_dir, seed=7)
+    server = OptimizationServer(
+        make_task(cfg.model_config), cfg, dataset, model_dir=model_dir,
+        val_dataset=(dataset if cfg.server_config.get("checkpoint_async")
+                     else None), seed=7)
     state = server.train()
     return np.asarray(ravel_pytree(jax.device_get(state.params))[0])
 
 
 def fuzz(depth: int = 0, rounds: int = 3, phase: str = "pre",
          kill_points=None, stride: int = 1, workdir: str | None = None,
-         verbose: bool = True) -> dict:
+         verbose: bool = True, writer: bool = False) -> dict:
     """Run the kill matrix for one loop mode; returns the record
     (census size, points fuzzed, per-point ops).  AssertionError on the
-    first kill point whose resumed run is not bit-identical."""
+    first kill point whose resumed run is not bit-identical.  A kill
+    point is an index into the census or ``("op:file", nth)``;
+    ``writer``: the async writer's matrix (see the module docstring),
+    every distinct named commit of its census by default."""
     import numpy as np
 
     from msrflute_tpu.utils.backend import force_cpu_backend
@@ -176,8 +228,11 @@ def fuzz(depth: int = 0, rounds: int = 3, phase: str = "pre",
     workdir = workdir or tempfile.mkdtemp(prefix="crashpoint_")
     dataset = _dataset()
 
-    baseline = _run(_config(depth, rounds),
-                    os.path.join(workdir, f"baseline_d{depth}"), dataset)
+    def config(resume=False):
+        return _config(depth, rounds, resume=resume, writer=writer)
+
+    baseline = _run(config(), os.path.join(workdir, f"baseline_d{depth}"),
+                    dataset)
 
     switch = KillSwitch()
     switch.install()
@@ -185,23 +240,35 @@ def fuzz(depth: int = 0, rounds: int = 3, phase: str = "pre",
         # census: how many durable commits does this loop mode perform?
         census_dir = os.path.join(workdir, f"census_d{depth}")
         switch.arm(census_dir)
-        _run(_config(depth, rounds), census_dir, dataset)
+        _run(config(), census_dir, dataset)
         n_ops = switch.count
         census = list(switch.log)
         switch.disarm()
 
-        points = sorted(set(kill_points)) if kill_points is not None \
-            else list(range(n_ops))
+        named = [f"{op}:{rel}" for op, rel in census]
+        if kill_points is not None:
+            points = sorted(set(kill_points), key=_point_order)
+        elif writer:
+            points = sorted({(name, named[:i].count(name))
+                             for i, name in enumerate(named)})
+        else:
+            points = list(range(n_ops))
         if stride > 1:
             # always keep the first and last commit; subsample between
-            points = sorted(set(points[::stride]) | {points[-1]})
-        for k in points:
-            assert 0 <= k < n_ops, f"kill point {k} outside census {n_ops}"
-            run_dir = os.path.join(workdir, f"d{depth}_{phase}_k{k:03d}")
+            points = sorted(set(points[::stride]) | {points[-1]},
+                            key=_point_order)
+        for i, k in enumerate(points):
+            if isinstance(k, int):
+                assert 0 <= k < n_ops, \
+                    f"kill point {k} outside census {n_ops}"
+            else:
+                assert named.count(k[0]) > k[1], \
+                    f"kill point {k} not in the census:\n" + "\n".join(named)
+            run_dir = os.path.join(workdir, f"d{depth}_{phase}_k{i:03d}")
             switch.arm(run_dir, kill_at=k, phase=phase)
             died = False
             try:
-                _run(_config(depth, rounds), run_dir, dataset)
+                _run(config(), run_dir, dataset)
             except CrashPoint as exc:
                 died = True
                 if verbose:
@@ -211,18 +278,17 @@ def fuzz(depth: int = 0, rounds: int = 3, phase: str = "pre",
             assert died, f"kill point {k} never fired (census drift?)"
             # the relaunch: resume must find a loadable tree (possibly
             # rolled back one anchor) and re-train to the same bits
-            flat = _run(_config(depth, rounds, resume=True), run_dir,
-                        dataset)
+            flat = _run(config(resume=True), run_dir, dataset)
             assert np.array_equal(baseline, flat), (
-                f"kill at durable op {k} ({census[k]}, phase {phase}, "
-                f"depth {depth}) resumed to DIFFERENT final params")
+                f"kill at durable op {k} (phase {phase}, depth {depth}) "
+                "resumed to DIFFERENT final params")
     finally:
         switch.uninstall()
 
     record = {
         "depth": depth, "rounds": rounds, "phase": phase,
         "durable_ops": n_ops, "points_fuzzed": len(points),
-        "census": [f"{op}:{rel}" for op, rel in census],
+        "census": named,
     }
     if verbose:
         print(f"[crashpoint] depth {depth} phase {phase}: "
@@ -240,6 +306,10 @@ def main(argv=None) -> int:
                     help="kill before the commit, after it, or both")
     ap.add_argument("--stride", type=int, default=1,
                     help="fuzz every stride-th kill point (1 = all)")
+    ap.add_argument("--writer", action="store_true",
+                    help="fuzz the async writer's matrix (best-model "
+                    "files through the writer, the durable tail behind "
+                    "the next dispatch) instead of the synchronous one")
     ap.add_argument("--report", default=None,
                     help="write the JSON record here")
     args = ap.parse_args(argv)
@@ -249,7 +319,8 @@ def main(argv=None) -> int:
     for depth in args.depths:
         for phase in phases:
             records.append(fuzz(depth=depth, rounds=args.rounds,
-                                phase=phase, stride=args.stride))
+                                phase=phase, stride=args.stride,
+                                writer=args.writer))
     out = {"kill_matrix": records}
     if args.report:
         with open(args.report, "w") as fh:
