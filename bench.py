@@ -114,6 +114,12 @@ def _record_failure(name: str, exc: BaseException) -> None:
 #: popped exactly once (atomic under the GIL, safe from signal handlers
 #: and threads alike) — whoever gets the token owns the one stdout line
 _FLUSH_TOKEN = [None]
+#: set once the line's owner has written and drained it.  A rescuer that
+#: lost the token waits for this before it may ``os._exit``: one SIGALRM
+#: reaches both the signal-watcher thread and the main thread's handler,
+#: and an exit between the winner's pop and its write left stdout empty
+_DELIVERED = threading.Event()
+_FLUSH_OWNER = None
 
 #: wall-clock of the last section boundary; the watchdog thread measures
 #: stall time against this
@@ -128,11 +134,16 @@ def _note_progress() -> None:
 def _flush(note: str | None = None) -> bool:
     """Emit the JSON contract line exactly once, whatever state we're in.
     Returns True iff THIS call owned (and delivered) the line."""
-    global _FLUSHED
+    global _FLUSHED, _FLUSH_OWNER
     try:
         _FLUSH_TOKEN.pop()
     except IndexError:
-        return False  # another thread/handler already owns the line
+        # another thread/handler already owns the line: let it finish,
+        # unless this is a handler on top of that very thread's flush
+        if _FLUSH_OWNER != threading.get_ident():
+            _DELIVERED.wait(5.0)
+        return False
+    _FLUSH_OWNER = threading.get_ident()
     _FLUSHED = True
     if note:
         _LINE["extras"]["flush_note"] = note
@@ -142,6 +153,7 @@ def _flush(note: str | None = None) -> bool:
         _LINE["vs_baseline"] = head.get("vs_baseline")
     sys.stdout.write(json.dumps(_LINE) + "\n")
     sys.stdout.flush()
+    _DELIVERED.set()
     # a fully-delivered line supersedes the on-disk partial mirror: a
     # stale one would read as evidence of an aborted run
     if not note:

@@ -64,3 +64,13 @@ def pytest_configure(config):
     config.addinivalue_line(
         "markers",
         "slow: heavyweight e2e/accuracy tests excluded from tier-1")
+    # under `--dist loadfile` xdist queues the files by their number of
+    # cases, most first, which says nothing of their time: the file that
+    # is most of tier-1's limit in one worker
+    # (tests/benchmarks/test_benchmark_harness.py, 9 cases) then starts
+    # minutes in, behind every file with more cases, and the whole run
+    # is its start plus its length.  Off, the queue is the collection
+    # order, in which that file is among the first.  The option exists
+    # only where xdist is loaded, hence the hasattr.
+    if hasattr(config.option, "loadscopereorder"):
+        config.option.loadscopereorder = False

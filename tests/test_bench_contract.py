@@ -166,6 +166,37 @@ def test_stalled_protocol_flushes_well_before_deadline():
     assert took < 120, f"stall budget not honored ({took:.0f}s)"
 
 
+def test_a_rescuer_that_lost_the_line_waits_for_its_owner(monkeypatch):
+    """One SIGALRM reaches the signal-watcher thread and the main thread's
+    handler; the one that loses the flush token goes on to ``os._exit``,
+    so it must not come back from ``_flush`` before the winner's line is
+    out (seen once in tier-1 as rc 0 and an empty stdout)."""
+    import io
+    import threading
+
+    import bench
+
+    class SlowOut(io.StringIO):
+        def write(self, text):
+            time.sleep(0.5)  # the window between the pop and the write
+            return super().write(text)
+
+    out = SlowOut()
+    monkeypatch.setattr(sys, "stdout", out)
+    monkeypatch.setattr(bench, "_FLUSH_TOKEN", [None])
+    monkeypatch.setattr(bench, "_FLUSHED", False)
+    monkeypatch.setattr(bench, "_FLUSH_OWNER", None)
+    monkeypatch.setattr(bench, "_DELIVERED", threading.Event())
+    monkeypatch.setitem(bench._LINE, "extras", {})
+    owner = threading.Thread(target=bench._flush, args=("the owner",))
+    owner.start()
+    time.sleep(0.1)
+    assert bench._flush("the loser") is False
+    line = out.getvalue()
+    owner.join()
+    assert json.loads(line)["extras"]["flush_note"] == "the owner"
+
+
 def test_wedged_native_call_rescued_by_watchdog_thread():
     """A hung native call: the main thread never re-enters the
     interpreter (simulated by blocking the signals on it), so main-thread

@@ -139,20 +139,28 @@ def test_assign_step_buckets_matches_brute_force_reference():
 
 
 def test_bucket_fns_at_million_entries_fast_and_sane():
-    rng = np.random.default_rng(0)
-    needs = rng.integers(1, 2**20, size=1_000_000)
-    # this process's CPU seconds, not the wall's: under six test workers
-    # on a loaded host the wall clock read 1.0-1.3 s for the same work.
-    # The bound still fails a pass that is no longer vectorised (a Python
-    # loop over 10^6 entries takes longer than this several times over)
-    tic = time.process_time()
-    bounds = bucket_boundaries(needs, max_buckets=4, max_steps=2**20)
-    caps = bucket_capacities(needs, bounds, cohort_size=1024, quantum=8)
-    assignment = assign_step_buckets(
-        rng.integers(1, 2**20, size=1_000_000), bounds,
-        capacities=caps)
-    elapsed = time.process_time() - tic
-    assert elapsed < 2.0, f"bucket pass took {elapsed:.2f} CPU-s at 10^6"
+    def bucket_pass(n):
+        rng = np.random.default_rng(0)
+        needs = rng.integers(1, 2**20, size=n)
+        others = rng.integers(1, 2**20, size=n)
+        tic = time.process_time()
+        bounds = bucket_boundaries(needs, max_buckets=4, max_steps=2**20)
+        caps = bucket_capacities(needs, bounds, cohort_size=1024, quantum=8)
+        assignment = assign_step_buckets(others, bounds, capacities=caps)
+        return time.process_time() - tic, needs, bounds, caps, assignment
+
+    # the subject is "not quadratic in the population", so the bound is a
+    # ratio against a tenth of the entries and not seconds: beside five
+    # other test workers this process's CPU seconds for the same pass read
+    # 0.4 to 3.8, which no limit in seconds holds.  A sort-bound pass reads
+    # 10-15 here, a quadratic one 100; the best of a few runs on each
+    # side, because a first call also pays for its fresh pages
+    small = min(bucket_pass(100_000)[0] for _ in range(3))
+    elapsed, needs, bounds, caps, assignment = min(
+        (bucket_pass(1_000_000) for _ in range(2)), key=lambda r: r[0])
+    assert elapsed < 40 * small, (
+        f"bucket pass took {elapsed:.2f} CPU-s at 10^6, "
+        f"{elapsed / small:.0f} x its {small:.3f} at 10^5")
     assert len(bounds) <= 4 and bounds == sorted(bounds)
     assert bounds[-1] >= int(needs.max())  # no silent truncation
     assert all(c % 8 == 0 for c in caps)  # mesh-quantized capacities
