@@ -74,6 +74,12 @@ class PackedStats:
     stacked: bool               #: True if ``vecs`` carry a leading [R] axis
     #: the engine's span factory when tracing is on (``stats_d2h``)
     span: Optional[Callable] = None
+    #: hands the host buffers this chunk's inputs were staged in back to
+    #: the engine, at the fence: the program's outputs being there is
+    #: the proof that it has read its inputs (``jax.device_put`` returns
+    #: before the transfer, and on the CPU backend the device array may
+    #: be the numpy memory itself)
+    release: Optional[Callable[[], None]] = None
 
     def is_ready(self) -> bool:
         """Whether the chunk's program has produced the stats: a
@@ -84,6 +90,12 @@ class PackedStats:
         """Block until the stats are there (the traced run's
         ``fence_wait``; :meth:`fetch` blocks by itself otherwise)."""
         jax.block_until_ready(self.vecs)
+        self._fenced()
+
+    def _fenced(self) -> None:
+        release, self.release = self.release, None
+        if release is not None:
+            release()
 
     def fetch(self) -> Dict[str, np.ndarray]:
         """Fetch + decode: ONE host transfer per dtype group (the honest
@@ -96,10 +108,54 @@ class PackedStats:
 
     def _fetch(self) -> Dict[str, np.ndarray]:
         host = jax.device_get(self.vecs)
+        self._fenced()
         if self.stacked:
             return self.packer.unpack_np_stacked(host)
         tree = self.packer.unpack_np(host)
         return {k: np.asarray(v)[None] for k, v in tree.items()}
+
+
+class StagingPool:
+    """The staged dispatch's host buffers, kept and written again.
+
+    A fresh ``[R, K, total]`` array a dispatch is mostly page faults: its
+    pages are first touched as they are filled and unmapped after the
+    launch (two 307 MB arrays a dispatch where five ResNet rounds are
+    fused).  Here a buffer is taken for a dispatch, handed back at the
+    fence of the chunk whose program read it (``PackedStats.release``),
+    and written again by a later dispatch of the same shapes.  With none
+    free, :meth:`take` allocates, as every dispatch did before:
+    correctness never depends on the pool.  It holds only the shapes of
+    the dispatch that took last (a shorter last chunk's or another
+    cohort padding's buffers are dropped), and of each at most ``keep``.
+    """
+
+    def __init__(self, keep: int):
+        self.keep = int(keep)
+        self._free: Dict[tuple, list] = {}
+
+    def take(self, shapes: Dict[str, Tuple[int, ...]]
+             ) -> Tuple[Dict[str, np.ndarray], bool]:
+        """``({dtype: buffer}, reused)`` for one dispatch: ``reused`` is
+        False when any of them had to be allocated."""
+        self._free = {(dt, shape): self._free.get((dt, shape), [])
+                      for dt, shape in shapes.items()}
+        bufs, reused = {}, True
+        for dt, shape in shapes.items():
+            free = self._free[dt, shape]
+            if free:
+                bufs[dt] = free.pop()
+            else:
+                bufs[dt] = np.empty(shape, jnp.dtype(dt))
+                reused = False
+        return bufs, reused
+
+    def give(self, bufs: Dict[str, np.ndarray]) -> None:
+        """Back from a fenced chunk; kept if a dispatch may want it."""
+        for dt, buf in bufs.items():
+            free = self._free.get((dt, buf.shape))
+            if free is not None and len(free) < self.keep:
+                free.append(buf)
 
 
 @dataclass
@@ -415,6 +471,10 @@ class RoundEngine:
         #: most recent dispatch
         self.last_dispatch_puts = 0
         self.last_staged_bytes = 0
+        #: host staging buffers: what the ring can have in flight plus
+        #: the one being filled, and one to spare
+        self._staging = StagingPool(
+            max(int(sc.get("pipeline_depth", 1) or 0), 0) + 2)
         #: the server's span factory (``Telemetry.span``) when tracing
         #: is on: what a dispatch is made of (``stage_host``, ``h2d``,
         #: ``launch``) and the stats transfer (``stats_d2h``) become
@@ -1777,14 +1837,15 @@ class RoundEngine:
 
     # ------------------------------------------------------------------
     def _chaos_host(self, chaos_vecs: Optional[list],
-                    stacked: bool) -> tuple:
+                    rounds: int) -> list:
         """Validate + assemble the per-round fault/staleness vectors as
-        HOST numpy arrays, one per trailing program operand: per round
-        ``(drop [K], keep_steps [K])`` when client faults compiled in,
-        followed by ``(corrupt_mode [K],)`` when corruption compiled in,
-        followed by ``(staleness [K],)`` when traced staleness compiled
-        in (fluteflow) — or nothing when the engine compiled without
-        any.  Mismatches are programming errors and raise."""
+        HOST numpy arrays, one tuple a round with one entry per trailing
+        program operand: ``(drop [K], keep_steps [K])`` when client
+        faults compiled in, followed by ``(corrupt_mode [K],)`` when
+        corruption compiled in, followed by ``(staleness [K],)`` when
+        traced staleness compiled in (fluteflow) — or empty tuples when
+        the engine compiled without any.  Mismatches are programming
+        errors and raise."""
         dtypes = ([np.float32, np.float32] if self.chaos_client_faults
                   else []) + \
                  ([np.int32] if self.chaos_corruption else []) + \
@@ -1795,24 +1856,23 @@ class RoundEngine:
                     "chaos vectors supplied but the engine was built "
                     "without chaos client faults, corruption, or traced "
                     "staleness (server_config.chaos / traffic)")
-            return ()
+            return [()] * rounds
         if not chaos_vecs:
             raise ValueError(
                 "engine built with chaos client faults/corruption/"
                 "traced staleness: every dispatch needs the per-round "
                 "vectors")
-        if any(len(entry) != len(dtypes) for entry in chaos_vecs):
+        if len(chaos_vecs) != rounds or \
+                any(len(entry) != len(dtypes) for entry in chaos_vecs):
             raise ValueError(
                 f"chaos vector arity mismatch: engine expects "
-                f"{len(dtypes)} per-round vectors "
-                f"(faults={self.chaos_client_faults}, "
+                f"{len(dtypes)} per-round vectors for each of {rounds} "
+                f"rounds (faults={self.chaos_client_faults}, "
                 f"corruption={self.chaos_corruption}, "
                 f"staleness={self.traffic_staleness})")
-        out = []
-        for i, dt in enumerate(dtypes):
-            vals = [np.asarray(entry[i], dt) for entry in chaos_vecs]
-            out.append(np.stack(vals) if stacked else vals[0])
-        return tuple(out)
+        return [tuple(np.asarray(entry[i], dt)
+                      for i, dt in enumerate(dtypes))
+                for entry in chaos_vecs]
 
     # ------------------------------------------------------------------
     # single-buffer input staging: the dispatch half of the flatpack
@@ -1822,7 +1882,11 @@ class RoundEngine:
     # ONE buffer per dtype group (clients-axis operands via AxisPacker,
     # replicated scalars via ScalarStager); the inverse runs INSIDE the
     # jitted program as static slices/reshapes XLA fuses away
-    # (the staging tests pin the transfer count).
+    # (the staging tests pin the transfer count).  The clients-axis
+    # buffers are the engine's own (StagingPool): each round's leaves
+    # are written once, straight into their slots of a buffer that an
+    # earlier dispatch used, and the buffer comes back at the fence of
+    # the chunk whose program read it.
     # ------------------------------------------------------------------
     def _build_staged_fn(self, R: int, ax_packer: AxisPacker,
                          stager: ScalarStager) -> Callable:
@@ -1867,15 +1931,21 @@ class RoundEngine:
         R = len(batches)
         stacked = R > 1
         with self._span("stage_host", rounds=R) as span:
-            ax_packer, stager, ax_bufs, sc_bufs, pool_args = \
+            ax_packer, stager, trees, sc_bufs, pool_args = \
                 self._stage_host(state, batches, client_lrs, server_lrs,
                                  leakage_threshold, quant_thresholds,
                                  chaos_vecs)
+            # each round's leaves once into a kept buffer per dtype
+            # group, a fresh one where none is free; given back at the
+            # chunk's fence (PackedStats.release)
+            kept, reused = self._staging.take(ax_packer.buffer_shapes())
+            ax_bufs = ax_packer.pack_rounds_into(kept, trees)
             staged_bytes = int(
                 sum(b.nbytes for b in ax_bufs.values()) +
                 sum(b.nbytes for b in sc_bufs.values()))
             if span is not None:
                 span["bytes"] = staged_bytes
+                span["reused"] = reused
         ax_sharding = (NamedSharding(self.mesh, P(None, CLIENTS_AXIS))
                        if stacked else self._client_sharding)
         with self._span("h2d", rounds=R, bytes=staged_bytes,
@@ -1908,38 +1978,28 @@ class RoundEngine:
                                 state.round + R)
         packer = self._stats_packers[
             ("single", batches[0].sample_mask.shape[0])]
-        return new_state, PackedStats(vecs, packer, rounds=R,
-                                      stacked=stacked,
-                                      span=self.span_factory)
+        return new_state, PackedStats(
+            vecs, packer, rounds=R, stacked=stacked,
+            span=self.span_factory,
+            release=(functools.partial(self._staging.give, kept)
+                     if kept else None))
 
     def _stage_host(self, state: ServerState, batches: list,
                     client_lrs: list, server_lrs: list,
                     leakage_threshold: Optional[float],
                     quant_thresholds: Optional[list],
                     chaos_vecs: Optional[list]) -> tuple:
-        """The host half of a staged dispatch: stack the rounds' arrays,
-        assemble the fault vectors and scalars, pack each dtype group
-        into one buffer.  Returns ``(ax_packer, stager, ax_bufs,
+        """The host half of a staged dispatch but for the one copy:
+        each round's clients-axis tree (arrays, masks, ids, fault
+        vectors: nothing stacked) and the packer of their stack, the
+        scalars packed.  Returns ``(ax_packer, stager, round_trees,
         sc_bufs, pool_args)``."""
         R = len(batches)
         stacked = R > 1
-
-        def stack(pick):
-            vals = [pick(b) for b in batches]
-            return vals[0] if R == 1 else np.stack(vals)
-
-        arrays_host, pool_args = self._host_arrays(batches)
-        axis_tree = {
-            "arrays": arrays_host,
-            "sample_mask": stack(lambda b: b.sample_mask),
-            "client_mask": stack(lambda b: b.client_mask),
-            "client_ids": stack(lambda b: b.client_ids),
-        }
-        if self.carry_paged:
-            axis_tree["carry_slots"] = stack(self._batch_slots)
-        chaos_host = self._chaos_host(chaos_vecs, stacked)
-        if chaos_host:
-            axis_tree["chaos"] = tuple(chaos_host)
+        trees = []
+        for batch, chaos in zip(batches, self._chaos_host(chaos_vecs, R)):
+            tree, pool_args = self._round_tree(batch, chaos)
+            trees.append(tree)
         lr_dt, rd_dt = np.float32, np.int32
         if stacked:
             sc_tree = {
@@ -1965,10 +2025,10 @@ class RoundEngine:
                 "quant": lr_dt(quant_thresholds[0]
                                if quant_thresholds is not None else -1.0),
             }
-        ax_packer = AxisPacker(axis_tree, lead_ndim=2 if stacked else 1)
+        ax_packer = AxisPacker.for_rounds(trees[0], R)
         stager = ScalarStager(sc_tree)
-        return (ax_packer, stager, ax_packer.pack_np(axis_tree),
-                stager.pack_np(sc_tree), pool_args)
+        return (ax_packer, stager, trees, stager.pack_np(sc_tree),
+                pool_args)
 
     # ------------------------------------------------------------------
     def run_round(self, state: ServerState, batch: RoundBatch,
@@ -2003,31 +2063,41 @@ class RoundEngine:
         return np.asarray(slots, np.int32)
 
     # ------------------------------------------------------------------
-    def _host_arrays(self, batches: list) -> Tuple[Dict[str, np.ndarray],
-                                                   tuple]:
-        """Assemble the data inputs of one round (``[batch]``) or a fused
-        chunk (stacked on a leading round axis) as HOST numpy arrays.
+    def _host_arrays(self, batch) -> Tuple[Dict[str, np.ndarray], tuple]:
+        """The data inputs of one round as HOST numpy arrays, and the
+        trailing program operands that go with them.
 
         Host-packed ``RoundBatch``es carry their gathered feature arrays;
         ``IndexRoundBatch``es carry only the int32 index grid and ride the
         resident pool (``attach_pool``) as a trailing program operand.
         """
         from ..data.batching import IndexRoundBatch
-        is_idx = isinstance(batches[0], IndexRoundBatch)
+        is_idx = isinstance(batch, IndexRoundBatch)
         if is_idx != (self._pool is not None):
             raise ValueError(
                 "round engine pool mode mismatch: "
                 f"batch={'indices' if is_idx else 'arrays'} but pool "
                 f"{'attached' if self._pool is not None else 'absent'}")
-
-        def stack(pick):
-            vals = [pick(b) for b in batches]
-            return vals[0] if len(vals) == 1 else np.stack(vals)
-
         if is_idx:
-            return {"__idx__": stack(lambda b: b.indices)}, (self._pool,)
-        return {k: stack(lambda b: b.arrays[k])
-                for k in batches[0].arrays}, ()
+            return {"__idx__": batch.indices}, (self._pool,)
+        return dict(batch.arrays), ()
+
+    def _round_tree(self, batch, chaos: tuple) -> Tuple[dict, tuple]:
+        """One round's clients-axis operands (every leaf ``[K, ...]``) as
+        the tree the staged programs unpack, and the trailing program
+        operands."""
+        arrays_host, pool_args = self._host_arrays(batch)
+        tree = {
+            "arrays": arrays_host,
+            "sample_mask": batch.sample_mask,
+            "client_mask": batch.client_mask,
+            "client_ids": batch.client_ids,
+        }
+        if self.carry_paged:
+            tree["carry_slots"] = self._batch_slots(batch)
+        if chaos:
+            tree["chaos"] = chaos
+        return tree, pool_args
 
     # ------------------------------------------------------------------
     def dispatch_rounds(self, state: ServerState, batches: list,
@@ -2599,22 +2669,11 @@ class RoundEngine:
                 span_rounds = 1 if b == 0 else 0
                 with self._span("stage_host", rounds=span_rounds,
                                 bucket=b) as span:
-                    arrays_host, pool_args = self._host_arrays([batch])
-                    axis_tree = {
-                        "arrays": arrays_host,
-                        "sample_mask": batch.sample_mask,
-                        "client_mask": batch.client_mask,
-                        "client_ids": batch.client_ids,
-                    }
-                    if self.carry_paged:
-                        axis_tree["carry_slots"] = self._batch_slots(batch)
                     entry = (chaos_vecs[r][b] if chaos_vecs is not None
                              else None)
-                    chaos_host = self._chaos_host(
-                        [entry] if entry is not None else None,
-                        stacked=False)
-                    if chaos_host:
-                        axis_tree["chaos"] = tuple(chaos_host)
+                    axis_tree, pool_args = self._round_tree(
+                        batch, self._chaos_host(
+                            [entry] if entry is not None else None, 1)[0])
                     sc_tree = {
                         "client_lr": lr_dt(client_lrs[r]),
                         "round_idx": rd_dt(cur.round),

@@ -150,6 +150,12 @@ class AxisPacker:
     :class:`FlatPacker`) is what lets the staged buffer carry a clients-
     axis sharding: the round program's inputs stay sharded over the mesh
     while still crossing the host boundary as one transfer per dtype.
+
+    Two ways to the same bytes: :meth:`pack_np` concatenates a tree that
+    already has the shared axes into fresh buffers; :meth:`pack_rounds_into`
+    writes the rounds' own trees, one leaf at a time and each element
+    once, into buffers the caller keeps (:meth:`for_rounds` builds the
+    slot table of the stacked tree without stacking anything).
     """
 
     def __init__(self, template: Any, lead_ndim: int):
@@ -181,31 +187,98 @@ class AxisPacker:
             sizes[dt] = off + size
         self.sizes = sizes
 
+    @classmethod
+    def for_rounds(cls, round_tree: Any, rounds: int) -> "AxisPacker":
+        """The packer of ``rounds`` trees like ``round_tree`` (leaves
+        ``[K, ...]``) stacked on a new leading axis, from one of them: the
+        slot table, and so the ``signature``, of
+        ``AxisPacker(stacked_tree, lead_ndim=2)``.  One round has no such
+        axis: ``AxisPacker(round_tree, lead_ndim=1)``."""
+        packer = cls(round_tree, lead_ndim=1)
+        if rounds > 1:
+            packer.lead_ndim = 2
+            packer.lead_shape = (int(rounds),) + packer.lead_shape
+        return packer
+
     @property
     def signature(self) -> Tuple:
         """Cache key for jitted unpackers: the full slot table."""
         return (self.lead_ndim, self.lead_shape, tuple(self._slots),
                 self.treedef)
 
-    def pack_np(self, tree: Any) -> Dict[str, np.ndarray]:
-        """One ``[*lead, total]`` numpy buffer per dtype (host-side —
-        the single memcpy that replaces N per-leaf transfers)."""
+    def buffer_shapes(self) -> Dict[str, Tuple[int, ...]]:
+        """``{dtype: [*lead, total]}`` of the groups that
+        :meth:`pack_rounds_into` writes into a buffer.  A group of one
+        leaf with no round axis to stack is not among them: there is no
+        copy to make, and it is handed through as it is."""
+        leaves: Dict[str, int] = {}
+        for dt, _, _, _ in self._slots:
+            leaves[dt] = leaves.get(dt, 0) + 1
+        return {dt: self.lead_shape + (total,)
+                for dt, total in self.sizes.items()
+                if self.lead_ndim > 1 or leaves[dt] > 1}
+
+    def pack_rounds_into(self, bufs: Dict[str, np.ndarray],
+                         round_trees: list) -> Dict[str, np.ndarray]:
+        """Write each round's leaves, once each, into their slots of
+        ``bufs`` (one array per entry of :meth:`buffer_shapes`, of that
+        shape and dtype; their contents are overwritten whole).  No
+        stack, no concatenate, no allocation.  Returns the per-dtype
+        dict to transfer: byte for byte what :meth:`pack_np` gives for
+        the stacked tree."""
+        stacked = self.lead_ndim > 1
+        rounds = self.lead_shape[0] if stacked else 1
+        lead = self.lead_shape[1:] if stacked else self.lead_shape
+        if len(round_trees) != rounds:
+            raise ValueError(
+                f"{len(round_trees)} round trees != the packer's "
+                f"{rounds} rounds")
+        shapes = self.buffer_shapes()
+        for dt, shape in shapes.items():
+            buf = bufs.get(dt)
+            if buf is None or buf.shape != shape or str(buf.dtype) != dt:
+                raise ValueError(
+                    f"staging buffer for {dt} is "
+                    f"{None if buf is None else (buf.dtype, buf.shape)}, "
+                    f"needs {shape}")
+        out = {dt: bufs[dt] for dt in shapes}
+        for r, tree in enumerate(round_trees):
+            for arr, (dt, off, size, _) in self._checked(tree, lead):
+                part = arr.reshape(lead + (size,))
+                if dt not in shapes:
+                    out[dt] = part
+                    continue
+                dest = out[dt][r] if stacked else out[dt]
+                dest[..., off:off + size] = part
+        return out
+
+    def _checked(self, tree: Any, lead: Tuple[int, ...]):
+        """The tree's leaves as the device will type them, each beside
+        its slot; another structure, shape (``lead`` + the slot's) or
+        dtype than the template's raises."""
         leaves, treedef = jax.tree.flatten(tree)
         if treedef != self.treedef or len(leaves) != len(self._slots):
             raise ValueError(
                 f"tree structure {treedef} != packer template "
                 f"{self.treedef}")
-        groups: Dict[str, list] = {}
-        for leaf, (dt, _, size, trailing) in zip(leaves, self._slots):
+        for leaf, slot in zip(leaves, self._slots):
             arr = canonical_np(leaf)
-            if tuple(arr.shape[self.lead_ndim:]) != trailing or \
-                    tuple(arr.shape[:self.lead_ndim]) != self.lead_shape:
+            if tuple(arr.shape) != lead + slot[3]:
                 raise ValueError(
                     f"leaf shape {arr.shape} != packer template "
-                    f"{self.lead_shape}+{trailing}")
-            if str(arr.dtype) != dt:
+                    f"{lead}+{slot[3]}")
+            if str(arr.dtype) != slot[0]:
                 raise ValueError(
-                    f"leaf dtype {arr.dtype} != packer template dtype {dt}")
+                    f"leaf dtype {arr.dtype} != packer template dtype "
+                    f"{slot[0]}")
+            yield arr, slot
+
+    def pack_np(self, tree: Any) -> Dict[str, np.ndarray]:
+        """One fresh ``[*lead, total]`` numpy buffer per dtype from a
+        tree that has the shared axes already (host-side: one
+        concatenate a group in place of N per-leaf transfers)."""
+        groups: Dict[str, list] = {}
+        for arr, (dt, _, size, _) in self._checked(tree, self.lead_shape):
             groups.setdefault(dt, []).append(
                 arr.reshape(self.lead_shape + (size,)))
         return {dt: (np.concatenate(parts, axis=-1) if len(parts) > 1

@@ -28,7 +28,7 @@ from msrflute_tpu.models.cv import ClassificationTask
 from msrflute_tpu.utils.logging import init_logging
 
 
-def _cfg(depth, **server_over):
+def _cfg(depth, step_bucketing=True, **server_over):
     sc = {
         "max_iteration": 9, "num_clients_per_iteration": 4,
         "initial_lr_client": 0.2, "pipeline_depth": depth,
@@ -53,6 +53,7 @@ def _cfg(depth, **server_over):
         "privacy_metrics_config": {"apply_metrics": True},
         "server_config": sc,
         "client_config": {
+            "step_bucketing": step_bucketing,
             "optimizer_config": {"type": "sgd", "lr": 0.2},
             "data_config": {"train": {"batch_size": 4}}},
     })
@@ -179,6 +180,74 @@ def test_pipeline_bit_identical_to_serial(synth_dataset, tmp_path, model):
         1, synth_dataset, str(tmp_path), model, tag="_resumed",
         resume_from_checkpoint=True)
     assert st2.round == 9 and srv2.pipelined_chunks == 2
+    np.testing.assert_array_equal(
+        flat1, np.asarray(ravel_pytree(jax.device_get(st2.params))[0]))
+    for leaf1, leaf2 in zip(jax.tree.leaves(latest1),
+                            jax.tree.leaves(latest2)):
+        np.testing.assert_array_equal(np.asarray(leaf1), np.asarray(leaf2))
+
+
+@pytest.mark.parametrize("depth", [1, 3])
+def test_fused_ring_reuses_staging_buffers_bit_identical(
+        synth_dataset, tmp_path, monkeypatch, depth):
+    """Fused chunks through a ring of ``depth``: the host buffers the
+    inputs are staged in are kept and written again, and none before the
+    fence of the chunk whose program read it.  On the CPU backend the
+    device array may BE the numpy buffer, so a buffer written too early
+    changes what a program still in flight reads: the run then leaves
+    the serial run's bits (ISSUE 34)."""
+    from msrflute_tpu.engine.round import StagingPool
+    # one step grid for every chunk, as where the clients hold equal
+    # shares: the staged shapes stay, and so do the buffers
+    fused = dict(rounds_per_step=2, max_iteration=24, val_freq=12,
+                 model_backup_freq=12, step_bucketing=False)
+    _, st0, rec0, latest0, status0 = _run(0, synth_dataset, str(tmp_path),
+                                          tag="_fused", **fused)
+    uses = []  # (buffer, reused) of every float32 group staged
+    take = StagingPool.take
+
+    def watched(pool, shapes):
+        bufs, reused = take(pool, shapes)
+        uses.append((bufs["float32"], reused))
+        return bufs, reused
+
+    monkeypatch.setattr(StagingPool, "take", watched)
+    srv1, st1, rec1, latest1, status1 = _run(
+        depth, synth_dataset, str(tmp_path), tag="_fused", **fused)
+    monkeypatch.setattr(StagingPool, "take", take)
+    # 12 chunks of two rounds, 5 of each period inside the boundaries
+    assert srv1.pipelined_chunks == 10 and len(uses) == 12
+    distinct = {id(buf): buf for buf, _ in uses}
+    # what the ring has in flight plus the one being filled, each of
+    # them written at least twice, none allocated after the ring filled
+    assert len(distinct) == depth + 1
+    assert min(sum(buf is b for b, _ in uses)
+               for buf in distinct.values()) >= 2
+    assert [reused for _, reused in uses] == \
+        [False] * (depth + 1) + [True] * (11 - depth)
+
+    flat0 = np.asarray(ravel_pytree(jax.device_get(st0.params))[0])
+    flat1 = np.asarray(ravel_pytree(jax.device_get(st1.params))[0])
+    np.testing.assert_array_equal(flat0, flat1)
+    assert st0.round == st1.round == 24
+    s0, s1 = _stepped_series(rec0), _stepped_series(rec1)
+    assert set(s0) == set(s1)
+    for name in s0:
+        assert s0[name] == s1[name], name
+    for leaf0, leaf1 in zip(jax.tree.leaves(latest0),
+                            jax.tree.leaves(latest1)):
+        np.testing.assert_array_equal(np.asarray(leaf0), np.asarray(leaf1))
+    assert status0 == status1
+
+    # stopped after the first period and resumed: a new engine, an
+    # empty pool, the same bits
+    resumed = dict(fused, max_iteration=12)
+    _run(depth, synth_dataset, str(tmp_path), tag="_fused_resumed",
+         **resumed)
+    _, st2, _, latest2, _ = _run(
+        depth, synth_dataset, str(tmp_path), tag="_fused_resumed",
+        resume_from_checkpoint=True, **fused)
+    assert st2.round == 24
     np.testing.assert_array_equal(
         flat1, np.asarray(ravel_pytree(jax.device_get(st2.params))[0]))
     for leaf1, leaf2 in zip(jax.tree.leaves(latest1),

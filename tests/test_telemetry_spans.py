@@ -136,6 +136,12 @@ def test_children_lie_inside_their_parent(run, parent):
     if parent == "dispatch":
         for kid in _spans(run["records"], "h2d"):
             assert kid["bytes"] > 0 and kid["puts"] >= 2
+        # every staging says whether its buffers were kept ones, and the
+        # first of a run can only have allocated
+        staged = _spans(run["records"], "stage_host")
+        assert all(isinstance(kid["reused"], bool) and kid["bytes"] > 0
+                   for kid in staged)
+        assert staged[0]["reused"] is False
         # `compiled` is the engine's compile log speaking; jax's own
         # report (a backend compile under that launch) agrees
         launches = _spans(run["records"], "launch")
