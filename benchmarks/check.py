@@ -30,7 +30,12 @@ Three parties:
 
 from __future__ import annotations
 
+import threading
+from concurrent.futures import ThreadPoolExecutor
+
 import numpy as np
+
+LEAVES_AT_A_TIME = 4
 
 
 def _leaves(tree, prefix=""):
@@ -57,17 +62,30 @@ def _sum_squares(trees) -> float:
 
 def compare(*, init_params, ref_check: dict, refs_timed: list, rounds: list,
             check_stats: dict, check_params, timed_first: dict,
-            timed_first_params, dp: dict | None) -> list:
+            timed_first_params, dp: dict | None,
+            leaf_kinds: dict | None = None,
+            leaf_gaps: dict | None = None) -> list:
     """``[(name, value)]``.  ``ref_check`` = the ``highest`` reference's
     round 0, ``refs_timed`` = the default-precision reference's result
     for each round of the first dispatch, ``rounds`` = those rounds'
     inputs.  ``dp`` = ``{"sigma", "max_grad"}`` where the configuration
-    adds global-DP noise to the aggregate.
+    adds global-DP noise to the aggregate.  ``leaf_kinds`` = ``{kind:
+    [parts of a leaf's path]}``: the leaves whose path holds one of a
+    kind's parts have a worst-leaf number of their own,
+    ``update_gap_worst_leaf.<kind>``, and ``update_gap_worst_leaf`` is
+    the worst of the leaves of no kind (a configuration whose expert
+    leaves take a discrete event that the others do not holds the two to
+    limits of their own).  ``leaf_gaps``: a dict to fill
+    with every leaf's own gap of update norms, ``{leaf's path: gap}``
+    (what ``update_gap_worst_leaf`` is the largest of; what its limit is
+    set from, ``run.py --readings 1``).
 
-    One leaf at a time: every norm, dot product and worst-leaf gap is
-    accumulated in float64 leaf by leaf, in sorted-key order, and at most
-    two float64 copies of ONE leaf are alive at a time, never a tree (a
-    600 M-parameter tree is 4.8 GB in float64).  Only the DP branch's
+    Leaf by leaf: every norm, dot product and worst-leaf gap is
+    accumulated in float64 over the leaves in sorted-key order;
+    ``LEAVES_AT_A_TIME`` leaves are worked on side by side, each thread
+    in two float64 buffers of the largest leaf's size, never a tree (a 600 M-parameter tree
+    is 4.8 GB in float64; seventeen passes over 0.47 B elements on one
+    core were 20 s of every run of the expert cell).  Only the DP branch's
     noise residuals keep all elements at once (their mean, std and
     kurtosis are taken over the whole vector): a configuration with
     global DP has to be small enough for two such vectors."""
@@ -93,42 +111,65 @@ def compare(*, init_params, ref_check: dict, refs_timed: list, rounds: list,
     timed_resid = np.empty(elements) if dp is not None else None
     ref_sq = diff_sq = want_sq = got_sq = got_want = 0
     ref_leaf_norms, check_leaf_norms = [], []
-    at = 0
-    for (_, init), (_, ref_new), (_, check_new), (_, want_new), \
-            (_, got_new) in zip(
-                _leaves(init_params), _leaves(ref["new_params"]),
-                _leaves(check_params),
-                _leaves(refs_timed[-1]["new_params"]),
-                _leaves(timed_first_params)):
-        ref_delta = _minus(init, ref_new)
-        buffer = np.multiply(ref_delta, ref_delta)
+    leaf_names = [name for name, _ in _leaves(init_params)]
+    sizes = [leaf.size for _, leaf in _leaves(init_params)]
+    offsets = np.cumsum([0] + sizes[:-1])
+    largest = max(sizes)
+    kept = threading.local()
+
+    def one_leaf(at, init, ref_new, check_new, want_new, got_new):
+        """The leaf's six sums, in two float64 buffers that its thread
+        keeps from leaf to leaf (fresh ones are mostly page faults)."""
+        init, ref_new, check_new, want_new, got_new = (
+            leaf.reshape(-1) for _, leaf in (init, ref_new, check_new,
+                                             want_new, got_new))
+        if not hasattr(kept, "pair"):
+            kept.pair = np.empty(largest), np.empty(largest)
+        ref_delta = _minus(init, ref_new, out=kept.pair[0][:init.size])
+        buffer = np.multiply(ref_delta, ref_delta,
+                             out=kept.pair[1][:init.size])
         leaf_sq = np.sum(buffer)
-        ref_sq += leaf_sq
-        ref_leaf_norms.append(float(np.sqrt(leaf_sq)))
         check_delta = _minus(init, check_new, out=buffer)
         diff = np.subtract(check_delta, ref_delta, out=ref_delta)
         np.multiply(check_delta, check_delta, out=check_delta)
-        check_leaf_norms.append(float(np.sqrt(np.sum(check_delta))))
+        check_sq = np.sum(check_delta)
+        leaf_diff_sq = 0
         if dp is None:
             np.multiply(diff, diff, out=diff)
-            diff_sq += np.sum(diff)
+            leaf_diff_sq = np.sum(diff)
         else:
-            resid[at:at + diff.size] = diff.ravel()
+            resid[at:at + diff.size] = diff
 
         want = _minus(init, want_new, out=diff)
         np.multiply(want, want, out=buffer)
-        want_sq += np.sum(buffer)
+        leaf_want_sq = np.sum(buffer)
         got = _minus(init, got_new, out=buffer)
         if dp is not None:
-            np.subtract(got, want, out=timed_resid[at:at + got.size].reshape(
-                got.shape))
+            np.subtract(got, want, out=timed_resid[at:at + got.size])
         np.multiply(want, got, out=want)
-        got_want += np.sum(want)
+        leaf_got_want = np.sum(want)
         np.multiply(got, got, out=got)
-        got_sq += np.sum(got)
-        at += init.size
-        # the next leaf's two buffers are made once these are gone
-        del ref_delta, buffer, check_delta, diff, want, got
+        return (leaf_sq, check_sq, leaf_diff_sq, leaf_want_sq,
+                leaf_got_want, np.sum(got))
+
+    # the leaves side by side on a few threads (numpy lets go of the
+    # interpreter inside a pass), their sums added up in the leaves'
+    # order: the digits of one leaf after the other
+    with ThreadPoolExecutor(max_workers=LEAVES_AT_A_TIME) as pool:
+        sums = list(pool.map(
+            one_leaf, offsets, _leaves(init_params),
+            _leaves(ref["new_params"]), _leaves(check_params),
+            _leaves(refs_timed[-1]["new_params"]),
+            _leaves(timed_first_params)))
+    for (leaf_sq, check_sq, leaf_diff_sq, leaf_want_sq, leaf_got_want,
+         leaf_got_sq) in sums:
+        ref_sq += leaf_sq
+        ref_leaf_norms.append(float(np.sqrt(leaf_sq)))
+        check_leaf_norms.append(float(np.sqrt(check_sq)))
+        diff_sq += leaf_diff_sq
+        want_sq += leaf_want_sq
+        got_want += leaf_got_want
+        got_sq += leaf_got_sq
 
     ref_norm = float(np.sqrt(ref_sq))
     ref_agg = float(np.sqrt(_sum_squares(_leaves(ref["aggregate"]))))
@@ -139,9 +180,18 @@ def compare(*, init_params, ref_check: dict, refs_timed: list, rounds: list,
         numbers.append(("update_diff",
                         float(np.sqrt(diff_sq)) / ref_norm))
         floor = float(np.median(ref_leaf_norms))
-        numbers.append(("update_gap_worst_leaf", max(
-            abs(c - r) / max(r, floor)
-            for c, r in zip(check_leaf_norms, ref_leaf_norms))))
+        per_leaf = [abs(c - r) / max(r, floor)
+                    for c, r in zip(check_leaf_norms, ref_leaf_norms)]
+        kind_of = [next((kind for kind, parts in (leaf_kinds or {}).items()
+                         if any(part in name for part in parts)), None)
+                   for name in leaf_names]
+        for kind in [None, *(leaf_kinds or {})]:
+            numbers.append((
+                f"update_gap_worst_leaf.{kind}" if kind
+                else "update_gap_worst_leaf",
+                max(gap for gap, k in zip(per_leaf, kind_of) if k == kind)))
+        if leaf_gaps is not None:
+            leaf_gaps.update(zip(leaf_names, per_leaf))
         numbers.append(("agg_norm_gap",
                         abs(float(check_stats["agg_grad_norm"]) - ref_agg) /
                         ref_agg))

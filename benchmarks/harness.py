@@ -35,7 +35,9 @@ A run, in order (all in one process, which holds the chips):
    ``PackedStats.fetch`` (the fence) — same arguments, same results;
 3. before the first timed dispatch, the CHECK program: the engine's own
    one-round dispatch of round 0's cohort traced under
-   ``jax.default_matmul_precision("highest")``, on a copy of the state;
+   ``jax.default_matmul_precision("highest")``, on a copy of the state,
+   and compiled at the effort of what is compared and never timed
+   (``compared_never_timed``, as is 5's round at ``highest``);
 4. one evaluation period (the traffic mix's ``period_rounds``) of warm-up
    (every program of a period has then run once), the window of whole
    evaluation periods, then a graceful preemption
@@ -47,6 +49,11 @@ A run, in order (all in one process, which holds the chips):
 
 ``setup_s`` is process start to window open and so holds 1-4's set-up
 and the check program, not the reference (5), which no user pays.
+
+The run's seconds by part, on the host's clock, are an earlier line of
+their own (``parts_s``, ``Parts``; ``run_s`` from ``run.py`` is the whole,
+clean-up included): what a cell costs every later check beside its
+window, and what a new cell is sized against (a run may take 360 s).
 """
 
 from __future__ import annotations
@@ -62,6 +69,7 @@ import shutil
 import sys
 import tempfile
 import time
+import typing
 
 import numpy as np
 
@@ -170,15 +178,25 @@ def build_config(cell: dict, trace: bool, control: dict | None) -> dict:
 # ----------------------------------------------------------------------
 # observation from outside
 # ----------------------------------------------------------------------
+class Compile(typing.NamedTuple):
+    """One program requested from the backend."""
+    end_ts: float
+    seconds: float
+    name: str
+    missed: bool   # the persistent cache had no entry for it
+    effort: float  # the compile effort it was requested at
+
+
 class CompileLog:
     """Every program jax requests from the backend (a persistent-cache hit
     is still a request) and every cache hit/miss, with the time it ended
     (copied from ``chip_smoke.py``)."""
 
     def __init__(self):
-        self.compiles = []  # (end_ts, seconds, fun_name)
+        self.compiles = []  # Compile, in the order they ended
         self.hits = []
         self.misses = []
+        self._missed = False
 
     def install(self) -> None:
         from jax import monitoring
@@ -187,17 +205,47 @@ class CompileLog:
 
     def _duration(self, event, duration, **kwargs):
         if event == BACKEND_COMPILE_EVENT:
-            self.compiles.append((time.time(), float(duration),
-                                  str(kwargs.get("fun_name"))))
+            # jax records a miss inside the request that it belongs to,
+            # and calls back on the thread that asked, inside its scope
+            from jax._src import config as jax_config
+            self.compiles.append(Compile(
+                time.time(), float(duration), str(kwargs.get("fun_name")),
+                self._missed,
+                float(jax_config.exec_time_optimization_effort.value)))
+            self._missed = False
 
     def _event(self, event, **kwargs):
         if event == CACHE_HIT_EVENT:
             self.hits.append(time.time())
         elif event == CACHE_MISS_EVENT:
             self.misses.append(time.time())
+            self._missed = True
 
     def between(self, t0: float, t1: float) -> list:
-        return [c for c in self.compiles if t0 < c[0] <= t1]
+        return [c for c in self.compiles if t0 < c.end_ts <= t1]
+
+
+class Parts(dict):
+    """Seconds of the run by part, on the host's clock: ``{name: s}``;
+    ``<name>_compile`` beside a part = the backend compile requests
+    (cache hits too) that ended inside it."""
+
+    def __init__(self, compiles: CompileLog):
+        super().__init__()
+        self.compiles = compiles
+
+    @contextlib.contextmanager
+    def part(self, name: str):
+        t0 = time.time()
+        try:
+            yield
+        finally:
+            t1 = time.time()
+            self[name] = self.get(name, 0.0) + t1 - t0
+            inside = sum(c.seconds for c in self.compiles.between(t0, t1))
+            if inside:
+                self[f"{name}_compile"] = \
+                    self.get(f"{name}_compile", 0.0) + inside
 
 
 _COMPILES = None
@@ -219,6 +267,27 @@ def _tree_copy(tree):
         lambda x: jnp.copy(x) if isinstance(x, jax.Array) else x, tree)
 
 
+def compared_never_timed():
+    """The compile effort of what is compared and never timed AND states
+    its precision (the check program and the plain reference's round 0,
+    both under ``highest``; a reader's counting program): XLA's lowest,
+    -1.0, for this thread and for the ``with`` block only.  Under
+    ``highest`` the TPU's compiler spends 5-7 core-seconds on every large
+    float32 product at its default effort; nobody reads these programs'
+    speed, and every run of every later check pays their compile or
+    their cache's miss.  Not for the reference's default-precision
+    rounds: "default" is what the compiler makes of it at the effort the
+    timed program is compiled at.  Nothing process-wide: the timed
+    programs are requested outside the block, with the options (and so
+    the cache entries) they always had.
+    The effort is no part of a jitted function's in-memory key, so what
+    is compiled inside must not be called again outside: the check
+    program is a trace of its own (``highest``), and the reference runs
+    after the trainer has returned."""
+    from jax._src import config as jax_config
+    return jax_config.exec_time_optimization_effort(-1.0)
+
+
 def round_inputs(batches, client_lrs, server_lrs, quant) -> list:
     """Copies of every round's packed input of one dispatch (every array
     of the packed batch, the two masks and the round's three numbers):
@@ -238,8 +307,9 @@ class Run:
 
     def __init__(self, cell: dict, seconds: float, trace: bool,
                  work: str, weights: dict, t_start: float,
-                 readings: bool = False):
+                 parts: Parts, readings: bool = False):
         traffic = cell["traffic_doc"]
+        self.parts = parts
         self.readings = bool(readings)
         self.seconds = float(seconds)
         self.trace = bool(trace)
@@ -299,6 +369,7 @@ class Run:
         self.first_rounds = round_inputs(batches, client_lrs, server_lrs,
                                          quant)
         t0 = time.time()
+        self.parts["before_check_program_s"] = t0 - self.t_start
         scratch = ServerState(_tree_copy(state.params),
                               _tree_copy(state.opt_state),
                               _tree_copy(state.strategy_state), state.round)
@@ -307,17 +378,22 @@ class Run:
             one["quant_thresholds"] = list(quant[:1])
         if one.get("chaos_vecs"):
             one["chaos_vecs"] = list(one["chaos_vecs"][:1])
-        with jax.default_matmul_precision("highest"):
+        # trace + lower + compile (or the cache's hit) + launch
+        with self.parts.part("check_dispatch_s"), \
+                jax.default_matmul_precision("highest"), \
+                compared_never_timed():
             new_state, stats = dispatch(
                 engine, scratch, batches[:1], list(client_lrs[:1]),
                 list(server_lrs[:1]), rng, **one)
-        jax.block_until_ready(stats.vecs)
-        out = fetch(stats)
-        self.check = {
-            "stats": {k: np.asarray(v)[0] for k, v in out.items()},
-            "new_params": jax.device_get(new_state.params),
-            "seconds": time.time() - t0,
-        }
+        with self.parts.part("check_execute_s"):
+            jax.block_until_ready(stats.vecs)
+        with self.parts.part("check_fetch_s"):
+            out = fetch(stats)
+            self.check = {
+                "stats": {k: np.asarray(v)[0] for k, v in out.items()},
+                "new_params": jax.device_get(new_state.params),
+            }
+        self.check["seconds"] = time.time() - t0
         del new_state, scratch
         say({"check_program_s": self.check["seconds"]})
 
@@ -333,8 +409,9 @@ class Run:
             import jax
             self.fences[0]["agg_grad_norm"] = np.asarray(
                 out["agg_grad_norm"]).tolist()
-            self.first_dispatch_params = jax.device_get(
-                self.first_state_params)
+            with self.parts.part("first_params_fetch_s"):
+                self.first_dispatch_params = jax.device_get(
+                    self.first_state_params)
             self.first_state_params = None
         at_boundary = done % self.period == 0
         index = len(self.fences) - 1
@@ -378,7 +455,8 @@ class Run:
     def stop_profile(self) -> None:
         import jax
         self.profile["t1"] = time.time()
-        jax.profiler.stop_trace()
+        with self.parts.part("profile_stop_s"):
+            jax.profiler.stop_trace()
         self.profile_state = "done"
 
     @contextlib.contextmanager
@@ -572,22 +650,26 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     from msrflute_tpu.utils.backend import enable_compilation_cache
     cache_dir = enable_compilation_cache()
     compiles = compile_log()
+    parts = Parts(compiles)
+    parts["before_run_cell_s"] = time.time() - t_start
 
     model = find_module(root, "reference", doc["reference"]["model"])
-    weights = model.init(
-        np.random.default_rng(np.random.SeedSequence([int(seed), 1])),
-        cfg["model_config"])
+    with parts.part("weights_s"):
+        weights = model.init(
+            np.random.default_rng(np.random.SeedSequence([int(seed), 1])),
+            cfg["model_config"])
 
     work = tempfile.mkdtemp(prefix="bench_")
     try:
         data_dir = os.path.join(work, "data")
-        t0 = time.time()
-        load_generator(root, doc["data"]).write_splits(
-            data_dir, seed, doc["data"])
+        with parts.part("data_gen_s"):
+            load_generator(root, doc["data"]).write_splits(
+                data_dir, seed, doc["data"])
         say({"cell": name, "seed": int(seed), "compilation_cache": cache_dir,
-             "data_gen_s": time.time() - t0, "control": control})
+             "data_gen_s": parts["data_gen_s"], "control": control})
 
-        run = Run(cell, seconds, trace, work, weights, t_start, readings)
+        run = Run(cell, seconds, trace, work, weights, t_start, parts,
+                  readings)
         out_dir = os.path.join(work, "out")
         with run.watching():
             status = run_cli(cfg, doc["task"], data_dir, out_dir)
@@ -595,12 +677,18 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
             raise RuntimeError(
                 f"the trainer ended (status {status}) before the window "
                 f"closed: {len(run.fences)} fences")
+        t0 = time.time()
+        # the drain, the last saves and the trainer's exit
+        parts["after_window_s"] = t0 - run.fences[run.window_close]["ts"]
         device = device_report()  # the program's peak, before the reference
         if not readings:
             win = window_metrics(run)
             in_window = compiles.between(win["t_open"], win["t_close"])
+            parts["setup_s"] = win["setup_s"]
+            parts["window_s"] = win["window_s"]
         spans = read_spans(out_dir) if trace else []
         run.server = None  # the program's state is freed before the reference
+        parts["read_back_s"] = time.time() - t0
 
         t0 = time.time()
         fedround = load_module(os.path.join(BENCH_DIR, "reference",
@@ -615,19 +703,36 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         # `highest` above); every round of the first dispatch at the
         # backend's default precision, which is what the configurations
         # state and the timed program runs at
-        ref_check = fedround.run_rounds(
-            rounds=run.first_rounds[:1], precision="highest", **reference)[0]
-        refs_timed = fedround.run_rounds(
-            rounds=run.first_rounds, precision=None, **reference)
+        with parts.part("reference_check_s"), compared_never_timed():
+            ref_check = fedround.run_rounds(
+                rounds=run.first_rounds[:1], precision="highest",
+                **reference)[0]
+        # at the compiler's own effort: what the backend's default
+        # precision computes is the compiler's choice and moves with the
+        # effort (the ResNet's rounds at -1.0 read 0.23-0.26 in
+        # `timed_later_loss_gap` against the timed program's: PR 35)
+        with parts.part("reference_timed_s"):
+            refs_timed = fedround.run_rounds(
+                rounds=run.first_rounds, precision=None, **reference)
         reference_s = time.time() - t0
+        for call, results in (("reference_check", [ref_check]),
+                              ("reference_timed", refs_timed)):
+            for lap in results[0]["seconds"]:
+                parts[f"{call}_{lap}_s"] = sum(
+                    r["seconds"][lap] for r in results)
 
-        numbers = check.compare(
-            init_params=weights, ref_check=ref_check, refs_timed=refs_timed,
-            rounds=run.first_rounds, check_stats=run.check["stats"],
-            check_params=run.check["new_params"],
-            timed_first=run.fences[0],
-            timed_first_params=run.first_dispatch_params,
-            dp=doc["reference"].get("dp"))
+        leaf_gaps = {} if readings else None
+        with parts.part("compare_s"):
+            numbers = check.compare(
+                init_params=weights, ref_check=ref_check,
+                refs_timed=refs_timed, rounds=run.first_rounds,
+                check_stats=run.check["stats"],
+                check_params=run.check["new_params"],
+                timed_first=run.fences[0],
+                timed_first_params=run.first_dispatch_params,
+                dp=doc["reference"].get("dp"),
+                leaf_kinds=doc["reference"].get("leaf_kinds"),
+                leaf_gaps=leaf_gaps)
         if not readings:
             numbers += [
                 ("window_compiles", float(len(in_window))),
@@ -642,17 +747,22 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
 
         if readings:
             say({"reference_s": reference_s,
-                 "first_fence_s": run.fences[0]["ts"] - t_start})
+                 "first_fence_s": run.fences[0]["ts"] - t_start,
+                 "leaf_gaps": leaf_gaps})
             return closed({"correct": bool(correct), "readings": True,
                            "device": device}, verdicts)
         say({"window_s": win["window_s"], "dispatches": win["dispatches"],
              "rounds": win["rounds"],
              "round_samples": len(win["per_round_s"]),
              "reference_s": reference_s,
-             "window_compile_names": [c[2] for c in in_window],
+             "window_compile_names": [c.name for c in in_window],
              "compile_requests": len(compiles.compiles),
              "cache_hits": len(compiles.hits),
              "cache_misses": len(compiles.misses),
+             # [program, effort]: on a warm cache none at effort 0.0,
+             # the timed programs'
+             "cache_missed": [[c.name, c.effort] for c in compiles.compiles
+                              if c.missed],
              "first_fence_s": run.fences[0]["ts"] - t_start})
 
         result = {
@@ -667,7 +777,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
             result["metrics"] = end_to_end(win, name)
             return closed(result, verdicts)
 
-        reduced = trace_reduce.reduce_profile(run.profile, spans)
+        with parts.part("trace_reduce_s"):
+            reduced = trace_reduce.reduce_profile(run.profile, spans)
         say({"trace": reduced["summary"]})
         result["device"]["busy_s"] = reduced["busy_s"]
         result["device"]["window_s"] = reduced["window_s"]
@@ -681,14 +792,19 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         }
         metrics = {}
         elsewhere = kept_to_other_cells("per_layer", name)
-        for metric, reader in load_layer_metrics(root).items():
-            if metric in elsewhere:
-                continue
-            value = reader.read(ctx)
-            if value is not None and math.isfinite(value):
-                metrics[metric] = {"value": float(value),
-                                   "unit": reader.UNIT}
+        # a reader may run a program of its own (a model reference's
+        # operation count routes one batch): counted, never timed
+        with parts.part("layer_metrics_s"), compared_never_timed():
+            for metric, reader in load_layer_metrics(root).items():
+                if metric in elsewhere:
+                    continue
+                value = reader.read(ctx)
+                if value is not None and math.isfinite(value):
+                    metrics[metric] = {"value": float(value),
+                                       "unit": reader.UNIT}
         result["metrics"] = metrics
         return closed(result, verdicts)
     finally:
-        shutil.rmtree(work, ignore_errors=True)
+        with parts.part("cleanup_s"):
+            shutil.rmtree(work, ignore_errors=True)
+        say({"parts_s": parts})  # as far as the run got

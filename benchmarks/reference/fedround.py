@@ -36,6 +36,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import time
 
 import jax
 import jax.numpy as jnp
@@ -163,28 +164,17 @@ def _block_fn(forward, loss, sample_count, model_items, strategy_items):
     return jax.jit(block)
 
 
-def run_round(forward, model_config: dict, params: dict, batch: dict,
-              client_lr: float, server_lr: float, strategy: dict,
-              block: int = 1, precision: str | None = "highest",
-              loss=None, sample_count=None) -> dict:
-    """One round over ``batch`` = ``{"x": [K,S,B,...], "y": [K,S,B],
-    "sample_mask": [K,S,B], "client_mask": [K]}`` (numpy; a token task's
-    batch has ``tok_mask`` and may have no ``y``: every ``[K,S,B,...]``
-    array goes to ``loss``), ``block`` clients at a time (the last block
-    is padded with masked-out clients, so one program serves every
-    block).  ``loss`` / ``sample_count``: see ``seam``.  ``strategy`` = ``{"name":
-    "fedavg"}`` or ``{"name": "dga", "beta", "quant_bits",
-    "quant_quantile"}``.  ``precision`` = the matmul precision (``None``:
-    the backend's default, which is what the program runs at as
-    configured).  Returns host numpy: the aggregate, the new
-    weights, and per live client the train loss, sample count, weight and
-    pseudo-gradient norm."""
-    static = {k: v for k, v in strategy.items() if k != "quant_quantile"}
-    fn = _block_fn(forward, loss, sample_count,
-                   tuple(sorted(model_config.items())),
-                   tuple(sorted(static.items())))
+def _fetch(tree) -> dict:
+    return jax.tree.map(np.asarray, tree)
+
+
+def _round_on_device(fn, dev_params, batch: dict, client_lr: float,
+                     server_lr: float, quantile, block: int) -> tuple:
+    """One round from weights that are on the device already:
+    ``(aggregate, new_params)`` still on the device, and the round's
+    result without them (per live client, on the host, and the seconds
+    of its two parts)."""
     steps = step_arrays(batch)
-    quantile = jnp.float32(strategy.get("quant_quantile") or 0.0)
     live_all = (np.asarray(batch["client_mask"]) > 0).astype(np.float32)
     total_k = len(live_all)
     block = max(1, min(int(block), total_k))
@@ -199,31 +189,30 @@ def run_round(forward, model_config: dict, params: dict, batch: dict,
                 [part, np.zeros((short,) + part.shape[1:], part.dtype)])
         return part
 
-    with (jax.default_matmul_precision(precision) if precision
-          else contextlib.nullcontext()):
-        dev_params = jax.tree.map(jnp.asarray, params)
-        for lo in range(0, total_k, block):
-            live = padded(live_all, lo)
-            term, loss_sum, n, w, norm = fn(
-                dev_params,
-                {k: on_device(padded(v, lo)) for k, v in steps.items()},
-                jnp.asarray(live), jnp.float32(client_lr), quantile)
-            weighted = term if weighted is None else jax.tree.map(
-                jnp.add, weighted, term)
-            keep = live > 0
-            losses += np.asarray(loss_sum)[keep].tolist()
-            counts += np.asarray(n)[keep].tolist()
-            weights += np.asarray(w)[keep].tolist()
-            norms += np.asarray(norm)[keep].tolist()
-        total = max(sum(weights), 1e-12)
-        aggregate = jax.tree.map(lambda g: g / total, weighted)
-        new_params = jax.tree.map(lambda p, g: p - server_lr * g,
-                                  dev_params, aggregate)
-    return {"aggregate": jax.tree.map(np.asarray, aggregate),
-            "new_params": jax.tree.map(np.asarray, new_params),
-            "train_loss": np.asarray(losses),
-            "num_samples": np.asarray(counts),
-            "weight": np.asarray(weights), "pseudo_norm": np.asarray(norms)}
+    t0 = time.time()
+    for lo in range(0, total_k, block):
+        live = padded(live_all, lo)
+        term, loss_sum, n, w, norm = fn(
+            dev_params,
+            {k: on_device(padded(v, lo)) for k, v in steps.items()},
+            jnp.asarray(live), jnp.float32(client_lr), quantile)
+        weighted = term if weighted is None else jax.tree.map(
+            jnp.add, weighted, term)
+        keep = live > 0
+        losses += np.asarray(loss_sum)[keep].tolist()
+        counts += np.asarray(n)[keep].tolist()
+        weights += np.asarray(w)[keep].tolist()
+        norms += np.asarray(norm)[keep].tolist()
+    t1 = time.time()
+    total = max(sum(weights), 1e-12)
+    aggregate = jax.tree.map(lambda g: g / total, weighted)
+    new_params = jax.block_until_ready(jax.tree.map(
+        lambda p, g: p - server_lr * g, dev_params, aggregate))
+    return aggregate, new_params, {
+        "train_loss": np.asarray(losses), "num_samples": np.asarray(counts),
+        "weight": np.asarray(weights), "pseudo_norm": np.asarray(norms),
+        # the clients (the first block's compile with them), the server
+        "seconds": {"clients": t1 - t0, "server": time.time() - t1}}
 
 
 def run_rounds(forward, model_config: dict, params: dict, rounds: list,
@@ -231,19 +220,55 @@ def run_rounds(forward, model_config: dict, params: dict, rounds: list,
                precision: str | None = "highest", loss=None,
                sample_count=None) -> list:
     """The rounds of one dispatch, in turn: round ``r + 1`` starts from
-    round ``r``'s new weights.  ``rounds`` = the packed inputs, each with
-    its ``client_lr``, ``server_lr`` and (quantised payloads)
-    ``quant_quantile``.  Returns one ``run_round`` result per round."""
+    round ``r``'s new weights, which stay on the device between the two.
+
+    ``rounds`` = the packed inputs, each ``{"x": [K,S,B,...], "y":
+    [K,S,B], "sample_mask": [K,S,B], "client_mask": [K]}`` (numpy; a
+    token task's batch has ``tok_mask`` and may have no ``y``: every
+    ``[K,S,B,...]`` array goes to ``loss``) with its ``client_lr``,
+    ``server_lr`` and (quantised payloads) ``quant_quantile``; ``block``
+    clients at a time (the last block is padded with masked-out clients,
+    so one program serves every block).  ``loss`` / ``sample_count``: see
+    ``seam``.  ``strategy`` = ``{"name": "fedavg"}`` or ``{"name": "dga",
+    "beta", "quant_bits"}``.  ``precision`` = the matmul precision
+    (``None``: the backend's default, which is what the program runs at
+    as configured).
+
+    Returns one result per round, host numpy: per live client the train
+    loss, sample count, weight and pseudo-gradient norm, and of the trees
+    what the comparison reads and no more: ROUND 0's ``aggregate`` (as
+    the server optimizer gets it) and the LAST round's ``new_params``.  A
+    tree that nobody reads is neither fetched nor kept: at 0.5 B
+    parameters each is 1.9 GB through the host's link and on the host.
+    ``seconds``, on each result: the round by part (``upload`` and
+    ``fetch`` where it had one)."""
+    static = {k: v for k, v in strategy.items() if k != "quant_quantile"}
+    fn = _block_fn(forward, loss, sample_count,
+                   tuple(sorted(model_config.items())),
+                   tuple(sorted(static.items())))
     out = []
-    for inputs in rounds:
-        per_round = dict(strategy)
-        if per_round.get("quant_bits") is not None:
-            per_round["quant_quantile"] = inputs["quant_quantile"]
-        out.append(run_round(forward, model_config, params, inputs,
-                             inputs["client_lr"], inputs["server_lr"],
-                             per_round, block, precision, loss,
-                             sample_count))
-        params = out[-1]["new_params"]
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        t0 = time.time()
+        dev_params = jax.block_until_ready(
+            jax.tree.map(jnp.asarray, params))
+        upload = time.time() - t0
+        for r, inputs in enumerate(rounds):
+            quantile = jnp.float32(
+                (inputs.get("quant_quantile")
+                 if static.get("quant_bits") is not None else None) or 0.0)
+            aggregate, dev_params, result = _round_on_device(
+                fn, dev_params, inputs, inputs["client_lr"],
+                inputs["server_lr"], quantile, block)
+            t0 = time.time()
+            if r == 0:
+                result["aggregate"] = _fetch(aggregate)
+            del aggregate
+            if r == len(rounds) - 1:
+                result["new_params"] = _fetch(dev_params)
+            result["seconds"].update(
+                upload=upload if r == 0 else 0.0, fetch=time.time() - t0)
+            out.append(result)
     return out
 
 

@@ -248,26 +248,7 @@ def forward(params: dict, x, model_config: dict):
     return h @ params["embedding"].T
 
 
-def _compile_the_reference_cheaply() -> None:
-    """From here on this process compiles at XLA's lowest effort
-    (``exec_time_optimization_effort`` -1.0; the TPU's compiler only).
-    Called where the reference's loss is TRACED, which the harness does
-    after the trainer has returned (``harness.run_cell``, step 5): the
-    programs that follow are the reference's own, at ``highest`` and at
-    the default precision, whose results are compared and whose speed
-    nobody reads.  At XLA's default effort the two take 89 s and 26 s to
-    compile at this size, at -1.0 13 s each (my chip runs, PR 28), out of
-    the 360 s a run may take.  Nothing of the program under test is
-    compiled after this point, and nothing here runs before it.  (The
-    setting belongs in the harness, around its reference calls: PERF.md
-    section 7 asks a benchmark PR for that; this file can reach only
-    the moment its own loss is traced.)"""
-    if jax.default_backend() == "tpu":
-        jax.config.update("jax_exec_time_optimization_effort", -1.0)
-
-
 def loss(params: dict, batch: dict, model_config: dict):
-    _compile_the_reference_cheaply()
     return fedround.next_token_loss(forward, params, batch, model_config)
 
 

@@ -53,6 +53,8 @@ def main() -> int:
                               bool(args.trace), control=args.control,
                               t_start=T_START,
                               readings=bool(args.readings))
+    # the whole run on the harness's clock, its clean-up included
+    harness.say({"run_s": time.time() - T_START})
     print(json.dumps(result), flush=True)
     return 0
 

@@ -1,6 +1,6 @@
-"""``check.compare`` one leaf at a time: the numbers of the comparison
-over whole float64 trees (the function as it stood at PR 25, kept below
-as the oracle), digit for digit, and a host peak of two float64 leaves."""
+"""``check.compare`` leaf by leaf: the numbers of the comparison over
+whole float64 trees (the function as it stood at PR 25, kept below as the
+oracle), digit for digit, and a host peak of a few float64 leaves."""
 
 import os
 import sys
@@ -192,10 +192,36 @@ def test_leaf_by_leaf_gives_the_whole_tree_numbers(dp):
         assert a == b, (name, a, b)  # every digit
 
 
-def test_compare_holds_two_leaves_not_six_trees():
+def test_leaf_kinds_split_the_worst_leaf_number_and_nothing_else():
+    """``leaf_kinds``: the leaves whose path holds a kind's part are held
+    apart; the worst of both numbers is the one number without kinds,
+    each is the worst of its own leaves, every other number is as it
+    was."""
+    case = _case(np.random.default_rng(7))
+    plain = dict(check.compare(dp=None, **case))
+    gaps = {}
+    split = dict(check.compare(dp=None, leaf_kinds={"routed": ["/group1/"]},
+                               leaf_gaps=gaps, **case))
+    assert list(split)[3:5] == ["update_gap_worst_leaf",
+                                "update_gap_worst_leaf.routed"]
+    assert len(gaps) == 12 and sum("/group1/" in name for name in gaps) == 4
+    assert split["update_gap_worst_leaf.routed"] == max(
+        gap for name, gap in gaps.items() if "/group1/" in name)
+    assert split["update_gap_worst_leaf"] == max(
+        gap for name, gap in gaps.items() if "/group1/" not in name)
+    assert max(split["update_gap_worst_leaf"],
+               split["update_gap_worst_leaf.routed"]) == \
+        plain["update_gap_worst_leaf"] == max(gaps.values())
+    split.pop("update_gap_worst_leaf.routed")
+    assert {k: v for k, v in split.items()
+            if k != "update_gap_worst_leaf"} == \
+        {k: v for k, v in plain.items() if k != "update_gap_worst_leaf"}
+
+
+def test_compare_holds_a_few_leaves_not_six_trees():
     """About 10**7 elements in a dozen leaves: the peak of host memory
-    stays under three float64 copies of the largest LEAF (the whole-tree
-    form held five to six float64 trees)."""
+    stays under two float64 copies of each LEAF being worked on, and one
+    to spare (the whole-tree form held five to six float64 trees)."""
     case = _case(np.random.default_rng(6), leaves=12, size=840_000)
     sizes = [leaf.size for group in case["init_params"].values()
              for leaf in group.values()]
@@ -206,4 +232,7 @@ def test_compare_holds_two_leaves_not_six_trees():
         peak = tracemalloc.get_traced_memory()[1]
     finally:
         tracemalloc.stop()
-    assert peak < 3 * 8 * max(sizes), (peak, 8 * max(sizes), 8 * sum(sizes))
+    at_a_time = 2 * check.LEAVES_AT_A_TIME + 1
+    assert at_a_time < len(sizes)
+    assert peak < at_a_time * 8 * max(sizes), (peak, 8 * max(sizes),
+                                               8 * sum(sizes))

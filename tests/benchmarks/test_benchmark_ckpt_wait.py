@@ -49,10 +49,13 @@ def readers():
     return harness.load_layer_metrics(harness.BENCH_DIR)
 
 
-def test_the_entry_is_an_addition_at_the_end(readers):
+def test_the_entry_is_found_by_its_name(readers):
+    """By its name, not by its place: a later PR's entries go after it."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         bench = json.load(fh)
-    entry = bench["per_layer"][-1]
+    found = [m for m in bench["per_layer"] if m["name"] == METRIC]
+    assert len(found) == 1
+    entry, = found
     assert entry["name"] == METRIC and entry["unit"] == readers[METRIC].UNIT
     assert entry["source"] == "program_span"
     assert entry["moves"] == "clients_per_s"

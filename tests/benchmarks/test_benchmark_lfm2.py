@@ -73,6 +73,9 @@ def test_sound_run_is_correct_against_the_plain_round(sound_run):
     assert got["loss_gap"] < 1e-6 and got["update_diff"] < 1e-4
     assert got["timed_update_projection_gap"] < 1e-5
     assert got["window_compiles"] == 0
+    # the expert layers' leaves and the rest, each a number of its own
+    assert got["update_gap_worst_leaf.routed"] < 1e-4
+    assert got["update_gap_worst_leaf"] < 1e-4
     assert {"clients_per_s", "setup_s"} <= set(sound_run["metrics"])
 
 

@@ -221,18 +221,60 @@ def test_image_round_is_bit_identical_through_an_explicit_loss():
     def real_rows(step):
         return jnp.sum(step["sample_mask"])
 
+    rounds = [{**batch, "client_lr": 0.1, "server_lr": 1.0,
+               "quant_quantile": 0.5}]
     for strategy in ({"name": "fedavg"},
-                     {"name": "dga", "beta": 1.0, "quant_bits": 8,
-                      "quant_quantile": 0.5}):
-        args = (ref.forward, cfg, weights, batch, 0.1, 1.0, strategy, 2,
-                None)
-        default = fedround.run_round(*args)
-        explicit = fedround.run_round(*args, loss=classification,
-                                      sample_count=real_rows)
+                     {"name": "dga", "beta": 1.0, "quant_bits": 8}):
+        args = (ref.forward, cfg, weights, rounds, strategy, 2, None)
+        default, = fedround.run_rounds(*args)
+        explicit, = fedround.run_rounds(*args, loss=classification,
+                                        sample_count=real_rows)
         assert list(default["num_samples"]) == [8.0, 6.0, 4.0]
         import jax
+        # a one-round dispatch: its aggregate and its new weights both
+        assert set(default) == {"aggregate", "new_params", "train_loss",
+                                "num_samples", "weight", "pseudo_norm",
+                                "seconds"}
+        default.pop("seconds"), explicit.pop("seconds")
         for a, b in zip(jax.tree.leaves(default), jax.tree.leaves(explicit)):
             assert np.array_equal(a, b)
+
+
+def test_rounds_chained_on_the_device_give_the_bits_of_rounds_through_the_host():
+    """A dispatch of three rounds with the weights left on the device
+    between rounds: every number and the two trees that are fetched
+    (round 0's aggregate, the last round's weights) are, bit for bit,
+    those of three one-round calls chained through the host, and the
+    rounds between carry no tree."""
+    import jax
+    ref, fedround = _reference("cnn_femnist"), _reference("fedround")
+    cfg = MODELS["cnn_femnist"]
+    rng = np.random.default_rng(4)
+    weights = ref.init(rng, cfg)
+    clients, steps, rows = 3, 2, 4
+    rounds = [{
+        "x": rng.standard_normal((clients, steps, rows, 28, 28, 1)).astype(
+            np.float32),
+        "y": rng.integers(0, 62, size=(clients, steps, rows)),
+        "sample_mask": np.ones((clients, steps, rows), np.float32),
+        "client_mask": np.ones(clients, np.float32),
+        "client_lr": 0.1, "server_lr": lr, "quant_quantile": 0.5}
+        for lr in (1.0, 0.9, 0.8)]
+    strategy = {"name": "dga", "beta": 1.0, "quant_bits": 8}
+    args = (ref.forward, cfg)
+    together = fedround.run_rounds(*args, weights, rounds, strategy, 2, None)
+    apart, params = [], weights
+    for one in rounds:
+        apart += fedround.run_rounds(*args, params, [one], strategy, 2, None)
+        params = apart[-1]["new_params"]
+    trees = {"aggregate", "new_params"}
+    assert [trees & set(r) for r in together] == [
+        {"aggregate"}, set(), {"new_params"}]
+    for a, b in zip(together, apart):
+        for key in set(a) - {"seconds"}:
+            for x, y in zip(jax.tree.leaves(a[key]), jax.tree.leaves(b[key])):
+                assert np.array_equal(x, y), key
+        assert set(a["seconds"]) == {"upload", "clients", "server", "fetch"}
 
 
 def test_train_mfu_reads_a_model_references_own_operation_count():
