@@ -21,12 +21,17 @@ plain float32 form the benchmark compares this module with); in short,
 
 The parameter tree's names are a checkpoint contract and are the plain
 reference's (``layer_<i>/{norm_op, norm_ffn, conv | attn, mlp | moe}``).
+RMSNorm, the dense SwiGLU, the held experts, the blocked plain attention
+rows and the task are ``models/token_blocks.py``'s, shared with
+``models/mla_moe.py``; this file holds what is LFM2's own: the gated
+short convolution, grouped-query attention with QK-norm and rotate-half
+RoPE, the layer pattern and the tied head.
 
-Attention is a BLOCKED PLAIN path: ``attention_block`` query rows at a
-time against the keys up to the block's end, each block a
-``jax.checkpoint`` (the scores of a 4,096-token row, 32 x 4096 x 4096
-floats, never stand whole).  The Pallas flash kernel
-(``ops/pallas_attention.py``) takes equal head counts and its planner's
+Attention is a BLOCKED PLAIN path (``token_blocks._blocked_attention``):
+``attention_block`` query rows at a time against the keys up to the
+block's end, each block a ``jax.checkpoint`` (the scores of a
+4,096-token row, 32 x 4096 x 4096 floats, never stand whole).  The
+Pallas flash kernel (``ops/pallas_attention.py``) takes equal head counts and its planner's
 dense fallback does not fit beside a 1.9 GB tree; this model does not
 call it.  The sequence is padded to a whole number of blocks inside the
 module and the padding's logits are cut off again (causal: padding at
@@ -43,34 +48,12 @@ from __future__ import annotations
 from typing import Any, Dict, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
 
-from ..ops.moe import held_experts_ffn
 from .base import parse_dtype
-from .nlp import SequenceLMTask, _TokenDatasetMixin
-
-#: what the expert layers count, summed over layers and local steps
-#: (``ops.moe.held_experts_ffn``); the engine carries them to the packed
-#: round stats as ``ctr_<name>``
-COUNTERS = ("moe_pairs_held", "moe_max_load", "moe_pairs_dropped",
-            "moe_layer_steps")
-
-
-def _normal(std: float):
-    return nn.initializers.normal(std)
-
-
-class _RMSNorm(nn.Module):
-    eps: float
-
-    @nn.compact
-    def __call__(self, x):
-        weight = self.param("weight", nn.initializers.ones, (x.shape[-1],))
-        xf = x.astype(jnp.float32)
-        y = xf * jax.lax.rsqrt(
-            jnp.mean(xf * xf, axis=-1, keepdims=True) + self.eps) * weight
-        return y.astype(x.dtype)
+from .token_blocks import (COUNTERS, ExpertLMTask, _blocked_attention,
+                           _DenseMLP, _HeldExperts, _normal, _RMSNorm,
+                           check_held, rope_angles)
 
 
 class _GatedShortConv(nn.Module):
@@ -98,28 +81,12 @@ class _GatedShortConv(nn.Module):
 def _rope(x, theta: float):
     """Rotate-half RoPE on ``[B, L, heads, D]`` at positions 0..L-1,
     angles in float32."""
-    length, dim = x.shape[1], x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
-    angles = jnp.arange(length, dtype=jnp.float32)[:, None] * inv_freq[None]
+    angles = rope_angles(x.shape[1], x.shape[-1], theta)
     angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
-    half = dim // 2
+    half = x.shape[-1] // 2
     rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
     return (x * jnp.cos(angles).astype(x.dtype) +
             rotated * jnp.sin(angles).astype(x.dtype))
-
-
-def _attention_rows(q_rows, k, v, row0: int):
-    """One block of query rows ``[B, R, KV, G, D]`` from position
-    ``row0`` over the keys ``[B, M, KV, D]`` up to the block's end;
-    softmax in float32."""
-    scale = q_rows.shape[-1] ** -0.5
-    scores = jnp.einsum("brkgd,bmkd->bkgrm", q_rows, k).astype(
-        jnp.float32) * scale
-    rows = row0 + jnp.arange(q_rows.shape[1])[:, None]
-    cols = jnp.arange(k.shape[1])[None, :]
-    scores = jnp.where(cols <= rows, scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    return jnp.einsum("bkgrm,bmkd->brkgd", probs, v)
 
 
 class _GQAttention(nn.Module):
@@ -147,56 +114,9 @@ class _GQAttention(nn.Module):
         q, k = _rope(q, self.theta), _rope(k, self.theta)
         # query head h reads key-value head h // (heads / kv_heads)
         q = q.reshape(batch, length, kv, heads // kv, dim)
-        rows = jax.checkpoint(_attention_rows, static_argnums=(3,))
-        out = [rows(q[:, r0:r0 + self.block], k[:, :r0 + self.block],
-                    v[:, :r0 + self.block], r0)
-               for r0 in range(0, length, self.block)]
-        out = jnp.concatenate(out, axis=1).reshape(batch, length,
-                                                   heads * dim)
+        out = _blocked_attention(q, k, v, self.block).reshape(
+            batch, length, heads * dim)
         return out @ wo.astype(self.dtype)
-
-
-class _DenseMLP(nn.Module):
-    width: int
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, z):
-        hidden = z.shape[-1]
-        w1 = self.param("w1", _normal(0.02), (hidden, self.width))
-        w3 = self.param("w3", _normal(0.02), (hidden, self.width))
-        w2 = self.param("w2", _normal(0.02), (self.width, hidden))
-        return (jax.nn.silu(z @ w1.astype(self.dtype)) *
-                (z @ w3.astype(self.dtype))) @ w2.astype(self.dtype)
-
-
-class _HeldExperts(nn.Module):
-    """``experts_held`` of ``num_experts`` routed SwiGLU experts, from
-    ``expert_offset``; returns ``(y, counters)``."""
-    num_experts: int
-    experts_held: int
-    expert_offset: int
-    per_token: int
-    width: int
-    scaling: float
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, z):
-        hidden = z.shape[-1]
-        held = self.experts_held
-        router = self.param("router", _normal(hidden ** -0.5),
-                            (hidden, self.num_experts))
-        bias = self.param("select_bias", _normal(0.1), (self.num_experts,))
-        w1 = self.param("w1", _normal(0.02), (held, hidden, self.width))
-        w3 = self.param("w3", _normal(0.02), (held, hidden, self.width))
-        w2 = self.param("w2", _normal(0.02), (held, self.width, hidden))
-        y, counters = held_experts_ffn(
-            z.reshape(-1, hidden), router, bias, w1.astype(self.dtype),
-            w3.astype(self.dtype), w2.astype(self.dtype),
-            experts_per_token=self.per_token,
-            expert_offset=self.expert_offset, scaling=self.scaling)
-        return y.reshape(z.shape), counters
 
 
 class _Layer(nn.Module):
@@ -268,48 +188,13 @@ def layer_kinds(model_config) -> Tuple[Tuple[str, str], ...]:
                  for i, op in enumerate(ops))
 
 
-class LFM2Task(_TokenDatasetMixin, SequenceLMTask):
-    """Causal-LM task over :class:`_LFM2`; int token rows pass through
-    the dataset as they are.  The expert layers' counters leave through
-    the loss's aux (``aux["counters"]``) and the engine sums them into
-    the packed round stats."""
-
-    counter_names = COUNTERS
-
-    def init_params(self, rng: jax.Array):
-        # nothing of the tree depends on the length (RoPE, no position
-        # table): a short dummy keeps the init program small.  ONE
-        # program: run eagerly, the module's init compiles every
-        # primitive of the forward pass on its own (24 s at the
-        # published widths, none of it kept by the persistent cache)
-        dummy = jnp.zeros((1, 8), jnp.int32)
-        return jax.jit(self.module.init)(rng, dummy)["params"]
-
-    def _apply(self, params, inputs):
-        return self.module.apply({"params": params}, inputs)[0]
-
-    def loss(self, params, batch, rng=None, train=True):
-        inputs, targets, tok_mask = self._inputs_targets(batch)
-        logits, counters = self.module.apply({"params": params}, inputs)
-        value, aux = self._masked_xent(logits.astype(jnp.float32), targets,
-                                       tok_mask, batch)
-        if counters:
-            aux["counters"] = counters
-        return value, aux
-
-
-def make_lfm2_task(model_config) -> LFM2Task:
+def make_lfm2_task(model_config) -> ExpertLMTask:
     layers = layer_kinds(model_config)
     hidden = int(model_config["hidden_size"])
     heads = int(model_config["num_attention_heads"])
     moe = any(ffn == "moe" for _, ffn in layers)
     num_experts = int(model_config.get("num_experts", 0) or 0)
-    held = int(model_config.get("experts_held", num_experts) or 0)
-    offset = int(model_config.get("expert_offset", 0) or 0)
-    if moe and not 0 < held <= num_experts - offset:
-        raise ValueError(
-            f"model_config: experts_held={held} from expert_offset={offset} "
-            f"does not lie within num_experts={num_experts}")
+    held, offset = check_held(model_config, num_experts) if moe else (0, 0)
     cfg = tuple(sorted({
         "dtype": parse_dtype(model_config),
         "norm_eps": float(model_config.get("norm_eps", 1e-5)),
@@ -335,8 +220,9 @@ def make_lfm2_task(model_config) -> LFM2Task:
     module = _LFM2(vocab_size=int(model_config["vocab_size"]),
                    hidden_size=hidden, layers=layers, cfg=cfg,
                    remat=bool(model_config.get("remat", False)))
-    task = LFM2Task(module, seq_len=int(model_config.get("seq_len", 4096)),
-                    name="lfm2_moe")
+    task = ExpertLMTask(module,
+                        seq_len=int(model_config.get("seq_len", 4096)),
+                        name="lfm2_moe")
     if not moe:
         task.counter_names = ()
     return task

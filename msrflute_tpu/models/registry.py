@@ -118,3 +118,8 @@ def _load_builtins() -> None:
         TASK_REGISTRY.setdefault("LFM2_MOE", lfm2.make_lfm2_task)
     except ImportError:
         pass
+    try:
+        from . import mla_moe
+        TASK_REGISTRY.setdefault("MLA_MOE", mla_moe.make_mla_moe_task)
+    except ImportError:
+        pass

@@ -324,14 +324,22 @@ def _grouped_matmul_bwd(saved, dy):
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
 
 
+#: the gate's denominator where the caller gives none: LFM2's published
+#: form.  It is the caller's to give: a model whose published form has
+#: another (DeepSeek-V3's block: 1e-20) passes its own
+ROUTE_EPS = 1e-6
+
+
 def route_tokens(z, router_w, select_bias, experts_per_token: int,
-                 scaling: float = 1.0):
+                 scaling: float = 1.0, eps: float = ROUTE_EPS):
     """Sigmoid routing with a selection bias over ALL experts:
     ``(chosen [T, k] int32, gate [T, k] float32)``.  The logits are
     float32 at ``highest`` whatever the context (a choice that flips on
     rounding is a discrete event); the bias enters the choice only and
     gets no gradient; the gate is renormalised over all chosen experts,
-    held here or not."""
+    held here or not: ``scaling * s_i / (sum of chosen s + eps)``, with
+    the CALLER's ``eps`` (the models' published forms differ:
+    ``ROUTE_EPS``)."""
     logits = jnp.matmul(z.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
@@ -339,7 +347,7 @@ def route_tokens(z, router_w, select_bias, experts_per_token: int,
         scores + lax.stop_gradient(select_bias.astype(jnp.float32)),
         experts_per_token)
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    gate = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-6) * scaling
+    gate = picked / (jnp.sum(picked, axis=-1, keepdims=True) + eps) * scaling
     return chosen, gate
 
 
@@ -438,17 +446,28 @@ tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
 
 def held_experts_ffn(z, router_w, select_bias, w1, w3, w2, *,
                      experts_per_token: int, expert_offset: int = 0,
-                     scaling: float = 1.0):
+                     scaling: float = 1.0, route_eps: float | None = None):
     """The held experts' part of a routed SwiGLU layer for tokens
     ``z [T, D]``: ``sum over chosen AND held i of g_i E_i(z)``.
     ``w1``/``w3``: ``[held, D, H]``, ``w2``: ``[held, H, D]``,
-    ``router_w``: ``[D, num_experts]``.  Returns ``(y [T, D],
-    counters)``; the counters (float32 scalars) are what the telemetry
-    reads: pairs on held experts, the largest held expert's load, and
-    pairs without a row (0 by construction)."""
+    ``router_w``: ``[D, num_experts]``.  ``route_eps``: the epsilon of
+    the gate's denominator, the caller's to give (None: ``route_tokens``'
+    own, ``ROUTE_EPS``).  Returns ``(y [T, D], counters)``; the counters
+    (float32 scalars) are what the telemetry reads: pairs on held experts
+    (``moe_pairs_held``), the largest held expert's load
+    (``moe_max_load``), pairs without a row (``moe_pairs_dropped``, 0 by
+    construction), 1 for this layer-step (``moe_layer_steps``) and the
+    tiles of ``TILE_ROWS`` rows that the grouped products ran
+    (``moe_tiles_active``: every held expert's pairs rounded up to whole
+    tiles, at least one each; ``moe_pairs_held`` over its rows is the
+    share of them that are real pairs)."""
     held_n = w1.shape[0]
-    chosen, gate = route_tokens(z, router_w, select_bias, experts_per_token,
-                                scaling)
+    # positional, and the epsilon only where the caller gave one: the
+    # benchmark's planted routing faults replace ``route_tokens`` with
+    # functions of these five arguments
+    chosen, gate = route_tokens(
+        z, router_w, select_bias, experts_per_token, scaling,
+        **({} if route_eps is None else {"eps": route_eps}))
     row_of_pair, held, pair_of_row, tile_expert, n_active, counts = \
         plan_pairs(chosen, held_n, expert_offset)
     x_sorted = rows_of_tokens(z, pair_of_row, row_of_pair, held)
@@ -463,5 +482,6 @@ def held_experts_ffn(z, router_w, select_bias, w1, w3, w2, *,
     counters = {"moe_pairs_held": on_held,
                 "moe_max_load": jnp.max(counts).astype(jnp.float32),
                 "moe_pairs_dropped": on_held - placed,
-                "moe_layer_steps": jnp.ones((), jnp.float32)}
+                "moe_layer_steps": jnp.ones((), jnp.float32),
+                "moe_tiles_active": n_active.astype(jnp.float32)}
     return y, counters

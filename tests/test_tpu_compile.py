@@ -249,3 +249,55 @@ def test_expert_layer_compiles_for_v5e_at_published_widths(
     # scores into a flat [tokens * experts], stays a scatter
     import re
     assert not re.search(r"= (f32|bf16)\[\d+,\d+\]\S* scatter\(", text)
+
+
+# ----------------------------------------------------------------------
+# the MLA-MoE task's whole step at Kanana-2-30B-A3B's widths (PR 36)
+# ----------------------------------------------------------------------
+def test_mla_moe_step_compiles_for_v5e_at_published_widths(chip,
+                                                           monkeypatch):
+    """One local step of ``experiments/mla_moe/config.yaml`` as the cell
+    runs it (a 4,096-token row, ``remat``, attention blocks of 2,048
+    rows; forward, backward and the SGD update of the 0.425 B tree),
+    traced under ``highest`` at the effort of what is compared and never
+    timed, as the benchmark's check program is: the expert layer's three
+    kernels are in the program (``tpu_custom_call``) under their stable
+    names, the four mechanisms under their scopes, and the step's scratch
+    fits beside the five copies of the tree."""
+    import yaml
+    from jax._src import config as jax_config
+
+    from msrflute_tpu.models import make_task
+    from msrflute_tpu.ops import moe
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "experiments", "mla_moe",
+                           "config.yaml")) as fh:
+        mc = yaml.safe_load(fh)["model_config"]
+    task = make_task({**mc, "remat": True, "attention_block": 2048})
+    shapes = jax.eval_shape(task.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        shapes)
+    batch = {"x": jax.ShapeDtypeStruct((1, mc["seq_len"]), jnp.int32,
+                                       sharding=chip),
+             "sample_mask": jax.ShapeDtypeStruct((1,), jnp.float32,
+                                                 sharding=chip)}
+
+    def step(p, b):
+        (loss, aux), grads = jax.value_and_grad(
+            lambda q: task.loss(q, b, None, True)[:2], has_aux=True)(p)
+        return (jax.tree.map(lambda a, g: a - 0.1 * g, p, grads), loss,
+                aux["counters"])
+
+    with jax.default_matmul_precision("highest"), \
+            jax_config.exec_time_optimization_effort(-1.0):
+        compiled = jax.jit(step).lower(params, batch).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for name in (moe.GMM_NAME, moe.GMM_T_NAME, moe.TGMM_NAME, "mla_proj",
+                 "mla_attn_core", "shared_expert", "routed_experts"):
+        assert name in text, name
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= 424_961_024 * 4
+    assert memory.temp_size_in_bytes < 4 * 2 ** 30
