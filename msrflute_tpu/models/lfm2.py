@@ -21,21 +21,24 @@ plain float32 form the benchmark compares this module with); in short,
 
 The parameter tree's names are a checkpoint contract and are the plain
 reference's (``layer_<i>/{norm_op, norm_ffn, conv | attn, mlp | moe}``).
-RMSNorm, the dense SwiGLU, the held experts, the blocked plain attention
-rows and the task are ``models/token_blocks.py``'s, shared with
+RMSNorm, the dense SwiGLU, the held experts, the causal attention core
+and the task are ``models/token_blocks.py``'s, shared with
 ``models/mla_moe.py``; this file holds what is LFM2's own: the gated
 short convolution, grouped-query attention with QK-norm and rotate-half
 RoPE, the layer pattern and the tied head.
 
-Attention is a BLOCKED PLAIN path (``token_blocks._blocked_attention``):
-``attention_block`` query rows at a time against the keys up to the
-block's end, each block a ``jax.checkpoint`` (the scores of a
-4,096-token row, 32 x 4096 x 4096 floats, never stand whole).  The
-Pallas flash kernel (``ops/pallas_attention.py``) takes equal head counts and its planner's
-dense fallback does not fit beside a 1.9 GB tree; this model does not
-call it.  The sequence is padded to a whole number of blocks inside the
-module and the padding's logits are cut off again (causal: padding at
-the end changes nothing before it).
+The attention core is ``token_blocks.causal_attention`` (scope
+``gqa_attn_core``): the tiled Pallas kernels of
+``ops/pallas_attention.py`` wherever a compiled kernel applies — query
+head ``h`` reads key-value head ``h // 4`` through the block index,
+``dk``/``dv`` are summed over the group inside the kernel, and the
+scores of a 4,096-token row, 32 x 4096 x 4096 floats, never leave VMEM —
+and elsewhere (the CPU; GSPMD outside ``shard_map``) the blocked plain
+path: ``attention_block`` query rows at a time against the keys up to
+the block's end, each block a ``jax.checkpoint``.  The sequence is
+padded to a whole number of blocks inside the module and the padding's
+logits are cut off again (causal: padding at the end changes nothing
+before it).
 
 ``model_config.dtype: bfloat16`` computes activations and matmul
 operands in bfloat16 over float32 master weights (norms, the router and
@@ -48,12 +51,13 @@ from __future__ import annotations
 from typing import Any, Dict, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from .base import parse_dtype
-from .token_blocks import (COUNTERS, ExpertLMTask, _blocked_attention,
-                           _DenseMLP, _HeldExperts, _normal, _RMSNorm,
-                           check_held, rope_angles)
+from .token_blocks import (COUNTERS, ExpertLMTask, _DenseMLP, _HeldExperts,
+                           _normal, _RMSNorm, causal_attention, check_held,
+                           rope_angles)
 
 
 class _GatedShortConv(nn.Module):
@@ -114,9 +118,10 @@ class _GQAttention(nn.Module):
         q, k = _rope(q, self.theta), _rope(k, self.theta)
         # query head h reads key-value head h // (heads / kv_heads)
         q = q.reshape(batch, length, kv, heads // kv, dim)
-        out = _blocked_attention(q, k, v, self.block).reshape(
-            batch, length, heads * dim)
-        return out @ wo.astype(self.dtype)
+        with jax.named_scope("gqa_attn_core"):
+            out = causal_attention(q, k, v, self.block)
+        return out.reshape(batch, length, heads * dim) @ \
+            wo.astype(self.dtype)
 
 
 class _Layer(nn.Module):

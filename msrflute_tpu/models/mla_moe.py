@@ -31,13 +31,16 @@ wkv_b, wo}, mlp | moe + shared}``, ``embedding``, ``norm_emb``,
 ``head``).  The layers are unrolled, not one scanned body over stacked
 leaves: the cold run fits its limit so (PERF.md section 6, PR 36).
 
-Attention is the BLOCKED PLAIN path of ``models/token_blocks.py`` (a
-group of one query head a key head, the shared rotary key broadcast to
-every head, value heads narrower than key heads); the Pallas flash
-kernel (``ops/pallas_attention.py``) has one head width for queries,
-keys and values and is not called.  ``jax.named_scope``s ``mla_proj``,
-``mla_attn_core``, ``shared_expert`` and ``routed_experts`` mark the
-mechanisms in the compiled program's metadata (docs/observability.md).
+The attention core (a group of one query head a key head, the shared
+rotary key broadcast to every head, value heads narrower than key
+heads) is ``models/token_blocks.causal_attention``: the tiled Pallas
+kernels of ``ops/pallas_attention.py`` (a value width of its own; scores,
+mask and softmax stay in VMEM, forward and backward) wherever a compiled
+kernel applies, the blocked plain path at ``attention_block`` rows
+elsewhere (the CPU; GSPMD outside ``shard_map``).  ``jax.named_scope``s
+``mla_proj``, ``mla_attn_core``, ``shared_expert`` and ``routed_experts``
+mark the mechanisms in the compiled program's metadata
+(docs/observability.md).
 
 ``model_config.dtype: bfloat16`` computes activations and matmul
 operands in bfloat16 over float32 master weights (norms, the router and
@@ -54,8 +57,8 @@ import jax
 import jax.numpy as jnp
 
 from .base import parse_dtype
-from .token_blocks import (ExpertLMTask, _blocked_attention, _DenseMLP,
-                           _HeldExperts, _normal, _RMSNorm, check_held,
+from .token_blocks import (ExpertLMTask, _DenseMLP, _HeldExperts, _normal,
+                           _RMSNorm, causal_attention, check_held,
                            rope_angles)
 
 #: the gate's denominator as the model's published form has it
@@ -118,7 +121,7 @@ class _LatentAttention(nn.Module):
             v = up[..., nope:]
         with jax.named_scope("mla_attn_core"):
             # a group of one: every query head has a key head of its own
-            out = _blocked_attention(q[:, :, :, None, :], k, v, self.block)
+            out = causal_attention(q[:, :, :, None, :], k, v, self.block)
         with jax.named_scope("mla_proj"):
             return out.reshape(batch, length, heads * v_dim) @ \
                 wo.astype(self.dtype)

@@ -1,8 +1,9 @@
 """What the token models with held experts have in common
 (``models/lfm2.py``, ``models/mla_moe.py``): RMSNorm, the dense SwiGLU,
-the held share of a routed expert layer, RoPE's angles, one block of
-plain attention rows, and the causal-LM task that carries the expert
-layers' counters.
+the held share of a routed expert layer, RoPE's angles, the causal
+attention core (the tiled kernels of ``ops/pallas_attention.py``, or
+blocks of plain attention rows where no compiled kernel applies), and
+the causal-LM task that carries the expert layers' counters.
 
 The modules' parameter names (``weight``; ``w1``/``w3``/``w2``;
 ``router``/``select_bias``/``w1``/``w3``/``w2``) are part of the two
@@ -19,6 +20,9 @@ import jax
 import jax.numpy as jnp
 
 from ..ops.moe import held_experts_ffn
+from ..ops.pallas_attention import (causal_flash_attention,
+                                    record_attention_path)
+from ..ops.pallas_kernels import compiled_kernels_apply
 from .nlp import SequenceLMTask, _TokenDatasetMixin
 
 #: what the expert layers count, summed over layers and local steps
@@ -72,12 +76,35 @@ def _blocked_attention(q, k, v, block: int):
     and ``v [B, L, KV, Dv]``, ``block`` query rows at a time against the
     keys up to the block's end, each block a ``jax.checkpoint`` (the
     scores of a long row never stand whole); ``L`` a multiple of
-    ``block``.  Returns ``[B, L, KV, G, Dv]``."""
+    ``block``.  Returns ``[B, L, KV, G, Dv]``.  The plain path: what
+    runs where the kernels do not, and the statement they are tested
+    against."""
     rows = jax.checkpoint(_attention_rows, static_argnums=(3,))
     out = [rows(q[:, r0:r0 + block], k[:, :r0 + block], v[:, :r0 + block],
                 r0)
            for r0 in range(0, q.shape[1], block)]
     return jnp.concatenate(out, axis=1)
+
+
+def causal_attention(q, k, v, block: int, interpret=None):
+    """The token models' causal core, ``q [B, L, KV, G, D]`` over
+    ``k [B, L, KV, D]`` and ``v [B, L, KV, Dv]`` -> ``[B, L, KV, G, Dv]``:
+    the tiled kernels of ``ops/pallas_attention.py`` wherever a compiled
+    kernel applies (scores, mask and softmax stay in VMEM, forward and
+    backward; no ``jax.checkpoint``: the kernels' ``custom_vjp`` saves
+    ``q``, ``k``, ``v``, ``out``, ``lse``), else :func:`_blocked_attention`
+    at ``block`` rows (the CPU; GSPMD outside ``shard_map``).  Head
+    widths and the group size are shapes: one path for both models.
+    ``interpret=True`` forces the kernels through the interpreter
+    (tests).  The trace says which path it took (``attention_path``)."""
+    batch, length, kv, group, dim = q.shape
+    if interpret is None and not compiled_kernels_apply():
+        record_attention_path("plain", (batch, length, kv * group, dim),
+                              k.shape, v.shape, block, length)
+        return _blocked_attention(q, k, v, block)
+    out = causal_flash_attention(q.reshape(batch, length, kv * group, dim),
+                                 k, v, interpret=interpret)
+    return out.reshape(batch, length, kv, group, v.shape[-1])
 
 
 class _DenseMLP(nn.Module):

@@ -1,25 +1,49 @@
 """Pallas flash attention — the long-context hot op, tiled for the MXU.
 
 Net-new vs the reference (FLUTE has no attention models beyond HF BERT and
-no long-context machinery, SURVEY.md §5.7).  This is the TPU-native
-answer for the RingLM family: exact attention computed blockwise in VMEM
-with an online softmax, O(L) memory instead of the O(L^2) score
-materialization of the jnp path (``models/ringlm.py`` local mode).  Both
-passes are Pallas kernels (FlashAttention-2 style tiling):
+no long-context machinery, SURVEY.md §5.7).  Exact attention computed
+blockwise in VMEM with an online softmax, O(L) memory instead of the
+O(L^2) score materialization of a jnp path.  Two families call it: the
+RingLM family (``models/ringlm.py``, ``ops/ring_attention.py``: one head
+width, a key head a query head, global offsets, the ``lse`` cotangent,
+behind the planner below) and the token models with held experts
+(``models/token_blocks.causal_attention``: a value width of its own,
+grouped key-value heads, static blocks, no planner:
+:func:`causal_flash_attention`).  Three kernels, under stable names in
+the device trace (``attn_flash_fwd``, ``attn_flash_dq``,
+``attn_flash_dkv``; FlashAttention-2 style tiling):
 
 - forward: grid ``(B, H, Lq/block_q, Lk/block_k)`` with the key/value
   block index INNERMOST and ``arbitrary`` semantics — mosaic pipelines
-  the next K/V block's HBM→VMEM fetch under the current block's MXU
-  work, and the ``(m, l, acc)`` online-softmax carry lives in VMEM
-  scratch across the inner sweep.  VMEM residency is O(block), never
-  O(L): the round-4 kernels loaded the WHOLE key sequence per program
-  (the kv BlockSpec spanned padded Lk), which both capped L at VMEM
-  size and serialized HBM fetches behind compute — the measured reason
-  dense beat flash at every length.
+  the next K/V block's HBM→VMEM fetch under the current block's work,
+  and the ``(m, l, acc)`` online-softmax carry lives in VMEM scratch
+  across the inner sweep.  VMEM residency is O(block), never O(L).
 - backward: ``dq`` on the same grid shape; ``dk``/``dv`` on
-  ``(B, H, Lk/block_k, Lq/block_q)`` (query blocks innermost), both
-  accumulating into VMEM scratch and recomputing probabilities from the
+  ``(B, KV, Lk/block_k, group * Lq/block_q)`` (the query blocks of every
+  query head of the key-value head's group innermost, summed into one
+  accumulator), on the TRANSPOSED score tile so that no product
+  transposes its left operand.  Both recompute probabilities from the
   saved ``lse`` (no O(L^2) residuals).
+- query head ``h`` reads key-value head ``h // group`` through the block
+  index; values (and ``out``, ``dO``, ``dv``) have a width of their own.
+- under ``causal`` the swept operand's block index stops at the
+  diagonal: blocks beyond it are neither fetched nor computed, tiles
+  wholly under it run WITHOUT a mask (no iota, compare or select), and
+  only the tiles the diagonal crosses (and a padded last key block) pay
+  for one.
+- the caller scales ``q`` once; the row statistics ride lane-replicated
+  ``[rows, 128]`` (whole vregs repeated to the tile's width, no lane
+  broadcast) in the forward and ``dq`` kernels and as rows ``[1, bq]`` in
+  the ``dk``/``dv`` kernel.
+
+Precision: the products' operands are given in ONE dtype a call
+(``mxu``).  The planner's API keeps float32 operands at the context's
+precision; the token models' path takes :func:`context_mxu_dtype`:
+bfloat16 operands (one MXU pass, float32 accumulation — what the TPU's
+default precision does to a float32 einsum) unless the context asks for
+more, float32 contracted in full under ``highest``.  Softmax, its
+running maximum and sum, ``lse`` and the accumulators are float32
+always.
 
 Causal masking is GLOBAL-position based: dynamic ``q_offset``/``k_offset``
 scalars (SMEM scalar-prefetch) shift the row/column ids, which is what
@@ -50,21 +74,23 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .moe import _dot_precision
 from .pallas_kernels import _resolve_interpret, compiled_kernels_apply
 
 _LANES = 128
-# row statistics (lse/delta/glse) ride lane-broadcast over the trailing
-# dim.  PR-12 retile: the stat streams use FULL (8, 128)-aligned tiles —
-# the old 8-lane blocks saved VMEM but made every stat load/store a
-# sub-tile access, which mosaic serviced with masked sub-lane ops on the
-# hot dq/dkv inner loops (device truth measured the kernel at 0.53x of
-# dense at seq 2048 before the retile).  VMEM cost per grid step is
-# 3 stat blocks x block_q x 128 x 4B — comparable to one head-dim block,
-# well inside budget at the block sizes the planner picks.
+# row statistics (lse/delta) ride lane-replicated over the trailing dim.
+# PR-12 retile: the stat streams use FULL (8, 128)-aligned tiles — the
+# old 8-lane blocks saved VMEM but made every stat load/store a sub-tile
+# access, which mosaic serviced with masked sub-lane ops on the hot dq
+# inner loop (device truth measured the kernel at 0.53x of dense at seq
+# 2048 before the retile).  VMEM cost per grid step is 2 stat blocks x
+# block_q x 128 x 4B — comparable to one head-dim block.
 _STAT_LANES = _LANES
 _NEG = -1e30  # "minus infinity" that survives exp/max without NaNs
 #: default kernel tile when the caller pins blocks explicitly
 _DEF_BLOCK = 128
+#: the token models' tile (:func:`causal_blocks`)
+_CAUSAL_BLOCK = 512
 
 
 def _pad_axis(x, axis, to):
@@ -80,24 +106,78 @@ def _ceil_to(n, m):
     return int(np.ceil(n / m)) * m
 
 
+#: stable kernel names (the trace's operation names; the benchmark's
+#: ``attn_kernel_*`` readers find the kernels by them)
+FWD_NAME = "attn_flash_fwd"
+DQ_NAME = "attn_flash_dq"
+DKV_NAME = "attn_flash_dkv"
+
+#: ``a @ b.T``: every product of the three kernels contracts the LAST
+#: dims of both operands or is a plain ``a @ b`` — none transposes its
+#: left operand (the dk/dv kernel works on the TRANSPOSED score tile)
+_NT = (((1,), (1,)), ((), ()))
+
+
+def _to_width(stat, width):
+    """A lane-replicated row statistic ``[rows, _STAT_LANES]`` at
+    ``width`` lanes: whole vregs repeated, no lane broadcast (the
+    fallback serves the interpreter's small test tiles only)."""
+    if width == _STAT_LANES:
+        return stat
+    if width % _STAT_LANES == 0:
+        return pltpu.repeat(stat, width // _STAT_LANES, axis=1)
+    return jnp.broadcast_to(stat[:, :1], (stat.shape[0], width))
+
+
+def _tile_mask(shape, q_axis, q_lo, k_lo, k_first, l_k, causal, pad_k):
+    """Which entries of a score tile count: key rows that exist
+    (``pad_k``: the last key block is padded) and, under ``causal``,
+    global key position <= global query position.  ``q_axis`` is the
+    tile's query axis (1 for the transposed tile of the dk/dv kernel)."""
+    q_ids = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_ids = jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    mask = None
+    if pad_k:
+        mask = k_first + k_ids < l_k
+    if causal:
+        seen = q_lo + q_ids >= k_lo + k_ids
+        mask = seen if mask is None else jnp.logical_and(mask, seen)
+    return mask
+
+
+def _each_tile(accumulate, *, causal, pad_k, q_lo, k_lo, block_q, block_k,
+               last_k):
+    """``accumulate(masked)`` for this grid step's tile: not at all where
+    every key lies above the (global) diagonal; without a mask where every
+    entry counts (the bulk of a long causal row: no iota, compare or
+    select there); with the mask on the diagonal and on a padded last key
+    block."""
+    if causal:
+        visible = k_lo <= q_lo + block_q - 1
+        whole = k_lo + block_k - 1 <= q_lo      # implies visible
+        if pad_k:
+            whole = jnp.logical_and(whole, jnp.logical_not(last_k))
+        pl.when(whole)(lambda: accumulate(False))
+        pl.when(jnp.logical_and(visible, jnp.logical_not(whole)))(
+            lambda: accumulate(True))
+    elif pad_k:
+        pl.when(jnp.logical_not(last_k))(lambda: accumulate(False))
+        pl.when(last_k)(lambda: accumulate(True))
+    else:
+        accumulate(False)
+
+
 # ----------------------------------------------------------------------
 # forward
 # ----------------------------------------------------------------------
-def _rows(stat_ref):
-    """Recover a per-row vector from a lane-broadcast [rows, _STAT_LANES]
-    scratch/stream (all lanes hold the same value)."""
-    return jnp.max(stat_ref[...], axis=-1)
-
-
-def _bcast_rows(vec, rows):
-    return jax.lax.broadcast_in_dim(vec, (rows, _STAT_LANES), (0,))
-
-
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
-                m_s, l_s, acc_s, *, causal, scale, block_q, block_k,
-                l_q, l_k, num_k):
+                m_s, l_s, acc_s, *, causal, block_q, block_k, l_k, num_k,
+                cdt):
     qi, kj = pl.program_id(2), pl.program_id(3)
-    q_off, k_off = offs_ref[0], offs_ref[1]
+    q_lo = offs_ref[0] + qi * block_q
+    k_lo = offs_ref[1] + kj * block_k
+    pad_k = num_k * block_k != l_k
+    precision = _dot_precision(cdt)
 
     @pl.when(kj == 0)
     def _init():
@@ -105,204 +185,185 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_s[...] = jnp.zeros_like(l_s)
         acc_s[...] = jnp.zeros_like(acc_s)
 
-    def _accumulate():
-        q = q_ref[0, 0, :, :].astype(jnp.float32)       # [bq, D]
-        k_blk = k_ref[0, 0, :, :].astype(jnp.float32)   # [bk, D]
-        v_blk = v_ref[0, 0, :, :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        k_loc = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_loc < l_k
-        if causal:
-            q_pos = q_off + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, q_pos >= k_off + k_loc)
-        s = jnp.where(mask, s, _NEG)
-        m = _rows(m_s)
-        l = _rows(l_s)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1))
-        # mask p explicitly: for fully-masked rows s == m_new == _NEG and
-        # exp(0) would resurrect the masked entries
-        p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m - m_new)
-        m_s[...] = jax.lax.broadcast_in_dim(m_new, m_s.shape, (0,))
-        l_s[...] = jax.lax.broadcast_in_dim(
-            l * corr + jnp.sum(p, axis=1), l_s.shape, (0,))
-        acc_s[...] = acc_s[...] * corr[:, None] + jnp.dot(
-            p, v_blk, preferred_element_type=jnp.float32)
+    def accumulate(masked):
+        q = q_ref[0, 0].astype(cdt)          # [bq, D], scaled by the caller
+        k_blk = k_ref[0, 0].astype(cdt)      # [bk, D]
+        v_blk = v_ref[0, 0].astype(cdt)      # [bk, Dv]
+        s = jax.lax.dot_general(q, k_blk, _NT, precision=precision,
+                                preferred_element_type=jnp.float32)
+        if masked:
+            mask = _tile_mask(s.shape, 0, q_lo, k_lo, kj * block_k, l_k,
+                              causal, pad_k)
+            s = jnp.where(mask, s, _NEG)
+        m_prev, l_prev = m_s[...], l_s[...]  # lane-replicated [bq, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - _to_width(m_new, block_k))
+        if masked:
+            # rows with every entry masked have s == m_new == _NEG, and
+            # exp(0) would resurrect them
+            p = jnp.where(mask, p, 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        m_s[...] = m_new
+        l_s[...] = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
+        acc_s[...] = acc_s[...] * _to_width(corr, acc_s.shape[1]) + \
+            jnp.dot(p.astype(cdt), v_blk, precision=precision,
+                    preferred_element_type=jnp.float32)
 
-    if causal:
-        # whole key blocks above the (global) diagonal contribute nothing;
-        # their fetch still pipelines but the MXU work is skipped
-        @pl.when(k_off + kj * block_k <= q_off + (qi + 1) * block_q - 1)
-        def _():
-            _accumulate()
-    else:
-        _accumulate()
+    _each_tile(accumulate, causal=causal, pad_k=pad_k, q_lo=q_lo, k_lo=k_lo,
+               block_q=block_q, block_k=block_k, last_k=kj == num_k - 1)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
-        m = _rows(m_s)
-        l = _rows(l_s)
-        out = acc_s[...] / jnp.maximum(l, 1e-30)[:, None]
-        o_ref[0, 0, :, :] = out.astype(o_ref.dtype)
-        lse = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), _NEG)
+        m, l = m_s[...], l_s[...]
+        safe = jnp.maximum(l, 1e-30)
+        o_ref[0, 0] = (acc_s[...] / _to_width(safe, acc_s.shape[1])
+                       ).astype(o_ref.dtype)
         # TPU mosaic requires the last two BLOCK dims be (8k, 128m)-
-        # aligned, so the per-row lse is stored lane-broadcast as
-        # [bq, _STAT_LANES] (same trick as jax's own tpu flash kernel)
-        lse_ref[0, 0, :, :] = _bcast_rows(lse, block_q)
+        # aligned, so the per-row lse is stored lane-replicated as
+        # [bq, _STAT_LANES] (same trick as jax's own tpu flash kernel);
+        # rows that saw no key: zeros, lse = _NEG
+        lse_ref[0, 0] = jnp.where(l > 0, m + jnp.log(safe), _NEG)
 
 
 # ----------------------------------------------------------------------
 # backward
 # ----------------------------------------------------------------------
 def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               glse_ref, dq_ref, dq_s, *, causal, scale, block_q, block_k,
-               l_q, l_k, num_k):
+               dq_ref, dq_s, *, causal, scale, block_q, block_k, l_k, num_k,
+               cdt):
     qi, kj = pl.program_id(2), pl.program_id(3)
-    q_off, k_off = offs_ref[0], offs_ref[1]
+    q_lo = offs_ref[0] + qi * block_q
+    k_lo = offs_ref[1] + kj * block_k
+    pad_k = num_k * block_k != l_k
+    precision = _dot_precision(cdt)
 
     @pl.when(kj == 0)
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
 
-    def _accumulate():
-        q = q_ref[0, 0, :, :].astype(jnp.float32)
-        do = do_ref[0, 0, :, :].astype(jnp.float32)
-        k_blk = k_ref[0, 0, :, :].astype(jnp.float32)   # [bk, D]
-        v_blk = v_ref[0, 0, :, :].astype(jnp.float32)
-        # lse/delta/glse arrive lane-broadcast [bq, _STAT_LANES]; any
-        # lane-reduce that preserves the (identical) value recovers rows
-        lse = _rows(lse_ref[0, 0])
-        delta = _rows(delta_ref[0, 0])
-        glse = _rows(glse_ref[0, 0])
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        k_loc = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_loc < l_k
-        if causal:
-            q_pos = q_off + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, q_pos >= k_off + k_loc)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        # d lse / d s = p, so the lse cotangent adds straight into ds
-        ds = p * (dp - delta[:, None] + glse[:, None]) * scale
-        dq_s[...] = dq_s[...] + jnp.dot(
-            ds, k_blk, preferred_element_type=jnp.float32)
+    def accumulate(masked):
+        q = q_ref[0, 0].astype(cdt)
+        do = do_ref[0, 0].astype(cdt)
+        k_blk = k_ref[0, 0].astype(cdt)
+        v_blk = v_ref[0, 0].astype(cdt)
+        s = jax.lax.dot_general(q, k_blk, _NT, precision=precision,
+                                preferred_element_type=jnp.float32)
+        # lse and delta arrive lane-replicated [bq, _STAT_LANES]
+        p = jnp.exp(s - _to_width(lse_ref[0, 0], block_k))
+        if masked:
+            p = jnp.where(_tile_mask(s.shape, 0, q_lo, k_lo, kj * block_k,
+                                     l_k, causal, pad_k), p, 0.0)
+        dp = jax.lax.dot_general(do, v_blk, _NT, precision=precision,
+                                 preferred_element_type=jnp.float32)
+        # d lse / d s = p: the caller has taken the lse cotangent off delta
+        ds = p * (dp - _to_width(delta_ref[0, 0], block_k))
+        dq_s[...] = dq_s[...] + jnp.dot(ds.astype(cdt), k_blk,
+                                        precision=precision,
+                                        preferred_element_type=jnp.float32)
 
-    if causal:
-        @pl.when(k_off + kj * block_k <= q_off + (qi + 1) * block_q - 1)
-        def _():
-            _accumulate()
-    else:
-        _accumulate()
+    _each_tile(accumulate, causal=causal, pad_k=pad_k, q_lo=q_lo, k_lo=k_lo,
+               block_q=block_q, block_k=block_k, last_k=kj == num_k - 1)
 
     @pl.when(kj == num_k - 1)
     def _finalize():
-        dq_ref[0, 0, :, :] = dq_s[...].astype(dq_ref.dtype)
+        # q came scaled, so s was; ds's own factor is applied once here
+        dq_ref[0, 0] = (dq_s[...] * scale).astype(dq_ref.dtype)
 
 
 def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                glse_ref, dk_ref, dv_ref, dk_s, dv_s, *, causal, scale,
-                block_q, block_k, l_q, l_k, num_q):
-    ki, qj = pl.program_id(2), pl.program_id(3)
-    q_off, k_off = offs_ref[0], offs_ref[1]
+                dk_ref, dv_ref, dk_s, dv_s, *, causal, block_q, block_k,
+                l_k, num_q, num_k, sweep, cdt):
+    """One key block against every query block of every query head of
+    its group (``sweep = group * num_q`` inner steps), on the TRANSPOSED
+    score tile ``[bk, bq]``: the row statistics ride as rows
+    ``[1, bq]`` (a sublane broadcast) and both accumulations are plain
+    ``a @ b`` products."""
+    ki, t = pl.program_id(2), pl.program_id(3)
+    qj = t % num_q
+    q_lo = offs_ref[0] + qj * block_q
+    k_lo = offs_ref[1] + ki * block_k
+    pad_k = num_k * block_k != l_k
+    precision = _dot_precision(cdt)
 
-    @pl.when(qj == 0)
+    @pl.when(t == 0)
     def _init():
         dk_s[...] = jnp.zeros_like(dk_s)
         dv_s[...] = jnp.zeros_like(dv_s)
 
-    def _accumulate():
-        k_blk = k_ref[0, 0, :, :].astype(jnp.float32)   # [bk, D]
-        v_blk = v_ref[0, 0, :, :].astype(jnp.float32)
-        q = q_ref[0, 0, :, :].astype(jnp.float32)       # [bq, D]
-        do = do_ref[0, 0, :, :].astype(jnp.float32)
-        lse = _rows(lse_ref[0, 0])
-        delta = _rows(delta_ref[0, 0])
-        glse = _rows(glse_ref[0, 0])
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [bq, bk]
-        q_loc = qj * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        k_loc = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_loc < l_k
-        if causal:
-            mask = jnp.logical_and(
-                mask, q_off + q_loc >= k_off + k_loc)
-        # padded q rows carry lse = _NEG -> exp(s - _NEG) would overflow;
-        # mask on the valid-q side too
-        mask = jnp.logical_and(mask, q_loc < l_q)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dv_s[...] = dv_s[...] + jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bk, D]
-        dp = jax.lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bq, bk]
-        ds = p * (dp - delta[:, None] + glse[:, None]) * scale
-        dk_s[...] = dk_s[...] + jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)          # [bk, D]
+    def accumulate(masked):
+        k_blk = k_ref[0, 0].astype(cdt)      # [bk, D]
+        v_blk = v_ref[0, 0].astype(cdt)      # [bk, Dv]
+        q = q_ref[0, 0].astype(cdt)          # [bq, D], scaled by the caller
+        do = do_ref[0, 0].astype(cdt)        # [bq, Dv]
+        s_t = jax.lax.dot_general(k_blk, q, _NT, precision=precision,
+                                  preferred_element_type=jnp.float32)
+        # padded query rows carry lse = delta = do = 0: they add nothing
+        p_t = jnp.exp(s_t - lse_ref[0, 0])   # [bk, bq] - [1, bq]
+        if masked:
+            p_t = jnp.where(_tile_mask(s_t.shape, 1, q_lo, k_lo,
+                                       ki * block_k, l_k, causal, pad_k),
+                            p_t, 0.0)
+        dv_s[...] = dv_s[...] + jnp.dot(p_t.astype(cdt), do,
+                                        precision=precision,
+                                        preferred_element_type=jnp.float32)
+        dp_t = jax.lax.dot_general(v_blk, do, _NT, precision=precision,
+                                   preferred_element_type=jnp.float32)
+        ds_t = p_t * (dp_t - delta_ref[0, 0])
+        # against the SCALED q: ds's own factor is in it
+        dk_s[...] = dk_s[...] + jnp.dot(ds_t.astype(cdt), q,
+                                        precision=precision,
+                                        preferred_element_type=jnp.float32)
 
-    if causal:
-        # q blocks strictly above this key block's (global) diagonal
-        # start see nothing
-        @pl.when(q_off + (qj + 1) * block_q - 1 >= k_off + ki * block_k)
-        def _():
-            _accumulate()
-    else:
-        _accumulate()
+    _each_tile(accumulate, causal=causal, pad_k=pad_k, q_lo=q_lo, k_lo=k_lo,
+               block_q=block_q, block_k=block_k, last_k=ki == num_k - 1)
 
-    @pl.when(qj == num_q - 1)
+    @pl.when(t == sweep - 1)
     def _finalize():
-        dk_ref[0, 0, :, :] = dk_s[...].astype(dk_ref.dtype)
-        dv_ref[0, 0, :, :] = dv_s[...].astype(dv_ref.dtype)
+        dk_ref[0, 0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_s[...].astype(dv_ref.dtype)
 
 
 # ----------------------------------------------------------------------
-# pallas_call plumbing
+# pallas_call plumbing (_fwd, _bwd jitted: one trace a shape, not a layer's)
 # ----------------------------------------------------------------------
-def _specs(block_q, block_k, d_p):
-    # kernel-side layout is [B, H, S, D]: the blocked dims (S, D) sit in
-    # the last two positions, as TPU mosaic tiling requires.  Grid is
-    # (B, H, q_block, kv_block) — the kv index j is INNERMOST so mosaic
-    # double-buffers the kv fetches while q/out/stat blocks (index maps
-    # ignoring j) stay resident across the inner sweep.
-    q_spec = pl.BlockSpec((1, 1, block_q, d_p),
-                          lambda b, h, i, j, *_: (b, h, i, 0))
-    kv_spec = pl.BlockSpec((1, 1, block_k, d_p),
-                           lambda b, h, i, j, *_: (b, h, j, 0))
-    # per-row lse rides lane-broadcast as [B, H, lq_p, _STAT_LANES] —
-    # full (8, 128) tiles since the PR-12 retile
-    lse_spec = pl.BlockSpec((1, 1, block_q, _STAT_LANES),
-                            lambda b, h, i, j, *_: (b, h, i, 0))
-    return q_spec, kv_spec, lse_spec
-
-
 #: grid semantics: batch/head/outer-block axes are parallel; the inner
 #: accumulation axis must execute in order (scratch carry)
 _PARALLEL = pltpu.GridDimensionSemantics.PARALLEL
 _ARBITRARY = pltpu.GridDimensionSemantics.ARBITRARY
 _SEMANTICS = (_PARALLEL, _PARALLEL, _PARALLEL, _ARBITRARY)
+#: the kernels' fast-memory limit: a 512 x 1024 float32 score tile and
+#: its exponentials, products and masks stand beside double-buffered
+#: operand blocks (the compiler's default scope is 16 MiB of the chip's
+#: 128)
+_VMEM_LIMIT = 64 * 2 ** 20
 
 
-def _bhsd(x):
-    """[B, L, H, D] -> [B, H, L, D] (kernel layout)."""
-    return x.transpose(0, 2, 1, 3)
+def _plain_interpret(interpret) -> bool:
+    """The token models' path: ``None`` -> compiled on TPU, else the
+    PLAIN interpreter.  The planner's API keeps the TPU-flavoured one
+    (``pallas_kernels._resolve_interpret``: ``pltpu.InterpretParams``),
+    but that one works through ordered callbacks, which the ``remat``
+    around a token model's layer refuses."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
+
+def _params():
+    return pltpu.CompilerParams(dimension_semantics=_SEMANTICS,
+                                vmem_limit_bytes=_VMEM_LIMIT)
+
+
+def _heads_first(x, length, width, dtype):
+    """``[B, L, H, D]`` -> the kernels' ``[B, H, length, width]`` (the
+    blocked dims last, as Mosaic's tiling requires), zero-padded, as
+    ``dtype``: one copy, which XLA fuses with what made ``x``."""
+    x = _pad_axis(_pad_axis(x, 1, length), 3, width)
+    return x.transpose(0, 2, 1, 3).astype(dtype)
 
 
 def _lanes(x, to):
-    """[B, H, L] -> lane-broadcast [B, H, to, _STAT_LANES] (f32)."""
+    """[B, H, L] -> lane-replicated [B, H, to, _STAT_LANES] (f32)."""
     return jnp.broadcast_to(
         _pad_axis(x.astype(jnp.float32), 2, to)[..., None],
         x.shape[:2] + (to, _STAT_LANES))
@@ -313,113 +374,174 @@ def _offs(q_offset, k_offset):
                       jnp.asarray(k_offset, jnp.int32)])
 
 
-def _fwd(q, k, v, q_offset, k_offset, causal, scale, block_q, block_k,
-         interpret):
-    B, Lq, H, D = q.shape
-    Lk = k.shape[1]
-    lq_p, lk_p = _ceil_to(Lq, block_q), _ceil_to(Lk, block_k)
-    d_p = _ceil_to(D, _LANES)
-    qp = _bhsd(_pad_axis(_pad_axis(q, 1, lq_p), 3, d_p))
-    kp = _bhsd(_pad_axis(_pad_axis(k, 1, lk_p), 3, d_p))
-    vp = _bhsd(_pad_axis(_pad_axis(v, 1, lk_p), 3, d_p))
-    q_spec, kv_spec, lse_spec = _specs(block_q, block_k, d_p)
-    nq, nk = lq_p // block_q, lk_p // block_k
-    kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
-                               block_q=block_q, block_k=block_k,
-                               l_q=Lq, l_k=Lk, num_k=nk)
+class _Geometry:
+    """What the three calls share: padded sizes, block counts, the group
+    of query heads a key-value head serves, and the block specs.  The
+    grid's inner axis sweeps key blocks (forward, dq) or query blocks
+    (dk/dv); under ``causal`` the swept operand's index map stops at the
+    diagonal, so blocks beyond it are neither fetched nor computed."""
+
+    def __init__(self, q, k, v, causal, block_q, block_k):
+        self.B, self.Lq, self.H, self.D = q.shape
+        self.Lk, self.KV, self.Dv = k.shape[1], k.shape[2], v.shape[3]
+        self.G = self.H // self.KV
+        self.causal, self.bq, self.bk = causal, block_q, block_k
+        self.lq_p, self.lk_p = _ceil_to(self.Lq, block_q), \
+            _ceil_to(self.Lk, block_k)
+        self.d_p, self.dv_p = _ceil_to(self.D, _LANES), \
+            _ceil_to(self.Dv, _LANES)
+        self.nq, self.nk = self.lq_p // block_q, self.lk_p // block_k
+        self.scale = float(self.D ** -0.5)
+
+    def static(self):
+        return dict(causal=self.causal, block_q=self.bq, block_k=self.bk,
+                    l_k=self.Lk, num_k=self.nk)
+
+    # forward and dq: grid (B, H, q block i, key block j)
+    def _key_block(self, offs, i, j):
+        if not self.causal:
+            return j
+        last = (offs[0] + (i + 1) * self.bq - 1 - offs[1]) // self.bk
+        return jnp.minimum(j, jnp.clip(last, 0, self.nk - 1))
+
+    def row_specs(self):
+        G = self.G
+
+        def q_side(width):
+            return pl.BlockSpec((1, 1, self.bq, width),
+                                lambda b, h, i, j, offs: (b, h, i, 0))
+
+        def k_side(width):
+            return pl.BlockSpec(
+                (1, 1, self.bk, width),
+                lambda b, h, i, j, offs: (b, h // G,
+                                          self._key_block(offs, i, j), 0))
+        return q_side, k_side
+
+    # dk/dv: grid (B, KV, key block i, t = (head of the group, q block))
+    def _query_block(self, offs, i, t):
+        j = t % self.nq
+        if not self.causal:
+            return j
+        first = (offs[1] + i * self.bk - offs[0]) // self.bq
+        return jnp.maximum(j, jnp.clip(first, 0, self.nq - 1))
+
+    def column_specs(self):
+        G, nq = self.G, self.nq
+
+        def q_side(width):
+            return pl.BlockSpec(
+                (1, 1, self.bq, width),
+                lambda b, h, i, t, offs: (b, h * G + t // nq,
+                                          self._query_block(offs, i, t), 0))
+
+        def k_side(width):
+            return pl.BlockSpec((1, 1, self.bk, width),
+                                lambda b, h, i, t, offs: (b, h, i, 0))
+        # the row statistics as rows: [B, H, 1, lq_p] in blocks [1, bq]
+        stat = pl.BlockSpec(
+            (1, 1, 1, self.bq),
+            lambda b, h, i, t, offs: (b, h * G + t // nq, 0,
+                                      self._query_block(offs, i, t)))
+        return q_side, k_side, stat
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7, 8, 9))
+def _fwd(q, k, v, q_offset, k_offset, causal, block_q, block_k, interpret,
+         mxu):
+    g = _Geometry(q, k, v, causal, block_q, block_k)
+    cdt = jnp.dtype(mxu)
+    qp = _heads_first(q.astype(jnp.float32) * g.scale, g.lq_p, g.d_p, cdt)
+    kp = _heads_first(k, g.lk_p, g.d_p, cdt)
+    vp = _heads_first(v, g.lk_p, g.dv_p, cdt)
+    q_side, k_side = g.row_specs()
     out, lse = pl.pallas_call(
-        kernel,
+        functools.partial(_fwd_kernel, cdt=cdt, **g.static()),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, nq, nk),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=[q_spec, lse_spec],
-            # m/l scratch at full 128 lanes (the proven shape of jax's
-            # own tpu flash kernel's carry scratch); the lse OUTPUT keeps
-            # _STAT_LANES — it is a block of a real array, where the
-            # equal-to-array-dim rule applies
+            grid=(g.B, g.H, g.nq, g.nk),
+            in_specs=[q_side(g.d_p), k_side(g.d_p), k_side(g.dv_p)],
+            out_specs=[q_side(g.dv_p), q_side(_STAT_LANES)],
+            # m/l carry at full 128 lanes (the proven shape of jax's own
+            # tpu flash kernel's carry scratch)
             scratch_shapes=[
-                pltpu.VMEM((block_q, _LANES), jnp.float32),
-                pltpu.VMEM((block_q, _LANES), jnp.float32),
-                pltpu.VMEM((block_q, d_p), jnp.float32),
+                pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
+                pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
+                pltpu.VMEM((block_q, g.dv_p), jnp.float32),
             ],
         ),
-        out_shape=[jax.ShapeDtypeStruct(qp.shape, q.dtype),
-                   jax.ShapeDtypeStruct((B, H, lq_p, _STAT_LANES), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
-        interpret=_resolve_interpret(interpret),
+        out_shape=[
+            jax.ShapeDtypeStruct((g.B, g.H, g.lq_p, g.dv_p), q.dtype),
+            jax.ShapeDtypeStruct((g.B, g.H, g.lq_p, _STAT_LANES),
+                                 jnp.float32)],
+        compiler_params=_params(),
+        interpret=interpret, name=FWD_NAME,
     )(_offs(q_offset, k_offset), qp, kp, vp)
-    return _bhsd(out)[:, :Lq, :, :D], lse[:, :, :Lq, 0]
+    return (out.transpose(0, 2, 1, 3)[:, :g.Lq, :, :g.Dv],
+            lse[:, :, :g.Lq, 0])
 
 
-def _bwd(q, k, v, out, lse, q_offset, k_offset, g, g_lse, causal, scale,
-         block_q, block_k, interpret):
-    B, Lq, H, D = q.shape
-    Lk = k.shape[1]
-    lq_p, lk_p = _ceil_to(Lq, block_q), _ceil_to(Lk, block_k)
-    d_p = _ceil_to(D, _LANES)
-    qp = _bhsd(_pad_axis(_pad_axis(q, 1, lq_p), 3, d_p))
-    kp = _bhsd(_pad_axis(_pad_axis(k, 1, lk_p), 3, d_p))
-    vp = _bhsd(_pad_axis(_pad_axis(v, 1, lk_p), 3, d_p))
-    gp = _bhsd(_pad_axis(_pad_axis(g, 1, lq_p), 3, d_p))
-    lse_p = _lanes(lse, lq_p)
-    glse_p = _lanes(g_lse, lq_p)
-    # delta_i = sum_d dO_i . O_i  (rowwise), the softmax-grad correction
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=3)                              # [B, Lq, H]
-    delta = _lanes(delta.transpose(0, 2, 1), lq_p)
-    interp = _resolve_interpret(interpret)
+@functools.partial(jax.jit, static_argnums=(9, 10, 11, 12, 13))
+def _bwd(q, k, v, out, lse, q_offset, k_offset, do, g_lse, causal, block_q,
+         block_k, interpret, mxu):
+    g = _Geometry(q, k, v, causal, block_q, block_k)
+    cdt = jnp.dtype(mxu)
+    qp = _heads_first(q.astype(jnp.float32) * g.scale, g.lq_p, g.d_p, cdt)
+    kp = _heads_first(k, g.lk_p, g.d_p, cdt)
+    vp = _heads_first(v, g.lk_p, g.dv_p, cdt)
+    dop = _heads_first(do, g.lq_p, g.dv_p, cdt)
+    # delta_i = sum_d dO_i . O_i (rowwise), the softmax-grad correction;
+    # d lse / d s = p too, so the lse cotangent comes off it here
+    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=3).transpose(0, 2, 1) - g_lse   # [B, H, Lq]
     offs = _offs(q_offset, k_offset)
-    q_spec, kv_spec, lse_spec = _specs(block_q, block_k, d_p)
-    nq, nk = lq_p // block_q, lk_p // block_k
 
-    dq_kernel = functools.partial(_dq_kernel, causal=causal, scale=scale,
-                                  block_q=block_q, block_k=block_k,
-                                  l_q=Lq, l_k=Lk, num_k=nk)
+    q_side, k_side = g.row_specs()
     dq = pl.pallas_call(
-        dq_kernel,
+        functools.partial(_dq_kernel, scale=g.scale, cdt=cdt, **g.static()),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, nq, nk),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, lse_spec, lse_spec,
-                      lse_spec],
-            out_specs=q_spec,
-            scratch_shapes=[pltpu.VMEM((block_q, d_p), jnp.float32)],
+            grid=(g.B, g.H, g.nq, g.nk),
+            in_specs=[q_side(g.d_p), k_side(g.d_p), k_side(g.dv_p),
+                      q_side(g.dv_p), q_side(_STAT_LANES),
+                      q_side(_STAT_LANES)],
+            out_specs=q_side(g.d_p),
+            scratch_shapes=[pltpu.VMEM((block_q, g.d_p), jnp.float32)],
         ),
-        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
-        interpret=interp,
-    )(offs, qp, kp, vp, gp, lse_p, delta, glse_p)
+        out_shape=jax.ShapeDtypeStruct((g.B, g.H, g.lq_p, g.d_p), q.dtype),
+        compiler_params=_params(), interpret=interpret, name=DQ_NAME,
+    )(offs, qp, kp, vp, dop, _lanes(lse, g.lq_p), _lanes(delta, g.lq_p))
 
-    # dk/dv: key blocks on the outer grid axis, query blocks streamed
-    # innermost (same pipelining story, axes swapped)
-    kq_spec = pl.BlockSpec((1, 1, block_q, d_p),
-                           lambda b, h, i, j, *_: (b, h, j, 0))
-    kk_spec = pl.BlockSpec((1, 1, block_k, d_p),
-                           lambda b, h, i, j, *_: (b, h, i, 0))
-    kq_lse_spec = pl.BlockSpec((1, 1, block_q, _STAT_LANES),
-                               lambda b, h, i, j, *_: (b, h, j, 0))
-    dkv_kernel = functools.partial(_dkv_kernel, causal=causal, scale=scale,
-                                   block_q=block_q, block_k=block_k,
-                                   l_q=Lq, l_k=Lk, num_q=nq)
+    # dk/dv: key blocks on the outer grid axis; the query blocks of every
+    # head of the key-value head's group stream innermost and sum into
+    # one accumulator
+    q_side, k_side, stat = g.column_specs()
+
+    def rows(x):  # [B, H, Lq] -> [B, H, 1, lq_p]
+        return _pad_axis(x.astype(jnp.float32), 2, g.lq_p)[:, :, None, :]
+
+    sweep = g.G * g.nq
     dk, dv = pl.pallas_call(
-        dkv_kernel,
+        functools.partial(_dkv_kernel, num_q=g.nq, sweep=sweep, cdt=cdt,
+                          **g.static()),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H, nk, nq),
-            in_specs=[kq_spec, kk_spec, kk_spec, kq_spec, kq_lse_spec,
-                      kq_lse_spec, kq_lse_spec],
-            out_specs=[kk_spec, kk_spec],
-            scratch_shapes=[pltpu.VMEM((block_k, d_p), jnp.float32),
-                            pltpu.VMEM((block_k, d_p), jnp.float32)],
+            grid=(g.B, g.KV, g.nk, sweep),
+            in_specs=[q_side(g.d_p), k_side(g.d_p), k_side(g.dv_p),
+                      q_side(g.dv_p), stat, stat],
+            out_specs=[k_side(g.d_p), k_side(g.dv_p)],
+            scratch_shapes=[pltpu.VMEM((block_k, g.d_p), jnp.float32),
+                            pltpu.VMEM((block_k, g.dv_p), jnp.float32)],
         ),
-        out_shape=[jax.ShapeDtypeStruct(kp.shape, k.dtype),
-                   jax.ShapeDtypeStruct(vp.shape, v.dtype)],
-        compiler_params=pltpu.CompilerParams(dimension_semantics=_SEMANTICS),
-        interpret=interp,
-    )(offs, qp, kp, vp, gp, lse_p, delta, glse_p)
-    return (_bhsd(dq)[:, :Lq, :, :D], _bhsd(dk)[:, :Lk, :, :D],
-            _bhsd(dv)[:, :Lk, :, :D])
+        out_shape=[jax.ShapeDtypeStruct((g.B, g.KV, g.lk_p, g.d_p), k.dtype),
+                   jax.ShapeDtypeStruct((g.B, g.KV, g.lk_p, g.dv_p),
+                                        v.dtype)],
+        compiler_params=_params(), interpret=interpret, name=DKV_NAME,
+    )(offs, qp, kp, vp, dop, rows(lse), rows(delta))
+
+    def back(x, length, width):
+        return x.transpose(0, 2, 1, 3)[:, :length, :, :width]
+    return (back(dq, g.Lq, g.D), back(dk, g.Lk, g.D), back(dv, g.Lk, g.Dv))
 
 
 def _dense_lse(q, k, v, q_offset, k_offset, causal):
@@ -448,29 +570,31 @@ def _dense_lse(q, k, v, q_offset, k_offset, causal):
     return out.astype(q.dtype), lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def _flash_lse(q, k, v, q_offset, k_offset, causal, block_q, block_k,
-               interpret):
-    D = q.shape[3]
-    scale = float(1.0 / np.sqrt(D))
-    return _fwd(q, k, v, q_offset, k_offset, causal, scale, block_q,
-                block_k, interpret)
+               interpret, mxu):
+    """``q [B, Lq, H, D]`` over ``k [B, Lk, KV, D]``, ``v [B, Lk, KV,
+    Dv]`` (query head ``h`` reads key-value head ``h // (H / KV)``),
+    scale ``D ** -0.5``; ``mxu``: the dtype the products' operands are
+    given in; ``interpret``: what ``pallas_call`` takes, resolved by the
+    caller.  Returns ``(out [B, Lq, H, Dv], lse [B, H, Lq])``."""
+    return _fwd(q, k, v, q_offset, k_offset, causal, block_q, block_k,
+                interpret, mxu)
 
 
 def _flash_lse_fwd(q, k, v, q_offset, k_offset, causal, block_q, block_k,
-                   interpret):
+                   interpret, mxu):
     out, lse = _flash_lse(q, k, v, q_offset, k_offset, causal, block_q,
-                          block_k, interpret)
+                          block_k, interpret, mxu)
     return (out, lse), (q, k, v, out, lse, q_offset, k_offset)
 
 
-def _flash_lse_bwd(causal, block_q, block_k, interpret, res, cotangents):
+def _flash_lse_bwd(causal, block_q, block_k, interpret, mxu, res,
+                   cotangents):
     q, k, v, out, lse, q_offset, k_offset = res
     g, g_lse = cotangents
-    D = q.shape[3]
-    scale = float(1.0 / np.sqrt(D))
     dq, dk, dv = _bwd(q, k, v, out, lse, q_offset, k_offset, g, g_lse,
-                      causal, scale, block_q, block_k, interpret)
+                      causal, block_q, block_k, interpret, mxu)
     zero = np.zeros((), jax.dtypes.float0)
     return dq, dk, dv, zero, zero
 
@@ -544,7 +668,6 @@ def _probe_costs(B, Lq, Lk, H, D, dtype, causal, candidates):
     from ..telemetry.xla import aot_cost
     q_s = jax.ShapeDtypeStruct((B, Lq, H, D), dtype)
     kv_s = jax.ShapeDtypeStruct((B, Lk, H, D), dtype)
-    scale = float(1.0 / np.sqrt(D))
 
     def dense_fn(q, k, v):
         return _dense_lse(q, k, v, 0, 0, causal)
@@ -553,7 +676,8 @@ def _probe_costs(B, Lq, Lk, H, D, dtype, causal, candidates):
     flash_costs = {}
     for bq, bk in candidates:
         def flash_fn(q, k, v, _bq=bq, _bk=bk):
-            return _fwd(q, k, v, 0, 0, causal, scale, _bq, _bk, None)
+            return _fwd(q, k, v, 0, 0, causal, _bq, _bk,
+                        _resolve_interpret(None), jnp.float32)
         flash_costs[(bq, bk)] = aot_cost(flash_fn, q_s, kv_s, kv_s)
     return dense_cost, flash_costs
 
@@ -641,6 +765,87 @@ def plan_attention(B: int, Lq: int, Lk: int, H: int, D: int, dtype,
     return plan
 
 
+# ----------------------------------------------------------------------
+# The token models' path (models/token_blocks.py): no planner.  The
+# blocks follow from the shapes, there is no dense fallback (at 4,096
+# tokens it does not fit beside a 1.9 GB tree), and the trace says which
+# path it took.
+# ----------------------------------------------------------------------
+#: context precisions that give float32 operands ONE bf16 MXU pass with
+#: float32 accumulation (what XLA does to a plain einsum under them)
+_ONE_PASS = (None, "default", "bfloat16", "fastest")
+
+
+def context_mxu_dtype(dtype):
+    """The dtype the products' operands are given in: the tensors' own
+    where that is bfloat16, else what the context's matmul precision
+    makes of float32 operands — bfloat16 (one MXU pass, float32
+    accumulation) under the default, float32 (contracted in full under
+    ``highest``) under anything above.  Softmax, its running maximum and
+    sum, ``lse`` and the accumulators are float32 whichever."""
+    if jnp.dtype(dtype) == jnp.bfloat16 or \
+            jax.config.jax_default_matmul_precision in _ONE_PASS:
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(jnp.float32)
+
+
+def causal_blocks(length: int) -> tuple:
+    """``(block_q, block_k)`` of the token models' causal core: static,
+    from the row's length alone — square tiles of 512, or the whole
+    (lane-padded) row where it is shorter.  On the chip at 4,096 tokens
+    (PERF.md section 6, PR 37) 512 x 512 is within 3% (32 heads of
+    192 / 128) and 8% (32 over 8 heads of 64) of the best of eight
+    tilings from 256 x 256 to 2,048 x 512, forward and backward, 256 x
+    256 is 25–40% slower, and 1,024 x 1,024 costs five times the
+    compile seconds under ``highest``: one rule, no test of the width."""
+    tile = min(_CAUSAL_BLOCK, _ceil_to(length, _LANES))
+    return tile, tile
+
+
+def record_attention_path(impl: str, q_shape, k_shape, v_shape,
+                          block_q: int, block_k: int) -> None:
+    """Buffer an ``attention_path`` event: which implementation this
+    trace of a token model's causal core took (``flash``: the kernels
+    above; ``plain``: ``models/token_blocks._blocked_attention``), with
+    the shapes and the blocks.  One record a distinct geometry between
+    two drains: a program traces its core once a layer and pass."""
+    record = {"kind": "attention_path", "impl": impl,
+              "q_shape": [int(n) for n in q_shape],
+              "k_shape": [int(n) for n in k_shape],
+              "v_shape": [int(n) for n in v_shape],
+              "block_q": int(block_q), "block_k": int(block_k)}
+    if record not in _PENDING_EVENTS and len(_PENDING_EVENTS) < _EVENTS_CAP:
+        _PENDING_EVENTS.append(record)
+
+
+def causal_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                           *, interpret: Optional[bool] = None):
+    """Causal self-attention through the kernels, for shapes the planner's
+    API does not take: ``q [B, L, H, D]`` over ``k [B, L, KV, D]`` and
+    ``v [B, L, KV, Dv]`` — query head ``h`` reads key-value head
+    ``h // (H / KV)`` through the block index, ``dk``/``dv`` are summed
+    over the group inside the dk/dv kernel's sweep, and the value width
+    is its own.  Scale ``D ** -0.5``; returns ``[B, L, H, Dv]``.
+
+    The operands' precision is the context's (:func:`context_mxu_dtype`);
+    the residuals are ``q``, ``k``, ``v``, ``out`` and ``lse``, the
+    probabilities are recomputed tile by tile in the backward kernels
+    and never leave VMEM."""
+    if q.ndim != 4 or k.ndim != 4 or v.ndim != 4:
+        raise ValueError(f"expected [B, L, heads, D]: {q.shape}, {k.shape}, "
+                         f"{v.shape}")
+    if q.shape[:2] != k.shape[:2] or k.shape[:3] != v.shape[:3] or \
+            q.shape[3] != k.shape[3] or q.shape[2] % k.shape[2]:
+        raise ValueError(f"q {q.shape}, k {k.shape}, v {v.shape}: not one "
+                         "row's queries over grouped key-value heads")
+    block_q, block_k = causal_blocks(q.shape[1])
+    record_attention_path("flash", q.shape, k.shape, v.shape, block_q,
+                          block_k)
+    return _flash_lse(q, k, v, 0, 0, True, block_q, block_k,
+                      _plain_interpret(interpret),
+                      context_mxu_dtype(q.dtype))[0]
+
+
 def flash_attention_lse(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         causal: bool = False, *, q_offset=0, k_offset=0,
                         block_q: Optional[int] = None,
@@ -682,7 +887,8 @@ def flash_attention_lse(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         block_q, block_k = plan["block_q"], plan["block_k"]
     return _flash_lse(q, k, v, q_offset, k_offset, bool(causal),
                       int(block_q or _DEF_BLOCK),
-                      int(block_k or _DEF_BLOCK), interpret)
+                      int(block_k or _DEF_BLOCK),
+                      _resolve_interpret(interpret), jnp.float32)
 
 
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

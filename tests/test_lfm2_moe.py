@@ -229,3 +229,41 @@ def test_config_errors_name_the_key():
     # no expert layer, no counters
     assert make_task({**TINY, "num_dense_layers": 4}).counter_names == ()
     assert make_task(TINY).counter_names == lfm2.COUNTERS
+
+
+def test_a_local_step_through_the_attention_kernels_is_the_plain_paths(
+        monkeypatch):
+    """The cell's local step with the grouped-query core forced through
+    the tiled kernels (``interpret=True``; ``remat`` on, as the cell has
+    it): query head ``h`` reads key-value head ``h // 2`` by the block
+    index and ``dk``/``dv`` are summed over the group inside the kernel;
+    the loss and every gradient leaf are the plain path's to float32
+    rounding, and the trace says which path each took."""
+    import functools
+    from msrflute_tpu.models import token_blocks
+    from msrflute_tpu.ops import pallas_attention as pa
+    config = {**TINY, "remat": True}
+    task, weights, batch = make_task(config), _weights(), _batch()
+
+    def step(p):
+        return jax.value_and_grad(_program_loss(task))(p, batch)
+
+    pa.drain_attention_events()
+    want, want_grads = jax.jit(step)(weights)
+    assert [e["impl"] for e in pa.drain_attention_events()] == ["plain"]
+    monkeypatch.setattr(lfm2, "causal_attention", functools.partial(
+        token_blocks.causal_attention, interpret=True))
+    # another function object: jit would hand back ``step``'s program
+    loss, grads = jax.jit(lambda p: step(p))(weights)
+    said = pa.drain_attention_events()
+    assert [(e["kind"], e["impl"]) for e in said] == \
+        [("attention_path", "flash")], said
+    # 16 tokens, 4 query heads over 2 key-value heads of 16
+    assert said[0]["q_shape"] == [2, 16, 4, 16]
+    assert said[0]["k_shape"] == [2, 16, 2, 16]
+    assert abs(float(loss) - float(want)) < 1e-6 * abs(float(want))
+    for (path, got), exp in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                jax.tree.leaves(want_grads)):
+        scale = max(float(jnp.max(jnp.abs(exp))), 1e-4)
+        assert float(jnp.max(jnp.abs(got - exp))) < 1e-5 * scale, \
+            jax.tree_util.keystr(path)

@@ -290,3 +290,41 @@ def test_the_tile_counter_rides_onto_host_tail_for_both_token_models(
         100.0 * sum(s["moe_pairs_held"] for s in tails) /
         (moe.TILE_ROWS * sum(s["moe_tiles_active"] for s in tails)))
     assert 0.0 < fill < 100.0
+
+
+def test_a_local_step_through_the_attention_kernels_is_the_plain_paths(
+        monkeypatch):
+    """The cell's local step with the causal core forced through the tiled
+    kernels (``interpret=True``; ``remat`` on, as the cell has it): the
+    loss and every gradient leaf are the plain path's to float32
+    rounding (the fixture's ``highest``: float32 operands, contracted in
+    full), and the trace says which path each took."""
+    import functools
+    from msrflute_tpu.ops import pallas_attention as pa
+    config = {**TINY, "remat": True}
+    task, weights, batch = make_task(config), _weights(), _batch()
+
+    def step(p):
+        return jax.value_and_grad(
+            lambda q: task.loss(q, batch, None, True)[0])(p)
+
+    pa.drain_attention_events()
+    want, want_grads = jax.jit(step)(weights)
+    said = pa.drain_attention_events()
+    assert [e["impl"] for e in said] == ["plain"], said
+    monkeypatch.setattr(mla_moe, "causal_attention", functools.partial(
+        token_blocks.causal_attention, interpret=True))
+    # another function object: jit would hand back ``step``'s program
+    loss, grads = jax.jit(lambda p: step(p))(weights)
+    said = pa.drain_attention_events()
+    assert [e["kind"] for e in said] == ["attention_path"], said
+    assert said[0]["impl"] == "flash"
+    # 16 tokens (padded to two plain blocks of 8), 4 heads of 16 + 8 / 16
+    assert said[0]["q_shape"] == [2, 16, 4, 24]
+    assert said[0]["v_shape"] == [2, 16, 4, 16]
+    assert abs(float(loss) - float(want)) < 1e-6 * abs(float(want))
+    for (path, got), exp in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                jax.tree.leaves(want_grads)):
+        scale = max(float(jnp.max(jnp.abs(exp))), 1e-4)
+        assert float(jnp.max(jnp.abs(got - exp))) < 1e-5 * scale, \
+            jax.tree_util.keystr(path)

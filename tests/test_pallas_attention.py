@@ -6,6 +6,8 @@ causal and full, including shapes that exercise the padding/masking path
 (L not a block multiple, D < 128) and bf16 inputs.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -292,3 +294,94 @@ def test_gate_treats_missing_flash_costs_as_probe_failure():
         assert pa.drain_attention_events() == []
     finally:
         pa.reset_attention_plans()
+
+
+# ======================================================================
+# PR 37: a value width of its own, grouped key-value heads, the
+# context's precision — the token models' path, held to the plain
+# statement ``models/token_blocks._attention_rows`` over the whole row
+# ======================================================================
+#: name -> (L, key-value heads, group, D, Dv, q_offset, k_offset)
+GEOMETRIES = {
+    # Kanana-2's latent attention: 192-wide keys, 128-wide values
+    "mla_d192_dv128_g1": (300, 2, 1, 192, 128, 0, 0),
+    # LFM2's grouped queries: four query heads a key-value head
+    "gqa_d64_g4": (300, 2, 4, 64, 64, 0, 0),
+    # the kernel's own callers (ring attention): one width, one head a
+    # key head, global offsets and an lse cotangent
+    "own_offsets_lse": (200, 2, 1, 32, 32, 72, 8),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_and_plain(geometry, precision):
+    """(forward, dq, dk, dv) through the kernels (interpreted, tiles of
+    128: three blocks, the last padded) and through the plain rows."""
+    import contextlib
+    from msrflute_tpu.models import token_blocks
+    from msrflute_tpu.ops import pallas_attention as pa
+    L, KV, G, D, Dv, q_off, k_off = GEOMETRIES[geometry]
+    rng = np.random.default_rng(11)
+    q = jnp.asarray(rng.normal(size=(1, L, KV, G, D)), jnp.float32)
+    k = jnp.asarray(rng.normal(size=(1, L, KV, D)), jnp.float32)
+    v = jnp.asarray(rng.normal(size=(1, L, KV, Dv)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(1, L, KV, G, Dv)), jnp.float32)
+    own = geometry.startswith("own")
+
+    def plain(q, k, v):
+        out = token_blocks._attention_rows(q, k, v, q_off - k_off)
+        if not own:
+            return out, jnp.sum(out * w)
+        # the row's logsumexp, written out: what the kernel also returns
+        s = jnp.einsum("brkgd,bmkd->bkgrm", q, k) * D ** -0.5
+        seen = (jnp.arange(L)[None, :] + k_off <=
+                jnp.arange(L)[:, None] + q_off)
+        lse = jax.nn.logsumexp(jnp.where(seen, s, -jnp.inf), axis=-1)
+        return out, jnp.sum(out * w) + jnp.sum(jnp.sin(lse))
+
+    def kernel(q, k, v):
+        if own:
+            out, lse = pa.flash_attention_lse(
+                q[:, :, :, 0], k, v, causal=True, q_offset=q_off,
+                k_offset=k_off, block_q=128, block_k=128, interpret=True)
+            out = out[:, :, :, None]
+            return out, jnp.sum(out * w) + jnp.sum(jnp.sin(lse))
+        out = token_blocks.causal_attention(q, k, v, 128, interpret=True)
+        return out, jnp.sum(out * w)
+
+    results = []
+    old = pa._CAUSAL_BLOCK
+    pa._CAUSAL_BLOCK = 128
+    try:
+        with (jax.default_matmul_precision(precision) if precision
+              else contextlib.nullcontext()):
+            for fn in (kernel, plain):
+                out = fn(q, k, v)[0]
+                grads = jax.grad(lambda *a: fn(*a)[1], (0, 1, 2))(q, k, v)
+                results.append((out,) + tuple(grads))
+    finally:
+        pa._CAUSAL_BLOCK = old
+    return results
+
+
+@pytest.mark.parametrize("quantity", ["forward", "dq", "dk", "dv"])
+@pytest.mark.parametrize("precision", [None, "highest"],
+                         ids=["default", "highest"])
+@pytest.mark.parametrize("geometry", list(GEOMETRIES))
+def test_kernels_match_the_plain_rows(geometry, precision, quantity):
+    """Forward and the three gradients, causal, ``L`` no multiple of the
+    block.  Under ``highest`` the operands are float32 and the products
+    full: float32 rounding.  Under the default the token models' path
+    hands the MXU bfloat16 operands (one pass, float32 accumulation:
+    what the chip's default does to the plain einsums), which the CPU's
+    plain path does not: bfloat16's rounding, 2^-8 an operand."""
+    got, want = (r[["forward", "dq", "dk", "dv"].index(quantity)]
+                 for r in _kernel_and_plain(geometry, precision))
+    assert got.shape == want.shape
+    exact = precision == "highest" or geometry.startswith("own")
+    scale = float(jnp.max(jnp.abs(want)))
+    assert float(jnp.max(jnp.abs(got - want))) < \
+        (3e-5 if exact else 3e-2) * scale
+    if not exact:
+        # and it IS the lower precision it says: not float32's result
+        assert float(jnp.max(jnp.abs(got - want))) > 1e-5 * scale

@@ -301,3 +301,113 @@ def test_mla_moe_step_compiles_for_v5e_at_published_widths(chip,
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes >= 424_961_024 * 4
     assert memory.temp_size_in_bytes < 4 * 2 ** 30
+    # this is the PLAIN path (``default_backend`` says cpu here): its
+    # blocks of float32 scores stand in the program
+    assert SCORES.search(text)
+
+
+# ----------------------------------------------------------------------
+# the token models' causal core through the tiled kernels (PR 37)
+# ----------------------------------------------------------------------
+#: a float32 array of heads x block x L scores, as the plain path's
+#: blocks of 2,048 rows have them ([1, 32, 1, 2048, 4096] for 32 heads of
+#: one; [1, 8, 4, 2048, 4096] for 32 over 8)
+SCORES = re.compile(
+    r"f32\[(?:1,)?(?:32|32,1|8,4),(?:512|1024|2048|4096),(?:2048|4096)\]")
+#: q, k, v of one 4,096-token row at the two token cells' widths
+CORES = {
+    # Kanana-2: 32 heads of 128 + 64 over keys of their own, values 128
+    "mla_32x192_128": ((1, 4096, 32, 192), (1, 4096, 32, 192),
+                       (1, 4096, 32, 128)),
+    # LFM2: 32 query heads over 8 key-value heads of 64
+    "gqa_32over8x64": ((1, 4096, 32, 64), (1, 4096, 8, 64),
+                       (1, 4096, 8, 64)),
+}
+
+
+@pytest.mark.parametrize("precision", [None, "highest"],
+                         ids=["default", "highest"])
+@pytest.mark.parametrize("core", list(CORES))
+def test_causal_core_kernels_compile_for_v5e_at_both_cells_shapes(
+        chip, core, precision):
+    """The three kernels under their stable names at the two token
+    cells' shapes (a value width of its own; grouped key-value heads by
+    the block index), with bfloat16 operands as the timed program hands
+    them over and with float32 operands contracted in full as the check
+    program does: what Mosaic refuses (VMEM, the tiling at width 192, a
+    float32 contraction) fails here.  One program a case: the gradient's,
+    which holds the forward kernel beside the two backward ones."""
+    import contextlib
+    specs = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+             for shape in CORES[core]]
+
+    def backward(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(pa.causal_flash_attention(
+            *a, interpret=False)), argnums=(0, 1, 2))(q, k, v)
+
+    pa.drain_attention_events()
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        compiled = jax.jit(backward).lower(*specs).compile()
+    text = compiled.as_text()
+    for name in (pa.FWD_NAME, pa.DQ_NAME, pa.DKV_NAME):
+        assert name in text, name
+    assert not SCORES.search(text)
+    said = [e for e in pa.drain_attention_events()
+            if e["kind"] == "attention_path"]
+    assert said and all(e["impl"] == "flash" and e["block_q"] == 512
+                        for e in said)
+
+
+@pytest.mark.parametrize("model", ["mla_moe", "lfm2_moe"])
+def test_token_model_step_compiles_for_v5e_with_the_core_in_the_kernels(
+        chip, monkeypatch, model):
+    """One local step of each token cell at published widths (a
+    4,096-token row, ``remat``) with the kernel path steered on, here in
+    the test, traced as the check program is: the three attention
+    kernels are in the program under their names, no block of float32
+    scores is, and the step's scratch is smaller than the plain path's
+    by the two blocks of scores."""
+    import functools
+
+    import yaml
+    from jax._src import config as jax_config
+
+    from msrflute_tpu.models import lfm2, make_task, mla_moe, token_blocks
+    from msrflute_tpu.ops import moe
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    for module in (mla_moe, lfm2):
+        monkeypatch.setattr(module, "causal_attention", functools.partial(
+            token_blocks.causal_attention, interpret=False))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "experiments", model,
+                           "config.yaml")) as fh:
+        mc = yaml.safe_load(fh)["model_config"]
+    task = make_task({**mc, "remat": True, "attention_block": 2048})
+    shapes = jax.eval_shape(task.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        shapes)
+    batch = {"x": jax.ShapeDtypeStruct((1, mc["seq_len"]), jnp.int32,
+                                       sharding=chip),
+             "sample_mask": jax.ShapeDtypeStruct((1,), jnp.float32,
+                                                 sharding=chip)}
+
+    def step(p, b):
+        loss, grads = jax.value_and_grad(
+            lambda q: task.loss(q, b, None, True)[0])(p)
+        return jax.tree.map(lambda a, g: a - 0.1 * g, p, grads), loss
+
+    pa.drain_attention_events()
+    with jax.default_matmul_precision("highest"), \
+            jax_config.exec_time_optimization_effort(-1.0):
+        compiled = jax.jit(step).lower(params, batch).compile()
+    text = compiled.as_text()
+    for name in (pa.FWD_NAME, pa.DQ_NAME, pa.DKV_NAME, moe.GMM_NAME):
+        assert name in text, name
+    assert not SCORES.search(text)
+    said = pa.drain_attention_events()
+    assert said and all(e["impl"] == "flash" for e in said), said
+    # the plain path's step holds 2.2-2.6 GB of scratch (two blocks of
+    # scores of 1.07 GB among it); this one stays under 1.5
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
