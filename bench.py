@@ -407,8 +407,8 @@ def _flute_config(model_cfg, batch_size, client_lr, fuse, eval_bs=128):
 # measurement
 # ----------------------------------------------------------------------
 def _one_client_batch(dataset, batch_size, max_steps):
-    """One client's packed ``[S, B, ...]`` batch + sample mask (shared by
-    the MFU estimate here and ``tools/profile_round.py``)."""
+    """One client's packed ``[S, B, ...]`` batch + sample mask (what the
+    MFU estimate here counts)."""
     from msrflute_tpu.data import pack_round_batches
     rb = pack_round_batches(dataset, [0], batch_size, max_steps,
                             rng=np.random.default_rng(0))
@@ -422,8 +422,8 @@ def grad_step_cost(task, params, batch):
 
     Routed through the ONE compiled-analysis helper
     (``msrflute_tpu.telemetry.xla.aot_cost`` — the same code behind the
-    live device-truth layer and ``tools/profile_round.py``), so the MFU
-    numerator can never drift between bench, profiler and telemetry.
+    live device-truth layer), so the MFU numerator can never drift
+    between bench and telemetry.
     Keys are the normalized ``flops`` / ``bytes_accessed`` /
     ``hbm_bytes`` spellings."""
     import jax
@@ -440,9 +440,7 @@ def grad_step_cost(task, params, batch):
 
 def make_val_ds(dataset, eval_users):
     """Val split used by the bench's ``secs_eval`` measurement: the first
-    ``eval_users`` users of the train pool.  Shared with
-    ``tools/profile_round.py``'s eval breakdown so the breakdown explains
-    the same eval the bench times."""
+    ``eval_users`` users of the train pool."""
     from msrflute_tpu.data import ArraysDataset
     n = min(int(eval_users), len(dataset.user_list))
     return ArraysDataset(dataset.user_list[:n],
@@ -770,7 +768,7 @@ def build_protocols(on_tpu: bool, rng, with_bf16: bool = False) -> dict:
     cadence.  Off-TPU (CI smoke on host CPU) the full protocols are
     compute-bound on host cores; shrink so harnesses still complete — the
     recorded number only means "vs baseline" on real TPU.  Shared with
-    ``tools/profile_round.py``."""
+    ``tools/static_flops_report.py``."""
     fuse = _bench_fuse(on_tpu)
 
     def img(pool, spu, shape, classes):

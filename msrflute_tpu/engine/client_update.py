@@ -278,26 +278,32 @@ def build_client_update(task: BaseTask, client_opt_cfg,
         carry = (params, opt_state, rng, loss_sum, s, s2, n_acc, wloss_acc,
                  ns_acc, {name: jnp.zeros((), jnp.float32)
                           for name in counter_names})
-        if hparams.num_epochs <= 1:
-            # one epoch: the scan reads the step grids as its xs
-            carry, _ = jax.lax.scan(one_step, carry, (arrays, sample_mask))
-        else:
-            # epoch fusion: ONE scan over the flattened
-            # [num_epochs * steps] grid — the body is traced once, and
-            # each step dynamic-slices its batch out of the resident
-            # [S, B, ...] grids (an HBM-local gather, no host bytes)
-            n_steps = sample_mask.shape[0]
-            step_ids = (jnp.arange(hparams.num_epochs * n_steps,
-                                   dtype=jnp.int32) % n_steps)
+        # the catalogue scope of the local steps (docs/observability.md,
+        # "Named scopes"): forward, backward, the client optimizer's
+        # update; every model-level scope lies inside it
+        with jax.named_scope("client_steps"):
+            if hparams.num_epochs <= 1:
+                # one epoch: the scan reads the step grids as its xs
+                carry, _ = jax.lax.scan(one_step, carry,
+                                        (arrays, sample_mask))
+            else:
+                # epoch fusion: ONE scan over the flattened
+                # [num_epochs * steps] grid — the body is traced once,
+                # and each step dynamic-slices its batch out of the
+                # resident [S, B, ...] grids (an HBM-local gather, no
+                # host bytes)
+                n_steps = sample_mask.shape[0]
+                step_ids = (jnp.arange(hparams.num_epochs * n_steps,
+                                       dtype=jnp.int32) % n_steps)
 
-            def fused_step(carry, t):
-                xs = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(
-                        a, t, 0, keepdims=False),
-                    (arrays, sample_mask))
-                return one_step(carry, xs)
+                def fused_step(carry, t):
+                    xs = jax.tree.map(
+                        lambda a: jax.lax.dynamic_index_in_dim(
+                            a, t, 0, keepdims=False),
+                        (arrays, sample_mask))
+                    return one_step(carry, xs)
 
-            carry, _ = jax.lax.scan(fused_step, carry, step_ids)
+                carry, _ = jax.lax.scan(fused_step, carry, step_ids)
         (params, opt_state, rng, loss_sum, s, s2, n_acc, wloss_acc,
          ns_acc, ctr_acc) = carry
 
@@ -570,9 +576,12 @@ def build_mega_update(task: BaseTask, client_opt_cfg,
         lane_carry0 = (lane_params0, lane_opt0, rng0, z_l, z_l, z_l, z_l,
                        z_l, z_l, z_l)
 
-        (_, outs), _ = jax.lax.scan(
-            scan_body, (lane_carry0, (pg0, tl0, ns0, stats0)),
-            (ptr_T, seg_T, start_T, end_T))
+        # the lanes' local steps: the same catalogue scope as
+        # ``client_update``'s loop
+        with jax.named_scope("client_steps"):
+            (_, outs), _ = jax.lax.scan(
+                scan_body, (lane_carry0, (pg0, tl0, ns0, stats0)),
+                (ptr_T, seg_T, start_T, end_T))
         return outs
 
     return mega_update

@@ -26,6 +26,7 @@ from jax import shard_map
 
 from ..models.base import BaseTask
 from ..parallel.mesh import CLIENTS_AXIS
+from ..telemetry import compiles as compile_spans
 from ..utils.metrics import MetricsDict
 
 
@@ -165,8 +166,13 @@ def evaluate(task: BaseTask, eval_fn: Callable, params: Any,
     # flint: disable=put-loop eval-boundary staging, not the per-round dispatch path
     staged = {k: jax.device_put(v, sharding) for k, v in batches.items()}
     if telemetry is not None:
+        # the evaluation program's first launch is followed by its scope
+        # map (telemetry/compiles.py); None while no tracer is attached
+        before = compile_spans.programs_before(eval_fn)
         with telemetry.span("eval_device"):
-            sums = jax.device_get(eval_fn(params, staged))
+            sums = eval_fn(params, staged)
+            compile_spans.program_scopes(eval_fn, before, (params, staged))
+            sums = jax.device_get(sums)
     else:
         sums = jax.device_get(eval_fn(params, staged))
     sums = dict(sums)

@@ -48,11 +48,31 @@ from ..robust import make_shield
 from ..strategies.base import BaseStrategy
 from ..telemetry import (NULL_SPAN, devbus_config_enabled,
                          xla_config_enabled)
+from ..telemetry import compiles as compile_spans
 from ..telemetry import xla as xla_telemetry
 from ..telemetry.devbus import DeviceMetricBus
 from ..utils.flatpack import AxisPacker, FlatPacker, ScalarStager
 from .client_update import (ClientHParams, build_client_update,
                             build_mega_update, _clip_by_global_norm)
+
+
+def _round_scoped(fn: Callable) -> Callable:
+    """``fn`` traced under the catalogue scope ``round_aggregate``
+    (docs/observability.md, "Named scopes"): the one place where every
+    round-program builder gets it.  The clients' local steps open
+    ``client_steps`` inside it (``engine/client_update.py``) and a
+    device operation counts under the innermost catalogue scope of its
+    path, so what reads as ``round_aggregate`` is the round outside the
+    local steps: the pseudo-gradient and its statistics, clipping, DP
+    noise, quantisation (``quant_select`` nested), the strategy's
+    weights, the weighted sum and its ``psum``, the server optimizer's
+    step and the packed stats.  Trace-time metadata only: the compiled
+    arithmetic, and so the compile cache's key, does not change."""
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("round_aggregate"):
+            return fn(*args, **kwargs)
+    return scoped
 
 
 @dataclass
@@ -782,6 +802,18 @@ class RoundEngine:
         out, self._mega_events = self._mega_events, []
         return out
 
+    @staticmethod
+    def _launch(fn: Callable, *args):
+        """Call one jitted entry point.  With a tracer attached, a
+        program's first launch is followed, once the call is issued, by
+        its scope map (``telemetry/compiles.py``: which compiled
+        operation belongs to which ``jax.named_scope``); with tracing
+        off both hooks return at once."""
+        before = compile_spans.programs_before(fn)
+        out = fn(*args)
+        compile_spans.program_scopes(fn, before, args)
+        return out
+
     def _span(self, name: str, **args):
         """One child span of the server's ``dispatch`` — the shared
         no-op context unless the server handed over a factory."""
@@ -1491,6 +1523,7 @@ class RoundEngine:
             # (enables tensor-parallel BERT, which the reference lacks).
             sharded_collect = shard_entry
 
+        @_round_scoped
         def round_step(params, opt_state, strategy_state, arrays, sample_mask,
                        client_mask, client_ids, client_lr, server_lr,
                        round_idx, leakage_threshold, quant_threshold, rng,
@@ -1731,6 +1764,7 @@ class RoundEngine:
         cspec = P(CLIENTS_AXIS)
         rspec = P()
 
+        @_round_scoped
         def shard_body(params, strategy_state, arrays, sample_mask,
                        client_mask, client_ids, client_lr, rng,
                        leakage_threshold, offsets_flat=None):
@@ -1800,7 +1834,7 @@ class RoundEngine:
                 grad_offsets = np.asarray(grad_offsets, np.float32)
             args.append(jax.device_put(grad_offsets, self._client_sharding))
         fn = getattr(self, key)
-        out = fn(*args)
+        out = self._launch(fn, *args)
         self._note_compiles(key.lstrip("_"), fn)
         return out
 
@@ -1968,8 +2002,8 @@ class RoundEngine:
                                       rounds=R)
                 self._staged_cache[key] = fn
             seen = len(self.compile_log)
-            params, opt_state, strategy_state, vecs = fn(
-                state.params, state.opt_state, state.strategy_state,
+            params, opt_state, strategy_state, vecs = self._launch(
+                fn, state.params, state.opt_state, state.strategy_state,
                 ax_dev, sc_dev, rng, *pool_args)
             self._note_compiles(f"staged_r{R}", fn)
             if span is not None:
@@ -2309,6 +2343,7 @@ class RoundEngine:
         else:
             sharded = shard_entry
 
+        @_round_scoped
         def collect_core(params, strategy_state, arrays, sample_mask,
                          client_mask, client_ids, client_lr, round_idx,
                          leakage_threshold, quant_threshold, rng,
@@ -2455,6 +2490,7 @@ class RoundEngine:
                 sa_stats["secagg_abort"] = abort.astype(jnp.float32)
             return gsum, sa_stats
 
+        @_round_scoped
         def finalize(params, opt_state, strategy_state, outs, server_lr,
                      rng):
             bcast = strategy.broadcast_params(params, strategy_state)
@@ -2769,14 +2805,15 @@ class RoundEngine:
                         self._mega_gate[(K, S)] = arm
                     if out is None:
                         if arm == "mega":
-                            out = fn_mega(cur.params, cur.strategy_state,
-                                          ax_dev, sc_dev, rngs[r], tp_dev,
-                                          *pool_args)
+                            out = self._launch(
+                                fn_mega, cur.params, cur.strategy_state,
+                                ax_dev, sc_dev, rngs[r], tp_dev, *pool_args)
                             self._note_compiles(f"megabatch_collect_s{S}",
                                                 fn_mega)
                         else:
-                            out = fn(cur.params, cur.strategy_state, ax_dev,
-                                     sc_dev, rngs[r], *pool_args)
+                            out = self._launch(
+                                fn, cur.params, cur.strategy_state, ax_dev,
+                                sc_dev, rngs[r], *pool_args)
                             self._note_compiles(f"bucket_collect_s{S}", fn)
                     if self.xla is not None and \
                             self.xla.last_dispatch is not None:
@@ -2789,8 +2826,8 @@ class RoundEngine:
                 outs.append(out)
             with self._span("launch", rounds=0, finalize=True) as span:
                 seen = len(self.compile_log)
-                params, opt_state, strategy_state, vecs = finalize(
-                    cur.params, cur.opt_state, cur.strategy_state,
+                params, opt_state, strategy_state, vecs = self._launch(
+                    finalize, cur.params, cur.opt_state, cur.strategy_state,
                     tuple(outs), jnp.asarray(server_lrs[r], jnp.float32),
                     rngs[r])
                 self._note_compiles("bucket_finalize", finalize)

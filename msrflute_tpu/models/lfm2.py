@@ -27,6 +27,12 @@ and the task are ``models/token_blocks.py``'s, shared with
 short convolution, grouped-query attention with QK-norm and rotate-half
 RoPE, the layer pattern and the tied head.
 
+``jax.named_scope``s ``embed``, ``short_conv``, ``gqa_proj``,
+``gqa_attn_core``, ``dense_ffn``, ``routed_experts`` and ``lm_head_loss``
+(the final norm and the tied head's product here, the log-softmax in the
+task's loss) mark the mechanisms in the compiled program's metadata: the
+catalogue of docs/observability.md, "Named scopes".
+
 The attention core is ``token_blocks.causal_attention`` (scope
 ``gqa_attn_core``): the tiled Pallas kernels of
 ``ops/pallas_attention.py`` wherever a compiled kernel applies — query
@@ -70,16 +76,17 @@ class _GatedShortConv(nn.Module):
         w_in = self.param("w_in", _normal(0.02), (hidden, 3 * hidden))
         w_conv = self.param("w_conv", _normal(0.5), (hidden, self.taps))
         w_out = self.param("w_out", _normal(0.02), (hidden, hidden))
-        gate_b, gate_c, u = jnp.split(z @ w_in.astype(self.dtype), 3,
-                                      axis=-1)
-        bu = gate_b * u
-        padded = jnp.pad(bu, ((0, 0), (self.taps - 1, 0), (0, 0)))
-        length = z.shape[1]
-        taps = w_conv.astype(self.dtype)
-        v = sum(taps[:, j] * padded[:, self.taps - 1 - j:
-                                    self.taps - 1 - j + length]
-                for j in range(self.taps))
-        return (gate_c * v) @ w_out.astype(self.dtype)
+        with jax.named_scope("short_conv"):
+            gate_b, gate_c, u = jnp.split(z @ w_in.astype(self.dtype), 3,
+                                          axis=-1)
+            bu = gate_b * u
+            padded = jnp.pad(bu, ((0, 0), (self.taps - 1, 0), (0, 0)))
+            length = z.shape[1]
+            taps = w_conv.astype(self.dtype)
+            v = sum(taps[:, j] * padded[:, self.taps - 1 - j:
+                                        self.taps - 1 - j + length]
+                    for j in range(self.taps))
+            return (gate_c * v) @ w_out.astype(self.dtype)
 
 
 def _rope(x, theta: float):
@@ -110,18 +117,21 @@ class _GQAttention(nn.Module):
         wk = self.param("wk", _normal(0.02), (hidden, kv * dim))
         wv = self.param("wv", _normal(0.02), (hidden, kv * dim))
         wo = self.param("wo", _normal(0.02), (heads * dim, hidden))
-        q = _RMSNorm(self.eps, name="norm_q")(
-            (z @ wq.astype(self.dtype)).reshape(batch, length, heads, dim))
-        k = _RMSNorm(self.eps, name="norm_k")(
-            (z @ wk.astype(self.dtype)).reshape(batch, length, kv, dim))
-        v = (z @ wv.astype(self.dtype)).reshape(batch, length, kv, dim)
-        q, k = _rope(q, self.theta), _rope(k, self.theta)
-        # query head h reads key-value head h // (heads / kv_heads)
-        q = q.reshape(batch, length, kv, heads // kv, dim)
+        with jax.named_scope("gqa_proj"):
+            q = _RMSNorm(self.eps, name="norm_q")(
+                (z @ wq.astype(self.dtype)).reshape(batch, length, heads,
+                                                    dim))
+            k = _RMSNorm(self.eps, name="norm_k")(
+                (z @ wk.astype(self.dtype)).reshape(batch, length, kv, dim))
+            v = (z @ wv.astype(self.dtype)).reshape(batch, length, kv, dim)
+            q, k = _rope(q, self.theta), _rope(k, self.theta)
+            # query head h reads key-value head h // (heads / kv_heads)
+            q = q.reshape(batch, length, kv, heads // kv, dim)
         with jax.named_scope("gqa_attn_core"):
             out = causal_attention(q, k, v, self.block)
-        return out.reshape(batch, length, heads * dim) @ \
-            wo.astype(self.dtype)
+        with jax.named_scope("gqa_proj"):
+            return out.reshape(batch, length, heads * dim) @ \
+                wo.astype(self.dtype)
 
 
 class _Layer(nn.Module):
@@ -143,12 +153,14 @@ class _Layer(nn.Module):
                 dtype, name="attn")(z)
         z = _RMSNorm(eps, name="norm_ffn")(h)
         if self.ffn == "dense":
-            return h + _DenseMLP(c["intermediate_size"], dtype,
-                                 name="mlp")(z), {}
-        y, counters = _HeldExperts(
-            c["num_experts"], c["experts_held"], c["expert_offset"],
-            c["num_experts_per_tok"], c["moe_intermediate_size"],
-            c["routed_scaling_factor"], dtype, name="moe")(z)
+            with jax.named_scope("dense_ffn"):
+                return h + _DenseMLP(c["intermediate_size"], dtype,
+                                     name="mlp")(z), {}
+        with jax.named_scope("routed_experts"):
+            y, counters = _HeldExperts(
+                c["num_experts"], c["experts_held"], c["expert_offset"],
+                c["num_experts_per_tok"], c["moe_intermediate_size"],
+                c["routed_scaling_factor"], dtype, name="moe")(z)
         return h + y, counters
 
 
@@ -167,7 +179,8 @@ class _LFM2(nn.Module):
         x = jnp.pad(x, ((0, 0), (0, -length % block)))
         table = self.param("embedding", _normal(0.02),
                            (self.vocab_size, self.hidden_size))
-        h = jnp.take(table, x, axis=0).astype(dtype)
+        with jax.named_scope("embed"):
+            h = jnp.take(table, x, axis=0).astype(dtype)
         layer_cls = nn.remat(_Layer) if self.remat else _Layer
         counters: Dict[str, jnp.ndarray] = {}
         for i, (op, ffn) in enumerate(self.layers):
@@ -175,8 +188,9 @@ class _LFM2(nn.Module):
             h, counted = layer_cls(op, ffn, self.cfg, name=f"layer_{i}")(h)
             for key, value in counted.items():
                 counters[key] = counters.get(key, 0.0) + value
-        h = _RMSNorm(c["norm_eps"], name="norm_emb")(h)
-        logits = h @ table.T.astype(dtype)
+        with jax.named_scope("lm_head_loss"):
+            h = _RMSNorm(c["norm_eps"], name="norm_emb")(h)
+            logits = h @ table.T.astype(dtype)
         return logits[:, :length], counters
 
 

@@ -38,9 +38,11 @@ kernels of ``ops/pallas_attention.py`` (a value width of its own; scores,
 mask and softmax stay in VMEM, forward and backward) wherever a compiled
 kernel applies, the blocked plain path at ``attention_block`` rows
 elsewhere (the CPU; GSPMD outside ``shard_map``).  ``jax.named_scope``s
-``mla_proj``, ``mla_attn_core``, ``shared_expert`` and ``routed_experts``
-mark the mechanisms in the compiled program's metadata
-(docs/observability.md).
+``embed``, ``mla_proj``, ``mla_attn_core``, ``dense_ffn``,
+``shared_expert``, ``routed_experts`` and ``lm_head_loss`` (the final
+norm and the head's product here, the log-softmax in the task's loss)
+mark the mechanisms in the compiled program's metadata: the catalogue
+of docs/observability.md, "Named scopes".
 
 ``model_config.dtype: bfloat16`` computes activations and matmul
 operands in bfloat16 over float32 master weights (norms, the router and
@@ -142,8 +144,9 @@ class _Layer(nn.Module):
             c["rope_theta"], c["attention_block"], dtype, name="attn")(z)
         z = _RMSNorm(eps, name="norm_ffn")(h)
         if self.ffn == "dense":
-            return h + _DenseMLP(c["intermediate_size"], dtype,
-                                 name="mlp")(z), {}
+            with jax.named_scope("dense_ffn"):
+                return h + _DenseMLP(c["intermediate_size"], dtype,
+                                     name="mlp")(z), {}
         # n_shared_experts SwiGLUs of the experts' width on every token
         # are one SwiGLU of their summed width
         with jax.named_scope("shared_expert"):
@@ -176,7 +179,8 @@ class _MLAMoE(nn.Module):
                            (self.vocab_size, self.hidden_size))
         head = self.param("head", _normal(0.02),
                           (self.vocab_size, self.hidden_size))
-        h = jnp.take(table, x, axis=0).astype(dtype)
+        with jax.named_scope("embed"):
+            h = jnp.take(table, x, axis=0).astype(dtype)
         layer_cls = nn.remat(_Layer) if self.remat else _Layer
         counters: Dict[str, jnp.ndarray] = {}
         for i in range(self.num_layers):
@@ -186,8 +190,9 @@ class _MLAMoE(nn.Module):
                 name=f"layer_{i}")(h)
             for key, value in counted.items():
                 counters[key] = counters.get(key, 0.0) + value
-        h = _RMSNorm(c["rms_norm_eps"], name="norm_emb")(h)
-        logits = h @ head.T.astype(dtype)
+        with jax.named_scope("lm_head_loss"):
+            h = _RMSNorm(c["rms_norm_eps"], name="norm_emb")(h)
+            logits = h @ head.T.astype(dtype)
         return logits[:, :length], counters
 
 

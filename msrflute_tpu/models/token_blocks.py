@@ -187,8 +187,9 @@ class ExpertLMTask(_TokenDatasetMixin, SequenceLMTask):
     def loss(self, params, batch, rng=None, train=True):
         inputs, targets, tok_mask = self._inputs_targets(batch)
         logits, counters = self.module.apply({"params": params}, inputs)
-        value, aux = self._masked_xent(logits.astype(jnp.float32), targets,
-                                       tok_mask, batch)
+        with jax.named_scope("lm_head_loss"):
+            value, aux = self._masked_xent(logits.astype(jnp.float32),
+                                           targets, tok_mask, batch)
         if counters:
             aux["counters"] = counters
         return value, aux

@@ -1,8 +1,7 @@
 """The one timing source of truth for bench/tools phase timers.
 
-Before flutescope, wall-clock timing lived in three ad-hoc probes:
-``bench.py``'s inline ``tic = time.time()`` pairs,
-``tools/profile_round.py``'s copies of them, and
+Before flutescope, wall-clock timing lived in ad-hoc probes:
+``bench.py``'s inline ``tic = time.time()`` pairs and
 ``tools/timing_probe.py``'s scalar-fetch fence.  They now all sit on the
 primitives here, so the methodology (perf_counter clock; scalar-fetch
 sync fence on remote backends) cannot drift between the harnesses that

@@ -29,9 +29,9 @@ extracted:
   vs. the previous compile; the ``recompile_storm`` watchdog detector
   (telemetry/watchdog.py) counts these after warmup.
 - MFU / HBM helpers — :func:`mfu` is the ONE place the
-  ``flops / (secs x chip_peak_flops)`` math lives (bench.py,
-  tools/profile_round.py and the server's live per-round MFU all call
-  it, so the three can never drift); :func:`aot_cost` is the shared
+  ``flops / (secs x chip_peak_flops)`` math lives (bench.py and the
+  server's live per-round MFU both call it, so the two can never
+  drift); :func:`aot_cost` is the shared
   "compile this and tell me what it costs" used by the ad-hoc
   call sites the tools had grown.
 
@@ -239,8 +239,8 @@ def mfu(flops: float, secs: float,
         peak_flops: Optional[float] = None) -> Optional[float]:
     """Model FLOPs utilization: ``flops / (secs x peak)``.
 
-    THE shared MFU formula (bench.py / tools/profile_round.py / the
-    server's live per-round value).  ``peak_flops`` defaults to this
+    THE shared MFU formula (bench.py / the server's live per-round
+    value).  ``peak_flops`` defaults to this
     process's chip via :func:`~msrflute_tpu.utils.compat.chip_peak_flops`
     — on CPU that is a documented NOMINAL peak, so CPU MFU values are
     comparable across CPU runs but never against a TPU's.  Returns None
@@ -307,6 +307,11 @@ class _InstrumentedFn:
                                           compile_seconds=secs)
         self._registry.note_dispatch(self.name, self._sig_by_key[key])
         return compiled(*args)
+
+    def lower(self, *args: Any):
+        """The wrapped jit's own lowering (in-memory after a call): what
+        ``telemetry/compiles.py`` reads a program's scope map from."""
+        return self._jitted.lower(*args)
 
     @property
     def cache_len(self) -> int:

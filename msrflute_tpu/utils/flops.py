@@ -17,8 +17,8 @@ class:
 ``cond`` branches are counted optimistically (max over branches) and
 ``while`` bodies cannot be counted statically (trip count unknown) —
 both are surfaced in the result so a consumer knows when the counts are
-approximate.  Used by ``tools/profile_round.py`` to show the headline
-round is MXU-bound (conv+dot share) without needing the chip.
+approximate.  Used by ``tools/static_flops_report.py`` to show the
+headline round is MXU-bound (conv+dot share) without needing the chip.
 """
 
 from __future__ import annotations

@@ -4,12 +4,12 @@ Writes PROFILE_STATIC.json: for each ``bench.build_protocols`` protocol
 (the TPU geometries, incl. mlm_bert), the exact
 conv/dot/elementwise/other FLOP split of one client grad step — the
 round's inner loop — from the jaxpr (``msrflute_tpu/utils/flops.py``).
-Configs and batches come from bench.py itself (same path
-``tools/profile_round.py`` uses), so the report cannot drift from what
-the benchmark actually runs.  Chip-independent: this is the half of the
+Configs and batches come from bench.py itself, so the report cannot
+drift from what the benchmark actually runs.  Chip-independent: this is the half of the
 compute-bound argument that needs no TPU — it shows the benchmark
 rounds are MXU work (conv+dot), not bookkeeping.  The on-chip half
-(wall-clock, MFU, pack_share) is ``tools/profile_round.py``.
+(device time by scope, MFU, the host chain by span) is
+``benchmarks/run.py --trace 1``.
 
 Usage: python tools/static_flops_report.py [--out PROFILE_STATIC.json]
 """

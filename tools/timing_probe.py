@@ -1,8 +1,7 @@
 """Honest on-chip wall-time measurement for the tools/ scripts.
 
 The implementation moved to :mod:`msrflute_tpu.telemetry.timing` (the
-one timing source of truth — bench.py and tools/profile_round.py sit on
-the same primitives); this module keeps the import path
+one timing source of truth — bench.py sits on the same primitives); this module keeps the import path
 ``flash_crossover_sweep.py`` / ``validate_flash_auto.py`` were written
 against.
 
