@@ -2059,11 +2059,14 @@ class OptimizationServer:
         """Emit the introspector's buffered compile/recompile events as
         structured records (metrics stream + trace instants), plus the
         attention dispatch gate's fallback records
-        (ops/pallas_attention.py — buffered at plan time, host-side)."""
+        (ops/pallas_attention.py — buffered at plan time, host-side)
+        and the convolutions' ``conv_taps`` records (ops/conv.py —
+        buffered at trace time)."""
         if self.scope is None:
             return
+        from ..ops.conv import drain_conv_events
         from ..ops.pallas_attention import drain_attention_events
-        for ev in drain_attention_events():
+        for ev in drain_attention_events() + drain_conv_events():
             self.scope.event(ev.pop("kind"), **ev)
         # megabatch dispatch-gate fallbacks (engine-buffered: the
         # server's analytic slots gate and the aot_cost shootout both

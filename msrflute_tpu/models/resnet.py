@@ -8,18 +8,31 @@ and for vmap-over-clients here — every client's stats stay self-contained).
 Flax implementation, NHWC, GroupNorm native (``nn.GroupNorm``).  The stem is
 the ImageNet-style 7x7/stride-2 + maxpool of the reference; CIFAR inputs
 (32x32) pass through it exactly as they do in the reference.
+
+Behind that stem the four stages run at 8x8, 4x4, 2x2 and, at 32x32
+inputs, 1x1: the last stage's three 3x3 512->512 kernels meet their one
+pixel at the centre tap only, and its first (256->512, stride 2, 2x2 ->
+1x1) at four of nine.  Every convolution goes through
+``ops/conv.py::live_tap_conv``, which reads only those taps (the kernels
+keep their 3x3 shape; a dead tap's gradient is the zero it always was);
+at 64x64 inputs and above no tap is dead and the call is the plain one.
 """
 
 from __future__ import annotations
 
-from typing import Any, Sequence, Tuple
+import functools
+from typing import Any, Sequence
 
 import flax.linen as nn
 import jax.numpy as jnp
 
+from ..ops.conv import live_tap_conv
 from .base import parse_dtype, to_float_image
 from .cv import ClassificationTask
 
+#: ``nn.Conv`` (same name, parameters and initialiser) whose product
+#: skips the taps that only ever meet padding
+_conv = functools.partial(nn.Conv, conv_general_dilated=live_tap_conv)
 
 #: He fan-out init, the reference's ``normal_(0, sqrt(2/n))`` on convs
 #: (``model.py:139-140``)
@@ -53,13 +66,13 @@ class _BasicBlock(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = False):
         residual = x
-        y = nn.Conv(self.planes, (3, 3), strides=(self.stride, self.stride),
-                    padding=1, use_bias=False, kernel_init=_he_init,
-                    dtype=self.dtype)(x)
+        y = _conv(self.planes, (3, 3), strides=(self.stride, self.stride),
+                  padding=1, use_bias=False, kernel_init=_he_init,
+                  dtype=self.dtype)(x)
         y = _gn(self.planes, self.channels_per_group, dtype=self.dtype)(y)
         y = nn.relu(y)
-        y = nn.Conv(self.planes, (3, 3), padding=1, use_bias=False,
-                    kernel_init=_he_init, dtype=self.dtype)(y)
+        y = _conv(self.planes, (3, 3), padding=1, use_bias=False,
+                  kernel_init=_he_init, dtype=self.dtype)(y)
         # block-final norm scale starts at zero so every block begins as
         # identity (the reference's zero_init_residual,
         # ``model.py:148-152``) — without it the 8-block stack amplifies
@@ -67,10 +80,10 @@ class _BasicBlock(nn.Module):
         y = _gn(self.planes, self.channels_per_group, zero_scale=True,
                 dtype=self.dtype)(y)
         if residual.shape[-1] != self.planes or self.stride != 1:
-            residual = nn.Conv(self.planes, (1, 1),
-                               strides=(self.stride, self.stride),
-                               use_bias=False, kernel_init=_he_init,
-                               dtype=self.dtype)(x)
+            residual = _conv(self.planes, (1, 1),
+                             strides=(self.stride, self.stride),
+                             use_bias=False, kernel_init=_he_init,
+                             dtype=self.dtype)(x)
             residual = _gn(self.planes, self.channels_per_group,
                            dtype=self.dtype)(residual)
         return nn.relu(y + residual)
@@ -85,8 +98,8 @@ class _ResNetGN(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = False):
         x = to_float_image(x, self.dtype)
-        x = nn.Conv(64, (7, 7), strides=(2, 2), padding=3, use_bias=False,
-                    kernel_init=_he_init, dtype=self.dtype)(x)
+        x = _conv(64, (7, 7), strides=(2, 2), padding=3, use_bias=False,
+                  kernel_init=_he_init, dtype=self.dtype)(x)
         x = _gn(64, self.channels_per_group, dtype=self.dtype)(x)
         x = nn.relu(x)
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=((1, 1), (1, 1)))

@@ -411,3 +411,48 @@ def test_token_model_step_compiles_for_v5e_with_the_core_in_the_kernels(
     # the plain path's step holds 2.2-2.6 GB of scratch (two blocks of
     # scores of 1.07 GB among it); this one stays under 1.5
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
+
+
+@pytest.mark.parametrize("live_taps_only", [True, False],
+                         ids=["live_tap_conv", "plain_conv"])
+def test_layer4_kernel_stack_is_not_moved_whole_on_v5e(chip,
+                                                       live_taps_only):
+    """ResNet-18's last stage at 32x32 inputs, as the fill cell's round
+    sees it: 50 clients' copies of one 3x3 512->512 kernel over a 1x1
+    map, local SGD steps in a scan.  Through ``ops/conv.py`` the compiled
+    loop has no operation that only moves the 472 MB stack (the
+    gradient's zero ``pad`` becomes the weight-gradient product's own
+    padding, fused with the update); the plain call reverses it for the
+    backward pass, every step."""
+    from jax import lax
+
+    from msrflute_tpu.ops.conv import live_tap_conv
+    fn = live_tap_conv if live_taps_only else lax.conv_general_dilated
+    clients, stack = 50, 50 * 3 * 3 * 512 * 512
+
+    def loss(w, x):
+        y = fn(x, w, (1, 1), [(1, 1), (1, 1)],
+               dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        return jnp.sum(y ** 2)
+
+    def client(w, x):
+        def step(w, _):
+            return w - 0.1 * jax.grad(loss)(w, x), None
+        return lax.scan(step, w, None, length=5)[0]
+
+    def cohort(w0, x):
+        w = jnp.broadcast_to(w0, (clients,) + w0.shape)
+        return jnp.mean(jax.vmap(client)(w, x) - w0, axis=0)
+
+    w0 = jax.ShapeDtypeStruct((3, 3, 512, 512), jnp.float32, sharding=chip)
+    x = jax.ShapeDtypeStruct((clients, 20, 1, 1, 512), jnp.float32,
+                             sharding=chip)
+    text = jax.jit(cohort).lower(w0, x).compile().as_text()
+    movers = []
+    for line in text.splitlines():
+        found = re.match(r"\s*%\S+ = \w+\[([\d,]+)\]\S* "
+                         r"(reverse|copy|transpose)\(", line)
+        if found and np.prod([int(n) for n in
+                              found.group(1).split(",")]) == stack:
+            movers.append(found.group(2))
+    assert bool(movers) == (not live_taps_only), movers
