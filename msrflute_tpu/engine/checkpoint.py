@@ -300,8 +300,9 @@ class CheckpointManager:
         self._mp_cond = threading.Condition()
         self._mp_mailbox = None   # single-slot device snapshot (see _mp_submit)
         self._mp_target = None    # ... and the file it is to become
-        #: the writer keeps a best-model snapshot on the device until the
-        #: training thread waits for it (:meth:`_await_writer`)
+        #: the writer keeps a held snapshot on the device until the
+        #: training thread waits for it (:meth:`_await_writer`) or lets
+        #: it go (:meth:`release`)
         self._mp_hold = False
         self._mp_busy = False
         self._mp_worker = None
@@ -474,6 +475,13 @@ class CheckpointManager:
                 while self._mp_mailbox is not None or self._mp_busy:
                     self._mp_cond.wait()
 
+    def release(self) -> None:
+        """Let the writer fetch a snapshot it was handed on hold, without
+        waiting for the file (nothing held: nothing happens)."""
+        with self._mp_cond:
+            self._mp_hold = False
+            self._mp_cond.notify_all()
+
     def _mp_wait(self, why: Optional[str] = None) -> None:
         """Wait until the writer holds nothing; a best-model save that
         was with it gets its other metric names linked to the file it
@@ -608,7 +616,7 @@ class CheckpointManager:
 
     # -- save ----------------------------------------------------------
     def save_latest(self, state: ServerState,
-                    same_as: Optional[str] = None
+                    same_as: Optional[str] = None, hold: bool = False
                     ) -> Optional[Dict[str, int]]:
         """Save ``latest``; the async msgpack path returns what its
         device snapshot launched (see :meth:`_mp_submit`).  ``same_as``:
@@ -619,7 +627,10 @@ class CheckpointManager:
         landed (:meth:`land_best`): durable on return, and no second
         1.9 GB through the disk.  A best-model save that failed leaves
         nothing to link to: the round then has no ``latest`` of its own,
-        as after any failed save."""
+        as after any failed save.  ``hold``: the async writer copies the
+        snapshot on the device now and fetches it once the caller says
+        :meth:`release` (or waits for the writer), as :meth:`save_best`
+        does."""
         if self.backend == "orbax":
             self._commit_pending_latest()
             committed = self._latest_slot()
@@ -637,7 +648,7 @@ class CheckpointManager:
             self.escalator.check()
             return None
         if self.async_latest:
-            return self._mp_submit(state)
+            return self._mp_submit(state, hold=hold)
         self._write((path,), state)
         return None
 

@@ -1657,6 +1657,9 @@ class OptimizationServer:
             # tail back: the device has its next program now, and the
             # wait for the writer falls beside it
             self._run_pending_tail(deferred=True)
+            # ... and a `latest` that it handed to the writer on hold
+            # starts its transfers
+            self.ckpt.release()
             round_no += R
 
             while len(pending) >= self.pipeline_depth and pending:
@@ -2130,7 +2133,10 @@ class OptimizationServer:
             "padding_efficiency": (
                 round(self.padding_efficiency, 6)
                 if self.padding_efficiency is not None else None),
-            "mfu_p50": p50(rs["mfuPerRound"]),
+            # six significant digits, not six decimals: a small model
+            # on a busy host reads 1e-7, which is not 0
+            "mfu_p50": (float(f"{np.percentile(rs['mfuPerRound'], 50):.6g}")
+                        if rs["mfuPerRound"] else None),
             "puts_per_dispatch": int(self.engine.last_dispatch_puts),
             "compiles": len(self.engine.compile_log),
             "recompiles": int(self.engine.recompile_count),
@@ -2579,8 +2585,17 @@ class OptimizationServer:
         # is a link to that file
         link = best_file if saved_state is self.state and \
             not skip_latest else None
+        # a `latest` of its own on the chunk loop (no evaluation, or
+        # one that found nothing better) is held like the best model's
+        # snapshot: copied on the device here, before the next dispatch
+        # donates the buffers, and fetched once that dispatch is launched
+        # (the loop says `release`).  Fetched at once, its 1.8 GB of
+        # transfers kept the next dispatch's inputs waiting: on the chip
+        # such a period took 7.2-7.4 s where an improving one took 6.4
+        hold = chunk is not None and link is None and not skip_latest
         tail = functools.partial(self._durable_tail, round_no,
-                                 status_update, link, skip_latest, chunk)
+                                 status_update, link, skip_latest, chunk,
+                                 hold)
         if chunk is not None and link is not None and \
                 self.ckpt.async_latest:
             # the file is still with the writer, and nothing the tail
@@ -2616,13 +2631,16 @@ class OptimizationServer:
 
     def _durable_tail(self, round_no: int, status_update: Dict[str, Any],
                       link: Optional[str], skip_latest: bool,
-                      chunk: Optional[int], deferred: bool) -> None:
+                      chunk: Optional[int], hold: bool,
+                      deferred: bool) -> None:
         """Everything that writes a NAME for round ``round_no``'s state,
         on the training thread and in the one order a hard kill may cut
         anywhere: the best-model file and its sidecar (the writer's,
         waited for here) -> the status log -> ``latest`` -> backups ->
         the paired stores' markers.  ``link``: the best-model file that
-        this round's ``latest`` is a link to."""
+        this round's ``latest`` is a link to; ``hold``: a ``latest``
+        of its own stays on the device until the chunk loop has launched
+        its next dispatch."""
         self.ckpt.land_best()
         # the status write leads the round's durable sequence (status ->
         # rows/marker -> checkpoint), and the ring keeps one snapshot
@@ -2633,7 +2651,7 @@ class OptimizationServer:
         with self._tspan("ckpt_submit", round=round_no, deferred=deferred,
                          **({} if chunk is None else {"chunk": chunk})):
             if not skip_latest:
-                self.ckpt.save_latest(self.state, same_as=link)
+                self.ckpt.save_latest(self.state, same_as=link, hold=hold)
             self.ckpt.backup(self.state, round_no,
                              best_names=tuple(self.best_val))
         if self.scaffold_store is not None:

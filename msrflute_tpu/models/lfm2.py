@@ -21,11 +21,11 @@ plain float32 form the benchmark compares this module with); in short,
 
 The parameter tree's names are a checkpoint contract and are the plain
 reference's (``layer_<i>/{norm_op, norm_ffn, conv | attn, mlp | moe}``).
-RMSNorm, the dense SwiGLU, the held experts, the causal attention core
-and the task are ``models/token_blocks.py``'s, shared with
-``models/mla_moe.py``; this file holds what is LFM2's own: the gated
-short convolution, grouped-query attention with QK-norm and rotate-half
-RoPE, the layer pattern and the tied head.
+RMSNorm, the dense SwiGLU, the held experts, grouped-query attention
+with QK-norm and rotate-half RoPE (shared with ``models/sdar_moe.py``),
+the causal attention core and the task are ``models/token_blocks.py``'s,
+shared with ``models/mla_moe.py``; this file holds what is LFM2's own:
+the gated short convolution, the layer pattern and the tied head.
 
 ``jax.named_scope``s ``embed``, ``short_conv``, ``gqa_proj``,
 ``gqa_attn_core``, ``dense_ffn``, ``routed_experts`` and ``lm_head_loss``
@@ -61,9 +61,8 @@ import jax
 import jax.numpy as jnp
 
 from .base import parse_dtype
-from .token_blocks import (COUNTERS, ExpertLMTask, _DenseMLP, _HeldExperts,
-                           _normal, _RMSNorm, causal_attention, check_held,
-                           rope_angles)
+from .token_blocks import (COUNTERS, ExpertLMTask, _DenseMLP, _GQAttention,
+                           _HeldExperts, _normal, _RMSNorm, check_held)
 
 
 class _GatedShortConv(nn.Module):
@@ -87,51 +86,6 @@ class _GatedShortConv(nn.Module):
                                         self.taps - 1 - j + length]
                     for j in range(self.taps))
             return (gate_c * v) @ w_out.astype(self.dtype)
-
-
-def _rope(x, theta: float):
-    """Rotate-half RoPE on ``[B, L, heads, D]`` at positions 0..L-1,
-    angles in float32."""
-    angles = rope_angles(x.shape[1], x.shape[-1], theta)
-    angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
-    half = x.shape[-1] // 2
-    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    return (x * jnp.cos(angles).astype(x.dtype) +
-            rotated * jnp.sin(angles).astype(x.dtype))
-
-
-class _GQAttention(nn.Module):
-    heads: int
-    kv_heads: int
-    head_dim: int
-    eps: float
-    theta: float
-    block: int
-    dtype: Any
-
-    @nn.compact
-    def __call__(self, z):  # [B, L, D], L a multiple of block
-        batch, length, hidden = z.shape
-        heads, kv, dim = self.heads, self.kv_heads, self.head_dim
-        wq = self.param("wq", _normal(0.02), (hidden, heads * dim))
-        wk = self.param("wk", _normal(0.02), (hidden, kv * dim))
-        wv = self.param("wv", _normal(0.02), (hidden, kv * dim))
-        wo = self.param("wo", _normal(0.02), (heads * dim, hidden))
-        with jax.named_scope("gqa_proj"):
-            q = _RMSNorm(self.eps, name="norm_q")(
-                (z @ wq.astype(self.dtype)).reshape(batch, length, heads,
-                                                    dim))
-            k = _RMSNorm(self.eps, name="norm_k")(
-                (z @ wk.astype(self.dtype)).reshape(batch, length, kv, dim))
-            v = (z @ wv.astype(self.dtype)).reshape(batch, length, kv, dim)
-            q, k = _rope(q, self.theta), _rope(k, self.theta)
-            # query head h reads key-value head h // (heads / kv_heads)
-            q = q.reshape(batch, length, kv, heads // kv, dim)
-        with jax.named_scope("gqa_attn_core"):
-            out = causal_attention(q, k, v, self.block)
-        with jax.named_scope("gqa_proj"):
-            return out.reshape(batch, length, heads * dim) @ \
-                wo.astype(self.dtype)
 
 
 class _Layer(nn.Module):

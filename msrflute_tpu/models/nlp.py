@@ -258,6 +258,13 @@ class _TokenDatasetMixin:
 
     tokenizer: str = "words"  # or "chars"
 
+    def row_fields(self, entry, split: str, user: int) -> dict:
+        """Further per-position arrays ``[rows, L]`` of one user's rows
+        (``entry``: its ``x`` and ``tok_mask``), carried to the loss
+        beside them; a task that has some also lists them in
+        ``seq_pad_keys``."""
+        return {}
+
     def make_dataset(self, blob, model_config, split, data_config=None):
         import numpy as np
         from ..data.dataset import ArraysDataset
@@ -301,6 +308,7 @@ class _TokenDatasetMixin:
                 y, y_mask = encode_rows(blob.user_labels[i])
                 entry["y"] = y
                 entry["tok_mask"] = y_mask
+            entry.update(self.row_fields(entry, split, i))
             per_user.append(entry)
         return ArraysDataset(blob.user_list, per_user,
                              [len(u["x"]) for u in per_user])

@@ -123,3 +123,8 @@ def _load_builtins() -> None:
         TASK_REGISTRY.setdefault("MLA_MOE", mla_moe.make_mla_moe_task)
     except ImportError:
         pass
+    try:
+        from . import sdar_moe
+        TASK_REGISTRY.setdefault("SDAR_MOE", sdar_moe.make_sdar_moe_task)
+    except ImportError:
+        pass

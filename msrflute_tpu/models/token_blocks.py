@@ -1,14 +1,17 @@
 """What the token models with held experts have in common
-(``models/lfm2.py``, ``models/mla_moe.py``): RMSNorm, the dense SwiGLU,
-the held share of a routed expert layer, RoPE's angles, the causal
-attention core (the tiled kernels of ``ops/pallas_attention.py``, or
-blocks of plain attention rows where no compiled kernel applies), and
-the causal-LM task that carries the expert layers' counters.
+(``models/lfm2.py``, ``models/mla_moe.py``, ``models/sdar_moe.py``):
+RMSNorm, the dense SwiGLU, the held share of a routed expert layer,
+RoPE's angles, grouped-query attention with QK-norm and rotate-half RoPE,
+the two attention cores (causal; block diffusion over a doubled row), each
+through the tiled kernels of ``ops/pallas_attention.py`` or through
+blocks of plain attention rows where no compiled kernel applies, and the
+two tasks that carry the expert layers' counters: the causal LM and the
+block-diffusion LM.
 
 The modules' parameter names (``weight``; ``w1``/``w3``/``w2``;
-``router``/``select_bias``/``w1``/``w3``/``w2``) are part of the two
-models' checkpoint contracts and of their plain references
-(``benchmarks/reference/``).
+``router``/``select_bias``/``w1``/``w3``/``w2``; ``wq``/``wk``/``wv``/
+``wo``/``norm_q``/``norm_k``) are part of the models' checkpoint
+contracts and of their plain references (``benchmarks/reference/``).
 """
 
 from __future__ import annotations
@@ -18,11 +21,15 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.moe import held_experts_ffn
-from ..ops.pallas_attention import (causal_flash_attention,
+from ..ops.pallas_attention import (block_diffusion_flash_attention,
+                                    causal_flash_attention,
                                     record_attention_path)
 from ..ops.pallas_kernels import compiled_kernels_apply
+from ..utils.metrics import Metric
+from .base import softmax_xent
 from .nlp import SequenceLMTask, _TokenDatasetMixin
 
 #: what the expert layers count, summed over layers and local steps
@@ -56,19 +63,26 @@ def rope_angles(length: int, dim: int, theta: float):
     return jnp.arange(length, dtype=jnp.float32)[:, None] * inv_freq[None]
 
 
-def _attention_rows(q_rows, k, v, row0: int):
-    """One block of query rows ``[B, R, KV, G, D]`` from position
-    ``row0`` over the keys ``[B, M, KV, D]`` up to the block's end and
-    the values ``[B, M, KV, Dv]`` (``Dv`` need not be ``D``); scale
-    ``D ** -0.5``, softmax in float32."""
+def _masked_rows(q_rows, k, v, seen_of):
+    """Softmax attention of one block of query rows ``[B, R, KV, G, D]``
+    over keys ``[B, M, KV, D]`` and values ``[B, M, KV, Dv]`` (``Dv``
+    need not be ``D``) under ``seen_of() -> bool [R, M]``; scale ``D **
+    -0.5``, softmax in float32."""
     scale = q_rows.shape[-1] ** -0.5
     scores = jnp.einsum("brkgd,bmkd->bkgrm", q_rows, k).astype(
         jnp.float32) * scale
-    rows = row0 + jnp.arange(q_rows.shape[1])[:, None]
-    cols = jnp.arange(k.shape[1])[None, :]
-    scores = jnp.where(cols <= rows, scores, -jnp.inf)
+    scores = jnp.where(seen_of(), scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
     return jnp.einsum("bkgrm,bmkd->brkgd", probs, v)
+
+
+def _attention_rows(q_rows, k, v, row0: int):
+    """One block of causal query rows from position ``row0`` over the
+    keys and values up to the block's end."""
+    def seen():
+        rows = row0 + jnp.arange(q_rows.shape[1])[:, None]
+        return jnp.arange(k.shape[1])[None, :] <= rows
+    return _masked_rows(q_rows, k, v, seen)
 
 
 def _blocked_attention(q, k, v, block: int):
@@ -107,6 +121,126 @@ def causal_attention(q, k, v, block: int, interpret=None):
     return out.reshape(batch, length, kv, group, v.shape[-1])
 
 
+def _bd_attention_rows(q_rows, k, v, row0: int, own: int, span: int):
+    """One block of query rows ``[B, R, KV, G, D]`` of a block-diffusion
+    row, in-half positions from ``row0``, over the keys it can see: the
+    first ``own`` are the noised half's rows from ``row0`` (a noised
+    query's own rows; it sees its own block of ``span`` there), the rest
+    the clean half's rows from 0 (a noised query sees the earlier
+    blocks, a clean one, ``own == 0``, its own block too)."""
+    def seen():
+        q_blk = (row0 + jnp.arange(q_rows.shape[1]))[:, None] // span
+        cols = jnp.arange(k.shape[1])[None, :]
+        k_blk = jnp.where(cols < own, row0 + cols, cols - own) // span
+        return jnp.where(cols < own, k_blk == q_blk,
+                         k_blk < q_blk if own else k_blk <= q_blk)
+    return _masked_rows(q_rows, k, v, seen)
+
+
+def _blocked_bd_attention(q, k, v, span: int, block: int):
+    """Block-diffusion attention of a doubled row (``[xt ; x0]``, ``L``
+    positions each, ``L`` a multiple of ``block`` and ``block`` of
+    ``span``), ``block`` query rows at a time over the keys they can
+    see, each a ``jax.checkpoint``: the plain path, and the statement
+    the kernels are tested against."""
+    length = q.shape[1] // 2
+    rows = jax.checkpoint(_bd_attention_rows, static_argnums=(3, 4, 5))
+    out = []
+    for clean in (0, 1):
+        for r0 in range(0, length, block):
+            own = slice(r0, r0 + block)
+            before = slice(length, length + r0 + block)
+            keys, values = (
+                (k[:, before], v[:, before]) if clean else
+                (jnp.concatenate([k[:, own], k[:, before]], axis=1),
+                 jnp.concatenate([v[:, own], v[:, before]], axis=1)))
+            out.append(rows(
+                q[:, clean * length + r0:clean * length + r0 + block],
+                keys, values, r0, 0 if clean else block, span))
+    return jnp.concatenate(out, axis=1)
+
+
+def block_diffusion_attention(q, k, v, span: int, block: int,
+                              interpret=None):
+    """The block-diffusion core over a doubled row: ``q [B, 2 L, KV, G,
+    D]`` over ``k [B, 2 L, KV, D]`` and ``v [B, 2 L, KV, Dv]``, rows
+    ``[xt ; x0]``, blocks of ``span`` positions.  With ``blk(i) = (i mod
+    L) // span``: an ``xt`` query sees the ``xt`` keys of its own block
+    and the ``x0`` keys of earlier blocks; an ``x0`` query the ``x0``
+    keys of its own and earlier blocks, and no ``xt`` key.  The kernels
+    of ``ops/pallas_attention.py`` (a static tile map: unseen tiles are
+    no grid step) wherever a compiled kernel applies, else
+    :func:`_blocked_bd_attention`, as :func:`causal_attention`."""
+    batch, rows, kv, group, dim = q.shape
+    if interpret is None and not compiled_kernels_apply():
+        record_attention_path("plain", (batch, rows, kv * group, dim),
+                              k.shape, v.shape, block, rows // 2 + block)
+        return _blocked_bd_attention(q, k, v, span, block)
+    out = block_diffusion_flash_attention(
+        q.reshape(batch, rows, kv * group, dim), k, v, span,
+        interpret=interpret)
+    return out.reshape(batch, rows, kv, group, v.shape[-1])
+
+
+def rope_half(x, theta: float, copies: int = 1):
+    """Rotate-half RoPE on ``[B, L, heads, D]`` (element ``i`` with ``i +
+    D / 2``), angles in float32, at positions 0..L-1, or, a row of
+    ``copies`` copies side by side, at 0..L/copies-1 in each."""
+    angles = rope_angles(x.shape[1] // copies, x.shape[-1], theta)
+    if copies > 1:
+        angles = jnp.tile(angles, (copies, 1))
+    angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
+    half = x.shape[-1] // 2
+    rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return (x * jnp.cos(angles).astype(x.dtype) +
+            rotated * jnp.sin(angles).astype(x.dtype))
+
+
+class _GQAttention(nn.Module):
+    """Grouped-query attention with an RMSNorm on every head's query and
+    key and rotate-half RoPE.  ``diffusion_block`` 0: causal over the
+    row; ``> 0``: the row is ``[xt ; x0]`` (both halves at positions
+    0..L-1) under the block-diffusion mask at that block length."""
+    heads: int
+    kv_heads: int
+    head_dim: int
+    eps: float
+    theta: float
+    block: int
+    dtype: Any
+    diffusion_block: int = 0
+
+    @nn.compact
+    def __call__(self, z):  # [B, L, D], L a multiple of block
+        batch, length, hidden = z.shape
+        heads, kv, dim = self.heads, self.kv_heads, self.head_dim
+        copies = 2 if self.diffusion_block else 1
+        wq = self.param("wq", _normal(0.02), (hidden, heads * dim))
+        wk = self.param("wk", _normal(0.02), (hidden, kv * dim))
+        wv = self.param("wv", _normal(0.02), (hidden, kv * dim))
+        wo = self.param("wo", _normal(0.02), (heads * dim, hidden))
+        with jax.named_scope("gqa_proj"):
+            q = _RMSNorm(self.eps, name="norm_q")(
+                (z @ wq.astype(self.dtype)).reshape(batch, length, heads,
+                                                    dim))
+            k = _RMSNorm(self.eps, name="norm_k")(
+                (z @ wk.astype(self.dtype)).reshape(batch, length, kv, dim))
+            v = (z @ wv.astype(self.dtype)).reshape(batch, length, kv, dim)
+            q = rope_half(q, self.theta, copies)
+            k = rope_half(k, self.theta, copies)
+            # query head h reads key-value head h // (heads / kv_heads)
+            q = q.reshape(batch, length, kv, heads // kv, dim)
+        with jax.named_scope("gqa_attn_core"):
+            if self.diffusion_block:
+                out = block_diffusion_attention(
+                    q, k, v, self.diffusion_block, self.block)
+            else:
+                out = causal_attention(q, k, v, self.block)
+        with jax.named_scope("gqa_proj"):
+            return out.reshape(batch, length, heads * dim) @ \
+                wo.astype(self.dtype)
+
+
 class _DenseMLP(nn.Module):
     width: int
     dtype: Any
@@ -124,7 +258,9 @@ class _DenseMLP(nn.Module):
 class _HeldExperts(nn.Module):
     """``experts_held`` of ``num_experts`` routed SwiGLU experts, from
     ``expert_offset``; returns ``(y, counters)``.  ``route_eps``: the
-    epsilon in the gate's denominator, None = ``route_tokens``' own."""
+    epsilon in the gate's denominator, None = ``route_tokens``' own.
+    ``scoring``: ``route_tokens``' law; ``softmax`` has no selection
+    bias, so no such leaf."""
     num_experts: int
     experts_held: int
     expert_offset: int
@@ -133,6 +269,7 @@ class _HeldExperts(nn.Module):
     scaling: float
     dtype: Any
     route_eps: Any = None
+    scoring: str = "sigmoid"
 
     @nn.compact
     def __call__(self, z):
@@ -140,7 +277,8 @@ class _HeldExperts(nn.Module):
         held = self.experts_held
         router = self.param("router", _normal(hidden ** -0.5),
                             (hidden, self.num_experts))
-        bias = self.param("select_bias", _normal(0.1), (self.num_experts,))
+        bias = (self.param("select_bias", _normal(0.1), (self.num_experts,))
+                if self.scoring == "sigmoid" else None)
         w1 = self.param("w1", _normal(0.02), (held, hidden, self.width))
         w3 = self.param("w3", _normal(0.02), (held, hidden, self.width))
         w2 = self.param("w2", _normal(0.02), (held, self.width, hidden))
@@ -149,7 +287,7 @@ class _HeldExperts(nn.Module):
             w3.astype(self.dtype), w2.astype(self.dtype),
             experts_per_token=self.per_token,
             expert_offset=self.expert_offset, scaling=self.scaling,
-            route_eps=self.route_eps)
+            route_eps=self.route_eps, scoring=self.scoring)
         return y.reshape(z.shape), counters
 
 
@@ -193,3 +331,127 @@ class ExpertLMTask(_TokenDatasetMixin, SequenceLMTask):
         if counters:
             aux["counters"] = counters
         return value, aux
+
+
+# ----------------------------------------------------------------------
+# the block-diffusion objective (the vectorised training form of BD3-LM,
+# arXiv:2503.09573, as SDAR, arXiv:2510.06303, adopts it)
+# ----------------------------------------------------------------------
+#: what the block-diffusion task counts beside the expert layers'
+#: counters, summed over rows and local steps: the real positions that
+#: were masked (and so scored), and the real positions
+BD_COUNTERS = ("bd_positions_masked", "bd_positions_real")
+#: the least masking rate of a block (the greatest weight is its inverse)
+BD_RATE_MIN = 0.05
+_SPLITS = {"train": 0, "val": 1, "test": 2}
+
+
+def bd_draws(noise_seed: int, split: str, user: int, row: int, length: int,
+             span: int) -> tuple:
+    """The noise of one row, a pure function of ``(noise_seed, split,
+    user, row)``: per block of ``span`` positions a rate ``t`` uniform on
+    ``[BD_RATE_MIN, 1]``, per position a uniform ``u``; returns ``(masked
+    [length] float32: 1.0 where u < t of the position's block, weight
+    [length] float32: 1 / t of the position's block)``.  numpy, on the
+    host, when the dataset is built: never inside the compiled step."""
+    rng = np.random.default_rng(np.random.SeedSequence(
+        [int(noise_seed), _SPLITS[split], int(user), int(row)]))
+    blocks = -(-length // span)
+    rate = np.repeat(rng.uniform(BD_RATE_MIN, 1.0, size=blocks),
+                     span)[:length]
+    masked = rng.uniform(size=length) < rate
+    return masked.astype(np.float32), (1.0 / rate).astype(np.float32)
+
+
+class BlockDiffusionLMTask(ExpertLMTask):
+    """Block-diffusion LM over a module that takes the doubled row
+    ``[xt ; x0]`` (``[B, 2 L]`` ids) and returns ``(logits of the xt half
+    [B, L, V], counters)``.
+
+    The noise is an INPUT: ``make_dataset`` draws, once a row, ``bd_mask``
+    (1.0 where the position is masked) and ``bd_weight`` (``1 / t`` of
+    its block) from ``(model_config.noise_seed, split, user, row)``
+    (:func:`bd_draws`), zero beyond the row's real positions, and the
+    batch carries them beside ``x`` and ``tok_mask``.  ``xt`` is ``x``
+    with the mask id (the vocabulary's last id) at the masked positions.
+
+    Loss of a batch: ``sum over rows and masked real positions i of
+    bd_weight_i * (-log softmax(logits_i)[x_i]) / sum over rows of real
+    positions``: no shift between a position's logits and its target.
+    The step's sample count is the rows.  Evaluation: the same loss, and
+    accuracy over the masked real positions."""
+
+    counter_names = COUNTERS + BD_COUNTERS
+    seq_pad_keys = ("x", "tok_mask", "bd_mask", "bd_weight")
+
+    def __init__(self, module, seq_len: int, name: str, span: int,
+                 mask_id: int, noise_seed: int):
+        super().__init__(module, seq_len=seq_len, name=name)
+        self.span, self.mask_id, self.noise_seed = span, mask_id, noise_seed
+
+    def init_params(self, rng: jax.Array):
+        # one block of each half (see ExpertLMTask.init_params)
+        dummy = jnp.zeros((1, 2 * self.span), jnp.int32)
+        return jax.jit(self.module.init)(rng, dummy)["params"]
+
+    def row_fields(self, entry, split: str, user: int) -> dict:
+        real = entry["tok_mask"]
+        draws = [bd_draws(self.noise_seed, split, user, row, real.shape[1],
+                          self.span) for row in range(real.shape[0])]
+        return {"bd_mask": real * np.stack([m for m, _ in draws]),
+                "bd_weight": real * np.stack([w for _, w in draws])}
+
+    def _scored(self, params, batch):
+        """``(weighted loss sum, logits [B, L, V], masked, real,
+        counters)``: the cross entropy of the masked real positions
+        (``masked [B, L]``), each times its ``bd_weight``, summed;
+        ``real [B, L]`` the real positions."""
+        x0 = batch["x"].astype(jnp.int32)
+        real = batch["tok_mask"].astype(jnp.float32) * \
+            batch["sample_mask"][:, None]
+        masked = batch["bd_mask"].astype(jnp.float32) * real
+        xt = jnp.where(masked > 0, self.mask_id, x0)
+        logits, counters = self.module.apply(
+            {"params": params}, jnp.concatenate([xt, x0], axis=1))
+        with jax.named_scope("lm_head_loss"):
+            logits = logits.astype(jnp.float32)
+            loss_sum = jnp.sum(softmax_xent(logits, x0) * masked *
+                               batch["bd_weight"].astype(jnp.float32))
+        counters = {**counters, "bd_positions_masked": jnp.sum(masked),
+                    "bd_positions_real": jnp.sum(real)}
+        return loss_sum, logits, masked, real, counters
+
+    def _logits_targets(self, params, batch):
+        # the causal tasks' helpers (top-k predictions, token log-probs)
+        # score shifted targets of a row read once
+        raise NotImplementedError(
+            "a block-diffusion model's logits are those of a noised copy "
+            "beside the clean one: BlockDiffusionLMTask._scored")
+
+    def loss(self, params, batch, rng=None, train=True):
+        loss_sum, _, _, real, counters = self._scored(params, batch)
+        return loss_sum / jnp.maximum(jnp.sum(real), 1.0), {
+            "sample_count": jnp.sum(batch["sample_mask"]),
+            "counters": counters}
+
+    def eval_stats(self, params, batch):
+        loss_sum, logits, masked, real, _ = self._scored(params, batch)
+        hit = (jnp.argmax(logits, axis=-1) ==
+               batch["x"].astype(jnp.int32)).astype(jnp.float32)
+        return {
+            # the evaluation's loss is loss_sum / sample_count: the
+            # training loss's normalisation, the real positions
+            "loss_sum": loss_sum,
+            "sample_count": jnp.sum(real),
+            # accuracy over the masked real positions
+            "correct_sum": jnp.sum(hit * masked),
+            "correct_count": jnp.sum(masked),
+            "seq_count": jnp.sum(batch["sample_mask"]),
+        }
+
+    def finalize_metrics(self, sums):
+        metrics = super().finalize_metrics(sums)
+        metrics["acc"] = Metric(
+            float(sums["correct_sum"]) /
+            max(float(sums["correct_count"]), 1.0), higher_is_better=True)
+        return metrics

@@ -331,17 +331,32 @@ ROUTE_EPS = 1e-6
 
 
 def route_tokens(z, router_w, select_bias, experts_per_token: int,
-                 scaling: float = 1.0, eps: float = ROUTE_EPS):
-    """Sigmoid routing with a selection bias over ALL experts:
-    ``(chosen [T, k] int32, gate [T, k] float32)``.  The logits are
-    float32 at ``highest`` whatever the context (a choice that flips on
-    rounding is a discrete event); the bias enters the choice only and
-    gets no gradient; the gate is renormalised over all chosen experts,
-    held here or not: ``scaling * s_i / (sum of chosen s + eps)``, with
-    the CALLER's ``eps`` (the models' published forms differ:
-    ``ROUTE_EPS``)."""
+                 scaling: float = 1.0, eps: float = ROUTE_EPS,
+                 scoring: str = "sigmoid"):
+    """Routing over ALL experts: ``(chosen [T, k] int32, gate [T, k]
+    float32)``, by one of two scoring laws.  The logits are float32 at
+    ``highest`` whatever the context (a choice that flips on rounding is
+    a discrete event) under both.
+
+    - ``sigmoid`` (LFM2, DeepSeek-V3's block): scores ``sigmoid(logits)``,
+      chosen = top k of ``score + select_bias``; the bias enters the
+      choice only and gets no gradient; the gate is renormalised over all
+      chosen experts, held here or not: ``scaling * s_i / (sum of chosen
+      s + eps)``, with the CALLER's ``eps`` (the models' published forms
+      differ: ``ROUTE_EPS``);
+    - ``softmax`` (the ``qwen3_moe`` form): scores ``softmax(logits)``
+      over all experts, chosen = top k of them, ``g_i = s_i / sum of
+      chosen s``: no bias (``select_bias`` is not read), no factor, no
+      epsilon."""
     logits = jnp.matmul(z.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
+    if scoring == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+        picked, chosen = lax.top_k(scores, experts_per_token)
+        return chosen, picked / jnp.sum(picked, axis=-1, keepdims=True)
+    if scoring != "sigmoid":
+        raise ValueError(f"route_tokens: scoring {scoring!r}; expected "
+                         "sigmoid or softmax")
     scores = jax.nn.sigmoid(logits)
     _, chosen = lax.top_k(
         scores + lax.stop_gradient(select_bias.astype(jnp.float32)),
@@ -446,15 +461,18 @@ tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
 
 def held_experts_ffn(z, router_w, select_bias, w1, w3, w2, *,
                      experts_per_token: int, expert_offset: int = 0,
-                     scaling: float = 1.0, route_eps: float | None = None):
+                     scaling: float = 1.0, route_eps: float | None = None,
+                     scoring: str = "sigmoid"):
     """The held experts' part of a routed SwiGLU layer for tokens
     ``z [T, D]``: ``sum over chosen AND held i of g_i E_i(z)``.
     ``w1``/``w3``: ``[held, D, H]``, ``w2``: ``[held, H, D]``,
     ``router_w``: ``[D, num_experts]``.  ``route_eps``: the epsilon of
     the gate's denominator, the caller's to give (None: ``route_tokens``'
-    own, ``ROUTE_EPS``).  Returns ``(y [T, D], counters)``; the counters
-    (float32 scalars) are what the telemetry reads: pairs on held experts
-    (``moe_pairs_held``), the largest held expert's load
+    own, ``ROUTE_EPS``).  ``scoring``: ``route_tokens``' law (``softmax``
+    reads no ``select_bias``: None will do).  Returns ``(y [T, D],
+    counters)``; the counters (float32 scalars) are what the telemetry
+    reads: pairs on held experts (``moe_pairs_held``), the largest held
+    expert's load
     (``moe_max_load``), pairs without a row (``moe_pairs_dropped``, 0 by
     construction), 1 for this layer-step (``moe_layer_steps``) and the
     tiles of ``TILE_ROWS`` rows that the grouped products ran
@@ -462,12 +480,13 @@ def held_experts_ffn(z, router_w, select_bias, w1, w3, w2, *,
     tiles, at least one each; ``moe_pairs_held`` over its rows is the
     share of them that are real pairs)."""
     held_n = w1.shape[0]
-    # positional, and the epsilon only where the caller gave one: the
-    # benchmark's planted routing faults replace ``route_tokens`` with
-    # functions of these five arguments
+    # positional, and the epsilon and the law only where the caller gave
+    # one: the benchmark's planted routing faults replace
+    # ``route_tokens`` with functions of these five arguments
     chosen, gate = route_tokens(
         z, router_w, select_bias, experts_per_token, scaling,
-        **({} if route_eps is None else {"eps": route_eps}))
+        **({} if route_eps is None else {"eps": route_eps}),
+        **({} if scoring == "sigmoid" else {"scoring": scoring}))
     row_of_pair, held, pair_of_row, tile_expert, n_active, counts = \
         plan_pairs(chosen, held_n, expert_offset)
     x_sorted = rows_of_tokens(z, pair_of_row, row_of_pair, held)

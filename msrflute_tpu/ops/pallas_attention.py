@@ -11,7 +11,11 @@ behind the planner below) and the token models with held experts
 grouped key-value heads, static blocks, no planner:
 :func:`causal_flash_attention`).  Three kernels, under stable names in
 the device trace (``attn_flash_fwd``, ``attn_flash_dq``,
-``attn_flash_dkv``; FlashAttention-2 style tiling):
+``attn_flash_dkv``; FlashAttention-2 style tiling); a third family, the
+block-diffusion model (``models/sdar_moe.py``), runs the same tile bodies
+under a mask that is not causal, on a static tile map, as three kernels
+of its own (``attn_bd_fwd``, ``attn_bd_dq``, ``attn_bd_dkv``:
+:func:`block_diffusion_flash_attention`, further down):
 
 - forward: grid ``(B, H, Lq/block_q, Lk/block_k)`` with the key/value
   block index INNERMOST and ``arbitrary`` semantics — mosaic pipelines
@@ -168,6 +172,102 @@ def _each_tile(accumulate, *, causal, pad_k, q_lo, k_lo, block_q, block_k,
 
 
 # ----------------------------------------------------------------------
+# one score tile of each kernel: what the causal kernels and the
+# block-diffusion kernels (further down) run at a grid step that counts.
+# ``mask_of``: falsy where every entry of the tile counts (no iota,
+# compare or select), else ``shape -> bool array`` of the entries that do
+# ----------------------------------------------------------------------
+def _fwd_tile(q_ref, k_ref, v_ref, m_s, l_s, acc_s, mask_of, cdt):
+    """The online-softmax step of the forward kernel for one tile."""
+    precision = _dot_precision(cdt)
+    q = q_ref[0, 0].astype(cdt)          # [bq, D], scaled by the caller
+    k_blk = k_ref[0, 0].astype(cdt)      # [bk, D]
+    v_blk = v_ref[0, 0].astype(cdt)      # [bk, Dv]
+    s = jax.lax.dot_general(q, k_blk, _NT, precision=precision,
+                            preferred_element_type=jnp.float32)
+    if mask_of:
+        mask = mask_of(s.shape)
+        s = jnp.where(mask, s, _NEG)
+    m_prev, l_prev = m_s[...], l_s[...]  # lane-replicated [bq, 128]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.exp(s - _to_width(m_new, s.shape[1]))
+    if mask_of:
+        # rows with every entry masked have s == m_new == _NEG, and
+        # exp(0) would resurrect them
+        p = jnp.where(mask, p, 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    m_s[...] = m_new
+    l_s[...] = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
+    acc_s[...] = acc_s[...] * _to_width(corr, acc_s.shape[1]) + \
+        jnp.dot(p.astype(cdt), v_blk, precision=precision,
+                preferred_element_type=jnp.float32)
+
+
+def _fwd_finalize(o_ref, lse_ref, m_s, l_s, acc_s):
+    m, l = m_s[...], l_s[...]
+    safe = jnp.maximum(l, 1e-30)
+    o_ref[0, 0] = (acc_s[...] / _to_width(safe, acc_s.shape[1])
+                   ).astype(o_ref.dtype)
+    # TPU mosaic requires the last two BLOCK dims be (8k, 128m)-
+    # aligned, so the per-row lse is stored lane-replicated as
+    # [bq, _STAT_LANES] (same trick as jax's own tpu flash kernel);
+    # rows that saw no key: zeros, lse = _NEG
+    lse_ref[0, 0] = jnp.where(l > 0, m + jnp.log(safe), _NEG)
+
+
+def _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_s, mask_of,
+             cdt):
+    """One tile's term of ``dq`` (against the SCALED q: the caller
+    applies ``ds``'s own factor once, at the end)."""
+    precision = _dot_precision(cdt)
+    q = q_ref[0, 0].astype(cdt)
+    do = do_ref[0, 0].astype(cdt)
+    k_blk = k_ref[0, 0].astype(cdt)
+    v_blk = v_ref[0, 0].astype(cdt)
+    s = jax.lax.dot_general(q, k_blk, _NT, precision=precision,
+                            preferred_element_type=jnp.float32)
+    # lse and delta arrive lane-replicated [bq, _STAT_LANES]
+    p = jnp.exp(s - _to_width(lse_ref[0, 0], s.shape[1]))
+    if mask_of:
+        p = jnp.where(mask_of(s.shape), p, 0.0)
+    dp = jax.lax.dot_general(do, v_blk, _NT, precision=precision,
+                             preferred_element_type=jnp.float32)
+    # d lse / d s = p: the caller has taken the lse cotangent off delta
+    ds = p * (dp - _to_width(delta_ref[0, 0], s.shape[1]))
+    dq_s[...] = dq_s[...] + jnp.dot(ds.astype(cdt), k_blk,
+                                    precision=precision,
+                                    preferred_element_type=jnp.float32)
+
+
+def _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_s, dv_s,
+              mask_of, cdt):
+    """One tile's terms of ``dk`` and ``dv``, on the TRANSPOSED score
+    tile ``[bk, bq]``: the row statistics ride as rows ``[1, bq]`` (a
+    sublane broadcast) and both accumulations are plain ``a @ b``."""
+    precision = _dot_precision(cdt)
+    k_blk = k_ref[0, 0].astype(cdt)      # [bk, D]
+    v_blk = v_ref[0, 0].astype(cdt)      # [bk, Dv]
+    q = q_ref[0, 0].astype(cdt)          # [bq, D], scaled by the caller
+    do = do_ref[0, 0].astype(cdt)        # [bq, Dv]
+    s_t = jax.lax.dot_general(k_blk, q, _NT, precision=precision,
+                              preferred_element_type=jnp.float32)
+    # padded query rows carry lse = delta = do = 0: they add nothing
+    p_t = jnp.exp(s_t - lse_ref[0, 0])   # [bk, bq] - [1, bq]
+    if mask_of:
+        p_t = jnp.where(mask_of(s_t.shape), p_t, 0.0)
+    dv_s[...] = dv_s[...] + jnp.dot(p_t.astype(cdt), do,
+                                    precision=precision,
+                                    preferred_element_type=jnp.float32)
+    dp_t = jax.lax.dot_general(v_blk, do, _NT, precision=precision,
+                               preferred_element_type=jnp.float32)
+    ds_t = p_t * (dp_t - delta_ref[0, 0])
+    # against the SCALED q: ds's own factor is in it
+    dk_s[...] = dk_s[...] + jnp.dot(ds_t.astype(cdt), q,
+                                    precision=precision,
+                                    preferred_element_type=jnp.float32)
+
+
+# ----------------------------------------------------------------------
 # forward
 # ----------------------------------------------------------------------
 def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -177,7 +277,6 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     q_lo = offs_ref[0] + qi * block_q
     k_lo = offs_ref[1] + kj * block_k
     pad_k = num_k * block_k != l_k
-    precision = _dot_precision(cdt)
 
     @pl.when(kj == 0)
     def _init():
@@ -186,43 +285,15 @@ def _fwd_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_s[...] = jnp.zeros_like(acc_s)
 
     def accumulate(masked):
-        q = q_ref[0, 0].astype(cdt)          # [bq, D], scaled by the caller
-        k_blk = k_ref[0, 0].astype(cdt)      # [bk, D]
-        v_blk = v_ref[0, 0].astype(cdt)      # [bk, Dv]
-        s = jax.lax.dot_general(q, k_blk, _NT, precision=precision,
-                                preferred_element_type=jnp.float32)
-        if masked:
-            mask = _tile_mask(s.shape, 0, q_lo, k_lo, kj * block_k, l_k,
-                              causal, pad_k)
-            s = jnp.where(mask, s, _NEG)
-        m_prev, l_prev = m_s[...], l_s[...]  # lane-replicated [bq, 128]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - _to_width(m_new, block_k))
-        if masked:
-            # rows with every entry masked have s == m_new == _NEG, and
-            # exp(0) would resurrect them
-            p = jnp.where(mask, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        m_s[...] = m_new
-        l_s[...] = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
-        acc_s[...] = acc_s[...] * _to_width(corr, acc_s.shape[1]) + \
-            jnp.dot(p.astype(cdt), v_blk, precision=precision,
-                    preferred_element_type=jnp.float32)
+        _fwd_tile(q_ref, k_ref, v_ref, m_s, l_s, acc_s, masked and (
+            lambda shape: _tile_mask(shape, 0, q_lo, k_lo, kj * block_k,
+                                     l_k, causal, pad_k)), cdt)
 
     _each_tile(accumulate, causal=causal, pad_k=pad_k, q_lo=q_lo, k_lo=k_lo,
                block_q=block_q, block_k=block_k, last_k=kj == num_k - 1)
 
-    @pl.when(kj == num_k - 1)
-    def _finalize():
-        m, l = m_s[...], l_s[...]
-        safe = jnp.maximum(l, 1e-30)
-        o_ref[0, 0] = (acc_s[...] / _to_width(safe, acc_s.shape[1])
-                       ).astype(o_ref.dtype)
-        # TPU mosaic requires the last two BLOCK dims be (8k, 128m)-
-        # aligned, so the per-row lse is stored lane-replicated as
-        # [bq, _STAT_LANES] (same trick as jax's own tpu flash kernel);
-        # rows that saw no key: zeros, lse = _NEG
-        lse_ref[0, 0] = jnp.where(l > 0, m + jnp.log(safe), _NEG)
+    pl.when(kj == num_k - 1)(
+        lambda: _fwd_finalize(o_ref, lse_ref, m_s, l_s, acc_s))
 
 
 # ----------------------------------------------------------------------
@@ -235,31 +306,16 @@ def _dq_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_lo = offs_ref[0] + qi * block_q
     k_lo = offs_ref[1] + kj * block_k
     pad_k = num_k * block_k != l_k
-    precision = _dot_precision(cdt)
 
     @pl.when(kj == 0)
     def _init():
         dq_s[...] = jnp.zeros_like(dq_s)
 
     def accumulate(masked):
-        q = q_ref[0, 0].astype(cdt)
-        do = do_ref[0, 0].astype(cdt)
-        k_blk = k_ref[0, 0].astype(cdt)
-        v_blk = v_ref[0, 0].astype(cdt)
-        s = jax.lax.dot_general(q, k_blk, _NT, precision=precision,
-                                preferred_element_type=jnp.float32)
-        # lse and delta arrive lane-replicated [bq, _STAT_LANES]
-        p = jnp.exp(s - _to_width(lse_ref[0, 0], block_k))
-        if masked:
-            p = jnp.where(_tile_mask(s.shape, 0, q_lo, k_lo, kj * block_k,
-                                     l_k, causal, pad_k), p, 0.0)
-        dp = jax.lax.dot_general(do, v_blk, _NT, precision=precision,
-                                 preferred_element_type=jnp.float32)
-        # d lse / d s = p: the caller has taken the lse cotangent off delta
-        ds = p * (dp - _to_width(delta_ref[0, 0], block_k))
-        dq_s[...] = dq_s[...] + jnp.dot(ds.astype(cdt), k_blk,
-                                        precision=precision,
-                                        preferred_element_type=jnp.float32)
+        _dq_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_s,
+                 masked and (lambda shape: _tile_mask(
+                     shape, 0, q_lo, k_lo, kj * block_k, l_k, causal,
+                     pad_k)), cdt)
 
     _each_tile(accumulate, causal=causal, pad_k=pad_k, q_lo=q_lo, k_lo=k_lo,
                block_q=block_q, block_k=block_k, last_k=kj == num_k - 1)
@@ -283,7 +339,6 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q_lo = offs_ref[0] + qj * block_q
     k_lo = offs_ref[1] + ki * block_k
     pad_k = num_k * block_k != l_k
-    precision = _dot_precision(cdt)
 
     @pl.when(t == 0)
     def _init():
@@ -291,28 +346,10 @@ def _dkv_kernel(offs_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_s[...] = jnp.zeros_like(dv_s)
 
     def accumulate(masked):
-        k_blk = k_ref[0, 0].astype(cdt)      # [bk, D]
-        v_blk = v_ref[0, 0].astype(cdt)      # [bk, Dv]
-        q = q_ref[0, 0].astype(cdt)          # [bq, D], scaled by the caller
-        do = do_ref[0, 0].astype(cdt)        # [bq, Dv]
-        s_t = jax.lax.dot_general(k_blk, q, _NT, precision=precision,
-                                  preferred_element_type=jnp.float32)
-        # padded query rows carry lse = delta = do = 0: they add nothing
-        p_t = jnp.exp(s_t - lse_ref[0, 0])   # [bk, bq] - [1, bq]
-        if masked:
-            p_t = jnp.where(_tile_mask(s_t.shape, 1, q_lo, k_lo,
-                                       ki * block_k, l_k, causal, pad_k),
-                            p_t, 0.0)
-        dv_s[...] = dv_s[...] + jnp.dot(p_t.astype(cdt), do,
-                                        precision=precision,
-                                        preferred_element_type=jnp.float32)
-        dp_t = jax.lax.dot_general(v_blk, do, _NT, precision=precision,
-                                   preferred_element_type=jnp.float32)
-        ds_t = p_t * (dp_t - delta_ref[0, 0])
-        # against the SCALED q: ds's own factor is in it
-        dk_s[...] = dk_s[...] + jnp.dot(ds_t.astype(cdt), q,
-                                        precision=precision,
-                                        preferred_element_type=jnp.float32)
+        _dkv_tile(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_s,
+                  dv_s, masked and (lambda shape: _tile_mask(
+                      shape, 1, q_lo, k_lo, ki * block_k, l_k, causal,
+                      pad_k)), cdt)
 
     _each_tile(accumulate, causal=causal, pad_k=pad_k, q_lo=q_lo, k_lo=k_lo,
                block_q=block_q, block_k=block_k, last_k=ki == num_k - 1)
@@ -844,6 +881,428 @@ def causal_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return _flash_lse(q, k, v, 0, 0, True, block_q, block_k,
                       _plain_interpret(interpret),
                       context_mxu_dtype(q.dtype))[0]
+
+
+# ----------------------------------------------------------------------
+# Block-diffusion attention (models/sdar_moe.py): a row is TWO copies of
+# ``L`` positions, the noised one (``xt``, rows 0..L-1) and the clean one
+# (``x0``, rows L..2L-1), in blocks of ``B``; with ``blk(i) = (i mod L)
+# // B`` a query sees
+#
+#   xt -> xt: blk(k) == blk(q)     xt -> x0: blk(k) <  blk(q)
+#   x0 -> x0: blk(k) <= blk(q)     x0 -> xt: never
+#
+# ``L (L + B)`` of the ``4 L^2`` pairs, in two regions a query tile, so
+# no sweep "up to the diagonal" serves it.  The three kernels below run
+# a STATIC TILE MAP instead: the tiles that hold a seen pair, listed on
+# the host from ``L``, ``B`` and the tile, one grid step each (a tile
+# with no seen pair is no grid step: neither fetched nor computed); the
+# operands' block indices and each step's flags (first / last of its
+# accumulator, masked) ride scalar-prefetched tables.  A tile wholly
+# seen runs without iota, compare or select; a tile a block boundary
+# crosses computes its mask from position ids and the static ``L``,
+# ``B`` (no mask array anywhere).  The tiles' bodies, the precision
+# rules and the grouped heads are the causal kernels'.
+# ----------------------------------------------------------------------
+BD_FWD_NAME = "attn_bd_fwd"
+BD_DQ_NAME = "attn_bd_dq"
+BD_DKV_NAME = "attn_bd_dkv"
+_FIRST, _LAST, _MASKED = 1, 2, 4
+_BD_SEMANTICS = (_PARALLEL, _PARALLEL, _ARBITRARY)
+
+
+def bd_seen(length: int, block: int) -> np.ndarray:
+    """The statement: ``seen[q, k]`` over the ``2 length`` positions of
+    a row ``[xt ; x0]``, as a boolean array (tests, small sizes)."""
+    pos = np.arange(2 * length)
+    half, blk = pos // length, (pos % length) // block
+    qh, kh, qb, kb = half[:, None], half[None, :], blk[:, None], blk[None, :]
+    return np.where(qh == 0, np.where(kh == 0, kb == qb, kb < qb),
+                    (kh == 1) & (kb <= qb))
+
+
+def bd_tile_map(length: int, block: int, block_q: int, block_k: int) -> dict:
+    """Which tiles of the ``[2 lp, 2 lp]`` square run (``lp``: ``length``
+    padded to whole tiles, each half on its own; padded positions are
+    positions like any other, and a real query sees none of them).
+    ``rows[i]``: query tile ``i``'s ``(key tile, masked)`` in sweep
+    order; the counts (``tiles_run``, ``tiles_masked``, ``tiles_total``)
+    and ``pairs_seen`` = ``length (length + block)``, the pairs a head
+    needs."""
+    if length % block or block_q % block or block_k % block:
+        raise ValueError(f"block-diffusion attention: the row ({length}) "
+                         f"and the tile ({block_q} x {block_k}) must be "
+                         f"whole blocks of {block}")
+    lp = _ceil_to(length, int(np.lcm(block_q, block_k)))
+    nq, nk = lp // block_q, lp // block_k
+    rows = []
+    for i in range(2 * nq):
+        q_lo, q_hi = ((i % nq) * block_q // block,
+                      ((i % nq + 1) * block_q - 1) // block)
+        row = []
+        for j in range(2 * nk):
+            k_lo, k_hi = ((j % nk) * block_k // block,
+                          ((j % nk + 1) * block_k - 1) // block)
+            if i < nq and j < nk:        # xt -> xt: the same block
+                some = k_lo <= q_hi and q_lo <= k_hi
+                whole = q_lo == q_hi == k_lo == k_hi
+            elif i < nq:                 # xt -> x0: an earlier block
+                some, whole = k_lo < q_hi, k_hi < q_lo
+            elif j >= nk:                # x0 -> x0: not a later block
+                some, whole = k_lo <= q_hi, k_hi <= q_lo
+            else:                        # x0 -> xt
+                some = whole = False
+            if some:
+                row.append((j, not whole))
+        rows.append(row)
+    run = sum(len(r) for r in rows)
+    return {"rows": rows, "lp": lp, "nq": nq, "nk": nk,
+            "tiles_run": run,
+            "tiles_masked": sum(m for r in rows for _, m in r),
+            "tiles_total": 4 * nq * nk,
+            "pairs_seen": length * (length + block)}
+
+
+def _bd_tables(tiles: dict, group: int) -> tuple:
+    """The map as flat int32 tables, one entry a grid step.  Forward and
+    ``dq``: ``(query tile, key tile, flags)`` query tile by query tile;
+    ``dk``/``dv``: ``(key tile, head of the group, query tile, flags)``
+    key tile by key tile, every head of the group in turn."""
+    def flags(n, at, masked):
+        return (_FIRST * (at == 0) + _LAST * (at == n - 1) +
+                _MASKED * bool(masked))
+
+    by_row = [(i, j, flags(len(row), at, m))
+              for i, row in enumerate(tiles["rows"])
+              for at, (j, m) in enumerate(row)]
+    columns = [[] for _ in range(2 * tiles["nk"])]
+    for i, row in enumerate(tiles["rows"]):
+        for j, m in row:
+            columns[j].append((i, m))
+    by_column = []
+    for j, column in enumerate(columns):
+        steps = [(g, i, m) for g in range(group) for i, m in column]
+        by_column += [(j, g, i, flags(len(steps), at, m))
+                      for at, (g, i, m) in enumerate(steps)]
+
+    def table(entries):
+        return tuple(jnp.asarray(np.asarray(col, np.int32))
+                     for col in zip(*entries))
+    return table(by_row), table(by_column)
+
+
+def _bd_mask(shape, q_axis, q_tile, k_tile, *, block, block_q, block_k,
+             num_q, num_k):
+    """The seen entries of one tile, from its two tile indices: with
+    ``d = blk(q) - blk(k)``, ``xt -> xt``: ``d == 0``; ``xt -> x0``:
+    ``d >= 1``; ``x0 -> x0``: ``d >= 0`` (one compare pair for the
+    three; ``x0 -> xt`` is no tile of the map)."""
+    q_ids = (q_tile % num_q) * block_q + \
+        jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    k_ids = (k_tile % num_k) * block_k + \
+        jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis)
+    if block & (block - 1) == 0:
+        shift = block.bit_length() - 1
+        d = (q_ids >> shift) - (k_ids >> shift)
+    else:
+        d = q_ids // block - k_ids // block
+    q_clean, k_clean = q_tile >= num_q, k_tile >= num_k
+    least = jnp.where(jnp.logical_and(k_clean, jnp.logical_not(q_clean)),
+                      1, 0)
+    most = jnp.where(k_clean, 2 * num_q * block_q, 0)
+    return jnp.logical_and(d >= least, d <= most)
+
+
+def _bd_step(flags, init, tile, finalize):
+    """One grid step of a table-driven kernel: ``tile(masked)`` between
+    its accumulator's first and last steps."""
+    pl.when(flags & _FIRST != 0)(init)
+    pl.when(flags & _MASKED == 0)(lambda: tile(False))
+    pl.when(flags & _MASKED != 0)(lambda: tile(True))
+    pl.when(flags & _LAST != 0)(finalize)
+
+
+def _bd_fwd_kernel(qt_ref, kt_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
+                   lse_ref, m_s, l_s, acc_s, *, cdt, **geo):
+    t = pl.program_id(2)
+
+    def init():
+        m_s[...] = jnp.full_like(m_s, _NEG)
+        l_s[...] = jnp.zeros_like(l_s)
+        acc_s[...] = jnp.zeros_like(acc_s)
+
+    _bd_step(fl_ref[t], init,
+             lambda masked: _fwd_tile(
+                 q_ref, k_ref, v_ref, m_s, l_s, acc_s, masked and (
+                     lambda shape: _bd_mask(shape, 0, qt_ref[t], kt_ref[t],
+                                            **geo)), cdt),
+             lambda: _fwd_finalize(o_ref, lse_ref, m_s, l_s, acc_s))
+
+
+def _bd_dq_kernel(qt_ref, kt_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
+                  lse_ref, delta_ref, dq_ref, dq_s, *, scale, cdt, **geo):
+    t = pl.program_id(2)
+
+    def init():
+        dq_s[...] = jnp.zeros_like(dq_s)
+
+    def finalize():
+        dq_ref[0, 0] = (dq_s[...] * scale).astype(dq_ref.dtype)
+
+    _bd_step(fl_ref[t], init,
+             lambda masked: _dq_tile(
+                 q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_s,
+                 masked and (lambda shape: _bd_mask(
+                     shape, 0, qt_ref[t], kt_ref[t], **geo)), cdt),
+             finalize)
+
+
+def _bd_dkv_kernel(kt_ref, head_ref, qt_ref, fl_ref, q_ref, k_ref, v_ref,
+                   do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s, *,
+                   cdt, **geo):
+    del head_ref  # the index maps read it
+    t = pl.program_id(2)
+
+    def init():
+        dk_s[...] = jnp.zeros_like(dk_s)
+        dv_s[...] = jnp.zeros_like(dv_s)
+
+    def finalize():
+        dk_ref[0, 0] = dk_s[...].astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_s[...].astype(dv_ref.dtype)
+
+    _bd_step(fl_ref[t], init,
+             lambda masked: _dkv_tile(
+                 q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_s, dv_s,
+                 masked and (lambda shape: _bd_mask(
+                     shape, 1, qt_ref[t], kt_ref[t], **geo)), cdt),
+             finalize)
+
+
+class _BDGeometry:
+    """What the three block-diffusion calls share: the tile map, the
+    padded sizes, the group of query heads a key-value head serves."""
+
+    def __init__(self, q, k, v, block, block_q, block_k):
+        self.B, rows, self.H, self.D = q.shape
+        self.L = rows // 2
+        self.KV, self.Dv = k.shape[2], v.shape[3]
+        self.G = self.H // self.KV
+        self.bq, self.bk = block_q, block_k
+        self.tiles = bd_tile_map(self.L, block, block_q, block_k)
+        self.lp = self.tiles["lp"]
+        self.d_p, self.dv_p = _ceil_to(self.D, _LANES), \
+            _ceil_to(self.Dv, _LANES)
+        self.scale = float(self.D ** -0.5)
+        self.by_row, self.by_column = _bd_tables(self.tiles, self.G)
+        self.static = dict(block=block, block_q=block_q, block_k=block_k,
+                           num_q=self.tiles["nq"], num_k=self.tiles["nk"])
+
+    def heads_first(self, x, width, dtype):
+        """``[B, 2 L, heads, D]`` -> ``[B, heads, 2 lp, width]``: each
+        half padded to whole tiles on its own."""
+        batch, _, heads, dim = x.shape
+        x = _pad_axis(x.reshape(batch, 2, self.L, heads, dim), 2, self.lp)
+        return _heads_first(x.reshape(batch, 2 * self.lp, heads, dim),
+                            2 * self.lp, width, dtype)
+
+    def back(self, x, width):
+        """The kernels' ``[B, heads, 2 lp, *]`` -> ``[B, 2 L, heads,
+        width]``."""
+        batch, heads = x.shape[:2]
+        x = x.transpose(0, 2, 1, 3).reshape(batch, 2, self.lp, heads, -1)
+        return x[:, :, :self.L, :, :width].reshape(batch, 2 * self.L, heads,
+                                                   width)
+
+    def row_specs(self):
+        """Forward and ``dq``: grid ``(B, H, step)``, tables ``(query
+        tile, key tile, flags)``."""
+        G = self.G
+
+        def q_side(width):
+            return pl.BlockSpec((1, 1, self.bq, width),
+                                lambda b, h, t, qt, kt, fl: (b, h, qt[t], 0))
+
+        def k_side(width):
+            return pl.BlockSpec(
+                (1, 1, self.bk, width),
+                lambda b, h, t, qt, kt, fl: (b, h // G, kt[t], 0))
+        return q_side, k_side
+
+    def column_specs(self):
+        """``dk``/``dv``: grid ``(B, KV, step)``, tables ``(key tile,
+        head of the group, query tile, flags)``; the row statistics as
+        rows ``[B, H, 1, 2 lp]`` in blocks ``[1, bq]``."""
+        G = self.G
+
+        def q_side(width):
+            return pl.BlockSpec(
+                (1, 1, self.bq, width),
+                lambda b, h, t, kt, hd, qt, fl: (b, h * G + hd[t], qt[t], 0))
+
+        def k_side(width):
+            return pl.BlockSpec(
+                (1, 1, self.bk, width),
+                lambda b, h, t, kt, hd, qt, fl: (b, h, kt[t], 0))
+        stat = pl.BlockSpec(
+            (1, 1, 1, self.bq),
+            lambda b, h, t, kt, hd, qt, fl: (b, h * G + hd[t], 0, qt[t]))
+        return q_side, k_side, stat
+
+    def stat(self, x):
+        """``[B, H, 2 L]`` row statistics, each half padded like the
+        rows (zeros: a padded query adds nothing in the backward)."""
+        batch, heads = x.shape[:2]
+        x = _pad_axis(x.astype(jnp.float32).reshape(batch, heads, 2, self.L),
+                      3, self.lp)
+        return x.reshape(batch, heads, 2 * self.lp)
+
+
+def _bd_params():
+    return pltpu.CompilerParams(dimension_semantics=_BD_SEMANTICS,
+                                vmem_limit_bytes=_VMEM_LIMIT)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _bd_fwd(q, k, v, block, block_q, block_k, interpret, mxu):
+    g = _BDGeometry(q, k, v, block, block_q, block_k)
+    cdt = jnp.dtype(mxu)
+    qp = g.heads_first(q.astype(jnp.float32) * g.scale, g.d_p, cdt)
+    kp, vp = g.heads_first(k, g.d_p, cdt), g.heads_first(v, g.dv_p, cdt)
+    q_side, k_side = g.row_specs()
+    out, lse = pl.pallas_call(
+        functools.partial(_bd_fwd_kernel, cdt=cdt, **g.static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(g.B, g.H, g.tiles["tiles_run"]),
+            in_specs=[q_side(g.d_p), k_side(g.d_p), k_side(g.dv_p)],
+            out_specs=[q_side(g.dv_p), q_side(_STAT_LANES)],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
+                pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
+                pltpu.VMEM((block_q, g.dv_p), jnp.float32)]),
+        out_shape=[
+            jax.ShapeDtypeStruct((g.B, g.H, 2 * g.lp, g.dv_p), q.dtype),
+            jax.ShapeDtypeStruct((g.B, g.H, 2 * g.lp, _STAT_LANES),
+                                 jnp.float32)],
+        compiler_params=_bd_params(), interpret=interpret, name=BD_FWD_NAME,
+    )(*g.by_row, qp, kp, vp)
+    lse = lse[..., 0].reshape(g.B, g.H, 2, g.lp)[..., :g.L]
+    return g.back(out, g.Dv), lse.reshape(g.B, g.H, 2 * g.L)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
+def _bd_bwd(q, k, v, out, lse, do, block, block_q, block_k, interpret, mxu):
+    g = _BDGeometry(q, k, v, block, block_q, block_k)
+    cdt = jnp.dtype(mxu)
+    qp = g.heads_first(q.astype(jnp.float32) * g.scale, g.d_p, cdt)
+    kp, vp = g.heads_first(k, g.d_p, cdt), g.heads_first(v, g.dv_p, cdt)
+    dop = g.heads_first(do, g.dv_p, cdt)
+    delta = g.stat(jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                           axis=3).transpose(0, 2, 1))
+    lse = g.stat(lse)
+
+    def lanes(x):  # [B, H, 2 lp] -> lane-replicated
+        return jnp.broadcast_to(x[..., None], x.shape + (_STAT_LANES,))
+
+    q_side, k_side = g.row_specs()
+    dq = pl.pallas_call(
+        functools.partial(_bd_dq_kernel, scale=g.scale, cdt=cdt, **g.static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(g.B, g.H, g.tiles["tiles_run"]),
+            in_specs=[q_side(g.d_p), k_side(g.d_p), k_side(g.dv_p),
+                      q_side(g.dv_p), q_side(_STAT_LANES),
+                      q_side(_STAT_LANES)],
+            out_specs=q_side(g.d_p),
+            scratch_shapes=[pltpu.VMEM((block_q, g.d_p), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((g.B, g.H, 2 * g.lp, g.d_p), q.dtype),
+        compiler_params=_bd_params(), interpret=interpret, name=BD_DQ_NAME,
+    )(*g.by_row, qp, kp, vp, dop, lanes(lse), lanes(delta))
+
+    # dk/dv: key tile by key tile, the query tiles of every head of the
+    # key-value head's group summed into one accumulator
+    q_col, k_col, stat = g.column_specs()
+    dk, dv = pl.pallas_call(
+        functools.partial(_bd_dkv_kernel, cdt=cdt, **g.static),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(g.B, g.KV, g.G * g.tiles["tiles_run"]),
+            in_specs=[q_col(g.d_p), k_col(g.d_p), k_col(g.dv_p),
+                      q_col(g.dv_p), stat, stat],
+            out_specs=[k_col(g.d_p), k_col(g.dv_p)],
+            scratch_shapes=[pltpu.VMEM((block_k, g.d_p), jnp.float32),
+                            pltpu.VMEM((block_k, g.dv_p), jnp.float32)]),
+        out_shape=[
+            jax.ShapeDtypeStruct((g.B, g.KV, 2 * g.lp, g.d_p), k.dtype),
+            jax.ShapeDtypeStruct((g.B, g.KV, 2 * g.lp, g.dv_p), v.dtype)],
+        compiler_params=_bd_params(), interpret=interpret, name=BD_DKV_NAME,
+    )(*g.by_column, qp, kp, vp, dop, lse[:, :, None, :],
+      delta[:, :, None, :])
+    return g.back(dq, g.D), g.back(dk, g.D), g.back(dv, g.Dv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _bd_attention(q, k, v, block, block_q, block_k, interpret, mxu):
+    return _bd_fwd(q, k, v, block, block_q, block_k, interpret, mxu)[0]
+
+
+def _bd_attention_fwd(q, k, v, block, block_q, block_k, interpret, mxu):
+    out, lse = _bd_fwd(q, k, v, block, block_q, block_k, interpret, mxu)
+    return out, (q, k, v, out, lse)
+
+
+def _bd_attention_bwd(block, block_q, block_k, interpret, mxu, saved, do):
+    return _bd_bwd(*saved, do, block, block_q, block_k, interpret, mxu)
+
+
+_bd_attention.defvjp(_bd_attention_fwd, _bd_attention_bwd)
+
+
+def record_attention_tiles(length: int, block: int, block_q: int,
+                           block_k: int) -> dict:
+    """Buffer an ``attn_tiles`` event (once a distinct geometry between
+    two drains, as ``attention_path``): what the block-diffusion core's
+    static tile map runs for a row of ``2 length`` positions.  Returns
+    the map's counts."""
+    tiles = bd_tile_map(length, block, block_q, block_k)
+    record = {"kind": "attn_tiles", "L": int(length), "B": int(block),
+              "block_q": int(block_q), "block_k": int(block_k),
+              **{key: int(tiles[key]) for key in
+                 ("tiles_run", "tiles_masked", "tiles_total",
+                  "pairs_seen")}}
+    if record not in _PENDING_EVENTS and len(_PENDING_EVENTS) < _EVENTS_CAP:
+        _PENDING_EVENTS.append(record)
+    return record
+
+
+def block_diffusion_flash_attention(q, k, v, block: int, *,
+                                    block_q: Optional[int] = None,
+                                    block_k: Optional[int] = None,
+                                    interpret: Optional[bool] = None):
+    """Block-diffusion self-attention through the kernels: ``q [B, 2 L,
+    H, D]`` over ``k [B, 2 L, KV, D]`` and ``v [B, 2 L, KV, Dv]``, rows
+    ``[xt ; x0]``, blocks of ``block`` positions, seen as written above
+    (:func:`bd_seen`); grouped heads, the value width, the scale, the
+    operands' precision and the residuals as
+    :func:`causal_flash_attention`.  ``block_q`` / ``block_k``: the
+    tile, :func:`causal_blocks`' where not given (tests give their own).
+    Returns ``[B, 2 L, H, Dv]``."""
+    if q.ndim != 4 or q.shape[:2] != k.shape[:2] or \
+            k.shape[:3] != v.shape[:3] or q.shape[3] != k.shape[3] or \
+            q.shape[2] % k.shape[2] or q.shape[1] % 2:
+        raise ValueError(f"q {q.shape}, k {k.shape}, v {v.shape}: not a "
+                         "doubled row's queries over grouped key-value "
+                         "heads")
+    length = q.shape[1] // 2
+    tile_q, tile_k = causal_blocks(length)
+    block_q, block_k = int(block_q or tile_q), int(block_k or tile_k)
+    record_attention_path("flash", q.shape, k.shape, v.shape, block_q,
+                          block_k)
+    record_attention_tiles(length, block, block_q, block_k)
+    return _bd_attention(q, k, v, int(block), block_q, block_k,
+                         _plain_interpret(interpret),
+                         context_mxu_dtype(q.dtype))
 
 
 def flash_attention_lse(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

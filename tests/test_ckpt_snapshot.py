@@ -649,3 +649,48 @@ def test_a_preemption_during_an_in_flight_best_save_leaves_all_paired(
     assert server.ckpt.load(state).round == 2
     assert [s["deferred"] for s in _span_records(tmp_path, "ckpt_submit")
             if s["round"] == 2] == [False]
+
+
+@pytest.mark.parametrize("depth", [0, 1], ids=["serial", "ring"])
+def test_a_latest_of_its_own_is_fetched_behind_the_next_dispatch(
+        tmp_path, monkeypatch, synth_dataset, mesh8, depth):
+    """Every dispatch ends at an evaluation that finds nothing better
+    (client learning rate 0: the state of round 0 stays).  The round's
+    ``latest`` is then a snapshot of its own, and the writer asks for
+    its transfers only once the NEXT dispatch is launched: asked for at
+    the submit, they held that dispatch's inputs back (on the chip such
+    a period took 0.8 s longer than one whose evaluation improved)."""
+    from msrflute_tpu.engine.round import RoundEngine
+    server = _server(tmp_path, synth_dataset, mesh8, depth,
+                     initial_lr_client=0.0)
+    launches, fetched = [], []
+    dispatch = RoundEngine.dispatch_rounds
+
+    def counted(engine, state, *args, **kwargs):
+        out = dispatch(engine, state, *args, **kwargs)
+        launches.append(int(state.round))
+        return out
+
+    monkeypatch.setattr(RoundEngine, "dispatch_rounds", counted)
+    real_chunks = ckpt_mod._state_chunks
+
+    def seen(payload):
+        fetched.append(len(launches))
+        return real_chunks(payload)
+
+    monkeypatch.setattr(ckpt_mod, "_state_chunks", seen)
+    state = server.train()
+    assert state.round == 6 and launches == [0, 2, 4]
+    writes = sorted(_span_records(tmp_path, "ckpt_async_write"),
+                    key=lambda s: s["ts"])
+    assert [s["file"] for s in writes] == [
+        "best_val_loss_model.msgpack"] + [ckpt_mod.LATEST] * 3, \
+        "the initial evaluation's best model, then rounds 2, 4, 6"
+    # round 0's file before any launch; round 2's latest behind the
+    # launch of rounds 2-4, round 4's behind that of 4-6, the last at
+    # the loop's end
+    assert fetched == [0, 2, 3, 3]
+    assert [s["deferred"] for s in _span_records(tmp_path, "ckpt_submit")
+            ] == [False] * 3
+    assert server.ckpt.load(state).round == 6
+    assert _status(tmp_path)["i"] == 6

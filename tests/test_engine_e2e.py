@@ -168,8 +168,8 @@ def test_status_log_never_names_a_best_model_that_is_not_on_disk(
                 assert meta["crc32"] == blob_checksum(path.read_bytes())
         return update_status(update)
 
-    def watched_latest(state, same_as=None):
-        out = save_latest(state, same_as=same_as)
+    def watched_latest(state, same_as=None, **how):
+        out = save_latest(state, same_as=same_as, **how)
         if same_as is not None:
             linked.append(os.path.samefile(d / LATEST, same_as))
         return out

@@ -251,7 +251,7 @@ def test_a_local_step_through_the_attention_kernels_is_the_plain_paths(
     pa.drain_attention_events()
     want, want_grads = jax.jit(step)(weights)
     assert [e["impl"] for e in pa.drain_attention_events()] == ["plain"]
-    monkeypatch.setattr(lfm2, "causal_attention", functools.partial(
+    monkeypatch.setattr(token_blocks, "causal_attention", functools.partial(
         token_blocks.causal_attention, interpret=True))
     # another function object: jit would hand back ``step``'s program
     loss, grads = jax.jit(lambda p: step(p))(weights)

@@ -373,10 +373,11 @@ def test_token_model_step_compiles_for_v5e_with_the_core_in_the_kernels(
     import yaml
     from jax._src import config as jax_config
 
-    from msrflute_tpu.models import lfm2, make_task, mla_moe, token_blocks
+    from msrflute_tpu.models import make_task, mla_moe, token_blocks
     from msrflute_tpu.ops import moe
     monkeypatch.setattr(moe, "_interpret", lambda: False)
-    for module in (mla_moe, lfm2):
+    # LFM2's grouped-query attention is token_blocks' own
+    for module in (mla_moe, token_blocks):
         monkeypatch.setattr(module, "causal_attention", functools.partial(
             token_blocks.causal_attention, interpret=False))
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -411,6 +412,106 @@ def test_token_model_step_compiles_for_v5e_with_the_core_in_the_kernels(
     # the plain path's step holds 2.2-2.6 GB of scratch (two blocks of
     # scores of 1.07 GB among it); this one stays under 1.5
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5 * 2 ** 30
+
+
+# ----------------------------------------------------------------------
+# the block-diffusion core through the kernels on a static tile map (PR 41)
+# ----------------------------------------------------------------------
+#: a float32 array that holds the scores of a whole doubled row, or of
+#: one of the plain path's blocks of it
+BD_SCORES = re.compile(
+    r"f32\[(?:1,)?(?:32|4,8),(?:512|1024|2048|4096|8192),(?:\d{4})\]")
+
+
+@pytest.mark.parametrize("precision", [None, "highest"],
+                         ids=["default", "highest"])
+def test_block_diffusion_kernels_compile_for_v5e_at_the_cells_shape(
+        chip, precision):
+    """The three kernels under their names at ``sdar_bd_k2_t4096``'s
+    shape (a doubled row of 8,192 positions, 32 query heads over 4
+    key-value heads of 128, blocks of 4), with bfloat16 operands and
+    with float32 operands contracted in full: what Mosaic refuses (the
+    scalar-prefetched tables, the index maps that read them, VMEM) fails
+    here.  No operation of the program holds a row's scores."""
+    import contextlib
+    specs = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+             for shape in ((1, 8192, 32, 128), (1, 8192, 4, 128),
+                           (1, 8192, 4, 128))]
+
+    def backward(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(
+            pa.block_diffusion_flash_attention(*a, 4, interpret=False)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    pa.drain_attention_events()
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        compiled = jax.jit(backward).lower(*specs).compile()
+    text = compiled.as_text()
+    for name in (pa.BD_FWD_NAME, pa.BD_DQ_NAME, pa.BD_DKV_NAME):
+        assert name in text, name
+    assert not BD_SCORES.search(text)
+    said = {e["kind"]: e for e in pa.drain_attention_events()}
+    assert said["attention_path"]["impl"] == "flash"
+    assert (said["attn_tiles"]["tiles_run"],
+            said["attn_tiles"]["tiles_total"]) == (80, 256)
+
+
+def test_block_diffusion_step_compiles_for_v5e_with_the_core_in_the_kernels(
+        chip, monkeypatch):
+    """One local step of ``sdar_bd_k2_t4096`` at published widths (a
+    4,096-id row doubled, ``remat``) with the kernel path steered on,
+    traced as the check program is: the three block-diffusion kernels
+    and the expert kernels are in the program under their names, and no
+    operation holds a whole row's scores."""
+    import functools
+
+    import yaml
+    from jax._src import config as jax_config
+
+    from msrflute_tpu.models import make_task, token_blocks
+    from msrflute_tpu.ops import moe
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    monkeypatch.setattr(
+        token_blocks, "block_diffusion_attention", functools.partial(
+            token_blocks.block_diffusion_attention, interpret=False))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "experiments", "sdar_moe",
+                           "config.yaml")) as fh:
+        mc = yaml.safe_load(fh)["model_config"]
+    task = make_task({**mc, "remat": True})
+    shapes = jax.eval_shape(task.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        shapes)
+    row = (1, mc["seq_len"])
+    batch = {"x": jax.ShapeDtypeStruct(row, jnp.int32, sharding=chip),
+             **{key: jax.ShapeDtypeStruct(row, jnp.float32, sharding=chip)
+                for key in ("tok_mask", "bd_mask", "bd_weight")},
+             "sample_mask": jax.ShapeDtypeStruct((1,), jnp.float32,
+                                                 sharding=chip)}
+
+    def step(p, b):
+        loss, grads = jax.value_and_grad(
+            lambda q: task.loss(q, b, None, True)[0])(p)
+        return jax.tree.map(lambda a, g: a - 0.1 * g, p, grads), loss
+
+    pa.drain_attention_events()
+    with jax.default_matmul_precision("highest"), \
+            jax_config.exec_time_optimization_effort(-1.0):
+        compiled = jax.jit(step).lower(params, batch).compile()
+    text = compiled.as_text()
+    for name in (pa.BD_FWD_NAME, pa.BD_DQ_NAME, pa.BD_DKV_NAME,
+                 moe.GMM_NAME):
+        assert name in text, name
+    assert not BD_SCORES.search(text)
+    said = pa.drain_attention_events()
+    assert {e["kind"] for e in said} == {"attention_path", "attn_tiles"}
+    assert all(e["impl"] == "flash" for e in said
+               if e["kind"] == "attention_path"), said
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= 456_346_624 * 4
+    assert memory.temp_size_in_bytes < 4 * 2 ** 30
 
 
 @pytest.mark.parametrize("live_taps_only", [True, False],

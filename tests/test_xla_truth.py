@@ -346,6 +346,20 @@ def test_depth3_chaos_device_truth_acceptance(tmp_path, monkeypatch):
     assert rc == 0
 
 
+def test_scorecard_keeps_a_small_mfu_above_zero(tmp_path):
+    """Six significant digits, not six decimals: the acceptance run
+    above reads 2e-6 on an idle host and a few 1e-7 on a busy one, and
+    a utilisation that is measured is never 0."""
+    cfg = _cfg(1, telemetry={"enable": True}, rounds=2)
+    server = OptimizationServer(make_task(cfg.model_config), cfg,
+                                _dataset(), model_dir=str(tmp_path),
+                                seed=0)
+    server.run_stats["mfuPerRound"] = [3.1234567e-8, 4.1234567e-8, 5e-8]
+    assert server.build_scorecard()["mfu_p50"] == 4.12346e-8
+    server.run_stats["mfuPerRound"] = []
+    assert server.build_scorecard()["mfu_p50"] is None
+
+
 # ======================================================================
 # 4. tooling gates: committed fixtures + trend + bench contract
 # ======================================================================
