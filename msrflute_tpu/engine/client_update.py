@@ -46,7 +46,7 @@ import optax
 from ..models.base import BaseTask
 from ..optim import make_optimizer
 from ..optim.fused import (combine_grad_terms, fused_apply, segment_select,
-                           sgd_pallas_fusable)
+                           sgd_pallas_fusable, zero_grad_is_noop)
 
 
 @dataclass(frozen=True)
@@ -93,11 +93,15 @@ def _clip_by_global_norm(tree: Any, max_norm: float) -> Any:
     return jax.tree.map(lambda g: g * scale, tree)
 
 
-def _suff_stats_of(tree: Any) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+def _suff_stats_of(tree: Any, zeros_left_out: int = 0
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """``zeros_left_out``: elements of the whole tree that ``tree`` does
+    not hold because they are exact zeros (a narrowed gradient's dead
+    taps): they add nothing to the sums and count in ``n``."""
     leaves = jax.tree.leaves(tree)
     s = sum(jnp.sum(g) for g in leaves)
     s2 = sum(jnp.sum(g * g) for g in leaves)
-    n = float(sum(g.size for g in leaves))
+    n = float(sum(g.size for g in leaves) + zeros_left_out)
     return s, s2, jnp.asarray(n)
 
 
@@ -182,6 +186,14 @@ def build_client_update(task: BaseTask, client_opt_cfg,
             "updatable_layers: the flat fused kernel has no per-leaf "
             "freeze mask — drop one of them")
     sgd_mu = float(client_opt_cfg.get("momentum", 0.0) or 0.0)
+    # the loop may carry a leaf as the window the model reads of it only
+    # where a coordinate whose gradient is always zero keeps its value
+    # and its optimizer state, and its pseudo-gradient is the exact zero
+    # (a working copy in another dtype leaves ``w0 - w0.astype(dt)``
+    # there); SCAFFOLD's offset is asked at the call
+    may_narrow = (not pallas_sgd and pdt is None and not freeze
+                  and hparams.updatable_layers is None
+                  and zero_grad_is_noop(client_opt_cfg))
     # what the model counts inside its forward pass (an expert layer's
     # load): summed over the local steps, out as ``stats["ctr_<name>"]``
     counter_names = tuple(getattr(task, "counter_names", ()))
@@ -196,6 +208,22 @@ def build_client_update(task: BaseTask, client_opt_cfg,
         compiles to the plain path."""
         local_params = (jax.tree.map(lambda w: w.astype(pdt), global_params)
                         if pdt is not None else global_params)
+        # which leaves the model reads a window of at these shapes (a
+        # kernel's taps that can meet an input): the loop carries, steps
+        # and differentiates the window alone; ``{}`` (every task but
+        # the ResNet's, every shape without a dead tap) narrows nothing
+        # and is the whole-leaf trace
+        windows = {}
+        if may_narrow and grad_offset is None:
+            windows = task.kernel_windows(global_params, {
+                **{k: jax.ShapeDtypeStruct(a.shape[1:], a.dtype)
+                   for k, a in arrays.items()},
+                "sample_mask": jax.ShapeDtypeStruct(
+                    sample_mask.shape[1:], sample_mask.dtype)})
+        anchor = _narrow(global_params, windows)
+        local_params = _narrow(local_params, windows)
+        dead = sum(a.size - b.size for a, b in zip(
+            jax.tree.leaves(global_params), jax.tree.leaves(anchor)))
         if pallas_sgd:
             # flat momentum carry + the trace-time unravel closure; the
             # optax state machinery is bypassed entirely
@@ -223,11 +251,11 @@ def build_client_update(task: BaseTask, client_opt_cfg,
             # three-pass spelling)
             grads = combine_grad_terms(
                 grads, offset=grad_offset, prox_mu=hparams.fedprox_mu,
-                params=params, global_params=global_params,
+                params=params, global_params=anchor,
                 max_norm=hparams.max_grad_norm)
             has_data = (jnp.sum(mask) > 0).astype(jnp.float32)
             # sufficient stats per batch (core/trainer.py:271-292)
-            ds, ds2, dn = _suff_stats_of(grads)
+            ds, ds2, dn = _suff_stats_of(grads, dead)
             # the .astype(sdt) keeps the scan carry dtype stable under a
             # non-f32 stats policy; same-dtype casts compile to nothing,
             # so the f32 default trace is unchanged
@@ -307,16 +335,20 @@ def build_client_update(task: BaseTask, client_opt_cfg,
         (params, opt_state, rng, loss_sum, s, s2, n_acc, wloss_acc,
          ns_acc, ctr_acc) = carry
 
-        pseudo_grad = jax.tree.map(lambda w0, w: w0 - w, global_params, params)
+        pseudo_grad = jax.tree.map(lambda w0, w: w0 - w, anchor, params)
         if freeze:
             pseudo_grad = _freeze_layers(pseudo_grad, freeze)
 
         if hparams.stats_on_smooth_grad:
             # recompute stats on the pseudo-gradient (dga.py:104-108)
-            s, s2, n = _suff_stats_of(pseudo_grad)
+            s, s2, n = _suff_stats_of(pseudo_grad, dead)
             stats = _derive_stats(s, s2, n)
         else:
             stats = _derive_stats(s, s2, n_acc)
+        # once a round: a window's pseudo-gradient into the shape of its
+        # leaf; a dead tap never moved, so its ``w0 - w`` is the zero
+        # the padding writes
+        pseudo_grad = _widen(pseudo_grad, global_params, windows)
 
         rows = jnp.sum(sample_mask)
         # per-SAMPLE (per-ROW) mean training loss, invariant to batch
@@ -587,6 +619,40 @@ def build_mega_update(task: BaseTask, client_opt_cfg,
     return mega_update
 
 
+def _key_path(path) -> Tuple[str, ...]:
+    return tuple(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
+
+
+def _narrow(tree: Any, windows: Dict[Tuple[str, ...], Tuple]) -> Any:
+    """``tree`` with each leaf named in ``windows`` (``BaseTask.
+    kernel_windows``) cut to its ``(start, limit)``; the tree itself
+    where there is none."""
+    if not windows:
+        return tree
+
+    def cut(path, leaf):
+        window = windows.get(_key_path(path))
+        return leaf if window is None else jax.lax.slice(leaf, *window)
+    return jax.tree_util.tree_map_with_path(cut, tree)
+
+
+def _widen(narrow: Any, whole: Any,
+           windows: Dict[Tuple[str, ...], Tuple]) -> Any:
+    """Inverse of :func:`_narrow` around zeros: each cut leaf of
+    ``narrow`` padded to the shape of ``whole``'s leaf, at its place."""
+    if not windows:
+        return narrow
+
+    def pad(path, cut, leaf):
+        window = windows.get(_key_path(path))
+        if window is None:
+            return cut
+        return jax.lax.pad(cut, jnp.zeros((), cut.dtype), [
+            (lo, size - hi, 0) for lo, hi, size
+            in zip(*window, leaf.shape)])
+    return jax.tree_util.tree_map_with_path(pad, narrow, whole)
+
+
 def _updatable_mask(params, patterns) -> Any:
     """Per-leaf PYTHON bools from the updatable_layers regex allowlist
     (names are '.'-joined like torch's named_parameters; patterns are
@@ -600,8 +666,7 @@ def _updatable_mask(params, patterns) -> Any:
     flat, treedef = jax.tree_util.tree_flatten_with_path(params)
     keeps = []
     for path, leaf in flat:
-        name = ".".join(str(getattr(p, "key", getattr(p, "idx", p)))
-                        for p in path)
+        name = ".".join(_key_path(path))
         keep = any(re.match(pat, name) for pat in patterns)
         print_rank(("updating " if keep else "freezing ") + name,
                    loglevel=logging.DEBUG)
@@ -617,7 +682,7 @@ def _freeze_layers(tree: Any, freeze: Tuple[str, ...]) -> Any:
     paths_leaves, treedef = flat
     out = []
     for path, leaf in paths_leaves:
-        name = "/".join(str(getattr(p, "key", getattr(p, "idx", p))) for p in path)
+        name = "/".join(_key_path(path))
         if any(f in name for f in freeze):
             out.append(jnp.zeros_like(leaf))
         else:

@@ -57,6 +57,18 @@ class BaseTask:
         """Masked mean loss over the batch + aux stats (e.g. sample count)."""
         raise NotImplementedError
 
+    def kernel_windows(self, params: Params, batch: Batch
+                       ) -> Dict[Tuple[str, ...], Tuple[tuple, tuple]]:
+        """``{parameter path: (start, limit)}`` of every leaf that, at
+        ``batch``'s static shapes, the forward pass reads only through
+        ``lax.slice(leaf, start, limit)`` AND that the model also takes
+        already cut to that slice (``ops/conv.py::Conv``: the kernel taps
+        that can meet an input).  A path is the tuple of the leaf's dict
+        keys; ``params`` and ``batch`` may be abstract.  The local-steps
+        loop carries such a leaf as its window
+        (``engine/client_update.py``).  No leaf by default."""
+        return {}
+
     def eval_stats(self, params: Params, batch: Batch) -> Dict[str, jnp.ndarray]:
         """Scalar *sums* for evaluation; must include ``loss_sum`` and
         ``sample_count``."""

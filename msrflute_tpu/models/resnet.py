@@ -12,27 +12,28 @@ the ImageNet-style 7x7/stride-2 + maxpool of the reference; CIFAR inputs
 Behind that stem the four stages run at 8x8, 4x4, 2x2 and, at 32x32
 inputs, 1x1: the last stage's three 3x3 512->512 kernels meet their one
 pixel at the centre tap only, and its first (256->512, stride 2, 2x2 ->
-1x1) at four of nine.  Every convolution goes through
-``ops/conv.py::live_tap_conv``, which reads only those taps (the kernels
-keep their 3x3 shape; a dead tap's gradient is the zero it always was);
-at 64x64 inputs and above no tap is dead and the call is the plain one.
+1x1) at four of nine.  Every convolution is an ``ops/conv.py::Conv``,
+which reads only those taps through ``live_tap_conv`` (the kernels keep
+their 3x3 shape; a dead tap's gradient is the zero it always was); at
+64x64 inputs and above no tap is dead and the call is the plain one.
+The task says which kernels have such a window
+(:meth:`ResNetTask.kernel_windows`), and the module takes a kernel that
+is already cut to it, so the local-steps loop carries the windows alone
+(``engine/client_update.py``).
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Any, Sequence
+from typing import Any, Dict, Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
-from ..ops.conv import live_tap_conv
+from ..ops.conv import Conv as _conv
+from ..ops.conv import collecting_windows
 from .base import parse_dtype, to_float_image
 from .cv import ClassificationTask
-
-#: ``nn.Conv`` (same name, parameters and initialiser) whose product
-#: skips the taps that only ever meet padding
-_conv = functools.partial(nn.Conv, conv_general_dilated=live_tap_conv)
 
 #: He fan-out init, the reference's ``normal_(0, sqrt(2/n))`` on convs
 #: (``model.py:139-140``)
@@ -67,12 +68,11 @@ class _BasicBlock(nn.Module):
     def __call__(self, x, train: bool = False):
         residual = x
         y = _conv(self.planes, (3, 3), strides=(self.stride, self.stride),
-                  padding=1, use_bias=False, kernel_init=_he_init,
-                  dtype=self.dtype)(x)
+                  padding=1, kernel_init=_he_init, dtype=self.dtype)(x)
         y = _gn(self.planes, self.channels_per_group, dtype=self.dtype)(y)
         y = nn.relu(y)
-        y = _conv(self.planes, (3, 3), padding=1, use_bias=False,
-                  kernel_init=_he_init, dtype=self.dtype)(y)
+        y = _conv(self.planes, (3, 3), padding=1, kernel_init=_he_init,
+                  dtype=self.dtype)(y)
         # block-final norm scale starts at zero so every block begins as
         # identity (the reference's zero_init_residual,
         # ``model.py:148-152``) — without it the 8-block stack amplifies
@@ -82,8 +82,7 @@ class _BasicBlock(nn.Module):
         if residual.shape[-1] != self.planes or self.stride != 1:
             residual = _conv(self.planes, (1, 1),
                              strides=(self.stride, self.stride),
-                             use_bias=False, kernel_init=_he_init,
-                             dtype=self.dtype)(x)
+                             kernel_init=_he_init, dtype=self.dtype)(x)
             residual = _gn(self.planes, self.channels_per_group,
                            dtype=self.dtype)(residual)
         return nn.relu(y + residual)
@@ -98,7 +97,7 @@ class _ResNetGN(nn.Module):
     @nn.compact
     def __call__(self, x, train: bool = False):
         x = to_float_image(x, self.dtype)
-        x = _conv(64, (7, 7), strides=(2, 2), padding=3, use_bias=False,
+        x = _conv(64, (7, 7), strides=(2, 2), padding=3,
                   kernel_init=_he_init, dtype=self.dtype)(x)
         x = _gn(64, self.channels_per_group, dtype=self.dtype)(x)
         x = nn.relu(x)
@@ -114,7 +113,18 @@ class _ResNetGN(nn.Module):
         return nn.Dense(self.num_classes, dtype=self.dtype)(x)
 
 
-def make_resnet_task(model_config) -> ClassificationTask:
+class ResNetTask(ClassificationTask):
+    """The classification task of a model built from ``ops/conv.py::Conv``:
+    it can say which kernels a batch's shape reads a window of."""
+
+    def kernel_windows(self, params, batch
+                       ) -> Dict[Tuple[str, ...], Tuple[tuple, tuple]]:
+        with collecting_windows() as found:
+            jax.eval_shape(self.apply, params, batch["x"])
+        return found
+
+
+def make_resnet_task(model_config) -> ResNetTask:
     num_classes = int(model_config.get("num_classes", 100))
     side = int(model_config.get("image_size", 32))
     depth = {18: (2, 2, 2, 2), 34: (3, 4, 6, 3)}[
@@ -126,6 +136,5 @@ def make_resnet_task(model_config) -> ClassificationTask:
     # in_channels: the reference model is RGB-only; grayscale corpora
     # (e.g. the bundled digits convergence probe) need 1 here
     chans = int(model_config.get("in_channels", 3))
-    return ClassificationTask(module, example_shape=(side, side, chans),
-                              name="cv_resnet_fedcifar100",
-                              num_classes=num_classes)
+    return ResNetTask(module, example_shape=(side, side, chans),
+                      name="cv_resnet_fedcifar100", num_classes=num_classes)

@@ -110,3 +110,15 @@ def sgd_pallas_fusable(opt_cfg: Any) -> bool:
     return (kind == "sgd"
             and not bool(opt_cfg.get("nesterov", False))
             and not float(opt_cfg.get("weight_decay", 0.0) or 0.0))
+
+
+def zero_grad_is_noop(opt_cfg: Any) -> bool:
+    """True when a coordinate whose gradient is zero at every step keeps
+    its value and its optimizer state under this optimizer, whatever the
+    rest of its leaf does: no weight decay (it moves a weight without a
+    gradient) and no per-leaf norm (lars / lamb scale a step by the whole
+    leaf's).  Then a loop may leave such coordinates out of what it
+    carries (``engine/client_update.py``: a kernel's dead taps)."""
+    kind = str(opt_cfg.get("type", "sgd")).lower()
+    return (kind in ("sgd", "adam", "adamax", "adamw", "yogi")
+            and not float(opt_cfg.get("weight_decay", 0.0) or 0.0))

@@ -1,6 +1,11 @@
 """``ops/conv.py::live_tap_conv``: a convolution over the kernel taps that
 can meet an input — the plain call's outputs and gradients, the plain
-call itself where no tap is dead, and ResNet-18+GN's tree untouched."""
+call itself where no tap is dead, and ResNet-18+GN's tree untouched;
+``ops/conv.py::Conv``, which also takes a kernel already cut to its live
+window, and the local-steps loop that then carries the windows alone
+(``engine/client_update.py``)."""
+
+import functools
 
 import flax.linen as nn
 import jax
@@ -9,7 +14,9 @@ import numpy as np
 import pytest
 from jax import lax
 
-from msrflute_tpu.models import resnet
+from msrflute_tpu.config import OptimizerConfig
+from msrflute_tpu.engine import client_update as cu
+from msrflute_tpu.models import make_task, resnet
 from msrflute_tpu.ops import conv as conv_ops
 from msrflute_tpu.ops.conv import live_tap_conv, live_taps
 
@@ -172,7 +179,8 @@ def test_resnet_at_32x32_is_the_plain_model_with_zero_dead_gradients(
         lambda a: (0.05 * rng.normal(size=a.shape)).astype(np.float32)
         if len(a.shape) > 1 else np.ones(a.shape, np.float32), shapes)
     logits, grads = _loss_and_grads(model, params, x, y)
-    monkeypatch.setattr(resnet, "_conv", nn.Conv)
+    monkeypatch.setattr(resnet, "_conv",
+                        functools.partial(nn.Conv, use_bias=False))
     plain_params = jax.eval_shape(model.init, jax.random.PRNGKey(0), x)
     plain_logits, plain_grads = _loss_and_grads(model, params, x, y)
 
@@ -250,7 +258,8 @@ def test_conv_taps_events_of_a_resnet_trace(side, cut_convs):
         assert said == []
         return
     assert all(e["kind"] == "conv_taps" and e["convs_traced"] == 20
-               for e in said)
+               and e["carried_live"] == 0
+               and e["weights_carried"] == e["weights_total"] for e in said)
     by_lhs = {tuple(e["lhs_shape"]): e for e in said}
     assert set(by_lhs) == {(2, 2, 2, 256), (2, 1, 1, 512)}
     first, rest = by_lhs[(2, 2, 2, 256)], by_lhs[(2, 1, 1, 512)]
@@ -263,3 +272,237 @@ def test_conv_taps_events_of_a_resnet_trace(side, cut_convs):
     total = sum(e["convs"] * e["weights_total"] for e in said)
     live = sum(e["convs"] * e["weights_live"] for e in said)
     assert (total, live) == (8_257_536, 1_310_720)
+
+
+# ----------------------------------------------------------------------
+# ``Conv``: the declared kernel or its live window, the same products
+# ----------------------------------------------------------------------
+#: id -> (input extent, stride, the window of a 3x3 kernel with padding 1)
+MODULE_CASES = {
+    "1x1": ((1, 1), 1, ((1, 1, 0, 0), (2, 2, 4, 6))),
+    "2x2_s2": ((2, 2), 2, ((1, 1, 0, 0), (3, 3, 4, 6))),
+    "2x2_no_dead_tap": ((2, 2), 1, None),
+}
+
+
+@pytest.mark.parametrize("case", list(MODULE_CASES))
+def test_conv_module_takes_the_whole_kernel_or_its_window(case):
+    size, stride, window = MODULE_CASES[case]
+    rng = np.random.default_rng(11)
+    x = rng.normal(size=(3,) + size + (4,)).astype(np.float32)
+    w = rng.normal(size=(3, 3, 4, 6)).astype(np.float32)
+    module = conv_ops.Conv(6, (3, 3), strides=(stride, stride), padding=1)
+    flax_conv = nn.Conv(6, (3, 3), strides=(stride, stride), padding=1,
+                        use_bias=False, precision="highest")
+
+    def apply(kernel):
+        with jax.default_matmul_precision("highest"):
+            return module.apply({"params": {"kernel": kernel}}, x)
+
+    key = jax.random.PRNGKey(0)
+    assert (jax.tree.map(jnp.shape, module.init(key, x))
+            == jax.tree.map(jnp.shape, flax_conv.init(key, x)))
+    whole = apply(w)
+    np.testing.assert_allclose(
+        whole, flax_conv.apply({"params": {"kernel": w}}, x),
+        rtol=1e-6, atol=1e-6)
+    with conv_ops.collecting_windows() as found:
+        jax.eval_shape(apply, w)
+    if window is None:
+        assert found == {}
+    else:
+        assert found == {("kernel",): window}
+        cut = w[tuple(slice(a, b) for a, b in zip(*window))]
+        np.testing.assert_array_equal(apply(cut), whole)
+    # neither the declared shape nor the window: refused, by name
+    with pytest.raises(ValueError, match="neither the declared"):
+        apply(w[:2])
+
+
+# ----------------------------------------------------------------------
+# the local-steps loop carries the windows: the whole-leaf carry's
+# numbers to the bit, and the whole-leaf carry itself wherever a zero
+# gradient is not a coordinate left alone
+# ----------------------------------------------------------------------
+CLIENTS, STEPS, ROWS = 2, 3, 4
+WHOLE_TREE = 11_227_812
+DEAD = 6_946_816
+
+
+def _resnet_task():
+    return resnet.make_resnet_task({"num_classes": 100, "image_size": 32})
+
+
+def _resnet_round(seed=5, side=32):
+    rng = np.random.default_rng(seed)
+    shapes = jax.eval_shape(_resnet_task().init_params, jax.random.PRNGKey(0))
+    # noise in the kernels, ones in the norms (the block-final scales
+    # start at zero: no kernel behind them would get a gradient)
+    params = jax.tree.map(
+        lambda a: (0.05 * rng.normal(size=a.shape)).astype(np.float32)
+        if len(a.shape) > 1 else np.ones(a.shape, np.float32), shapes)
+    arrays = {
+        "x": rng.normal(size=(CLIENTS, STEPS, ROWS, side, side, 3)).astype(
+            np.float32),
+        "y": rng.integers(0, 100, size=(CLIENTS, STEPS, ROWS)).astype(
+            np.int32)}
+    mask = np.ones((CLIENTS, STEPS, ROWS), np.float32)
+    mask[1, 2] = 0.0  # an all-padding step: pinned to a no-op
+    return params, arrays, mask
+
+
+def _cohort(update, offset=None):
+    def run(params, arrays, mask):
+        return jax.vmap(
+            lambda a, m, r: update(params, a, m, jnp.float32(0.05), r,
+                                   offset))(
+            arrays, mask, jax.random.split(jax.random.PRNGKey(1), CLIENTS))
+    return run
+
+
+def _whole_leaf(monkeypatch):
+    """The carry every leaf whole, as before the windows: no optimizer
+    is taken to leave a zero gradient's coordinate alone."""
+    monkeypatch.setattr(cu, "zero_grad_is_noop", lambda cfg: False)
+
+
+@pytest.mark.parametrize("opt, smooth", [
+    (dict(type="sgd", lr=0.05), True),
+    (dict(type="sgd", lr=0.05), False),
+    (dict(type="sgd", lr=0.05, momentum=0.9), True),
+    (dict(type="adam", lr=0.001), True)],
+    ids=["sgd", "sgd_stats_of_steps", "momentum", "adam"])
+def test_windowed_carry_is_the_whole_leaf_carry_to_the_bit(
+        opt, smooth, monkeypatch):
+    hparams = cu.ClientHParams(stats_on_smooth_grad=smooth, fedprox_mu=0.01)
+    params, arrays, mask = _resnet_round()
+    conv_ops.drain_conv_events()
+    got = jax.jit(_cohort(cu.build_client_update(
+        _resnet_task(), OptimizerConfig(**opt), hparams)))(
+        params, arrays, mask)
+    said = conv_ops.drain_conv_events()
+    # the four cut kernels of the forward trace arrived as their windows
+    assert sum(e["carried_live"] for e in said) == 4 == sum(
+        e["convs"] for e in said)
+    assert sum(e["carried_live"] * e["weights_carried"] for e in said) \
+        == 8_257_536 - DEAD
+    assert all(e["convs_traced"] == 20 for e in said)
+
+    _whole_leaf(monkeypatch)
+    want = jax.jit(_cohort(cu.build_client_update(
+        _resnet_task(), OptimizerConfig(**opt), hparams)))(
+        params, arrays, mask)
+    assert sum(e["carried_live"] for e in conv_ops.drain_conv_events()) == 0
+
+    (pg, loss, samples, stats), (pg0, loss0, samples0, stats0) = got, want
+    flat, _ = jax.tree_util.tree_flatten_with_path(pg)
+    for (path, a), b in zip(flat, jax.tree.leaves(pg0)):
+        assert a.shape == b.shape
+        np.testing.assert_array_equal(a, b, err_msg=jax.tree_util.keystr(path))
+    np.testing.assert_array_equal(loss, loss0)
+    np.testing.assert_array_equal(samples, samples0)
+    dead_seen = 0
+    for (block, name), window in CUT_AT_32.items():
+        dead = np.ones((3, 3), bool)
+        dead[tuple(slice(a, b + 1) for a, b in window)] = False
+        leaf = np.asarray(pg[block][name]["kernel"])
+        assert not np.any(leaf[:, dead]) and np.any(leaf[:, ~dead])
+        assert not np.any(np.signbit(leaf[:, dead]))
+        dead_seen += int(dead.sum()) * int(np.prod(leaf.shape[3:]))
+    assert dead_seen == DEAD
+    # a sum's order changes with the shape; the count is the whole tree's
+    steps = STEPS if not smooth else 1
+    np.testing.assert_array_equal(
+        stats["n"], np.float32([steps * WHOLE_TREE,
+                                (steps - (not smooth)) * WHOLE_TREE]))
+    for name in stats:
+        np.testing.assert_allclose(stats[name], stats0[name], rtol=2e-5,
+                                   atol=1e-9, err_msg=name)
+
+
+def _traces_windows(update, *args):
+    conv_ops.drain_conv_events()
+    jaxpr = jax.make_jaxpr(_cohort(update, *args[3:]))(*args[:3])
+    carried = sum(e["carried_live"] for e in conv_ops.drain_conv_events())
+    # what the local-steps loop carries of a 512->512 kernel: its centre
+    # tap or the whole of it, never both
+    (loop,) = [e for e in jaxpr.jaxpr.eqns if e.primitive.name == "scan"]
+    shapes = {v.aval.shape for v in loop.outvars}
+    assert ((CLIENTS, 1, 1, 512, 512) in shapes) == bool(carried)
+    assert ((CLIENTS, 3, 3, 512, 512) in shapes) != bool(carried)
+    return carried
+
+
+FALLBACKS = {
+    "weight_decay": (dict(type="sgd", lr=0.05, weight_decay=1e-4), {}),
+    "lars": (dict(type="lars", lr=0.05), {}),
+    "freeze_layers": (dict(type="sgd", lr=0.05),
+                      dict(freeze_layers=("Dense_0",))),
+    "updatable_layers": (dict(type="sgd", lr=0.05),
+                         dict(updatable_layers=("_BasicBlock_7",))),
+    "param_dtype": (dict(type="sgd", lr=0.05),
+                    dict(param_dtype="bfloat16")),
+    "grad_offset": (dict(type="sgd", lr=0.05), {}),
+}
+
+
+@pytest.mark.parametrize("why", list(FALLBACKS))
+def test_whole_leaf_carry_where_a_zero_gradient_is_not_a_no_op(why):
+    opt, hparams = FALLBACKS[why]
+    params, arrays, mask = _resnet_round()
+    update = cu.build_client_update(
+        _resnet_task(), OptimizerConfig(**opt), cu.ClientHParams(**hparams))
+    offset = (jax.tree.map(np.ones_like, params)
+              if why == "grad_offset" else None)
+    assert _traces_windows(update, params, arrays, mask, offset) == 0
+    if why == "grad_offset":
+        # the same builder without the offset carries the windows
+        assert _traces_windows(update, params, arrays, mask) == 4
+
+
+def _token_round(model):
+    if model == "LFM2_MOE":
+        from test_lfm2_moe import TINY, _weights
+    else:
+        from test_mla_moe import TINY, _weights
+    ids = np.random.default_rng(4).integers(
+        1, TINY["vocab_size"], size=(CLIENTS, 2, 1, 17)).astype(np.int32)
+    return (make_task(TINY), _weights(), {"x": ids},
+            np.ones((CLIENTS, 2, 1), np.float32))
+
+
+def _cnn_round():
+    task = make_task({"model_type": "CNN", "num_classes": 62})
+    rng = np.random.default_rng(2)
+    return (task, task.init_params(jax.random.PRNGKey(0)),
+            {"x": rng.normal(size=(CLIENTS, 2, 3, 28, 28, 1)).astype(
+                np.float32),
+             "y": rng.integers(0, 62, size=(CLIENTS, 2, 3)).astype(np.int32)},
+            np.ones((CLIENTS, 2, 3), np.float32))
+
+
+def _resnet64_round():
+    params, arrays, mask = _resnet_round(side=64)
+    arrays = jax.tree.map(lambda a: a[:, :1, :2], arrays)
+    return _resnet_task(), params, arrays, mask[:, :1, :2]
+
+
+@pytest.mark.parametrize("make_round", [
+    _cnn_round, lambda: _token_round("LFM2_MOE"),
+    lambda: _token_round("MLA_MOE"), _resnet64_round],
+    ids=["cnn_femnist", "lfm2_moe", "mla_moe", "resnet_at_64x64"])
+def test_a_task_without_windows_traces_to_the_whole_leaf_program(
+        make_round, monkeypatch):
+    task, params, arrays, mask = make_round()
+    assert task.kernel_windows(params, jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape[2:], a.dtype), arrays)) == {}
+
+    def jaxpr():
+        update = cu.build_client_update(
+            task, OptimizerConfig(type="sgd", lr=0.05, momentum=0.9),
+            cu.ClientHParams(fedprox_mu=0.01))
+        return str(jax.make_jaxpr(_cohort(update))(params, arrays, mask))
+
+    asked = jaxpr()
+    _whole_leaf(monkeypatch)
+    assert asked == jaxpr()

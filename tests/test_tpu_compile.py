@@ -557,3 +557,55 @@ def test_layer4_kernel_stack_is_not_moved_whole_on_v5e(chip,
                               found.group(1).split(",")]) == stack:
             movers.append(found.group(2))
     assert bool(movers) == (not live_taps_only), movers
+
+
+@pytest.mark.parametrize("carry", ["windows", "whole_leaves"])
+def test_local_steps_loop_makes_no_pass_over_layer4_stacks_on_v5e(
+        chip, carry, monkeypatch):
+    """The fill cell's cohort step (ResNet-18+GN at 32x32, 50 clients,
+    five local SGD steps of 20 rows) compiled for a described v5e.  With
+    the local-steps loop carrying layer4's kernels as their live windows
+    (``engine/client_update.py``) no fusion and no copy anywhere in the
+    program writes a ``[50, 3, 3, 512, 512]`` or ``[50, 3, 3, 256, 512]``
+    stack: the update runs over ``[50, 1, 1, 512, 512]``, and the
+    pseudo-gradient's padding is read by the sum over clients.  With the
+    whole leaves carried, each step has the update fusions and the
+    loop-carry copies the ledger names."""
+    from msrflute_tpu.config import OptimizerConfig
+    from msrflute_tpu.engine import client_update as cu
+    from msrflute_tpu.models.resnet import make_resnet_task
+    if carry == "whole_leaves":
+        monkeypatch.setattr(cu, "zero_grad_is_noop", lambda cfg: False)
+    task = make_resnet_task({"num_classes": 100, "image_size": 32})
+    update = cu.build_client_update(
+        task, OptimizerConfig(type="sgd", lr=0.1), cu.ClientHParams())
+    clients, steps, rows = 50, 5, 20
+
+    def cohort(params, arrays, mask, lr, rngs):
+        pseudo, loss, _, stats = jax.vmap(
+            update, in_axes=(None, 0, 0, None, 0))(
+            params, arrays, mask, lr, rngs)
+        mean = jax.tree.map(lambda g: jnp.mean(g, axis=0), pseudo)
+        return jax.tree.map(jnp.subtract, params, mean), loss, stats
+
+    def on_chip(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = jax.tree.map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(task.init_params, jax.random.PRNGKey(0)))
+    text = jax.jit(cohort).lower(
+        params,
+        {"x": on_chip((clients, steps, rows, 32, 32, 3)),
+         "y": on_chip((clients, steps, rows), jnp.int32)},
+        on_chip((clients, steps, rows)), on_chip(()),
+        on_chip((clients, 2), jnp.uint32)).compile().as_text()
+    passes = re.findall(
+        r"^\s*%(\S+) = f32\[50,3,3,(?:512|256),512\]\S* (?:fusion|copy)\(",
+        text, flags=re.M)
+    if carry == "windows":
+        assert not passes, passes
+        assert re.search(r"= f32\[50,1,1,512,512\]\S* fusion\(", text)
+    else:
+        assert sum(name.startswith("add_select_fusion")
+                   for name in passes) == 4, passes
