@@ -128,3 +128,8 @@ def _load_builtins() -> None:
         TASK_REGISTRY.setdefault("SDAR_MOE", sdar_moe.make_sdar_moe_task)
     except ImportError:
         pass
+    try:
+        from . import laguna
+        TASK_REGISTRY.setdefault("LAGUNA_MOE", laguna.make_laguna_moe_task)
+    except ImportError:
+        pass

@@ -1,22 +1,24 @@
 """What the token models with held experts have in common
-(``models/lfm2.py``, ``models/mla_moe.py``, ``models/sdar_moe.py``):
-RMSNorm, the dense SwiGLU, the held share of a routed expert layer,
-RoPE's angles, grouped-query attention with QK-norm and rotate-half RoPE,
-the two attention cores (causal; block diffusion over a doubled row), each
-through the tiled kernels of ``ops/pallas_attention.py`` or through
-blocks of plain attention rows where no compiled kernel applies, and the
-two tasks that carry the expert layers' counters: the causal LM and the
-block-diffusion LM.
+(``models/lfm2.py``, ``models/mla_moe.py``, ``models/sdar_moe.py``,
+``models/laguna.py``): RMSNorm, the dense SwiGLU, the held share of a
+routed expert layer, RoPE's angles and rotary laws, grouped-query
+attention (head counts, QK-norm, rotary law, window and output gate an
+instance's own), the attention cores (causal, with or without a sliding
+window; block diffusion over a doubled row), each through the tiled
+kernels of ``ops/pallas_attention.py`` or through blocks of plain
+attention rows where no compiled kernel applies, and the two tasks that
+carry the expert layers' counters: the causal LM and the block-diffusion
+LM.
 
 The modules' parameter names (``weight``; ``w1``/``w3``/``w2``;
 ``router``/``select_bias``/``w1``/``w3``/``w2``; ``wq``/``wk``/``wv``/
-``wo``/``norm_q``/``norm_k``) are part of the models' checkpoint
+``wo``/``wg``/``norm_q``/``norm_k``) are part of the models' checkpoint
 contracts and of their plain references (``benchmarks/reference/``).
 """
 
 from __future__ import annotations
 
-from typing import Any
+from typing import Any, NamedTuple
 
 import flax.linen as nn
 import jax
@@ -26,7 +28,8 @@ import numpy as np
 from ..ops.moe import held_experts_ffn
 from ..ops.pallas_attention import (block_diffusion_flash_attention,
                                     causal_flash_attention,
-                                    record_attention_path)
+                                    record_attention_path,
+                                    window_flash_attention)
 from ..ops.pallas_kernels import compiled_kernels_apply
 from ..utils.metrics import Metric
 from .base import softmax_xent
@@ -76,31 +79,40 @@ def _masked_rows(q_rows, k, v, seen_of):
     return jnp.einsum("bkgrm,bmkd->brkgd", probs, v)
 
 
-def _attention_rows(q_rows, k, v, row0: int):
+def _attention_rows(q_rows, k, v, row0: int, col0: int = 0,
+                    window: int = 0):
     """One block of causal query rows from position ``row0`` over the
-    keys and values up to the block's end."""
+    keys and values from position ``col0`` up to the block's end; under
+    ``window > 0`` a query sees the ``window`` keys up to its own."""
     def seen():
         rows = row0 + jnp.arange(q_rows.shape[1])[:, None]
-        return jnp.arange(k.shape[1])[None, :] <= rows
+        cols = jnp.arange(k.shape[1])[None, :]
+        if not window:
+            return cols <= rows
+        cols = col0 + cols
+        return (cols <= rows) & (rows - cols < window)
     return _masked_rows(q_rows, k, v, seen)
 
 
-def _blocked_attention(q, k, v, block: int):
+def _blocked_attention(q, k, v, block: int, window: int = 0):
     """Causal attention of ``q [B, L, KV, G, D]`` over ``k [B, L, KV, D]``
     and ``v [B, L, KV, Dv]``, ``block`` query rows at a time against the
-    keys up to the block's end, each block a ``jax.checkpoint`` (the
+    keys up to the block's end (under ``window > 0``: from the first key
+    the block's first row sees), each block a ``jax.checkpoint`` (the
     scores of a long row never stand whole); ``L`` a multiple of
     ``block``.  Returns ``[B, L, KV, G, Dv]``.  The plain path: what
     runs where the kernels do not, and the statement they are tested
     against."""
-    rows = jax.checkpoint(_attention_rows, static_argnums=(3,))
-    out = [rows(q[:, r0:r0 + block], k[:, :r0 + block], v[:, :r0 + block],
-                r0)
-           for r0 in range(0, q.shape[1], block)]
+    rows = jax.checkpoint(_attention_rows, static_argnums=(3, 4, 5))
+    out = []
+    for r0 in range(0, q.shape[1], block):
+        c0 = max(0, r0 - window + 1) if window else 0
+        out.append(rows(q[:, r0:r0 + block], k[:, c0:r0 + block],
+                        v[:, c0:r0 + block], r0, c0, window))
     return jnp.concatenate(out, axis=1)
 
 
-def causal_attention(q, k, v, block: int, interpret=None):
+def causal_attention(q, k, v, block: int, interpret=None, window: int = 0):
     """The token models' causal core, ``q [B, L, KV, G, D]`` over
     ``k [B, L, KV, D]`` and ``v [B, L, KV, Dv]`` -> ``[B, L, KV, G, Dv]``:
     the tiled kernels of ``ops/pallas_attention.py`` wherever a compiled
@@ -108,16 +120,23 @@ def causal_attention(q, k, v, block: int, interpret=None):
     backward; no ``jax.checkpoint``: the kernels' ``custom_vjp`` saves
     ``q``, ``k``, ``v``, ``out``, ``lse``), else :func:`_blocked_attention`
     at ``block`` rows (the CPU; GSPMD outside ``shard_map``).  Head
-    widths and the group size are shapes: one path for both models.
-    ``interpret=True`` forces the kernels through the interpreter
-    (tests).  The trace says which path it took (``attention_path``)."""
+    widths and the group size are shapes: one path for all models.
+    ``window > 0``: a query sees the ``window`` keys up to its own (the
+    window law's kernels; a window no shorter than the row is the causal
+    law and takes the causal kernels).  ``interpret=True`` forces the
+    kernels through the interpreter (tests).  The trace says which path
+    it took (``attention_path``)."""
     batch, length, kv, group, dim = q.shape
+    if window >= length:
+        window = 0
     if interpret is None and not compiled_kernels_apply():
         record_attention_path("plain", (batch, length, kv * group, dim),
                               k.shape, v.shape, block, length)
-        return _blocked_attention(q, k, v, block)
-    out = causal_flash_attention(q.reshape(batch, length, kv * group, dim),
-                                 k, v, interpret=interpret)
+        return _blocked_attention(q, k, v, block, window)
+    flat = q.reshape(batch, length, kv * group, dim)
+    out = (window_flash_attention(flat, k, v, window, interpret=interpret)
+           if window else
+           causal_flash_attention(flat, k, v, interpret=interpret))
     return out.reshape(batch, length, kv, group, v.shape[-1])
 
 
@@ -196,11 +215,45 @@ def rope_half(x, theta: float, copies: int = 1):
             rotated * jnp.sin(angles).astype(x.dtype))
 
 
+class RotaryLaw(NamedTuple):
+    """A layer type's rotary law as numbers: the inverse frequencies of
+    the rotated pairs (the first ``2 len(inv_freq)`` elements of a head
+    turn, element ``i`` with ``i + len(inv_freq)``; the rest pass
+    through) and the factor on cos and sin (YaRN's attention factor).
+    The table is made on the host by the model that owns the law
+    (``models/laguna.py``); hashable, so a module can carry it."""
+    inv_freq: tuple
+    factor: float = 1.0
+
+
+def rope_law(x, law: RotaryLaw):
+    """Rotate-half RoPE by ``law`` on ``[B, L, heads, D]`` at positions
+    0..L-1, angles in float32."""
+    half = len(law.inv_freq)
+    angles = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * \
+        jnp.asarray(law.inv_freq, jnp.float32)[None]
+    angles = jnp.concatenate([angles, angles], axis=-1)[None, :, None, :]
+    cos = (jnp.cos(angles) * law.factor).astype(x.dtype)
+    sin = (jnp.sin(angles) * law.factor).astype(x.dtype)
+    turn, rest = x[..., :2 * half], x[..., 2 * half:]
+    rotated = jnp.concatenate([-turn[..., half:], turn[..., :half]], axis=-1)
+    turned = turn * cos + rotated * sin
+    return jnp.concatenate([turned, rest], axis=-1) if rest.shape[-1] \
+        else turned
+
+
 class _GQAttention(nn.Module):
-    """Grouped-query attention with an RMSNorm on every head's query and
-    key and rotate-half RoPE.  ``diffusion_block`` 0: causal over the
-    row; ``> 0``: the row is ``[xt ; x0]`` (both halves at positions
-    0..L-1) under the block-diffusion mask at that block length."""
+    """Grouped-query attention, rotate-half RoPE.  Four families use it;
+    what differs between them, and between one model's layer types, is
+    the instance's: the head counts, ``qk_norm`` (an RMSNorm on every
+    head's query and key: LFM2, SDAR; Laguna has none), the rotary law
+    (``rotary`` None: every element turned at ``theta``; else a
+    :class:`RotaryLaw`), ``window`` (0: causal over the row; ``> 0``: a
+    query sees the ``window`` keys up to its own), ``gate`` (the output
+    times ``sigmoid(z wg)`` elementwise before ``wo``: Laguna) and
+    ``diffusion_block`` (``> 0``: the row is ``[xt ; x0]``, both halves
+    at positions 0..L-1, under the block-diffusion mask at that block
+    length)."""
     heads: int
     kv_heads: int
     head_dim: int
@@ -209,6 +262,10 @@ class _GQAttention(nn.Module):
     block: int
     dtype: Any
     diffusion_block: int = 0
+    qk_norm: bool = True
+    rotary: Any = None
+    window: int = 0
+    gate: bool = False
 
     @nn.compact
     def __call__(self, z):  # [B, L, D], L a multiple of block
@@ -219,15 +276,21 @@ class _GQAttention(nn.Module):
         wk = self.param("wk", _normal(0.02), (hidden, kv * dim))
         wv = self.param("wv", _normal(0.02), (hidden, kv * dim))
         wo = self.param("wo", _normal(0.02), (heads * dim, hidden))
+        wg = (self.param("wg", _normal(0.02), (hidden, heads * dim))
+              if self.gate else None)
         with jax.named_scope("gqa_proj"):
-            q = _RMSNorm(self.eps, name="norm_q")(
-                (z @ wq.astype(self.dtype)).reshape(batch, length, heads,
-                                                    dim))
-            k = _RMSNorm(self.eps, name="norm_k")(
-                (z @ wk.astype(self.dtype)).reshape(batch, length, kv, dim))
+            q = (z @ wq.astype(self.dtype)).reshape(batch, length, heads, dim)
+            if self.qk_norm:
+                q = _RMSNorm(self.eps, name="norm_q")(q)
+            k = (z @ wk.astype(self.dtype)).reshape(batch, length, kv, dim)
+            if self.qk_norm:
+                k = _RMSNorm(self.eps, name="norm_k")(k)
             v = (z @ wv.astype(self.dtype)).reshape(batch, length, kv, dim)
-            q = rope_half(q, self.theta, copies)
-            k = rope_half(k, self.theta, copies)
+            if self.rotary is None:
+                q = rope_half(q, self.theta, copies)
+                k = rope_half(k, self.theta, copies)
+            else:
+                q, k = rope_law(q, self.rotary), rope_law(k, self.rotary)
             # query head h reads key-value head h // (heads / kv_heads)
             q = q.reshape(batch, length, kv, heads // kv, dim)
         with jax.named_scope("gqa_attn_core"):
@@ -235,10 +298,13 @@ class _GQAttention(nn.Module):
                 out = block_diffusion_attention(
                     q, k, v, self.diffusion_block, self.block)
             else:
-                out = causal_attention(q, k, v, self.block)
+                out = causal_attention(q, k, v, self.block,
+                                       window=self.window)
         with jax.named_scope("gqa_proj"):
-            return out.reshape(batch, length, heads * dim) @ \
-                wo.astype(self.dtype)
+            out = out.reshape(batch, length, heads * dim)
+            if self.gate:
+                out = out * jax.nn.sigmoid(z @ wg.astype(self.dtype))
+            return out @ wo.astype(self.dtype)
 
 
 class _DenseMLP(nn.Module):

@@ -15,7 +15,12 @@ the device trace (``attn_flash_fwd``, ``attn_flash_dq``,
 block-diffusion model (``models/sdar_moe.py``), runs the same tile bodies
 under a mask that is not causal, on a static tile map, as three kernels
 of its own (``attn_bd_fwd``, ``attn_bd_dq``, ``attn_bd_dkv``:
-:func:`block_diffusion_flash_attention`, further down):
+:func:`block_diffusion_flash_attention`, further down); a fourth, the
+sliding layers of ``models/laguna.py``, runs them under the WINDOW LAW
+(a query sees the ``window`` keys up to its own: a band under the
+diagonal) as a second law of that tile map, again as three kernels of
+its own (``attn_win_fwd``, ``attn_win_dq``, ``attn_win_dkv``:
+:func:`window_flash_attention`, further down):
 
 - forward: grid ``(B, H, Lq/block_q, Lk/block_k)`` with the key/value
   block index INNERMOST and ``arbitrary`` semantics — mosaic pipelines
@@ -839,6 +844,14 @@ def causal_blocks(length: int) -> tuple:
     return tile, tile
 
 
+def _buffer_event(record: dict) -> None:
+    """Buffer ``record`` for the next drain unless an equal one waits
+    there already (a program traces its core once a layer and pass) or
+    the buffer is full."""
+    if record not in _PENDING_EVENTS and len(_PENDING_EVENTS) < _EVENTS_CAP:
+        _PENDING_EVENTS.append(record)
+
+
 def record_attention_path(impl: str, q_shape, k_shape, v_shape,
                           block_q: int, block_k: int) -> None:
     """Buffer an ``attention_path`` event: which implementation this
@@ -851,8 +864,7 @@ def record_attention_path(impl: str, q_shape, k_shape, v_shape,
               "k_shape": [int(n) for n in k_shape],
               "v_shape": [int(n) for n in v_shape],
               "block_q": int(block_q), "block_k": int(block_k)}
-    if record not in _PENDING_EVENTS and len(_PENDING_EVENTS) < _EVENTS_CAP:
-        _PENDING_EVENTS.append(record)
+    _buffer_event(record)
 
 
 def causal_flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -956,7 +968,7 @@ def bd_tile_map(length: int, block: int, block_q: int, block_k: int) -> dict:
                 row.append((j, not whole))
         rows.append(row)
     run = sum(len(r) for r in rows)
-    return {"rows": rows, "lp": lp, "nq": nq, "nk": nk,
+    return {"rows": rows, "lp": lp, "nq": nq, "nk": nk, "key_tiles": 2 * nk,
             "tiles_run": run,
             "tiles_masked": sum(m for r in rows for _, m in r),
             "tiles_total": 4 * nq * nk,
@@ -975,7 +987,7 @@ def _bd_tables(tiles: dict, group: int) -> tuple:
     by_row = [(i, j, flags(len(row), at, m))
               for i, row in enumerate(tiles["rows"])
               for at, (j, m) in enumerate(row)]
-    columns = [[] for _ in range(2 * tiles["nk"])]
+    columns = [[] for _ in range(tiles["key_tiles"])]
     for i, row in enumerate(tiles["rows"]):
         for j, m in row:
             columns[j].append((i, m))
@@ -1023,7 +1035,7 @@ def _bd_step(flags, init, tile, finalize):
 
 
 def _bd_fwd_kernel(qt_ref, kt_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
-                   lse_ref, m_s, l_s, acc_s, *, cdt, **geo):
+                   lse_ref, m_s, l_s, acc_s, *, cdt, mask, **geo):
     t = pl.program_id(2)
 
     def init():
@@ -1034,13 +1046,14 @@ def _bd_fwd_kernel(qt_ref, kt_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
     _bd_step(fl_ref[t], init,
              lambda masked: _fwd_tile(
                  q_ref, k_ref, v_ref, m_s, l_s, acc_s, masked and (
-                     lambda shape: _bd_mask(shape, 0, qt_ref[t], kt_ref[t],
-                                            **geo)), cdt),
+                     lambda shape: mask(shape, 0, qt_ref[t], kt_ref[t],
+                                        **geo)), cdt),
              lambda: _fwd_finalize(o_ref, lse_ref, m_s, l_s, acc_s))
 
 
 def _bd_dq_kernel(qt_ref, kt_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
-                  lse_ref, delta_ref, dq_ref, dq_s, *, scale, cdt, **geo):
+                  lse_ref, delta_ref, dq_ref, dq_s, *, scale, cdt, mask,
+                  **geo):
     t = pl.program_id(2)
 
     def init():
@@ -1052,14 +1065,14 @@ def _bd_dq_kernel(qt_ref, kt_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
     _bd_step(fl_ref[t], init,
              lambda masked: _dq_tile(
                  q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_s,
-                 masked and (lambda shape: _bd_mask(
+                 masked and (lambda shape: mask(
                      shape, 0, qt_ref[t], kt_ref[t], **geo)), cdt),
              finalize)
 
 
 def _bd_dkv_kernel(kt_ref, head_ref, qt_ref, fl_ref, q_ref, k_ref, v_ref,
                    do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_s, dv_s, *,
-                   cdt, **geo):
+                   cdt, mask, **geo):
     del head_ref  # the index maps read it
     t = pl.program_id(2)
 
@@ -1074,45 +1087,53 @@ def _bd_dkv_kernel(kt_ref, head_ref, qt_ref, fl_ref, q_ref, k_ref, v_ref,
     _bd_step(fl_ref[t], init,
              lambda masked: _dkv_tile(
                  q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_s, dv_s,
-                 masked and (lambda shape: _bd_mask(
+                 masked and (lambda shape: mask(
                      shape, 1, qt_ref[t], kt_ref[t], **geo)), cdt),
              finalize)
 
 
-class _BDGeometry:
-    """What the three block-diffusion calls share: the tile map, the
-    padded sizes, the group of query heads a key-value head serves."""
+class _MapGeometry:
+    """What the three calls of a table-driven family share: the tile
+    map, the padded sizes, the group of query heads a key-value head
+    serves, the law's mask and its statics, the kernels' names.  A row
+    is ``copies`` copies of ``L`` positions side by side (2: the
+    block-diffusion row; 1: a windowed row), each padded to whole tiles
+    on its own."""
 
-    def __init__(self, q, k, v, block, block_q, block_k):
+    def __init__(self, q, k, v, tiles, copies, block_q, block_k, names,
+                 mask, **law):
         self.B, rows, self.H, self.D = q.shape
-        self.L = rows // 2
+        self.copies, self.L = copies, rows // copies
         self.KV, self.Dv = k.shape[2], v.shape[3]
         self.G = self.H // self.KV
         self.bq, self.bk = block_q, block_k
-        self.tiles = bd_tile_map(self.L, block, block_q, block_k)
-        self.lp = self.tiles["lp"]
+        self.tiles, self.lp = tiles, tiles["lp"]
+        self.rows_p = copies * self.lp
         self.d_p, self.dv_p = _ceil_to(self.D, _LANES), \
             _ceil_to(self.Dv, _LANES)
         self.scale = float(self.D ** -0.5)
-        self.by_row, self.by_column = _bd_tables(self.tiles, self.G)
-        self.static = dict(block=block, block_q=block_q, block_k=block_k,
-                           num_q=self.tiles["nq"], num_k=self.tiles["nk"])
+        self.by_row, self.by_column = _bd_tables(tiles, self.G)
+        self.names = names
+        self.static = dict(mask=mask, block_q=block_q, block_k=block_k,
+                           **law)
 
     def heads_first(self, x, width, dtype):
-        """``[B, 2 L, heads, D]`` -> ``[B, heads, 2 lp, width]``: each
-        half padded to whole tiles on its own."""
+        """``[B, copies L, heads, D]`` -> ``[B, heads, copies lp,
+        width]``: each copy padded to whole tiles on its own."""
         batch, _, heads, dim = x.shape
-        x = _pad_axis(x.reshape(batch, 2, self.L, heads, dim), 2, self.lp)
-        return _heads_first(x.reshape(batch, 2 * self.lp, heads, dim),
-                            2 * self.lp, width, dtype)
+        x = _pad_axis(x.reshape(batch, self.copies, self.L, heads, dim), 2,
+                      self.lp)
+        return _heads_first(x.reshape(batch, self.rows_p, heads, dim),
+                            self.rows_p, width, dtype)
 
     def back(self, x, width):
-        """The kernels' ``[B, heads, 2 lp, *]`` -> ``[B, 2 L, heads,
-        width]``."""
+        """The kernels' ``[B, heads, copies lp, *]`` -> ``[B, copies L,
+        heads, width]``."""
         batch, heads = x.shape[:2]
-        x = x.transpose(0, 2, 1, 3).reshape(batch, 2, self.lp, heads, -1)
-        return x[:, :, :self.L, :, :width].reshape(batch, 2 * self.L, heads,
-                                                   width)
+        x = x.transpose(0, 2, 1, 3).reshape(batch, self.copies, self.lp,
+                                            heads, -1)
+        return x[:, :, :self.L, :, :width].reshape(
+            batch, self.copies * self.L, heads, width)
 
     def row_specs(self):
         """Forward and ``dq``: grid ``(B, H, step)``, tables ``(query
@@ -1132,7 +1153,7 @@ class _BDGeometry:
     def column_specs(self):
         """``dk``/``dv``: grid ``(B, KV, step)``, tables ``(key tile,
         head of the group, query tile, flags)``; the row statistics as
-        rows ``[B, H, 1, 2 lp]`` in blocks ``[1, bq]``."""
+        rows ``[B, H, 1, copies lp]`` in blocks ``[1, bq]``."""
         G = self.G
 
         def q_side(width):
@@ -1150,12 +1171,19 @@ class _BDGeometry:
         return q_side, k_side, stat
 
     def stat(self, x):
-        """``[B, H, 2 L]`` row statistics, each half padded like the
-        rows (zeros: a padded query adds nothing in the backward)."""
+        """``[B, H, copies L]`` row statistics, each copy padded like
+        the rows (zeros: a padded query adds nothing in the backward)."""
         batch, heads = x.shape[:2]
-        x = _pad_axis(x.astype(jnp.float32).reshape(batch, heads, 2, self.L),
-                      3, self.lp)
-        return x.reshape(batch, heads, 2 * self.lp)
+        x = _pad_axis(x.astype(jnp.float32).reshape(
+            batch, heads, self.copies, self.L), 3, self.lp)
+        return x.reshape(batch, heads, self.rows_p)
+
+
+def _bd_geometry(q, k, v, block, block_q, block_k):
+    tiles = bd_tile_map(q.shape[1] // 2, block, block_q, block_k)
+    return _MapGeometry(q, k, v, tiles, 2, block_q, block_k,
+                        (BD_FWD_NAME, BD_DQ_NAME, BD_DKV_NAME), _bd_mask,
+                        block=block, num_q=tiles["nq"], num_k=tiles["nk"])
 
 
 def _bd_params():
@@ -1163,9 +1191,9 @@ def _bd_params():
                                 vmem_limit_bytes=_VMEM_LIMIT)
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def _bd_fwd(q, k, v, block, block_q, block_k, interpret, mxu):
-    g = _BDGeometry(q, k, v, block, block_q, block_k)
+def _map_fwd(g, q, k, v, interpret, mxu):
+    """The forward call of a table-driven family: ``(out, lse [B, H,
+    copies L])``."""
     cdt = jnp.dtype(mxu)
     qp = g.heads_first(q.astype(jnp.float32) * g.scale, g.d_p, cdt)
     kp, vp = g.heads_first(k, g.d_p, cdt), g.heads_first(v, g.dv_p, cdt)
@@ -1178,22 +1206,22 @@ def _bd_fwd(q, k, v, block, block_q, block_k, interpret, mxu):
             in_specs=[q_side(g.d_p), k_side(g.d_p), k_side(g.dv_p)],
             out_specs=[q_side(g.dv_p), q_side(_STAT_LANES)],
             scratch_shapes=[
-                pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
-                pltpu.VMEM((block_q, _STAT_LANES), jnp.float32),
-                pltpu.VMEM((block_q, g.dv_p), jnp.float32)]),
+                pltpu.VMEM((g.bq, _STAT_LANES), jnp.float32),
+                pltpu.VMEM((g.bq, _STAT_LANES), jnp.float32),
+                pltpu.VMEM((g.bq, g.dv_p), jnp.float32)]),
         out_shape=[
-            jax.ShapeDtypeStruct((g.B, g.H, 2 * g.lp, g.dv_p), q.dtype),
-            jax.ShapeDtypeStruct((g.B, g.H, 2 * g.lp, _STAT_LANES),
+            jax.ShapeDtypeStruct((g.B, g.H, g.rows_p, g.dv_p), q.dtype),
+            jax.ShapeDtypeStruct((g.B, g.H, g.rows_p, _STAT_LANES),
                                  jnp.float32)],
-        compiler_params=_bd_params(), interpret=interpret, name=BD_FWD_NAME,
+        compiler_params=_bd_params(), interpret=interpret, name=g.names[0],
     )(*g.by_row, qp, kp, vp)
-    lse = lse[..., 0].reshape(g.B, g.H, 2, g.lp)[..., :g.L]
-    return g.back(out, g.Dv), lse.reshape(g.B, g.H, 2 * g.L)
+    lse = lse[..., 0].reshape(g.B, g.H, g.copies, g.lp)[..., :g.L]
+    return g.back(out, g.Dv), lse.reshape(g.B, g.H, g.copies * g.L)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
-def _bd_bwd(q, k, v, out, lse, do, block, block_q, block_k, interpret, mxu):
-    g = _BDGeometry(q, k, v, block, block_q, block_k)
+def _map_bwd(g, q, k, v, out, lse, do, interpret, mxu):
+    """The two backward calls of a table-driven family: ``(dq, dk,
+    dv)``."""
     cdt = jnp.dtype(mxu)
     qp = g.heads_first(q.astype(jnp.float32) * g.scale, g.d_p, cdt)
     kp, vp = g.heads_first(k, g.d_p, cdt), g.heads_first(v, g.dv_p, cdt)
@@ -1202,7 +1230,7 @@ def _bd_bwd(q, k, v, out, lse, do, block, block_q, block_k, interpret, mxu):
                            axis=3).transpose(0, 2, 1))
     lse = g.stat(lse)
 
-    def lanes(x):  # [B, H, 2 lp] -> lane-replicated
+    def lanes(x):  # [B, H, copies lp] -> lane-replicated
         return jnp.broadcast_to(x[..., None], x.shape + (_STAT_LANES,))
 
     q_side, k_side = g.row_specs()
@@ -1215,9 +1243,9 @@ def _bd_bwd(q, k, v, out, lse, do, block, block_q, block_k, interpret, mxu):
                       q_side(g.dv_p), q_side(_STAT_LANES),
                       q_side(_STAT_LANES)],
             out_specs=q_side(g.d_p),
-            scratch_shapes=[pltpu.VMEM((block_q, g.d_p), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((g.B, g.H, 2 * g.lp, g.d_p), q.dtype),
-        compiler_params=_bd_params(), interpret=interpret, name=BD_DQ_NAME,
+            scratch_shapes=[pltpu.VMEM((g.bq, g.d_p), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((g.B, g.H, g.rows_p, g.d_p), q.dtype),
+        compiler_params=_bd_params(), interpret=interpret, name=g.names[1],
     )(*g.by_row, qp, kp, vp, dop, lanes(lse), lanes(delta))
 
     # dk/dv: key tile by key tile, the query tiles of every head of the
@@ -1231,32 +1259,55 @@ def _bd_bwd(q, k, v, out, lse, do, block, block_q, block_k, interpret, mxu):
             in_specs=[q_col(g.d_p), k_col(g.d_p), k_col(g.dv_p),
                       q_col(g.dv_p), stat, stat],
             out_specs=[k_col(g.d_p), k_col(g.dv_p)],
-            scratch_shapes=[pltpu.VMEM((block_k, g.d_p), jnp.float32),
-                            pltpu.VMEM((block_k, g.dv_p), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((g.bk, g.d_p), jnp.float32),
+                            pltpu.VMEM((g.bk, g.dv_p), jnp.float32)]),
         out_shape=[
-            jax.ShapeDtypeStruct((g.B, g.KV, 2 * g.lp, g.d_p), k.dtype),
-            jax.ShapeDtypeStruct((g.B, g.KV, 2 * g.lp, g.dv_p), v.dtype)],
-        compiler_params=_bd_params(), interpret=interpret, name=BD_DKV_NAME,
+            jax.ShapeDtypeStruct((g.B, g.KV, g.rows_p, g.d_p), k.dtype),
+            jax.ShapeDtypeStruct((g.B, g.KV, g.rows_p, g.dv_p), v.dtype)],
+        compiler_params=_bd_params(), interpret=interpret, name=g.names[2],
     )(*g.by_column, qp, kp, vp, dop, lse[:, :, None, :],
       delta[:, :, None, :])
     return g.back(dq, g.D), g.back(dk, g.D), g.back(dv, g.Dv)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _bd_attention(q, k, v, block, block_q, block_k, interpret, mxu):
-    return _bd_fwd(q, k, v, block, block_q, block_k, interpret, mxu)[0]
+def _map_family(prefix, geometry):
+    """The differentiable call of one law of the tile map: ``attention(q,
+    k, v, law, block_q, block_k, interpret, mxu)`` with ``law`` the one
+    static that ``geometry(q, k, v, law, block_q, block_k)`` takes beside
+    the tile (block diffusion's block, the window's keys).  The forward
+    and the backward call are jitted under ``<prefix>_fwd`` /
+    ``<prefix>_bwd`` and the whole under ``<prefix>_attention``: the
+    names a jaxpr and a profile show."""
+    def fwd(q, k, v, law, block_q, block_k, interpret, mxu):
+        return _map_fwd(geometry(q, k, v, law, block_q, block_k), q, k, v,
+                        interpret, mxu)
+
+    def bwd(q, k, v, out, lse, do, law, block_q, block_k, interpret, mxu):
+        return _map_bwd(geometry(q, k, v, law, block_q, block_k), q, k, v,
+                        out, lse, do, interpret, mxu)
+
+    def attention(q, k, v, law, block_q, block_k, interpret, mxu):
+        return fwd(q, k, v, law, block_q, block_k, interpret, mxu)[0]
+
+    def attention_fwd(q, k, v, law, block_q, block_k, interpret, mxu):
+        out, lse = fwd(q, k, v, law, block_q, block_k, interpret, mxu)
+        return out, (q, k, v, out, lse)
+
+    def attention_bwd(law, block_q, block_k, interpret, mxu, saved, do):
+        return bwd(*saved, do, law, block_q, block_k, interpret, mxu)
+
+    for fn, name in ((fwd, "_fwd"), (bwd, "_bwd"), (attention, "_attention"),
+                     (attention_fwd, "_attention_fwd"),
+                     (attention_bwd, "_attention_bwd")):
+        fn.__name__ = fn.__qualname__ = prefix + name
+    fwd = jax.jit(fwd, static_argnums=(3, 4, 5, 6, 7))
+    bwd = jax.jit(bwd, static_argnums=(6, 7, 8, 9, 10))
+    attention = jax.custom_vjp(attention, nondiff_argnums=(3, 4, 5, 6, 7))
+    attention.defvjp(attention_fwd, attention_bwd)
+    return attention
 
 
-def _bd_attention_fwd(q, k, v, block, block_q, block_k, interpret, mxu):
-    out, lse = _bd_fwd(q, k, v, block, block_q, block_k, interpret, mxu)
-    return out, (q, k, v, out, lse)
-
-
-def _bd_attention_bwd(block, block_q, block_k, interpret, mxu, saved, do):
-    return _bd_bwd(*saved, do, block, block_q, block_k, interpret, mxu)
-
-
-_bd_attention.defvjp(_bd_attention_fwd, _bd_attention_bwd)
+_bd_attention = _map_family("_bd", _bd_geometry)
 
 
 def record_attention_tiles(length: int, block: int, block_q: int,
@@ -1271,8 +1322,7 @@ def record_attention_tiles(length: int, block: int, block_q: int,
               **{key: int(tiles[key]) for key in
                  ("tiles_run", "tiles_masked", "tiles_total",
                   "pairs_seen")}}
-    if record not in _PENDING_EVENTS and len(_PENDING_EVENTS) < _EVENTS_CAP:
-        _PENDING_EVENTS.append(record)
+    _buffer_event(record)
     return record
 
 
@@ -1303,6 +1353,141 @@ def block_diffusion_flash_attention(q, k, v, block: int, *,
     return _bd_attention(q, k, v, int(block), block_q, block_k,
                          _plain_interpret(interpret),
                          context_mxu_dtype(q.dtype))
+
+
+# ----------------------------------------------------------------------
+# Sliding-window attention (models/laguna.py's sliding layers): query
+# ``i`` sees key ``j`` where ``j <= i`` and ``i - j < window`` (``window``
+# keys, its own among them): a band under the diagonal, ``W L - W (W -
+# 1) / 2`` of the causal half's ``L (L + 1) / 2`` pairs.  The causal
+# kernels stop the swept operand at the diagonal only and would pay for
+# the whole half; the band's lower edge is a SECOND LAW OF THE STATIC
+# TILE MAP instead (``win_tile_map`` beside ``bd_tile_map``): the tiles
+# that hold a seen pair, one grid step each, so a tile wholly below the
+# band (or above the diagonal) is neither fetched nor computed, a tile
+# wholly inside it runs without iota, compare or select, and a tile that
+# the diagonal or the lower edge crosses computes its mask from position
+# ids and the static ``window`` (no mask array).  Why the map and not a
+# ``window`` static of ``_Geometry`` / ``_each_tile``: a rectangular
+# grid would still step through the ``Lk / block_k`` key blocks of every
+# query block (64 steps a head at 4,096 / 512 where 15 hold a seen pair)
+# and clamp its index map at both ends of a moving range; the map's
+# kernels, tables and specs are there already and take any law that is a
+# function of two tile indices.  Three kernels under names of their own
+# (``attn_win_fwd``, ``attn_win_dq``, ``attn_win_dkv``) on the shared
+# tile bodies.
+# ----------------------------------------------------------------------
+WIN_FWD_NAME = "attn_win_fwd"
+WIN_DQ_NAME = "attn_win_dq"
+WIN_DKV_NAME = "attn_win_dkv"
+
+
+def window_seen(length: int, window: int) -> np.ndarray:
+    """The statement: ``seen[q, k]`` over a row of ``length`` positions,
+    as a boolean array (tests, small sizes)."""
+    d = np.arange(length)[:, None] - np.arange(length)[None, :]
+    return (d >= 0) & (d < window)
+
+
+def window_pairs_seen(length: int, window: int) -> int:
+    """Seen (query, key) pairs of one head: query ``i`` sees ``min(i +
+    1, window)`` keys."""
+    w = min(window, length)
+    return w * length - w * (w - 1) // 2
+
+
+def win_tile_map(length: int, window: int, block_q: int,
+                 block_k: int) -> dict:
+    """Which tiles of the ``[lp, lp]`` square run under the window law
+    (``lp``: ``length`` padded to whole tiles; a real query sees no
+    padded key, which lies above its diagonal).  ``rows[i]``: query tile
+    ``i``'s ``(key tile, masked)`` in sweep order; the counts
+    (``tiles_run``, ``tiles_whole``, ``tiles_masked``, ``tiles_total``)
+    and ``pairs_seen``, the pairs a head needs."""
+    if window < 1:
+        raise ValueError(f"sliding-window attention: window={window}")
+    lp = _ceil_to(length, int(np.lcm(block_q, block_k)))
+    nq, nk = lp // block_q, lp // block_k
+    rows = []
+    for i in range(nq):
+        q_lo, q_hi = i * block_q, (i + 1) * block_q - 1
+        row = []
+        for j in range(nk):
+            k_lo, k_hi = j * block_k, (j + 1) * block_k - 1
+            if k_lo <= q_hi and q_lo - k_hi < window:      # some pair seen
+                whole = k_hi <= q_lo and q_hi - k_lo < window
+                row.append((j, not whole))
+        rows.append(row)
+    run = sum(len(r) for r in rows)
+    masked = sum(m for r in rows for _, m in r)
+    return {"rows": rows, "lp": lp, "nq": nq, "nk": nk, "key_tiles": nk,
+            "tiles_run": run, "tiles_whole": run - masked,
+            "tiles_masked": masked, "tiles_total": nq * nk,
+            "pairs_seen": window_pairs_seen(length, window)}
+
+
+def _win_mask(shape, q_axis, q_tile, k_tile, *, window, block_q, block_k):
+    """The seen entries of one tile, from its two tile indices: ``0 <=
+    q - k < window``."""
+    d = (q_tile * block_q +
+         jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)) - \
+        (k_tile * block_k +
+         jax.lax.broadcasted_iota(jnp.int32, shape, 1 - q_axis))
+    return jnp.logical_and(d >= 0, d < window)
+
+
+def _win_geometry(q, k, v, window, block_q, block_k):
+    return _MapGeometry(
+        q, k, v, win_tile_map(q.shape[1], window, block_q, block_k), 1,
+        block_q, block_k, (WIN_FWD_NAME, WIN_DQ_NAME, WIN_DKV_NAME),
+        _win_mask, window=window)
+
+
+_win_attention = _map_family("_win", _win_geometry)
+
+
+def record_window_tiles(length: int, window: int, block_q: int,
+                        block_k: int) -> dict:
+    """Buffer an ``attn_window_tiles`` event (once a distinct geometry
+    between two drains, as ``attn_tiles``): what the window law's tile
+    map runs for a row of ``length`` positions.  Returns the record."""
+    tiles = win_tile_map(length, window, block_q, block_k)
+    record = {"kind": "attn_window_tiles", "L": int(length),
+              "window": int(window), "block_q": int(block_q),
+              "block_k": int(block_k),
+              **{key: int(tiles[key]) for key in
+                 ("tiles_run", "tiles_whole", "tiles_masked", "tiles_total",
+                  "pairs_seen")}}
+    _buffer_event(record)
+    return record
+
+
+def window_flash_attention(q, k, v, window: int, *,
+                           block_q: Optional[int] = None,
+                           block_k: Optional[int] = None,
+                           interpret: Optional[bool] = None):
+    """Sliding-window causal self-attention through the kernels: ``q [B,
+    L, H, D]`` over ``k [B, L, KV, D]`` and ``v [B, L, KV, Dv]``, query
+    ``i`` over the keys ``i - window < j <= i`` (:func:`window_seen`; a
+    window no shorter than the row is the causal law); grouped heads,
+    the value width, the scale, the operands' precision and the
+    residuals as :func:`causal_flash_attention`.  ``block_q`` /
+    ``block_k``: the tile, :func:`causal_blocks`' where not given (tests
+    give their own).  Returns ``[B, L, H, Dv]``."""
+    if q.ndim != 4 or q.shape[:2] != k.shape[:2] or \
+            k.shape[:3] != v.shape[:3] or q.shape[3] != k.shape[3] or \
+            q.shape[2] % k.shape[2]:
+        raise ValueError(f"q {q.shape}, k {k.shape}, v {v.shape}: not one "
+                         "row's queries over grouped key-value heads")
+    length = q.shape[1]
+    tile_q, tile_k = causal_blocks(length)
+    block_q, block_k = int(block_q or tile_q), int(block_k or tile_k)
+    record_attention_path("flash", q.shape, k.shape, v.shape, block_q,
+                          block_k)
+    record_window_tiles(length, window, block_q, block_k)
+    return _win_attention(q, k, v, int(window), block_q, block_k,
+                          _plain_interpret(interpret),
+                          context_mxu_dtype(q.dtype))
 
 
 def flash_attention_lse(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

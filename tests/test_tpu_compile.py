@@ -415,6 +415,109 @@ def test_token_model_step_compiles_for_v5e_with_the_core_in_the_kernels(
 
 
 # ----------------------------------------------------------------------
+# the sliding-window core through the tile map's second law (PR 43)
+# ----------------------------------------------------------------------
+#: a float32 array that holds a block of a row's scores at either of
+#: Laguna's head counts (48 full: groups of 6; 64 sliding: groups of 8)
+WIN_SCORES = re.compile(
+    r"f32\[(?:1,)?(?:48|64|8,6|8,8),(?:512|1024|2048|4096),(?:\d{4})\]")
+
+
+@pytest.mark.parametrize("precision", [None, "highest"],
+                         ids=["default", "highest"])
+def test_window_kernels_compile_for_v5e_at_the_cells_shape(chip, precision):
+    """The three kernels under their names at ``laguna_swa_k2_t4096``'s
+    sliding layers' shape (a row of 4,096, 64 query heads over 8
+    key-value heads of 128, a window of 512 keys), with bfloat16
+    operands and with float32 operands contracted in full: what Mosaic
+    refuses fails here.  No operation of the program holds a block of a
+    row's scores, and the event says what the map runs."""
+    import contextlib
+    specs = [jax.ShapeDtypeStruct(shape, jnp.float32, sharding=chip)
+             for shape in ((1, 4096, 64, 128), (1, 4096, 8, 128),
+                           (1, 4096, 8, 128))]
+
+    def backward(q, k, v):
+        return jax.grad(lambda *a: jnp.sum(
+            pa.window_flash_attention(*a, 512, interpret=False)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    pa.drain_attention_events()
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        compiled = jax.jit(backward).lower(*specs).compile()
+    text = compiled.as_text()
+    for name in (pa.WIN_FWD_NAME, pa.WIN_DQ_NAME, pa.WIN_DKV_NAME):
+        assert name in text, name
+    assert pa.FWD_NAME not in text and not WIN_SCORES.search(text)
+    said = {e["kind"]: e for e in pa.drain_attention_events()}
+    assert said["attention_path"]["impl"] == "flash"
+    tiles = said["attn_window_tiles"]
+    assert (tiles["tiles_run"], tiles["tiles_masked"],
+            tiles["tiles_total"], tiles["pairs_seen"]) == \
+        (15, 15, 64, 1_966_336)
+
+
+def test_laguna_step_compiles_for_v5e_with_both_cores_in_the_kernels(
+        chip, monkeypatch):
+    """One local step of ``laguna_swa_k2_t4096`` at published widths (a
+    4,096-id row, ``remat``) with the kernel path steered on, traced as
+    the check program is: the window law's kernels (three sliding
+    layers), the causal kernels (two full layers) and the expert kernels
+    are in the program under their names, and no operation holds a
+    block of a row's scores."""
+    import functools
+
+    import yaml
+    from jax._src import config as jax_config
+
+    from msrflute_tpu.models import make_task, token_blocks
+    from msrflute_tpu.ops import moe
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    monkeypatch.setattr(token_blocks, "causal_attention", functools.partial(
+        token_blocks.causal_attention, interpret=False))
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "experiments", "laguna_moe",
+                           "config.yaml")) as fh:
+        mc = yaml.safe_load(fh)["model_config"]
+    task = make_task({**mc, "remat": True})
+    shapes = jax.eval_shape(task.init_params, jax.random.PRNGKey(0))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=chip),
+        shapes)
+    batch = {"x": jax.ShapeDtypeStruct((1, mc["seq_len"]), jnp.int32,
+                                       sharding=chip),
+             "sample_mask": jax.ShapeDtypeStruct((1,), jnp.float32,
+                                                 sharding=chip)}
+
+    def step(p, b):
+        loss, grads = jax.value_and_grad(
+            lambda q: task.loss(q, b, None, True)[0])(p)
+        return jax.tree.map(lambda a, g: a - 0.1 * g, p, grads), loss
+
+    pa.drain_attention_events()
+    with jax.default_matmul_precision("highest"), \
+            jax_config.exec_time_optimization_effort(-1.0):
+        compiled = jax.jit(step).lower(params, batch).compile()
+    text = compiled.as_text()
+    for name in (pa.WIN_FWD_NAME, pa.WIN_DQ_NAME, pa.WIN_DKV_NAME,
+                 pa.FWD_NAME, pa.DQ_NAME, pa.DKV_NAME, moe.GMM_NAME):
+        assert name in text, name
+    assert not WIN_SCORES.search(text)
+    said = pa.drain_attention_events()
+    assert {e["kind"] for e in said} == {"attention_path",
+                                         "attn_window_tiles"}
+    assert all(e["impl"] == "flash" for e in said
+               if e["kind"] == "attention_path"), said
+    # both head counts went through the kernels
+    assert {e["q_shape"][2] for e in said
+            if e["kind"] == "attention_path"} == {48, 64}
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= 464_541_696 * 4
+    assert memory.temp_size_in_bytes < 4 * 2 ** 30
+
+
+# ----------------------------------------------------------------------
 # the block-diffusion core through the kernels on a static tile map (PR 41)
 # ----------------------------------------------------------------------
 #: a float32 array that holds the scores of a whole doubled row, or of
