@@ -175,17 +175,54 @@ class MoEFFN(nn.Module):
 # result for the tokens routed to them.  Nothing is dropped: the pair
 # buffer is sized for the worst routing.  On one chip there is no
 # exchange, and no code stands in for one.
+#
+# The pair buffer.  Every ``[M, *]`` array on the row side (the sorted
+# tokens, the two up-products, their activation, the down-product and the
+# cotangent of each) has ``M = (ceil(T * k / TILE_ROWS) + held) *
+# TILE_ROWS`` rows, a static shape with room for every pair of every
+# token on held experts.  A step's routing fills the first ``n_active``
+# tiles (``plan_pairs``: every held expert's pairs rounded up to whole
+# tiles, at least one tile each), and every pass on the row side walks
+# those tiles and nothing else, each a kernel on a grid of ``n_active``.
+# So of a row-side array
+# - a row that a pair owns holds that pair's value;
+# - a padding row INSIDE an active tile (an expert's last tile beyond
+#   its pairs; the whole tile of an expert without a pair) holds zeros,
+#   and must: ``expert_gmm_dw`` sums ``x.T @ dy`` over whole tiles, and
+#   ``0 * NaN`` is not 0.  The gathers fill zeros there and every later
+#   pass maps zeros to zeros;
+# - a row BEYOND the active tiles is never written and never read, and
+#   may hold anything (the interpreter leaves NaN there, the chip what
+#   the memory held).  The moves back to token order read only rows that
+#   a pair owns (row 0, always written, for a pair held elsewhere).
+# ``moe_tiles_active * TILE_ROWS / M`` is the share of the buffer that a
+# step walks (a sixth to a sixteenth at the benchmark's shapes).
 # ======================================================================
 #: rows of one tile of the sorted pair buffer; every held expert's rows
 #: start on a tile boundary, so a tile belongs to exactly one expert
 TILE_ROWS = 128
-#: output columns a grid step
+#: output columns a grid step of the grouped products
 TILE_COLS = 256
+#: most columns a grid step of the element-wise kernels
+WIDE_COLS = 1024
+#: bytes of the tokens' column block that a grid step of the gather to
+#: the rows reads from (float32; the pipeline holds two such blocks)
+GATHER_BLOCK_BYTES = 16 * 2 ** 20
+#: rows whose scalars a grid step of that gather holds in SMEM: XLA lays
+#: a long 32-bit vector out in tiles of so many, and a block is whole
+#: tiles of the layout
+SCALAR_ROWS = 8 * TILE_ROWS
 #: stable kernel names (the trace's operation names; the benchmark's
 #: roofline readers find the kernels by them)
 GMM_NAME = "expert_gmm_fwd"
 GMM_T_NAME = "expert_gmm_dx"
 TGMM_NAME = "expert_gmm_dw"
+#: the element-wise kernels over the active tiles
+SWIGLU_NAME = "expert_swiglu_fwd"
+SWIGLU_BWD_NAME = "expert_swiglu_bwd"
+ROWS_ADD_NAME = "expert_rows_add"
+#: the gather to the rows of the active tiles
+ROWS_GATHER_NAME = "expert_rows_gather"
 
 
 def _interpret() -> bool:
@@ -301,9 +338,13 @@ def _tgmm(x, dy, tile_expert, n_active, num_experts: int):
 @jax.custom_vjp
 def grouped_matmul(x, w, tile_expert, n_active):
     """Rows of ``x`` (sorted by expert, each expert's rows starting on a
-    tile boundary) times their expert's matrix.  Rows beyond the active
-    tiles come back unwritten and must not be read: ``held_experts_ffn``
-    gathers only rows that a pair owns."""
+    tile boundary) times their expert's matrix, over the first
+    ``n_active`` tiles; so are both transposes (``expert_gmm_dx``,
+    ``expert_gmm_dw``).  Rows beyond the active tiles are not read and
+    come back unwritten: they may hold anything and must not be read
+    (``held_experts_ffn`` gathers only rows that a pair owns).  Padding
+    rows inside an active tile must be zeros in ``x`` and in the
+    cotangent: ``expert_gmm_dw`` sums over whole tiles."""
     return _gmm(x, w, tile_expert, n_active, transpose_rhs=False,
                 name=GMM_NAME)
 
@@ -322,6 +363,102 @@ def _grouped_matmul_bwd(saved, dy):
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
+
+
+def _lane_tile(n: int, most: int) -> int:
+    """Columns a grid step of a kernel without a contraction: all ``n``
+    where that is no more than ``most`` (or no multiple of 128: a narrow
+    test shape), else ``n``'s largest divisor that is whole lanes (a
+    multiple of 128) and no more than ``most``, one lane tile at least."""
+    if n <= most or n % 128:
+        return n
+    return next(tile for tile in range(max(most // 128, 1) * 128, 0, -128)
+                if n % tile == 0)
+
+
+def _over_active_tiles(kernel, n_out: int, n_active, *operands, name: str):
+    """``kernel(*operand blocks, *output blocks)`` over the first
+    ``n_active`` tiles of ``[M, N]`` operands of one shape, block by
+    block of ``TILE_ROWS`` rows and ``WIDE_COLS`` columns at most;
+    ``n_out`` outputs of that shape.  The rows of later tiles are
+    neither read nor written."""
+    m, n = operands[0].shape
+    tn = _lane_tile(n, WIDE_COLS)
+    block = pl.BlockSpec((TILE_ROWS, tn), lambda i, j: (i, j))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct((m, n), operands[0].dtype)] * n_out,
+        grid=(n_active, n // tn),
+        in_specs=[block] * len(operands), out_specs=[block] * n_out,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=_interpret(), name=name,
+    )(*operands)
+    return out[0] if n_out == 1 else tuple(out)
+
+
+def _swiglu_kernel(h1_ref, h3_ref, o_ref):
+    h1 = h1_ref[...].astype(jnp.float32)
+    o_ref[...] = (h1 * jax.nn.sigmoid(h1) *
+                  h3_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
+
+
+def _swiglu_bwd_kernel(h1_ref, h3_ref, d_ref, d1_ref, d3_ref):
+    h1 = h1_ref[...].astype(jnp.float32)
+    d = d_ref[...].astype(jnp.float32)
+    gate = jax.nn.sigmoid(h1)
+    silu = h1 * gate
+    d3_ref[...] = (d * silu).astype(d3_ref.dtype)
+    d1_ref[...] = (d * h3_ref[...].astype(jnp.float32) *
+                   (gate + silu * (1.0 - gate))).astype(d1_ref.dtype)
+
+
+def _rows_add_kernel(a_ref, b_ref, o_ref):
+    o_ref[...] = a_ref[...] + b_ref[...]
+
+
+@jax.custom_vjp
+def swiglu_rows(h1, h3, n_active):
+    """``silu(h1) * h3`` over the rows of the first ``n_active`` tiles of
+    the two up-products ``[M, H]``; later rows come back unwritten.  A
+    padding row inside an active tile is zeros in both and stays zeros,
+    in both directions."""
+    return _over_active_tiles(_swiglu_kernel, 1, n_active, h1, h3,
+                              name=SWIGLU_NAME)
+
+
+def _swiglu_rows_fwd(h1, h3, n_active):
+    return swiglu_rows(h1, h3, n_active), (h1, h3, n_active)
+
+
+def _swiglu_rows_bwd(saved, d_hidden):
+    h1, h3, n_active = saved
+    d_h1, d_h3 = _over_active_tiles(_swiglu_bwd_kernel, 2, n_active, h1, h3,
+                                    d_hidden, name=SWIGLU_BWD_NAME)
+    return d_h1, d_h3, None
+
+
+swiglu_rows.defvjp(_swiglu_rows_fwd, _swiglu_rows_bwd)
+
+
+@jax.custom_vjp
+def read_twice(rows, n_active):
+    """``(rows, rows)`` for two readers of one row-side array, so that
+    the sum of their two cotangents (which autodiff would take over all
+    ``M`` rows) walks the active tiles like every other pass."""
+    return rows, rows
+
+
+def _read_twice_fwd(rows, n_active):
+    return (rows, rows), n_active
+
+
+def _read_twice_bwd(n_active, d_both):
+    return _over_active_tiles(_rows_add_kernel, 1, n_active, *d_both,
+                              name=ROWS_ADD_NAME), None
+
+
+read_twice.defvjp(_read_twice_fwd, _read_twice_bwd)
 
 
 #: the gate's denominator where the caller gives none: LFM2's published
@@ -409,51 +546,109 @@ def plan_pairs(chosen, experts_held: int, expert_offset: int):
 # one pair and a held pair to one row, so the scatter-add that autodiff
 # would make of a gather is the other map's gather (XLA's scatter of
 # 17,408 rows of 2,048 floats took 14 s to compile for the chip, per
-# expert layer, and runs row by row).
+# expert layer, and runs row by row).  The gather TO the rows is a kernel
+# over the active tiles (XLA's own gather walks all ``M`` rows, a fifth
+# of the memory's rate over rows of which most are never read).
+def _gather_active_rows(values, pair_of_row, per_token: int, n_active,
+                        weight=None):
+    """``[M, D]`` rows of ``values [T, D]``: row ``r`` of the first
+    ``n_active`` tiles is its pair's token's (``values[pair_of_row[r] //
+    per_token]``, zeros where no pair owns the row), times its pair's
+    ``weight [T * per_token]`` where one is given; later rows are
+    unwritten.  A grid step holds a column block of all ``T`` tokens in
+    VMEM (``GATHER_BLOCK_BYTES`` of float32 at most; read once for all
+    tiles: the block's index does not change along the tiles) and
+    copies a tile's 128 rows out of it one by one, the row's token
+    (``T``: no pair) and weight read from SMEM."""
+    tokens, dim = values.shape
+    m = pair_of_row.shape[0]
+    cols = _lane_tile(dim, GATHER_BLOCK_BYTES // (4 * tokens))
+    scalar_rows = min(SCALAR_ROWS, m)
+    tiles_a_block = scalar_rows // TILE_ROWS
+    scalars = [pair_of_row // per_token]
+    if weight is not None:
+        scalars.append(weight.astype(jnp.float32).at[pair_of_row].get(
+            mode="fill", fill_value=0))
+
+    def kernel(*refs):
+        token_ref, *weight_ref = refs[:len(scalars)]
+        values_ref, out_ref, tile_ref = refs[len(scalars):]
+        first = (pl.program_id(1) % tiles_a_block) * TILE_ROWS
+
+        def row(r, carry):
+            token = token_ref[first + r]
+            got = values_ref[pl.ds(jnp.minimum(token, tokens - 1), 1), :]
+            got = jnp.where(token < tokens, got, jnp.zeros_like(got))
+            if weight_ref:
+                got = got * weight_ref[0][first + r]
+            tile_ref[pl.ds(r, 1), :] = got
+            return carry
+
+        lax.fori_loop(0, TILE_ROWS, row, 0)
+        out_ref[...] = tile_ref[...].astype(out_ref.dtype)
+
+    scalar_block = pl.BlockSpec(
+        (scalar_rows,), lambda j, i: (i // tiles_a_block,),
+        memory_space=pltpu.SMEM)
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((m, dim), values.dtype),
+        grid=(dim // cols, n_active),
+        in_specs=[scalar_block] * len(scalars) +
+        [pl.BlockSpec((tokens, cols), lambda j, i: (0, j))],
+        out_specs=pl.BlockSpec((TILE_ROWS, cols), lambda j, i: (i, j)),
+        scratch_shapes=[pltpu.VMEM((TILE_ROWS, cols), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=2 * 4 * tokens * cols + 16 * 2 ** 20),
+        interpret=_interpret(), name=ROWS_GATHER_NAME,
+    )(*scalars, values.astype(jnp.float32))
+
+
 @jax.custom_vjp
-def rows_of_tokens(z, pair_of_row, row_of_pair, held):
-    """``z [T, D]`` -> the pair buffer ``[M, D]``: row ``r`` is its
-    pair's token (zeros where no pair owns the row)."""
-    per_token = row_of_pair.shape[1]
-    return z.at[pair_of_row // per_token].get(mode="fill", fill_value=0)
+def rows_of_tokens(z, pair_of_row, row_of_pair, held, n_active):
+    """``z [T, D]`` -> the pair buffer ``[M, D]``: a row of the first
+    ``n_active`` tiles is its pair's token (zeros where no pair owns the
+    row); later rows are unwritten."""
+    return _gather_active_rows(z, pair_of_row, row_of_pair.shape[1],
+                               n_active)
 
 
-def _rows_of_tokens_fwd(z, pair_of_row, row_of_pair, held):
-    return rows_of_tokens(z, pair_of_row, row_of_pair, held), \
+def _rows_of_tokens_fwd(z, pair_of_row, row_of_pair, held, n_active):
+    return rows_of_tokens(z, pair_of_row, row_of_pair, held, n_active), \
         (row_of_pair, held)
 
 
 def _rows_of_tokens_bwd(saved, d_rows):
     row_of_pair, held = saved
     d_z = jnp.sum(jnp.where(held[..., None], d_rows[row_of_pair], 0), axis=1)
-    return d_z, None, None, None
+    return d_z, None, None, None, None
 
 
 rows_of_tokens.defvjp(_rows_of_tokens_fwd, _rows_of_tokens_bwd)
 
 
 @jax.custom_vjp
-def tokens_of_rows(y_rows, weight, pair_of_row, row_of_pair):
+def tokens_of_rows(y_rows, weight, pair_of_row, row_of_pair, n_active):
     """The pair buffer ``y_rows [M, D]`` -> ``y [T, D]``: each token's
-    rows, weighted (``weight [T, k]``, 0 for a pair held elsewhere)."""
+    rows, weighted (``weight [T, k]``, 0 for a pair held elsewhere).
+    Reads rows that a pair owns and row 0 only; its cotangent writes the
+    first ``n_active`` tiles (zeros on their padding rows)."""
     return jnp.einsum("tkd,tk->td", y_rows[row_of_pair], weight)
 
 
-def _tokens_of_rows_fwd(y_rows, weight, pair_of_row, row_of_pair):
-    return tokens_of_rows(y_rows, weight, pair_of_row, row_of_pair), \
-        (y_rows, weight, pair_of_row, row_of_pair)
+def _tokens_of_rows_fwd(y_rows, weight, pair_of_row, row_of_pair, n_active):
+    return tokens_of_rows(y_rows, weight, pair_of_row, row_of_pair,
+                          n_active), \
+        (y_rows, weight, pair_of_row, row_of_pair, n_active)
 
 
 def _tokens_of_rows_bwd(saved, d_y):
-    y_rows, weight, pair_of_row, row_of_pair = saved
-    per_token = weight.shape[1]
+    y_rows, weight, pair_of_row, row_of_pair, n_active = saved
     d_weight = jnp.einsum("tkd,td->tk", y_rows[row_of_pair], d_y)
-    weight_of_row = weight.reshape(-1).at[pair_of_row].get(
-        mode="fill", fill_value=0)
-    d_rows = d_y.at[pair_of_row // per_token].get(
-        mode="fill", fill_value=0) * weight_of_row[:, None]
+    d_rows = _gather_active_rows(d_y, pair_of_row, weight.shape[1], n_active,
+                                 weight=weight.reshape(-1))
     return d_rows.astype(y_rows.dtype), d_weight.astype(weight.dtype), \
-        None, None
+        None, None, None
 
 
 tokens_of_rows.defvjp(_tokens_of_rows_fwd, _tokens_of_rows_bwd)
@@ -478,7 +673,15 @@ def held_experts_ffn(z, router_w, select_bias, w1, w3, w2, *,
     tiles of ``TILE_ROWS`` rows that the grouped products ran
     (``moe_tiles_active``: every held expert's pairs rounded up to whole
     tiles, at least one each; ``moe_pairs_held`` over its rows is the
-    share of them that are real pairs)."""
+    share of them that are real pairs).
+
+    Every ``[M, *]`` array between the two moves is walked over those
+    tiles only, by the gathers, the three products, the activation and
+    the sum of the two up-products' cotangents alike, so
+    ``moe_tiles_active * TILE_ROWS / M`` is the share of the pair buffer
+    that a step touches; the rows beyond hold whatever was there (the
+    account of the pair buffer above says which rows are written, which
+    must be zeros and which may hold anything)."""
     held_n = w1.shape[0]
     # positional, and the epsilon and the law only where the caller gave
     # one: the benchmark's planted routing faults replace
@@ -489,13 +692,15 @@ def held_experts_ffn(z, router_w, select_bias, w1, w3, w2, *,
         **({} if scoring == "sigmoid" else {"scoring": scoring}))
     row_of_pair, held, pair_of_row, tile_expert, n_active, counts = \
         plan_pairs(chosen, held_n, expert_offset)
-    x_sorted = rows_of_tokens(z, pair_of_row, row_of_pair, held)
-    hidden = jax.nn.silu(grouped_matmul(x_sorted, w1, tile_expert,
-                                        n_active)) * \
-        grouped_matmul(x_sorted, w3, tile_expert, n_active)
+    x_gate, x_up = read_twice(
+        rows_of_tokens(z, pair_of_row, row_of_pair, held, n_active),
+        n_active)
+    hidden = swiglu_rows(grouped_matmul(x_gate, w1, tile_expert, n_active),
+                         grouped_matmul(x_up, w3, tile_expert, n_active),
+                         n_active)
     y_sorted = grouped_matmul(hidden, w2, tile_expert, n_active)
     weight = jnp.where(held, gate, 0.0).astype(z.dtype)
-    y = tokens_of_rows(y_sorted, weight, pair_of_row, row_of_pair)
+    y = tokens_of_rows(y_sorted, weight, pair_of_row, row_of_pair, n_active)
     placed = jnp.sum((pair_of_row < chosen.size).astype(jnp.float32))
     on_held = jnp.sum(counts).astype(jnp.float32)
     counters = {"moe_pairs_held": on_held,

@@ -204,7 +204,8 @@ def test_grouped_matmul_transposes_are_the_dense_ones():
     expert_of_row = jnp.repeat(tile_expert, moe.TILE_ROWS)
 
     def kernels(z, w):
-        rows = moe.rows_of_tokens(z, pair_of_row, row_of_pair, held)
+        rows = moe.rows_of_tokens(z, pair_of_row, row_of_pair, held,
+                                  n_active)
         out = moe.grouped_matmul(rows, w, tile_expert, n_active)
         return jnp.sum(jnp.where(owned, out, 0.0) ** 2)
 

@@ -194,8 +194,74 @@ def test_latest_snapshot_is_one_copy_program_on_the_four_chip_mesh(topology):
 # ----------------------------------------------------------------------
 # the expert layer's grouped matmuls at LFM2-24B-A2B's widths (PR 28)
 # ----------------------------------------------------------------------
-EXPERT = {"tokens": 4096, "per_token": 4, "held": 8, "hidden": 2048,
-          "width": 1536}
+#: tokens a step, experts per token, hidden, expert width, experts held,
+#: experts in all, of the four token cells
+EXPERT_CELLS = {
+    "sdar_bd_k2_t4096": (8192, 8, 2048, 768, 16, 128),
+    "lfm2_moe_k4_t4096": (4096, 4, 2048, 1536, 8, 64),
+    "kanana2_mla_k2_t4096": (4096, 6, 2048, 768, 8, 128),
+    "laguna_swa_k2_t4096": (4096, 8, 2048, 512, 8, 256),
+}
+
+
+def _expert_layer_text(chip, monkeypatch, cell, direction, dtype, precision,
+                       routed=True):
+    """The compiled text of one expert layer at ``cell``'s shape, forward
+    or forward and backward; ``default_backend`` is steered here, in the
+    test.  Not ``routed``: the scores and their top k (half of such a
+    compile, and nothing of the row side) give way to a choice by
+    position."""
+    import contextlib
+    from msrflute_tpu.ops import moe
+    monkeypatch.setattr(moe, "_interpret", lambda: False)
+    tokens, per_token, hidden, width, held, experts = EXPERT_CELLS[cell]
+    if not routed:
+        monkeypatch.setattr(moe, "route_tokens", lambda z, *_: (
+            (jnp.arange(tokens * per_token, dtype=jnp.int32) % experts
+             ).reshape(tokens, per_token),
+            jax.nn.sigmoid(z[:, :per_token].astype(jnp.float32))))
+
+    def spec(*shape, kind=dtype):
+        return jax.ShapeDtypeStruct(shape, kind, sharding=chip)
+
+    def ffn(z, router, bias, w1, w3, w2):
+        return moe.held_experts_ffn(z, router, bias, w1, w3, w2,
+                                    experts_per_token=per_token)
+
+    def backward(*args):
+        return jax.grad(lambda *a: jnp.sum(ffn(*a)[0] ** 2),
+                        argnums=(0, 1, 3, 4, 5))(*args)
+
+    args = (spec(tokens, hidden), spec(hidden, experts, kind=jnp.float32),
+            spec(experts, kind=jnp.float32), spec(held, hidden, width),
+            spec(held, hidden, width), spec(held, width, hidden))
+    with (jax.default_matmul_precision(precision) if precision
+          else contextlib.nullcontext()):
+        return jax.jit(ffn if direction == "forward" else
+                       backward).lower(*args).compile().as_text()
+
+
+#: what may have a whole row-side array for its result: a kernel, what
+#: only names a buffer that is there, and the compiler's own move of a
+#: buffer into the fast memory beside other work (where an ``[M, width]``
+#: array fits there: 69-79 MB at two of the shapes), which is no pass of
+#: the program's
+ROW_SIDE_WRITERS = {"custom-call", "parameter", "get-tuple-element",
+                    "bitcast", "copy-done"}
+
+
+def _whole_row_side_results(cell, text) -> set:
+    """The operations of ``text`` whose result is a whole ``[M, hidden]``
+    or ``[M, width]`` array of ``cell``'s pair buffer."""
+    from msrflute_tpu.ops import moe
+    tokens, per_token, hidden, width, held, _ = EXPERT_CELLS[cell]
+    rows = (-(-tokens * per_token // moe.TILE_ROWS) + held) * moe.TILE_ROWS
+    whole = re.compile(
+        rf"= f32\[{rows},(?:{hidden}|{width})\]\S* ([a-z\-]+)\(")
+    found = {hit.group(1) for hit in map(whole.search, text.splitlines())
+             if hit}
+    assert "custom-call" in found
+    return found
 
 
 @pytest.mark.parametrize("dtype, precision", [
@@ -205,50 +271,59 @@ EXPERT = {"tokens": 4096, "per_token": 4, "held": 8, "hidden": 2048,
 def test_expert_layer_compiles_for_v5e_at_published_widths(
         chip, monkeypatch, direction, dtype, precision):
     """One chip's share of an expert layer (8 of 64 experts, hidden 2,048,
-    expert width 1,536, a 4,096-token row): the three Pallas kernels are
-    in the program under their stable names and fit the chip's fast
+    expert width 1,536, a 4,096-token row): the Pallas kernels are in
+    the program under their stable names and fit the chip's fast
     memory, in float32 as the cell runs them, under ``highest`` as the
     benchmark's check program traces them, and with bfloat16 operands
     under ``highest`` as its lower-precision control does (Mosaic refuses
     a float32 contraction of bfloat16 operands: the kernels ask for one
-    pass there).  ``default_backend`` is steered here, in the test."""
-    import contextlib
+    pass there).  In float32, forward and backward, no operation has a
+    whole ``[M, hidden]`` or ``[M, width]`` array of the pair buffer for
+    its result but the kernels: no gather, select, add or activation over
+    all ``M`` rows, no loop that carries the buffer, no copy of it, no
+    initial value."""
     from msrflute_tpu.ops import moe
-    monkeypatch.setattr(moe, "_interpret", lambda: False)
-    e = EXPERT
-
-    def spec(*shape, kind=dtype):
-        return jax.ShapeDtypeStruct(shape, kind, sharding=chip)
-
-    def ffn(z, router, bias, w1, w3, w2):
-        return moe.held_experts_ffn(z, router, bias, w1, w3, w2,
-                                    experts_per_token=e["per_token"])
-
-    def backward(*args):
-        return jax.grad(lambda *a: jnp.sum(ffn(*a)[0]),
-                        argnums=(0, 1, 3, 4, 5))(*args)
-
-    args = (spec(e["tokens"], e["hidden"]),
-            spec(e["hidden"], 64, kind=jnp.float32),
-            spec(64, kind=jnp.float32),
-            spec(e["held"], e["hidden"], e["width"]),
-            spec(e["held"], e["hidden"], e["width"]),
-            spec(e["held"], e["width"], e["hidden"]))
-    with (jax.default_matmul_precision(precision) if precision
-          else contextlib.nullcontext()):
-        compiled = jax.jit(ffn if direction == "forward" else
-                           backward).lower(*args).compile()
-    text = compiled.as_text()
-    names = [moe.GMM_NAME] if direction == "forward" else \
-        [moe.GMM_NAME, moe.GMM_T_NAME, moe.TGMM_NAME]
+    text = _expert_layer_text(chip, monkeypatch, "lfm2_moe_k4_t4096",
+                              direction, dtype, precision)
+    if direction == "backward" and dtype == jnp.float32:
+        assert not _whole_row_side_results("lfm2_moe_k4_t4096", text) - \
+            ROW_SIDE_WRITERS
+    names = [moe.GMM_NAME, moe.SWIGLU_NAME, moe.ROWS_GATHER_NAME] \
+        if direction == "forward" else \
+        [moe.GMM_NAME, moe.GMM_T_NAME, moe.TGMM_NAME, moe.SWIGLU_NAME,
+         moe.SWIGLU_BWD_NAME, moe.ROWS_ADD_NAME, moe.ROWS_GATHER_NAME]
     for name in names:
         assert name in text, name
     # the moves between token order and the pair buffer are gathers in
     # both directions: no scatter of [rows, hidden] (14 s of compile a
     # layer, and run row by row); the routing's own transpose, 16,384
     # scores into a flat [tokens * experts], stays a scatter
-    import re
     assert not re.search(r"= (f32|bf16)\[\d+,\d+\]\S* scatter\(", text)
+
+
+# ----------------------------------------------------------------------
+# the expert layer's row side at the four token cells' shapes (PR 44)
+# ----------------------------------------------------------------------
+@pytest.mark.parametrize("precision", [None, "highest"],
+                         ids=["default", "highest"])
+@pytest.mark.parametrize("cell", [c for c in EXPERT_CELLS
+                                  if c != "lfm2_moe_k4_t4096"])
+def test_row_side_of_the_expert_layer_compiles_for_v5e_at_the_other_cells(
+        chip, monkeypatch, cell, precision):
+    """Forward and backward of one expert layer at the shapes of the
+    three other token cells, at the default precision as a cell runs it
+    and under ``highest`` as the check program is traced: the kernels
+    are in the program under their names and nothing else has a whole
+    row-side array for its result (the test above holds LFM2's shape to
+    the same, under its own routing)."""
+    from msrflute_tpu.ops import moe
+    text = _expert_layer_text(chip, monkeypatch, cell, "backward",
+                              jnp.float32, precision, routed=False)
+    for name in (moe.GMM_NAME, moe.GMM_T_NAME, moe.TGMM_NAME,
+                 moe.SWIGLU_NAME, moe.SWIGLU_BWD_NAME, moe.ROWS_ADD_NAME,
+                 moe.ROWS_GATHER_NAME):
+        assert name in text, name
+    assert not _whole_row_side_results(cell, text) - ROW_SIDE_WRITERS
 
 
 # ----------------------------------------------------------------------
